@@ -14,6 +14,7 @@ This is the HOST side: feeding, compressing, staging.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,7 +24,6 @@ import numpy as np
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "csrc")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libdl4jtpu.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -36,23 +36,28 @@ def _sources():
         for f in os.listdir(_SRC_DIR) if f.endswith(".cpp"))
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > lib_mtime for s in _sources())
+def _lib_path() -> str:
+    """The library is named by a hash of its sources: a copy of the tree
+    keeps no mtimes to judge staleness by, and a build of other sources
+    is never loaded."""
+    h = hashlib.sha1()
+    for src in _sources():
+        h.update(os.path.basename(src).encode())
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_BUILD_DIR, f"libdl4jtpu-{h.hexdigest()[:12]}.so")
 
 
-def _build() -> bool:
+def _build(path: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = _LIB_PATH + ".tmp"
+    tmp = path + ".tmp"
     cmd = ["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
            "-o", tmp] + _sources()
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError):
         return False
-    os.replace(tmp, _LIB_PATH)
+    os.replace(tmp, path)
     return True
 
 
@@ -106,14 +111,29 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _build_failed:
             return _lib
         try:
-            if _needs_build() and not _build():
+            path = _lib_path()
+            if not os.path.exists(path) and not _build(path):
                 _build_failed = True
                 return None
-            _lib = _bind(ctypes.CDLL(_LIB_PATH))
+            _lib = _bind(ctypes.CDLL(path))
         except OSError:
             _build_failed = True
             return None
     return _lib
+
+
+def rebuild() -> bool:
+    """Compile `csrc/` again whatever `_build/` holds and load the
+    result; False when the toolchain is missing or the compile fails.
+    For entry points that must show the tree builds from its sources
+    alone (`chip_smoke.py`). Call it before anything else loads the
+    library: a process keeps the mapping it loaded first."""
+    global _build_failed
+    with _lock:
+        if not _build(_lib_path()):
+            return False
+        _build_failed = False
+    return get_lib() is not None
 
 
 def available() -> bool:

@@ -311,15 +311,36 @@ def _arg_specs(args, kw):
         return None
 
 
+def _cost_of(artifact) -> dict:
+    cost = artifact.cost_analysis()
+    if isinstance(cost, (list, tuple)):
+        cost = cost[0] if cost else {}
+    return cost or {}
+
+
 def _record_lowered_cost(fn, specs, owner_tag, owner_class, key) -> None:
-    lowered = None
     try:
         spec_args, spec_kw = specs
         lowered = fn.lower(*spec_args, **spec_kw)
-        cost = lowered.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        cost = cost or {}
+    except Exception as e:
+        note_cost_analysis_failure(
+            f"lowering cost analysis failed: {type(e).__name__}")
+        return
+    # the comm ledger rides the same lowering; a failed cost_analysis
+    # does not forfeit the collective walk (and vice versa)
+    compiled = None
+    if _comm_ledger_enabled():
+        try:
+            compiled = lowered.compile()
+        except Exception as e:
+            note_cost_analysis_failure(
+                f"compiled-HLO comm walk failed: {type(e).__name__}")
+    try:
+        cost = _cost_of(lowered)
+        if not cost.get("flops") and compiled is not None:
+            # the TPU client prices compiled modules only: there
+            # Lowered.cost_analysis() is None
+            cost = _cost_of(compiled)
         get_watchdog().record_cost(owner_tag, owner_class, key, {
             "flops": float(cost.get("flops") or 0.0),
             "bytes_accessed": float(cost.get("bytes accessed") or 0.0),
@@ -327,13 +348,11 @@ def _record_lowered_cost(fn, specs, owner_tag, owner_class, key) -> None:
     except Exception as e:
         note_cost_analysis_failure(
             f"lowering cost analysis failed: {type(e).__name__}")
-    # the comm ledger rides the same lowering; a failed cost_analysis
-    # does not forfeit the collective walk (and vice versa)
-    if lowered is not None and _comm_ledger_enabled():
-        _record_compiled_comm(lowered, owner_tag, owner_class, key)
+    if compiled is not None:
+        _record_compiled_comm(compiled, owner_tag, owner_class, key)
 
 
-def _record_compiled_comm(lowered, owner_tag, owner_class, key) -> None:
+def _record_compiled_comm(compiled, owner_tag, owner_class, key) -> None:
     """Walk the compiled artifact for the collective inventory.
 
     Degradation contract (commsmon): a backend that cannot AOT-compile,
@@ -344,15 +363,10 @@ def _record_compiled_comm(lowered, owner_tag, owner_class, key) -> None:
     cache seam. An artifact that compiles but yields unparseable text
     records an EMPTY inventory (parse tolerance lives in the parser)."""
     try:
-        text = lowered.compile().as_text()
-    except Exception as e:
-        note_cost_analysis_failure(
-            f"compiled-HLO comm walk failed: {type(e).__name__}")
-        return
-    try:
         from deeplearning4j_tpu.observe.commsmon import (
             parse_hlo_collectives, summarize_collectives,
         )
+        text = compiled.as_text()
         if not isinstance(text, str):       # as_text() shape drifted
             raise TypeError(type(text).__name__)
         summary = summarize_collectives(parse_hlo_collectives(text))
@@ -376,10 +390,13 @@ class _CostProbe:
     itself. `Lowered.cost_analysis()` traces but does not compile, so
     the cost leg costs one extra trace. The comm-ledger leg
     (`DL4J_TPU_COMPILE_COMM`, default on) additionally AOT-compiles the
-    lowering to walk the post-GSPMD module for collectives — one extra
-    background compile per FIRST-seen program, never counted as a jit
-    cache insertion and never on a steady-state path; nothing either
-    leg touches can force a device sync."""
+    lowering to walk the post-GSPMD module for collectives, and prices
+    the program from that artifact where the lowering has no cost (the
+    TPU client). JAX serves that compile from its in-memory cache when
+    the lowering matches the dispatch's (one XLA compile, not two: chip
+    run, PR 21); it is never counted as a jit cache insertion and never
+    on a steady-state path; nothing either leg touches can force a
+    device sync."""
 
     __slots__ = ("fn", "_owner_tag", "_owner_class", "_key", "_done",
                  "_lock")

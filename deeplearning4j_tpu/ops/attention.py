@@ -39,11 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 _LSE_LANES = 128   # lane width for per-row statistics outputs (TPU tiling)
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# whichever this jax ships so the kernels are not pinned to one side.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def _dense_attention(q, k, v, causal: bool, scale: float):
     """Reference O(T^2) attention used for the recompute backward."""
@@ -175,7 +170,7 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
         ],
         # batch/Q-block dims have no cross-step state -> Mosaic may
         # parallelize and pipeline them; the K sweep carries scratch.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -326,7 +321,7 @@ def _run_flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -337,7 +332,7 @@ def _run_flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)

@@ -45,8 +45,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.ops.attention import _CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -216,7 +214,7 @@ def _run_banded(q, k, v, *, window: int, causal: bool, scale: float,
             pltpu.VMEM((g * block_q, 1), jnp.float32),
             pltpu.VMEM((g * block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q5, k3, v3)
@@ -478,7 +476,7 @@ def banded_decode_attention(q, cache_k, cache_v, qpos, end,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s_, h, dh), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qpos, end, *inputs)
@@ -635,7 +633,7 @@ def paged_decode_attention(q, cache_k, cache_v, page_table, qpos,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s_, h, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qpos, page_table, *inputs)

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.ops.attention import _CompilerParams
 
 _LANES = 128
 _SUBLANES = 8
@@ -121,7 +120,8 @@ def _run(kernel, coeff, arrays, out_dtypes, *, block_rows: int,
         out_shape=[jax.ShapeDtypeStruct((rows, _LANES), d)
                    for d in out_dtypes],
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(c, *tiles)
     return tuple(_untile(o, shape, n) for o in out)

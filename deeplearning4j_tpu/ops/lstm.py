@@ -46,9 +46,16 @@ def _sigmoid(x):
 
 # --------------------------------------------------------------- forward
 def _cell(xw_t, h_prev, c_prev, rw, p):
-    """Shared gate math for both forward kernel variants."""
+    """Shared gate math for both forward kernel variants. Everything
+    past the loads is f32 whatever the storage dtype: Mosaic requires a
+    32-bit matmul accumulator, and the recurrence compounds rounding
+    over T steps. Callers cast back on store."""
     hsz = h_prev.shape[-1]
-    gates = xw_t + jnp.dot(h_prev, rw, preferred_element_type=h_prev.dtype)
+    f32 = jnp.float32
+    gates = xw_t.astype(f32) + jnp.dot(h_prev, rw,
+                                       preferred_element_type=f32)
+    c_prev = c_prev.astype(f32)
+    p = p.astype(f32)
     i = _sigmoid(gates[:, :hsz] + c_prev * p[0:1, :])
     f = _sigmoid(gates[:, hsz:2 * hsz] + c_prev * p[1:2, :])
     g = jnp.tanh(gates[:, 2 * hsz:3 * hsz])
@@ -73,15 +80,16 @@ def _fwd_kernel(xw_ref, rw_ref, p_ref, h0_ref, c0_ref, m_ref,
     h_prev, c_prev = h_scr[:], c_scr[:]
     h_new, c_new, i, f, g, o = _cell(
         xw_ref[0], h_prev, c_prev, rw_ref[:], p_ref[:])
-    m = jnp.transpose(m_ref[pl.ds(t, 1), :])    # [B, 1]
-    h = m * h_new + (1.0 - m) * h_prev
-    c = m * c_new + (1.0 - m) * c_prev
+    m = jnp.transpose(m_ref[pl.ds(t, 1), :])    # [B, 1] f32
+    dt = h_scr.dtype
+    h = (m * h_new + (1.0 - m) * h_prev).astype(dt)
+    c = (m * c_new + (1.0 - m) * c_prev).astype(dt)
 
     h_scr[:] = h
     c_scr[:] = c
     hs_ref[0] = h
     cs_ref[0] = c
-    gates_ref[0] = jnp.concatenate([i, f, g, o], axis=-1)
+    gates_ref[0] = jnp.concatenate([i, f, g, o], axis=-1).astype(dt)
 
     @pl.when(t == T - 1)
     def _():
@@ -105,8 +113,9 @@ def _fwd_kernel_inference(xw_ref, rw_ref, p_ref, h0_ref, c0_ref, m_ref,
     h_new, c_new, _, _, _, _ = _cell(
         xw_ref[0], h_prev, c_prev, rw_ref[:], p_ref[:])
     m = jnp.transpose(m_ref[pl.ds(t, 1), :])
-    h = m * h_new + (1.0 - m) * h_prev
-    c = m * c_new + (1.0 - m) * c_prev
+    dt = h_scr.dtype
+    h = (m * h_new + (1.0 - m) * h_prev).astype(dt)
+    c = (m * c_new + (1.0 - m) * c_prev).astype(dt)
     h_scr[:] = h
     c_scr[:] = c
     hs_ref[0] = h
@@ -155,7 +164,7 @@ def _run_forward(xw, rw, p, h0, c0, mask, *, interpret: bool,
             pltpu.VMEM((B, H), dt),
         ],
         interpret=interpret,
-    )(xw, rw, p, h0, c0, mask)
+    )(xw, rw, p, h0, c0, mask.astype(jnp.float32))
     if with_residuals:
         return out  # (hs, cs, gates, hT, cT)
     hs, hT, cT = out
@@ -172,27 +181,30 @@ def _bwd_kernel(dhs_ref, gates_ref, cs_ref, csp_ref, hsp_ref, rw_ref, p_ref,
 
     @pl.when(idx == 0)
     def _():
-        dh_scr[:] = dhT_ref[:]
-        dc_scr[:] = dcT_ref[:]
+        dh_scr[:] = dhT_ref[:].astype(dh_scr.dtype)
+        dc_scr[:] = dcT_ref[:].astype(dc_scr.dtype)
         drw_scr[:] = jnp.zeros_like(drw_scr)
         dp_scr[:] = jnp.zeros_like(dp_scr)
 
-    gates = gates_ref[0]
+    # f32 from the loads on (see _cell); only the MXU operands h_prev /
+    # dgates / rw stay in the storage dtype
+    f32 = jnp.float32
+    gates = gates_ref[0].astype(f32)
     hsz = gates.shape[-1] // 4
     i = gates[:, :hsz]
     f = gates[:, hsz:2 * hsz]
     g = gates[:, 2 * hsz:3 * hsz]
     o = gates[:, 3 * hsz:]
-    c_t = cs_ref[0]
+    c_t = cs_ref[0].astype(f32)
     # csp/hsp alias cs/hs with a t-1 index map (clamped at 0); the true t=0
     # predecessors are the initial carry.
     t_is_0 = idx == T - 1
-    c_prev = jnp.where(t_is_0, c0_ref[:], csp_ref[0])
+    c_prev = jnp.where(t_is_0, c0_ref[:], csp_ref[0]).astype(f32)
     h_prev = jnp.where(t_is_0, h0_ref[:], hsp_ref[0])
-    p = p_ref[:]
-    m = jnp.transpose(m_ref[pl.ds(T - 1 - idx, 1), :])   # [B, 1]
+    p = p_ref[:].astype(f32)
+    m = jnp.transpose(m_ref[pl.ds(T - 1 - idx, 1), :])   # [B, 1] f32
 
-    dh_in = dhs_ref[0] + dh_scr[:]
+    dh_in = dhs_ref[0].astype(f32) + dh_scr[:]
     dh_t = m * dh_in            # grad into the freshly computed h at step t
     pass_h = (1.0 - m) * dh_in  # grad flowing straight to h_{t-1} (mask hold)
 
@@ -206,13 +218,14 @@ def _bwd_kernel(dhs_ref, gates_ref, cs_ref, csp_ref, hsp_ref, rw_ref, p_ref,
     dc_prev = (dc_new * f + (1.0 - m) * dc_scr[:]
                + di_pre * p[0:1, :] + df_pre * p[1:2, :])
 
-    dgates = jnp.concatenate([di_pre, df_pre, dg_pre, do_pre], axis=-1)
+    dgates = jnp.concatenate(
+        [di_pre, df_pre, dg_pre, do_pre], axis=-1).astype(dxw_ref.dtype)
     dh_prev = jnp.dot(dgates, rw_ref[:].T,
-                      preferred_element_type=dgates.dtype) + pass_h
+                      preferred_element_type=f32) + pass_h
 
     dxw_ref[0] = dgates
     drw_scr[:] = drw_scr[:] + jnp.dot(
-        h_prev.T, dgates, preferred_element_type=dgates.dtype)
+        h_prev.T, dgates, preferred_element_type=f32)
     dp_scr[0:1, :] = dp_scr[0:1, :] + jnp.sum(di_pre * c_prev, axis=0,
                                                keepdims=True)
     dp_scr[1:2, :] = dp_scr[1:2, :] + jnp.sum(df_pre * c_prev, axis=0,
@@ -224,10 +237,10 @@ def _bwd_kernel(dhs_ref, gates_ref, cs_ref, csp_ref, hsp_ref, rw_ref, p_ref,
 
     @pl.when(idx == T - 1)
     def _():
-        dh0_ref[:] = dh_scr[:]
-        dc0_ref[:] = dc_scr[:]
-        drw_ref[:] = drw_scr[:]
-        dp_ref[:] = dp_scr[:]
+        dh0_ref[:] = dh_scr[:].astype(dh0_ref.dtype)
+        dc0_ref[:] = dc_scr[:].astype(dc0_ref.dtype)
+        drw_ref[:] = drw_scr[:].astype(drw_ref.dtype)
+        dp_ref[:] = dp_scr[:].astype(dp_ref.dtype)
 
 
 def _run_backward(res, dhs, dhT, dcT, *, interpret: bool):
@@ -272,14 +285,15 @@ def _run_backward(res, dhs, dhT, dcT, *, interpret: bool):
             pl.BlockSpec((3, H), lambda t: (0, 0)),
         ],
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((B, H), dt),
-            pltpu.VMEM((B, H), dt),
-            pltpu.VMEM((H, H4), dt),
-            pltpu.VMEM((3, H), dt),
+        scratch_shapes=[               # f32: accumulated over T steps
+            pltpu.VMEM((B, H), jnp.float32),
+            pltpu.VMEM((B, H), jnp.float32),
+            pltpu.VMEM((H, H4), jnp.float32),
+            pltpu.VMEM((3, H), jnp.float32),
         ],
         interpret=interpret,
-    )(dhs, gates, cs, cs, hs, rw, p, mask, dhT, dcT, h0, c0)
+    )(dhs, gates, cs, cs, hs, rw, p, mask.astype(jnp.float32), dhT, dcT,
+      h0, c0)
 
 
 # ------------------------------------------------------------ public op

@@ -277,18 +277,9 @@ def use_mesh_context(ctx: Optional[MeshContext]):
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, *, check: bool = False):
-    """One shard_map entry point across jax versions: new-API
-    `jax.shard_map` (check_vma) or the old experimental import
-    (check_rep). Every shard_map call site in the package routes
-    through here so an API change is a one-line fix. `check=True`
-    keeps jax's default replication/vma checking (pipeline's psum-
-    reduced outputs pass it); False disables it (ring attention's
-    merged partials do not)."""
-    try:
-        from jax import shard_map
-        kw = {} if check else {"check_vma": False}
-    except ImportError:                      # older jax
-        from jax.experimental.shard_map import shard_map
-        kw = {} if check else {"check_rep": False}
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
+    """The package's one `jax.shard_map` entry point. `check=True` keeps
+    jax's default vma checking (pipeline's psum-reduced outputs pass
+    it); False disables it (ring attention's merged partials do not)."""
+    kw = {} if check else {"check_vma": False}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

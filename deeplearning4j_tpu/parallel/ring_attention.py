@@ -167,12 +167,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
     (src < my: plain kernel), diagonal (src == my: the kernel's aligned
     causal mask), or fully masked (src > my: skipped, zero FLOPs) — and
     partial outputs merge via logaddexp of the emitted lse."""
-    try:
-        n = lax.axis_size(axis_name)
-    # graft: allow(GL403): version probe — pre-0.5 jax has no axis_size;
-    # psum of a python scalar constant-folds to the axis size statically
-    except AttributeError:
-        n = lax.psum(1, axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
     scale_ = scale if scale is not None else 1.0 / (D ** 0.5)

@@ -71,8 +71,10 @@ def launch_replica(config: dict, *, timeout_s: float = 120.0,
                    log_dir: Optional[str] = None) -> ReplicaProcess:
     """Start one replica process from a declarative config and block
     until its HTTP server is up. The child inherits this interpreter
-    (no install assumptions) and is pinned to the CPU platform unless
-    FLEET_REPLICA_PLATFORM overrides."""
+    (no install assumptions) and this environment plus `env`, and runs
+    on the platform JAX resolves from it: a caller that wants CPU
+    replicas passes `env={"JAX_PLATFORMS": "cpu"}`. A chip belongs to
+    one process, so replicas on chips need one chip each."""
     name = config.get("name", "replica")
     log_dir = log_dir or tempfile.mkdtemp(prefix="fleet_")
     log_path = os.path.join(log_dir, f"{name}.log")

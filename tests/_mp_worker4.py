@@ -31,8 +31,6 @@ if "xla_force_host_platform_device_count" not in flags:
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 

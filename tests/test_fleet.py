@@ -647,9 +647,10 @@ class TestReplicaKillChaos:
             launch_replica,
         )
 
-        procs = [launch_replica(_replica_cfg("ka", "mixed"),
+        cpu = {"JAX_PLATFORMS": "cpu"}
+        procs = [launch_replica(_replica_cfg("ka", "mixed"), env=cpu,
                                 log_dir=str(tmp_path)),
-                 launch_replica(_replica_cfg("kb", "mixed"),
+                 launch_replica(_replica_cfg("kb", "mixed"), env=cpu,
                                 log_dir=str(tmp_path))]
         router = FleetRouter([(p.name, p.url, p.role) for p in procs],
                              poll_interval=None)

@@ -221,7 +221,7 @@ def test_bench_regression_guard_keeps_best_record(tmp_path, monkeypatch):
 
 
 # --------------------------------------------------------------------------
-# _timed_ips: the adaptive two-point timing under synthetic tunnel noise
+# _timed_ips: the adaptive two-point timing under synthetic latency noise
 # (the measurement layer itself regressed twice on real hardware — a
 # clamped-negative differential recorded 32e9 seq/s, then a relative-only
 # dominance condition accepted 0.9ms/step for a true 3.1ms model; these

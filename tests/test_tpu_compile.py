@@ -1,0 +1,149 @@
+"""The main path's Pallas kernels, compiled at real widths for a TPU v5e
+that is described and not attached.
+
+Interpret mode (every other kernel test here) never meets Mosaic: a slice
+off the tiling, a 16-bit matmul accumulator or too much VMEM only shows
+when the chip's compiler sees the kernel. The compiler is installed in the
+sandbox and compiles for a described topology, so these cases guard every
+later PR at no chip time. Nothing runs: a pass says the kernel compiles,
+not that it is right or fast.
+
+The chip runs with x64 off and refuses every one of these kernels with it
+on, so the module turns off what `conftest.py` turned on; it also turns the
+persistent compile cache off, because an entry compiled for a described
+chip cannot be read back without one.
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+# by module path: `ops/__init__` re-exports functions under these names
+lstm = importlib.import_module("deeplearning4j_tpu.ops.lstm")
+flash = importlib.import_module("deeplearning4j_tpu.ops.attention")
+banded = importlib.import_module("deeplearning4j_tpu.ops.banded_attention")
+update = importlib.import_module("deeplearning4j_tpu.ops.fused_update")
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e device; x64 and the persistent
+    compile cache are off while the module runs."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+# --- cases: name -> (function, [(shape, dtype), ...]) at the widths the
+# chip smoke and the policies use
+def _lstm(dt, train, T=128, B=64, H=512):
+    shapes = [((T, B, 4 * H), dt), ((H, 4 * H), dt), ((3, H), dt),
+              ((B, H), dt), ((B, H), dt), ((T, B), dt)]
+
+    def fwd(xw, rw, p, h0, c0, m):
+        return lstm.fused_lstm(xw, rw, p, h0, c0, m, False)
+
+    def loss(*args):
+        return sum(o.astype(F32).sum() for o in fwd(*args))
+
+    return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if train else fwd), shapes
+
+
+def _flash(train, T=8192, heads=8, d=64, block=512):
+    # T 8192 is where the policy routes to flash for memory, with the
+    # Pallas backward (ops/kernel_defaults.attention_policy)
+    shapes = [((1, T, heads, d), BF16)] * 3
+
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, True, None, block, block,
+                                     False, "pallas")
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(F32).sum()
+
+    return (jax.grad(loss, argnums=(0, 1, 2)) if train else fwd), shapes
+
+
+def _banded(T=2048, heads=8, kv_heads=2, d=64, window=512):
+    def fwd(q, k, v):
+        return banded.banded_attention(q, k, v, window, True, None, 256,
+                                       256, False)
+
+    return fwd, [((2, T, heads, d), BF16), ((2, T, kv_heads, d), BF16),
+                 ((2, T, kv_heads, d), BF16)]
+
+
+def _decode(paged, cache_dtype, slots=8, cache=1024, page=128, heads=8,
+            kv_heads=2, d=64):
+    quant = cache_dtype == I8
+    rows = (slots * cache // page, page) if paged else (slots, cache)
+    shapes = [((slots, heads, d), BF16),
+              (rows + (kv_heads, d), cache_dtype),
+              (rows + (kv_heads, d), cache_dtype)]
+    shapes += ([((slots, cache // page), I32), ((slots,), I32)] if paged
+               else [((slots,), I32), ((slots,), I32)])
+    if quant:
+        shapes += [(rows + (kv_heads,), F32)] * 2
+
+    def fwd(q, ck, cv, a, b, *scales):
+        kw = (dict(scale_k=scales[0], scale_v=scales[1]) if quant else {})
+        if paged:
+            return banded.paged_decode_attention(q, ck, cv, a, b,
+                                                 interpret=False, **kw)
+        return banded.banded_decode_attention(q, ck, cv, a, b,
+                                              interpret=False, **kw)
+
+    return fwd, shapes
+
+
+def _adam(shape=(512, 2048), dt=BF16):
+    def fwd(p, g, m, v, lrbc):
+        return update.adam_update(p, g, m, v, lrbc, interpret=False)
+
+    return fwd, [(shape, dt)] * 4 + [((), F32)]
+
+
+CASES = {
+    "lstm_fwd_f32": lambda: _lstm(F32, train=False),
+    "lstm_fwd_bf16": lambda: _lstm(BF16, train=False),
+    "lstm_train_f32": lambda: _lstm(F32, train=True),
+    "lstm_train_bf16": lambda: _lstm(BF16, train=True),
+    "flash_fwd": lambda: _flash(train=False),
+    "flash_pallas_bwd": lambda: _flash(train=True),
+    "banded_fwd_gqa": _banded,
+    "slot_decode_bf16": lambda: _decode(False, BF16),
+    "slot_decode_int8": lambda: _decode(False, I8),
+    "paged_decode_bf16": lambda: _decode(True, BF16),
+    "paged_decode_int8": lambda: _decode(True, I8),
+    "fused_adam_bf16": _adam,
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, shapes = CASES[name]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

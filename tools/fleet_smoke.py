@@ -104,8 +104,9 @@ def main() -> int:
         for name, role in (("pf0", "prefill"), ("dc0", "decode")):
             procs.append(launch_replica(
                 _cfg(name, role), log_dir=log_dir,
-                env={"DL4J_TPU_FLIGHT_DIR": tempfile.mkdtemp(
-                    prefix=f"fleet_{name}_flight_")}))
+                env={"JAX_PLATFORMS": "cpu",
+                     "DL4J_TPU_FLIGHT_DIR": tempfile.mkdtemp(
+                         prefix=f"fleet_{name}_flight_")}))
         pf0, dc0 = procs
         router = FleetRouter([p.handle() for p in procs],
                              poll_interval=None)
