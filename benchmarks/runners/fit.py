@@ -1,0 +1,385 @@
+"""Runner `fit`: one training job through the program's `fit()`.
+
+A pool of seeded host batches is handed out cyclically by an iterator of
+the benchmark's own, which stops handing out when `--seconds` have passed
+since the first timed batch. One `fit()` call, one epoch, spans the window,
+so the executor's one host sync at the epoch's end closes it:
+
+    train_items_per_s = batches handed out x global batch
+                        / (first hand-out -> fit() returned)
+
+Set-up builds ONE net (and, where the mix says so, its `ParallelWrapper`),
+gives it the benchmark's weights, drives it through its first
+`warmup_steps` steps with that same iterator and `fit()` call, and hands the
+same object to the window. Those first steps are what `correct` compares
+with the plain reference, which ran in a process of its own before this one
+touched JAX (`benchmarks/reference_main.py`) and is not part of `setup_s`.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+from benchmarks import (
+    compare, harness, trace_reduce, traffic_gen, xplane_schema,
+)
+
+
+def run_reference(cell: dict, seed: int, steps: int, require_chip: bool):
+    """(numbers, wall seconds) of the reference child."""
+    os.makedirs(harness.SCRATCH, exist_ok=True)
+    out = os.path.join(harness.SCRATCH, f"reference_{cell['name']}.json")
+    if os.path.exists(out):
+        os.remove(out)
+    t0 = time.perf_counter()
+    done = subprocess.run(
+        [sys.executable, os.path.join(harness.BENCH_DIR, "reference_main.py"),
+         "--config", cell["config_file"], "--traffic", cell["traffic_file"],
+         "--chips", str(cell["chips"]), "--seed", str(seed),
+         "--steps", str(steps), "--out", out,
+         "--require-chip", str(int(require_chip))],
+        stdout=sys.stderr, check=False)
+    wall = time.perf_counter() - t0
+    if done.returncode == 2 and require_chip:
+        raise harness.NoChip("the reference found no chip")
+    if done.returncode != 0:
+        raise RuntimeError(f"reference exited with {done.returncode}")
+    import numpy as np
+
+    with open(out, encoding="utf-8") as fh:
+        numbers = json.load(fh)
+    with np.load(out + ".npz") as samples:
+        numbers["grad_sample"] = {k: samples[k] for k in samples.files}
+    os.remove(out)
+    os.remove(out + ".npz")
+    return numbers, wall
+
+
+def _stream(pool, *, steps=None, seconds=None):
+    """An iterator on the program's `DataSetIterator` protocol that hands
+    the pool out cyclically, for `steps` batches or until `seconds` have
+    passed since the first hand-out."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.data.iterators import DataSetIterator
+
+    batches = [DataSet(x, y) for x, y in pool]
+
+    class Stream(DataSetIterator):
+        handed = 0
+        t_first = None
+        t_last = None
+
+        def reset(self):
+            self.handed, self.t_first, self.t_last = 0, None, None
+
+        def __next__(self):
+            now = time.perf_counter()
+            if self.t_first is None:
+                self.t_first = now
+            if steps is not None and self.handed >= steps:
+                raise StopIteration
+            if seconds is not None and now - self.t_first >= seconds:
+                raise StopIteration
+            ds = batches[self.handed % len(batches)]
+            self.handed += 1
+            self.t_last = now
+            return ds
+
+        @property
+        def batch_size(self):
+            return batches[0].num_examples()
+
+    return Stream()
+
+
+class FirstSteps(harness.Listener):
+    """Reads, on the device and without a host sync, each warm-up step's
+    loss, the first gradient's per-leaf norms and samples out of the
+    optimizer's state after step one (how is the update rule's to say),
+    and the per-leaf norms of the parameters' change after the last step."""
+
+    def __init__(self, steps, first_grad, delta_norms):
+        self.steps = steps
+        self._first_grad, self._delta_norms = first_grad, delta_norms
+        self.losses, self.grad, self.sample, self.delta = [], None, None, None
+
+    def iteration_done(self, model, iteration, epoch, score):
+        self.losses.append(score)
+        if len(self.losses) == 1:
+            self.grad, self.sample = self._first_grad(model.updater_state)
+        if len(self.losses) == self.steps:
+            self.delta = self._delta_norms(model.params_tree)
+
+
+class TraceWindow(harness.Listener):
+    """The profiler over the window's first `seconds`. It is started just
+    BEFORE the window (starting it stalls the host for a second or two,
+    which inside the window drained the device's queue and read as 55 to
+    70% idle: chip runs, PR 24) and stopped from the fit loop's own thread.
+    Device ops only. With the host tracer on, even at its lowest level,
+    the runtime's per-chunk events of the host-side layout change of every
+    batch made a 330 MB trace and a dispatch of 0.4 s (chip run, PR 24)."""
+
+    def __init__(self, stream, seconds):
+        self.stream, self.seconds = stream, seconds
+        self.session, self.xspace, self.stop_s = None, None, None
+
+    def start(self):
+        import jax
+        from jax._src.lib import _profiler
+
+        jax.devices()           # the backend before the tracer, as JAX does
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 0
+        options.enable_hlo_proto = False
+        self.session = _profiler.ProfilerSession(options)
+
+    def iteration_done(self, model, iteration, epoch, score):
+        if self.session is not None and self.stream.t_first is not None \
+                and time.perf_counter() - self.stream.t_first >= self.seconds:
+            self.stop()
+
+    def stop(self):
+        if self.session is not None:
+            t0 = time.perf_counter()
+            self.xspace, self.session = self.session.stop(), None
+            self.stop_s = time.perf_counter() - t0
+
+    def on_fit_end(self, model):
+        self.stop()
+
+
+def _place_weights(net, ref_params, dtype):
+    """Give the net the benchmark's weights, in the dtype it is trained
+    in, and return a copy of them as placed. Every leaf of the net must be
+    one the reference made."""
+    import jax
+    import jax.numpy as jnp
+
+    full = {}
+    for name, leaves in net.params_tree.items():
+        made = ref_params.get(name, {})
+        if set(made) != set(leaves):
+            raise RuntimeError(f"vertex {name!r}: the program has leaves "
+                               f"{sorted(leaves)}, the reference {sorted(made)}")
+        for leaf, arr in leaves.items():
+            if made[leaf].shape != arr.shape:
+                raise RuntimeError(f"{name}/{leaf}: program "
+                                   f"{arr.shape}, reference {made[leaf].shape}")
+        full[name] = made
+    missing = set(ref_params) - set(full)
+    if missing:
+        raise RuntimeError(f"the program lacks {sorted(missing)}")
+    rounded = jax.jit(lambda t: jax.tree_util.tree_map(
+        lambda a: a.astype(dtype), t))(full)
+    net.params_tree = rounded
+    # a copy: the step donates the net's own buffers
+    return jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t))(rounded)
+
+
+def _histograms():
+    from deeplearning4j_tpu.observe import get_registry
+
+    reg = get_registry()
+    return {name: (reg.histogram(name).count, reg.histogram(name).sum)
+            for name in ("train_etl_ms", "train_dispatch_ms")}
+
+
+def prepare(cell: dict, seed: int, used, stamp=lambda name: None) -> dict:
+    """Set-up proper: ONE net (and its wrapper), the benchmark's weights,
+    the pool, and the first `warmup_steps` steps through `fit()`, read for
+    the comparison. Returns the trainer the window goes on with."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    config, traffic = cell["config_data"], cell["traffic_data"]
+    chips, steps = cell["chips"], int(traffic["warmup_steps"])
+    follow = harness.load_module("reference", "follow.py")
+    ref_mod = harness.load_module("reference", config["reference"] + ".py")
+    model = harness.load_module("models", config["model"] + ".py")
+    rule = harness.load_module("reference", "rules",
+                               config["updater"]["rule"] + ".py")
+    global_batch = config["batch_per_chip"] * chips
+
+    net = harness.init_in_one_program(model.build(config, seed))
+    initial = _place_weights(net, ref_mod.init_params(seed, config),
+                             jnp.dtype(config["dtype"]))
+    stamp("net_and_weights")
+    trainer = net
+    if traffic.get("wrapper") == "ParallelWrapper":
+        from deeplearning4j_tpu.parallel import ParallelWrapper, make_mesh
+
+        trainer = ParallelWrapper(
+            net, mesh=make_mesh({"data": chips}, devices=list(used)))
+    elif traffic.get("wrapper"):
+        raise KeyError(f"unknown wrapper {traffic['wrapper']!r}")
+    pool = traffic_gen.make_pool(traffic, config, seed, global_batch)
+    stamp("pool")
+
+    @jax.jit
+    def first_grad(state):
+        g = rule.first_gradient(state, config["updater"])
+        return follow.leaf_norms(g), follow.leaf_samples(g)
+
+    delta_of = jax.jit(lambda p, p0: follow.leaf_norms(
+        jax.tree_util.tree_map(
+            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
+            p, p0)))
+    names = follow.leaf_paths(initial)
+    first = FirstSteps(steps, first_grad, lambda p: delta_of(p, initial))
+    net.set_listeners(first)
+    trainer.fit(_stream(pool, steps=steps), epochs=1)
+    program = {
+        "loss": [float(x) for x in first.losses],
+        "grad_norm": dict(zip(names, map(float, np.asarray(first.grad)))),
+        "delta_norm": dict(zip(names, map(float, np.asarray(first.delta)))),
+        "grad_sample": dict(zip(names, map(np.asarray, first.sample))),
+    }
+    stamp("first_steps")
+    return {"net": net, "trainer": trainer, "pool": pool,
+            "program": program, "global_batch": global_batch,
+            "forward_macs_per_item": ref_mod.forward_macs(config)}
+
+
+def run(cell: dict, *, seed: int, seconds: float, trace: bool,
+        require_chip: bool, t_start: float, limits=None) -> dict:
+    config, traffic = cell["config_data"], cell["traffic_data"]
+    chips, steps = cell["chips"], int(traffic["warmup_steps"])
+    if limits is None:
+        limits = harness.load_json("limits", cell["name"] + ".json")
+    reference, ref_wall = run_reference(cell, seed, steps, require_chip)
+    harness.say("reference", seconds=ref_wall, loss=reference["loss"],
+                phases=reference.get("phases"),
+                xla_compiles=reference["xla_compiles"])
+
+    import jax
+    import numpy as np
+
+    cache_dir = harness.enable_compile_cache()
+    used = (harness.require_chips(chips) if require_chip
+            else jax.devices()[:chips])
+    if len(used) < chips:
+        raise harness.NoChip(f"{chips} devices asked, {len(used)} seen")
+    peaks = harness.peaks_for(used[0].device_kind) if require_chip else None
+    phases, t_phase = {}, [time.perf_counter()]
+    phases["start_to_devices"] = t_phase[0] - t_start - ref_wall
+
+    def stamp(name):
+        now = time.perf_counter()
+        phases[name] = now - t_phase[0]
+        t_phase[0] = now
+
+    with harness.XlaLog() as xla_setup:
+        ready = prepare(cell, seed, used, stamp)
+    net, trainer, pool = ready["net"], ready["trainer"], ready["pool"]
+    program, global_batch = ready["program"], ready["global_batch"]
+
+    # ------------------------------------------------------------ window
+    log = harness.LossLog()
+    stream = _stream(pool, seconds=seconds)
+    listeners = [log]
+    tracer = None
+    if trace:
+        tracer = TraceWindow(stream, min(float(traffic["trace_seconds"]),
+                                         seconds / 2.0))
+        listeners.append(tracer)
+        tracer.start()
+    net.set_listeners(*listeners)
+    hist_before = _histograms()
+    t_window = time.perf_counter()
+    setup_s = t_window - t_start - ref_wall
+    with harness.XlaLog() as xla_window:
+        trainer.fit(stream, epochs=1)
+        t_end = time.perf_counter()
+    hist_after = _histograms()
+    losses = log.host()
+    elapsed = t_end - stream.t_first
+    items_per_s = stream.handed * global_batch / elapsed
+
+    # ----------------------------------------------------------- correct
+    numbers = compare.first_steps(program, reference)
+    numbers["window_loss_not_finite"] = {
+        "value": int(np.sum(~np.isfinite(losses)))}
+    numbers["window_steps_missing"] = {
+        "value": int(stream.handed - len(losses))}
+    on = harness.platforms(net.params_tree, net.updater_state)
+    numbers["state_off_device"] = {
+        "value": int(on != [used[0].platform]), "on": on}
+    correct = compare.judge(numbers, {
+        "window_loss_not_finite": 0, "window_steps_missing": 0,
+        "state_off_device": 0, **limits})
+    for name, row in numbers.items():
+        harness.say("check", name=name, **row)
+
+    device = harness.device_facts(used)
+    run_facts = {
+        "workload": cell["name"], "seed": seed, "chips": chips,
+        "global_batch": global_batch, "steps": int(stream.handed),
+        "window_s": elapsed, "handing_out_s": stream.t_last - stream.t_first,
+        "items_per_s": items_per_s, "setup_s": setup_s,
+        "reference_s": ref_wall, "loss_first": float(losses[0]),
+        "loss_last": float(losses[-1]),
+        "program_first_losses": program["loss"],
+        "reference_first_losses": reference["loss"],
+        "xla_compiles_in_window": xla_window.compiled,
+        "compile_cache_dir": cache_dir,
+        "setup_xla": xla_setup.facts(),
+        "forward_macs_per_item": ready["forward_macs_per_item"],
+        "setup_phases_s": phases,
+        "item": config["item"], "peaks": peaks,
+        "device_kind": device["kind"], "platform": device["platform"],
+        "memory_peak_bytes": device["memory_peak_bytes"],
+        "memory_stats": {k: int(v) for k, v in
+                         (used[0].memory_stats() or {}).items()},
+    }
+    harness.say("run", **run_facts)
+
+    result = {"correct": bool(correct), "attempted": int(stream.handed),
+              "failed": int(numbers["window_loss_not_finite"]["value"]
+                            + numbers["window_steps_missing"]["value"]),
+              "metrics": {}, "device": device}
+    if not trace:
+        values = {"train_items_per_s": items_per_s, "setup_s": setup_s}
+        for m in cell["end_to_end"]:
+            result["metrics"][m["name"]] = {"value": values[m["name"]],
+                                            "unit": m["unit"]}
+        return result
+
+    reduction = None
+    if tracer.xspace:
+        space = xplane_schema.xspace_class()()
+        space.ParseFromString(tracer.xspace)
+        reduction = trace_reduce.reduce_space(
+            space, devices=[d.id for d in used],
+            skip_s=float(traffic.get("trace_skip_s", 0.0)))
+        harness.say("trace", bytes=len(tracer.xspace),
+                    stop_s=tracer.stop_s, **{
+                        k: reduction[k] for k in (
+                            "window_s", "busy_s", "idle_share_worst",
+                            "category_s", "main_module", "main_module_runs_per_s")
+                        if reduction is not None},
+                    lines=trace_reduce.line_counts(space))
+    facts = {
+        "trace": reduction,
+        "registry": {k: {"count": hist_after[k][0] - hist_before[k][0],
+                         "sum": hist_after[k][1] - hist_before[k][1]}
+                     for k in hist_after},
+        "run": run_facts,
+    }
+    for m in cell["per_layer"]:
+        reader = harness.load_module("layer_metrics", m["name"] + ".py")
+        value = reader.read(facts)
+        if value is not None:
+            result["metrics"][m["name"]] = {"value": float(value),
+                                            "unit": m["unit"]}
+    if reduction is not None:
+        result["device"]["busy_s"] = reduction["busy_s"]
+        result["device"]["window_s"] = reduction["window_s"]
+        result["breakdown"] = reduction["breakdown"]
+    return result
