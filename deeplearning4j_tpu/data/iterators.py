@@ -22,7 +22,7 @@ from typing import Callable, Iterator, List, Optional
 import numpy as np
 
 from deeplearning4j_tpu.data.dataset import DataSet
-from deeplearning4j_tpu.observe import get_registry
+from deeplearning4j_tpu.observe import get_registry, span
 
 
 class DataSetIterator:
@@ -374,6 +374,11 @@ class DevicePrefetchIterator(DataSetIterator):
         self._m_batches = reg.counter("etl_batches_total", stage="device")
 
     def _put(self, ds):
+        """One batch to the device(s), under a `data.put` span (a child of
+        the fit loop's `fit.etl` wait) whose `bytes` are those handed to
+        `device_put`: a put that blocks on a full transfer queue and a
+        slow feed both read as a long etl wait, and this span tells them
+        apart."""
         import jax
 
         put = self._put_fn
@@ -387,15 +392,23 @@ class DevicePrefetchIterator(DataSetIterator):
             put = ctx.put_batch if ctx is not None else jax.device_put
         if self._transform is not None:
             ds = self._transform(ds)
-        if hasattr(ds, "features_masks"):   # MultiDataSet
-            cls = type(ds)
-            pl = lambda xs: None if xs is None else type(xs)(
-                None if x is None else put(x) for x in xs)
-            return cls(pl(ds.features), pl(ds.labels),
-                       pl(ds.features_masks), pl(ds.labels_masks))
-        p = lambda a: None if a is None else put(a)
-        return DataSet(p(ds.features), p(ds.labels),
-                       p(ds.features_mask), p(ds.labels_mask))
+        sent = span("data.put", bytes=0)
+
+        def p(a):
+            if a is None:
+                return None
+            sent.attrs["bytes"] += getattr(a, "nbytes", 0)
+            return put(a)
+
+        with sent:
+            if hasattr(ds, "features_masks"):   # MultiDataSet
+                cls = type(ds)
+                pl = lambda xs: None if xs is None else type(xs)(
+                    p(x) for x in xs)
+                return cls(pl(ds.features), pl(ds.labels),
+                           pl(ds.features_masks), pl(ds.labels_masks))
+            return DataSet(p(ds.features), p(ds.labels),
+                           p(ds.features_mask), p(ds.labels_mask))
 
     def _fill(self):
         while (not self._exhausted and self._pending is None

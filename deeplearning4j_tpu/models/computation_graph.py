@@ -182,24 +182,30 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                         if i in fmasks:
                             mask = fmasks[i]
                             break
-            if isinstance(v, LayerVertex) and v.layer.is_output_layer:
-                x = ins[0]
-                if v.preprocessor is not None:
-                    x = v.preprocessor.apply(x)
-                out_inputs[name] = x
-                y, new_st = v.layer.apply(
-                    params[name], x, state=st, train=train, rng=lrng, mask=mask)
-            elif (train and self.conf.gradient_checkpointing
-                  and isinstance(v, LayerVertex)):
-                # remat this layer vertex in the backward pass; cheap
-                # parameterless vertices (merge/elementwise/...) are NOT
-                # wrapped — their outputs are checkpoint residuals
-                # anyway, so wrapping buys nothing and blocks CSE
-                y, new_st = _checkpointed(v.apply, mask)(
-                    params[name], ins, st, lrng)
-            else:
-                y, new_st = v.apply(
-                    params[name], ins, state=st, train=train, rng=lrng, mask=mask)
+            # the vertex's name on its device ops (and, as
+            # `transpose(jvp(<name>))`, on its backward ops): debug
+            # locations only, the compiled program is the same
+            with jax.named_scope(name):
+                if isinstance(v, LayerVertex) and v.layer.is_output_layer:
+                    x = ins[0]
+                    if v.preprocessor is not None:
+                        x = v.preprocessor.apply(x)
+                    out_inputs[name] = x
+                    y, new_st = v.layer.apply(
+                        params[name], x, state=st, train=train, rng=lrng,
+                        mask=mask)
+                elif (train and self.conf.gradient_checkpointing
+                      and isinstance(v, LayerVertex)):
+                    # remat this layer vertex in the backward pass; cheap
+                    # parameterless vertices (merge/elementwise/...) are
+                    # NOT wrapped — their outputs are checkpoint residuals
+                    # anyway, so wrapping buys nothing and blocks CSE
+                    y, new_st = _checkpointed(v.apply, mask)(
+                        params[name], ins, st, lrng)
+                else:
+                    y, new_st = v.apply(
+                        params[name], ins, state=st, train=train, rng=lrng,
+                        mask=mask)
             values[name] = y
             new_states[name] = new_st
         return values, out_inputs, new_states
@@ -217,16 +223,21 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                 continue
             lm = lmasks.get(name) if lmasks else None
             lab = labels[name]
-            if isinstance(v.layer, CenterLossOutputLayer):
-                s, cstate = v.layer.score_and_state(
-                    params[name], out_inputs[name], lab, states[name], lm)
-                new_states[name] = cstate
-            else:
-                s = v.layer.score(params[name], out_inputs[name], lab, lm)
-            total = total + s
-        for name, v in self.conf.vertices.items():
-            if isinstance(v, LayerVertex):
-                total = total + v.layer.regularization(params[name])
+            # the output layer's own work in a train step is its score
+            with jax.named_scope(name), jax.named_scope("loss"):
+                if isinstance(v.layer, CenterLossOutputLayer):
+                    s, cstate = v.layer.score_and_state(
+                        params[name], out_inputs[name], lab, states[name],
+                        lm)
+                    new_states[name] = cstate
+                else:
+                    s = v.layer.score(params[name], out_inputs[name], lab,
+                                      lm)
+                total = total + s
+        with jax.named_scope("regularization"):
+            for name, v in self.conf.vertices.items():
+                if isinstance(v, LayerVertex):
+                    total = total + v.layer.regularization(params[name])
         # Activity-dependent auxiliary losses (e.g. MoE load balancing)
         # reported via vertex state — differentiated with the score.
         for st in new_states.values():
@@ -268,11 +279,13 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                 loss_fn, has_aux=True)(params)
             grads = _normalize_grads(grads, mode, thr)
             new_params, new_opt = {}, {}
-            for name, u in updaters.items():
-                # Whole-update seam (fused-kernel capable): see
-                # MultiLayerNetwork._build_step.
-                new_params[name], new_opt[name] = u.update_with_params(
-                    grads[name], opt_state[name], params[name], step)
+            with jax.named_scope("updater"):
+                for name, u in updaters.items():
+                    # Whole-update seam (fused-kernel capable): see
+                    # MultiLayerNetwork._build_step.
+                    new_params[name], new_opt[name] = \
+                        u.update_with_params(grads[name], opt_state[name],
+                                             params[name], step)
             persist = {
                 n: (new_states[n] if n in stateful else states.get(n, {}))
                 for n in states

@@ -217,26 +217,30 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         out_in = x
         n = len(self.layers)
         for i, layer in enumerate(self.layers):
-            if i in self.conf.preprocessors:
-                x = self.conf.preprocessors[i].apply(x, fmask)
-            if layer.is_output_layer and i == n - 1:
-                out_in = x
-            st = states.get(layer.name) or None
-            if carries is not None and layer.name in carries:
-                st = carries[layer.name]
-            lrng = None if rng is None else jax.random.fold_in(rng, i)
-            if (train and self.conf.gradient_checkpointing
-                    and not (layer.is_output_layer and i == n - 1)):
-                # remat this layer's activations in the backward pass
-                # (memory ∝ depth → memory ∝ 1, +~33% FLOPs); the output
-                # layer is skipped — its input is retained for the loss
-                # anyway
-                x, new_st = _checkpointed(layer.apply, fmask)(
-                    params[layer.name], x, st, lrng)
-            else:
-                x, new_st = layer.apply(
-                    params[layer.name], x, state=st, train=train,
-                    rng=lrng, mask=fmask)
+            # the layer's name on its device ops (and, as
+            # `transpose(jvp(<name>))`, on its backward ops): debug
+            # locations only, the compiled program is the same
+            with jax.named_scope(layer.name):
+                if i in self.conf.preprocessors:
+                    x = self.conf.preprocessors[i].apply(x, fmask)
+                if layer.is_output_layer and i == n - 1:
+                    out_in = x
+                st = states.get(layer.name) or None
+                if carries is not None and layer.name in carries:
+                    st = carries[layer.name]
+                lrng = None if rng is None else jax.random.fold_in(rng, i)
+                if (train and self.conf.gradient_checkpointing
+                        and not (layer.is_output_layer and i == n - 1)):
+                    # remat this layer's activations in the backward pass
+                    # (memory ∝ depth → memory ∝ 1, +~33% FLOPs); the
+                    # output layer is skipped — its input is retained for
+                    # the loss anyway
+                    x, new_st = _checkpointed(layer.apply, fmask)(
+                        params[layer.name], x, st, lrng)
+                else:
+                    x, new_st = layer.apply(
+                        params[layer.name], x, state=st, train=train,
+                        rng=lrng, mask=fmask)
             new_states[layer.name] = new_st
             if collect:
                 acts.append(x)
@@ -255,17 +259,22 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         score_mask = lmask if lmask is not None else (
             fmask if labels is not None and labels.ndim == 3 else None
         )
-        if isinstance(out_layer, CenterLossOutputLayer):
-            score, cstate = out_layer.score_and_state(
-                params[out_layer.name], out_in, labels,
-                states[out_layer.name], score_mask,
+        # the output layer's own work in a train step is its score
+        with jax.named_scope(out_layer.name), jax.named_scope("loss"):
+            if isinstance(out_layer, CenterLossOutputLayer):
+                score, cstate = out_layer.score_and_state(
+                    params[out_layer.name], out_in, labels,
+                    states[out_layer.name], score_mask,
+                )
+                new_states[out_layer.name] = cstate
+            else:
+                score = out_layer.score(params[out_layer.name], out_in,
+                                        labels, score_mask)
+        with jax.named_scope("regularization"):
+            reg = sum(
+                layer.regularization(params[layer.name])
+                for layer in self.layers
             )
-            new_states[out_layer.name] = cstate
-        else:
-            score = out_layer.score(params[out_layer.name], out_in, labels, score_mask)
-        reg = sum(
-            layer.regularization(params[layer.name]) for layer in self.layers
-        )
         # Activity-dependent auxiliary losses (e.g. MoE load balancing)
         # reported through layer state — added INSIDE the differentiated
         # closure so they contribute gradients.
@@ -326,14 +335,17 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                 loss_fn, has_aux=True)(params)
             grads = _normalize_grads(grads, mode, thr)
             new_params, new_opt = {}, {}
-            for name, u in updaters.items():
-                # One seam for the whole read-modify-write: the default
-                # is apply() + dtype-preserving subtract exactly as
-                # before; Adam/Nesterovs may route through the one-pass
-                # fused Pallas kernel (ops/fused_update.py) when the
-                # measured policy selects it.
-                new_params[name], new_opt[name] = u.update_with_params(
-                    grads[name], opt_state[name], params[name], step)
+            with jax.named_scope("updater"):
+                for name, u in updaters.items():
+                    # One seam for the whole read-modify-write: the
+                    # default is apply() + dtype-preserving subtract
+                    # exactly as before; Adam/Nesterovs may route through
+                    # the one-pass fused Pallas kernel
+                    # (ops/fused_update.py) when the measured policy
+                    # selects it.
+                    new_params[name], new_opt[name] = \
+                        u.update_with_params(grads[name], opt_state[name],
+                                             params[name], step)
             persist = {
                 n: (new_states[n] if n in stateful else states.get(n, {}))
                 for n in states

@@ -12,10 +12,17 @@ every layer shares:
   histograms with bounded reservoirs and labeled series; thread-safe;
   snapshot + Prometheus-text + JSONL exporters. The serving `/metrics`
   endpoint and the training listeners are renderers over this registry.
-- `span()` (`trace.py`) — async-dispatch-safe host-side tracing spans.
+- `span()` (`trace.py`) — the program's one span record: name, start
+  and end on `time.perf_counter_ns()`, id, parent, thread and plain
+  attributes, kept in a bounded in-memory store and written out only
+  when a `SpanLog` is closed, a flight dump is taken or `fit()` ends.
   Spans time HOST work only and never call `float()` /
-  `block_until_ready()` on device values, so enabling tracing cannot
-  stall the dispatch pipeline (pinned by the ≤1-sync-per-epoch test).
+  `block_until_ready()` on device values, so recording cannot stall the
+  dispatch pipeline (pinned by the ≤1-sync-per-epoch test). The fit
+  loop's segments (`fit.etl` > `data.put`, `fit.dispatch`,
+  `fit.listeners`, `fit.epoch_sync`) are timed once, by their spans;
+  `utils/profiling.ProfilerListener` writes them beside a device trace
+  with what links the two clocks.
 - `RecompileWatchdog` (`watchdog.py`) — counts every jit-cache compile
   across the per-model `_jit_cache` seams and warns once per model when
   compiles cross a churn threshold (the classic silent 10x).
@@ -44,7 +51,8 @@ every layer shares:
   GL802-tagged (`reshard_events_total{owner}`), string-comparable with
   static shardflow findings; off means the dispatch path is unchanged.
 - `python -m deeplearning4j_tpu.observe.dump` (`dump.py`) — pretty-print
-  a registry snapshot or tail a span JSONL.
+  a registry snapshot or tail a written span JSONL (a `SpanLog`'s, or
+  the `.spans.jsonl` beside a device trace).
 - `reqtrace.py` — request-scoped causal trace trees (TraceContext at the
   HTTP edge, fan-in dispatch spans, per-step session spans, training
   dispatch windows) with head-based sampling and a bounded TraceStore;
@@ -68,8 +76,8 @@ from deeplearning4j_tpu.observe.registry import (
     MetricsRegistry, get_registry, set_registry,
 )
 from deeplearning4j_tpu.observe.trace import (
-    SpanLog, emit_manual_span, install_span_log, read_spans, span,
-    tracing_enabled, uninstall_span_log,
+    SpanLog, emit_manual_span, get_span_store, install_span_log, read_spans,
+    span, tracing_enabled, uninstall_span_log,
 )
 from deeplearning4j_tpu.observe.watchdog import (
     RecompileWatchdog, WatchedJitCache, get_watchdog, set_watchdog,
@@ -112,7 +120,7 @@ from deeplearning4j_tpu.observe.slo import (
 __all__ = [
     "MetricsRegistry", "get_registry", "set_registry",
     "SpanLog", "span", "install_span_log", "uninstall_span_log",
-    "tracing_enabled", "read_spans", "emit_manual_span",
+    "tracing_enabled", "read_spans", "emit_manual_span", "get_span_store",
     "RecompileWatchdog", "WatchedJitCache", "get_watchdog", "set_watchdog",
     "HostSyncMonitor", "current_monitor",
     "LockWitness", "MonitoredLock", "get_witness", "lockmon_enabled",

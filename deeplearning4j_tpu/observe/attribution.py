@@ -16,9 +16,15 @@ contract). Attribution measures around it:
 - per iteration (host clock, no syncs): `etl_ms` (batch wait),
   `dispatch_ms` (the step call — trace/enqueue), `host_ms` (listener
   fan-out + after_step);
-- per window (materialize to materialize): `block_ms`, the time
-  `float(loss)` actually waited for the device to drain the queue —
-  measured at the boundary the tracker already owns.
+- per window (epoch sync to epoch sync): `block_ms`, the time the
+  epoch's one `float(loss)` waited for the device to drain the queue.
+
+None of these is timed here. The fit loop records each segment once, as a
+span (`fit.etl`, `fit.dispatch`, `fit.listeners`, `fit.epoch_sync`;
+`observe/trace.py`), and `close_window` reads the window's spans out of
+the span store when the epoch's sync has returned. With span recording
+off (`DL4J_TPU_FLIGHT=0` and no SpanLog) there is nothing to read and no
+window closes.
 
 Device-execute time for the window is then inferred:
 
@@ -31,12 +37,12 @@ pipeline. Device-bound runs converge to `wall - etl` (the queue never
 drains early); host-bound runs are bounded by dispatch+host (an upper
 bound: the device may have idled). Per-step device time is the window
 total divided by its step count — published as the `device` segment of
-`train_step_attribution_ms`, the `train_device_step_ms` gauge, a
-`fit.attribution_window` span, and `last_device_step_ms()` which
-PerformanceListener uses as the measured MFU denominator.
+`train_step_attribution_ms`, the `train_device_step_ms` gauge, and
+`last_device_step_ms()` which PerformanceListener uses as the measured
+MFU denominator. An epoch of more spans than the store holds is read
+from its oldest span still held.
 
-Env: DL4J_TPU_ATTRIBUTION=0 disables (the executor then skips all
-timing aggregation).
+Env: DL4J_TPU_ATTRIBUTION=0 disables.
 
 Stdlib-only; one instance per `TrainingExecutor.run`, so instrument
 handles bind to the registry active at fit start.
@@ -50,9 +56,11 @@ import time
 from typing import Optional
 
 from deeplearning4j_tpu.observe.registry import get_registry
-from deeplearning4j_tpu.observe.trace import emit_manual_span
+from deeplearning4j_tpu.observe.trace import get_span_store
 
 SEGMENTS = ("etl", "dispatch", "host", "device")
+_SEGMENT_OF = {"fit.etl": "etl", "fit.dispatch": "dispatch",
+               "fit.listeners": "host"}
 
 
 def attribution_enabled() -> bool:
@@ -60,15 +68,12 @@ def attribution_enabled() -> bool:
 
 
 class StepAttribution:
-    """Per-fit accumulator of step-time segments.
+    """Per-fit reader of the fit loop's spans: nothing on the hot path,
+    one pass over the window's spans per epoch sync."""
 
-    `record_iteration` is the hot path: three histogram observes + one
-    short lock. `on_device_block` runs once per materialization (≤1 per
-    epoch steady-state) and closes the inference window.
-    """
-
-    def __init__(self, registry=None):
+    def __init__(self, registry=None, store=None):
         reg = registry or get_registry()
+        self._store = store or get_span_store()
         self._hist = {seg: reg.histogram("train_step_attribution_ms",
                                          segment=seg)
                       for seg in SEGMENTS}
@@ -76,59 +81,42 @@ class StepAttribution:
         self._lock = threading.Lock()
         self.windows = 0
         self._last_device_ms: Optional[float] = None
-        self._w_t0 = time.perf_counter()
-        self._w_ts = time.time()
-        self._steps = 0
-        self._etl = self._dispatch = self._host = 0.0
+        self._from = self._store.count       # the window's first span
+        self._t0 = time.perf_counter_ns()
 
-    def _reset_window_locked(self, t0: float, ts: float) -> None:
-        # the _locked suffix is the contract: every caller holds self._lock
-        self._w_t0 = t0    # graft: allow(GL301): caller holds self._lock
-        self._w_ts = ts    # graft: allow(GL301): caller holds self._lock
-        self._steps = 0    # graft: allow(GL301): caller holds self._lock
-        self._etl = self._dispatch = self._host = 0.0  # graft: allow(GL301): caller holds self._lock
-
-    # ------------------------------------------------------------ hot path
-    def record_iteration(self, etl_ms: float, dispatch_ms: float,
-                         host_ms: float) -> None:
+    def close_window(self, sync_start_ns: int, sync_end_ns: int) -> None:
+        """The epoch's `fit.epoch_sync` span just ended: sum this thread's
+        segment spans since the last window and infer the device time."""
+        store, thread = self._store, threading.current_thread().name
         with self._lock:
-            self._steps += 1
-            self._etl += etl_ms
-            self._dispatch += dispatch_ms
-            self._host += host_ms
-        self._hist["etl"].observe(etl_ms)
-        self._hist["dispatch"].observe(dispatch_ms)
-        self._hist["host"].observe(host_ms)
-
-    # -------------------------------------------------- the block boundary
-    def on_device_block(self, block_ms: float) -> None:
-        """LossTracker callback: a device loss just materialized after
-        blocking for `block_ms`. Closes the attribution window."""
-        now = time.perf_counter()
-        ts = time.time()
-        with self._lock:
-            steps = self._steps
-            wall = (now - self._w_t0) * 1e3
-            etl, disp, host = self._etl, self._dispatch, self._host
-            w_ts = self._w_ts
-            self._reset_window_locked(now, ts)
+            since, t0 = self._from, self._t0
+            self._from, self._t0 = store.count, sync_end_ns
+        records = [r for r in store.records(since)
+                   if r[5] == thread and r[2] in _SEGMENT_OF
+                   and not r[6].get("exhausted")]
+        if store.count - since > store.capacity and records:
+            t0 = records[0][3]
+        ms = dict.fromkeys(("etl", "dispatch", "host"), 0.0)
+        steps = 0
+        for _, _, name, start, end, _, attrs in records:
+            seg, n = _SEGMENT_OF[name], attrs.get("steps", 1)
+            ms[seg] += (end - start) / 1e6
+            for _ in range(n):
+                self._hist[seg].observe((end - start) / 1e6 / n)
+            if seg == "dispatch":
+                steps += n
         if steps == 0:
-            return   # a re-read between windows (score_ accessed twice)
-        device_total = min(block_ms + disp + host,
-                           max(wall - etl, block_ms))
+            return
+        block = (sync_end_ns - sync_start_ns) / 1e6
+        wall = (sync_end_ns - t0) / 1e6
+        device_total = min(block + ms["dispatch"] + ms["host"],
+                           max(wall - ms["etl"], block))
         per_step = device_total / steps
         with self._lock:
             self.windows += 1
             self._last_device_ms = per_step
         self._hist["device"].observe(per_step)
         self._g_device.set(per_step)
-        emit_manual_span("fit.attribution_window", w_ts, ts,
-                         steps=steps,
-                         etl_ms=round(etl, 3),
-                         dispatch_ms=round(disp, 3),
-                         host_ms=round(host, 3),
-                         block_ms=round(block_ms, 3),
-                         device_ms_per_step=round(per_step, 4))
 
     # ---------------------------------------------------------- reporting
     def last_device_step_ms(self) -> Optional[float]:
@@ -136,9 +124,3 @@ class StepAttribution:
         measured MFU denominator); None until a window has closed."""
         with self._lock:
             return self._last_device_ms
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return {"windows": self.windows,
-                    "last_device_step_ms": self._last_device_ms,
-                    "open_window_steps": self._steps}

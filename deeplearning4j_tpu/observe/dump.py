@@ -7,8 +7,9 @@
 Three inputs, auto-detected:
 - a registry snapshot (`MetricsRegistry.snapshot()` saved as JSON, or a
   BENCH_*.json blob embedding one under "registry") → aligned table;
-- a span/metric JSONL log (`SpanLog` / `export_jsonl`) → one formatted
-  line per event, `--tail N` for the last N;
+- a span/metric JSONL log (`SpanLog`, the `.spans.jsonl` that
+  `ProfilerListener` writes beside a device trace, `export_jsonl`) → one
+  formatted line per event, `--tail N` for the last N;
 - `--live` → the current process-wide registry (for use from a REPL or
   under `python -c`).
 
@@ -61,6 +62,9 @@ def format_span(ev: dict) -> str:
 def format_jsonl_line(ev: dict) -> str:
     if "dur_ms" in ev:                       # span event
         return format_span(ev)
+    if "span_clock" in ev:      # first line of a `.spans.jsonl` file
+        return "span_clock " + " ".join(
+            f"{k}={v}" for k, v in sorted(ev["span_clock"].items()))
     labels = ",".join(f"{k}={v}"
                       for k, v in sorted((ev.get("labels") or {}).items()))
     val = (f"count={ev.get('count')} sum={_fmt(ev.get('sum'))}"

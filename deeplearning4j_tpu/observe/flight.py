@@ -15,9 +15,10 @@ wrong:
   signal, captured with full context instead of one log line).
 
 Sources feeding the ring:
-- every `span()` / `emit_manual_span()` event (wired through
-  `trace._set_flight_sink`, so the ring fills even when no SpanLog is
-  installed — recording costs one deque append);
+- the span store of `observe/trace.py`: while the process-wide recorder
+  is enabled, spans are recorded with no SpanLog installed (wired through
+  `trace._set_flight_sink`), and `events()` / `dump()` read the newest
+  `capacity` of them out of that store. Nothing is copied per span;
 - watchdog compile + cost + threshold events;
 - device-memory samples from `observe.devicemon`;
 - serving dispatch errors.
@@ -104,6 +105,10 @@ class FlightRecorder:
                          or tempfile.gettempdir())
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=self.capacity)
+        # set by `_wire` on the process-wide recorder: the span store and
+        # the number of its first span that belongs to this recorder
+        self._span_store = None
+        self._span_from = 0
         self._seq = 0
         self._dump_seq = 0
         self.dumps: List[str] = []
@@ -116,8 +121,7 @@ class FlightRecorder:
         self.record_event(kind, _plain(payload))
 
     def record_event(self, kind: str, data: Dict[str, Any]) -> None:
-        """Fast path for pre-sanitized payloads (span events arrive here
-        already scrubbed by trace._sanitize)."""
+        """Fast path for pre-sanitized payloads."""
         if not self.enabled:
             return
         ev = {"kind": kind, "ts": round(time.time(), 6), "data": data}
@@ -127,15 +131,25 @@ class FlightRecorder:
             self._events.append(ev)
 
     # ---------------------------------------------------------- reporting
+    def _span_events(self) -> List[dict]:
+        store = self._span_store
+        if store is None or not self.enabled:
+            return []
+        since = max(self._span_from, store.count - self.capacity)
+        return [{"kind": "span", "ts": ev["ts"], "data": ev}
+                for ev in store.events(since)]
+
     def events(self) -> List[dict]:
+        """Ring events, then the newest spans of the span store."""
         with self._lock:
-            return list(self._events)
+            evs = list(self._events)
+        return evs + self._span_events()
 
     def snapshot(self) -> dict:
+        events = self.events()
         with self._lock:
             return {"enabled": self.enabled, "capacity": self.capacity,
-                    "recorded_total": self._seq,
-                    "events": list(self._events),
+                    "recorded_total": self._seq, "events": events,
                     "dumps": list(self.dumps)}
 
     # ------------------------------------------------------------ dumping
@@ -311,9 +325,14 @@ _install_lock = threading.Lock()
 
 
 def _wire(fr: Optional[FlightRecorder]) -> None:
-    """Point the span emitters at the ring (None detaches)."""
+    """Turn span recording on for an enabled recorder and let it read
+    the span store from here on (None detaches)."""
     from deeplearning4j_tpu.observe import trace
-    trace._set_flight_sink(fr if (fr is not None and fr.enabled) else None)
+    on = fr is not None and fr.enabled
+    if on:
+        store = trace.get_span_store()
+        fr._span_store, fr._span_from = store, store.count
+    trace._set_flight_sink(fr if on else None)
 
 
 def get_flight() -> FlightRecorder:

@@ -155,6 +155,7 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
             jax.ShapeDtypeStruct((bh, tq, _LSE_LANES), jnp.float32))
     out = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(bh, tq // block_q, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -311,6 +312,7 @@ def _run_flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
     kv_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, causal=causal, scale=scale),
+        name="flash_attention_bwd_dkdv",
         grid=(bh, tk // block_k, tq // block_q),
         in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
                   row_spec_t],
@@ -327,6 +329,7 @@ def _run_flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
     )(q, k, v, do, lse, delta)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale),
+        name="flash_attention_bwd_dq",
         grid=(bh, tq // block_q, tk // block_k),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,

@@ -198,6 +198,7 @@ def _run_banded(q, k, v, *, window: int, causal: bool, scale: float,
     o = pl.pallas_call(
         functools.partial(_banded_kernel, nk=nk, window=window,
                           causal=causal, scale=scale),
+        name="banded_attention_fwd",
         grid=(b * hkv, t // block_q, nkb),
         in_specs=[
             pl.BlockSpec((1, g, block_q, dh), lambda bb, i, j: (bb, 0, i, 0)),
@@ -463,6 +464,7 @@ def banded_decode_attention(q, cache_k, cache_v, qpos, end,
         functools.partial(_decode_kernel, cache_len=cache_len,
                           window=window, rolling=rolling, hkv=hkv,
                           scale=sc, quant=quant),
+        name="banded_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s_, cache_len // block_l),
@@ -620,6 +622,7 @@ def paged_decode_attention(q, cache_k, cache_v, page_table, qpos,
     return pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_len=page_len,
                           window=window, hkv=hkv, scale=sc, quant=quant),
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s_, npg),

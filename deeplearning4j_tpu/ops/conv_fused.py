@@ -112,6 +112,7 @@ def matmul_with_channel_stats(x2d, w, *, interpret: bool = False):
     nm, nn, nk = m // bm, n // bn, k // bk
     y, ps, pq = pl.pallas_call(
         functools.partial(_mm_stats_kernel, acc_dtype=acc),
+        name="matmul_channel_stats",
         grid=(nm, nn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -219,6 +220,7 @@ def conv3x3_with_channel_stats(x, w, *, interpret: bool = False):
     nm, nn = b // nb, cout // bn
     y, ps, pq = pl.pallas_call(
         functools.partial(_conv3_stats_kernel, acc_dtype=acc),
+        name="conv3x3_channel_stats",
         grid=(nm, nn),
         in_specs=[
             pl.BlockSpec((nb, h, wd, cin), lambda i, j: (i, 0, 0, 0)),

@@ -94,7 +94,7 @@ def _geometry(n: int, block_rows: int):
     return rows, block
 
 
-def _run(kernel, coeff, arrays, out_dtypes, *, block_rows: int,
+def _run(kernel, name, coeff, arrays, out_dtypes, *, block_rows: int,
          interpret: bool):
     """Shared driver: tile leaves to [rows, 128], sweep row blocks, alias
     every state input onto its output slot (inputs after the coefficient
@@ -113,6 +113,7 @@ def _run(kernel, coeff, arrays, out_dtypes, *, block_rows: int,
         aliases[idx] = idx - 2
     out = pl.pallas_call(
         kernel,
+        name=name,
         grid=(rows // block,),
         in_specs=[pl.BlockSpec((1, _LANES), lambda i: (0, 0))]
         + [row_spec] * len(tiles),
@@ -136,7 +137,7 @@ def adam_update(p, g, m, v, lrbc, *, beta1: float = 0.9,
     Returns (p', m', v') in the argument dtypes."""
     return _run(functools.partial(_adam_kernel, b1=beta1, b2=beta2,
                                   eps=eps),
-                lrbc, [p, g, m, v], [p.dtype, m.dtype, v.dtype],
+                "fused_adam_update", lrbc, [p, g, m, v], [p.dtype, m.dtype, v.dtype],
                 block_rows=block_rows, interpret=interpret)
 
 
@@ -144,5 +145,5 @@ def nesterov_update(p, g, vel, lr, *, momentum: float = 0.9,
                     block_rows: int = 512, interpret: bool = False):
     """One-leaf fused Nesterovs step; returns (p', v')."""
     return _run(functools.partial(_nesterov_kernel, mu=momentum),
-                lr, [p, g, vel], [p.dtype, vel.dtype],
+                "fused_nesterov_update", lr, [p, g, vel], [p.dtype, vel.dtype],
                 block_rows=block_rows, interpret=interpret)

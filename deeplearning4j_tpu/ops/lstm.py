@@ -141,6 +141,7 @@ def _run_forward(xw, rw, p, h0, c0, mask, *, interpret: bool,
     ] if with_residuals else []
     out = pl.pallas_call(
         _fwd_kernel if with_residuals else _fwd_kernel_inference,
+        name="lstm_fwd" if with_residuals else "lstm_fwd_inference",
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, B, H4), lambda t: (t, 0, 0)),
@@ -262,6 +263,7 @@ def _run_backward(res, dhs, dhT, dcT, *, interpret: bool):
     )
     return pl.pallas_call(
         _bwd_kernel,
+        name="lstm_bwd",
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, B, H), rev),       # dhs

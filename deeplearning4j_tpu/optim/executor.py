@@ -28,13 +28,13 @@ round-trips per step):
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, List, Optional
 
 from deeplearning4j_tpu.observe import get_registry, reqtrace, span
 from deeplearning4j_tpu.observe.attribution import (
     StepAttribution, attribution_enabled,
 )
+from deeplearning4j_tpu.observe.trace import flush_span_log, get_span_store
 from deeplearning4j_tpu.observe.commsmon import get_reshard_witness
 from deeplearning4j_tpu.observe.devicemon import maybe_start_monitor
 from deeplearning4j_tpu.observe.flight import get_flight
@@ -45,6 +45,7 @@ __all__ = ["LossTracker", "TrainingExecutor", "SKIP", "STOP"]
 # before_batch sentinels: skip this batch (resume replay) / stop cleanly
 SKIP = object()
 STOP = object()
+_END = object()     # the iterator is exhausted
 
 
 def _is_device_array(x) -> bool:
@@ -73,10 +74,6 @@ class LossTracker:
         self._since_sync = 0
         self.host_syncs = 0     # device materializations (perf-guard seam)
         self.updates = 0
-        # attribution seam: fn(block_ms) invoked after a device loss
-        # materializes, with how long the float() blocked — THE measured
-        # device boundary StepAttribution infers device time from
-        self.on_block: Optional[Callable[[float], None]] = None
 
     def set(self, loss) -> None:
         """Overwrite the tracked loss without counting an update (the
@@ -97,18 +94,9 @@ class LossTracker:
         if self._raw is None:
             return None
         if self._cached is None:
-            blocked = _is_device_array(self._raw)
-            if blocked:
+            if _is_device_array(self._raw):
                 self.host_syncs += 1
-            t0 = time.perf_counter()
             self._cached = float(self._raw)
-            if blocked and self.on_block is not None:
-                try:
-                    self.on_block((time.perf_counter() - t0) * 1e3)
-                # graft: allow(GL403): attribution must never break the
-                # fit loop; the loss value below is the payload
-                except Exception:
-                    pass
             self._since_sync = 0
         return self._cached
 
@@ -142,8 +130,15 @@ class TrainingExecutor:
     """The shared epoch/batch/listener loop with async-dispatch semantics.
 
     The model (or parallel trainer) supplies the step callables; the
-    executor owns iteration bookkeeping, the fused-dispatch buffer, ETL
-    timing, listener fan-out, and the epoch-end materialization.
+    executor owns iteration bookkeeping, the fused-dispatch buffer,
+    listener fan-out, and the epoch-end materialization. Each segment of
+    a step is timed once, by its span (`observe/trace.py`): `fit.etl`
+    (the wait for the next batch; the prefetch iterator's `data.put`
+    spans are its children), `fit.dispatch`, `fit.listeners`, and once an
+    epoch `fit.epoch_sync`. The `train_etl_ms` / `train_dispatch_ms`
+    histograms take their values from those spans' own clock reads;
+    `StepAttribution` and the sampled `train.epoch` request trace read
+    the span store when an epoch ends.
 
     Hooks:
       step(ds) -> loss                one training step (device loss)
@@ -187,17 +182,12 @@ class TrainingExecutor:
         self.epoch_start = epoch_start
         self.epoch_end = epoch_end
         self.stopped = False
-        self._attr: Optional[StepAttribution] = None
-        # per-epoch request trace (reqtrace) — None when sampling is off,
-        # so the hot loop pays one attribute read per dispatch window
+        # per-epoch request trace (reqtrace): None when sampling is off
         self._rt = None
+        self._rt_from = 0       # the span store's count at the epoch's start
         # commsmon reshard witness — None when DL4J_TPU_COMMSMON is off,
         # so the disabled hot loop pays one attribute read per dispatch
         self._reshard = get_reshard_witness()
-        reg = get_registry()
-        self._iter_counter = reg.counter("train_iterations")
-        self._etl_hist = reg.histogram("train_etl_ms")
-        self._dispatch_hist = reg.histogram("train_dispatch_ms")
 
     # ------------------------------------------------------------- loop
     def run(self, iterable, epochs: int, *, start_epoch: int = 0):
@@ -212,25 +202,21 @@ class TrainingExecutor:
     def _run(self, iterable, epochs: int, *, start_epoch: int = 0):
         net = self.net
         listeners = net.listeners
-        # registry handles cached once per run; _finish only bumps them.
-        # Spans carry only host-side scalars — never the device loss.
+        # registry handles bound once per run, to the registry active at
+        # fit start; _finish only bumps them
         reg = get_registry()
         self._iter_counter = reg.counter("train_iterations")
         self._etl_hist = reg.histogram("train_etl_ms")
         self._dispatch_hist = reg.histogram("train_dispatch_ms")
-        # black box + device telemetry: wire the span ring before the
-        # first fit span so a crash dump carries this run from the start
+        # black box + device telemetry: the flight recorder turns span
+        # recording on, so a crash dump carries this run from the start
         flight = get_flight()
         maybe_start_monitor()
-        tracker = getattr(net, "_loss_tracker", None)
         attr = None
-        if attribution_enabled() and tracker is not None:
-            attr = StepAttribution(reg)
-            # PerformanceListener reads the measured device step time
+        if attribution_enabled() and hasattr(net, "_loss_tracker"):
+            # PerformanceListener reads the inferred device step time
             # (MFU denominator) from here
-            net._attribution = attr
-            tracker.on_block = attr.on_device_block
-        self._attr = attr
+            attr = net._attribution = StepAttribution(reg)
         try:
             with span("fit", epochs=epochs, start_epoch=start_epoch,
                       steps_per_dispatch=self.k):
@@ -239,67 +225,15 @@ class TrainingExecutor:
                 self.stopped = False
                 for _ in range(start_epoch, epochs):
                     ep = net.epoch
-                    # one sampled trace per epoch: dispatch windows hang
-                    # off this root (trace ids key on (epoch, window))
+                    # one sampled trace per epoch, built from the epoch's
+                    # fit.dispatch spans when it ends
                     self._rt = reqtrace.new_trace("train.epoch")
-                    with span("fit.epoch", epoch=net.epoch):
-                        if self.epoch_start is not None:
-                            self.epoch_start()
-                        for l in listeners:
-                            l.on_epoch_start(net, net.epoch)
-                        buf: List = []
-                        etl_start = time.perf_counter()
-                        for bi, ds in enumerate(iter(iterable)):
-                            etl_ms = (time.perf_counter() - etl_start) * 1e3
-                            if self.before_batch is not None:
-                                ds = self.before_batch(bi, ds)
-                                if ds is SKIP:
-                                    etl_start = time.perf_counter()
-                                    continue
-                                if ds is STOP:
-                                    self.stopped = True
-                                    break
-                            fusible = (self.k > 1
-                                       and self.fused_step is not None
-                                       and self.can_fuse(ds))
-                            if fusible and buf and \
-                                    batch_signature(buf[0][1]) != \
-                                    batch_signature(ds):
-                                self._drain(buf)
-                                buf = []
-                            if fusible:
-                                buf.append((bi, ds, etl_ms))
-                                if len(buf) == self.k:
-                                    self._run_fused(buf)
-                                    buf = []
-                            else:
-                                self._drain(buf)
-                                buf = []
-                                if self._reshard is not None:
-                                    self._witness_batch(ds)
-                                t_d = time.perf_counter()
-                                loss = self.step(ds)
-                                dispatch_ms = (time.perf_counter()
-                                               - t_d) * 1e3
-                                self._trace_window(bi, bi, dispatch_ms)
-                                self._finish(bi, loss, etl_ms, dispatch_ms)
-                                if self.after_dispatch is not None:
-                                    self.after_dispatch(bi)
-                            etl_start = time.perf_counter()
-                        self._drain(buf)
-                        if self.stopped:
-                            self._finish_epoch_trace(ep, stopped=True)
-                            break
-                        for l in listeners:
-                            l.on_epoch_end(net, net.epoch)
-                        net.epoch += 1
-                        if self.epoch_end is not None:
-                            self.epoch_end()
-                        # the ONE guaranteed materialization per epoch:
-                        # score_ is a float at every epoch boundary
-                        # without per-step syncs — and the block boundary
-                        # attribution infers device time from
-                        net._loss_tracker.materialize()
+                    self._rt_from = get_span_store().count
+                    with span("fit.epoch", epoch=ep):
+                        self._run_epoch(iterable, attr)
+                    if self.stopped:
+                        self._finish_epoch_trace(ep, stopped=True)
+                        break
                     self._finish_epoch_trace(ep)
                 for l in listeners:
                     l.on_fit_end(net)
@@ -312,40 +246,97 @@ class TrainingExecutor:
             flight.dump("training_exception", exc=e)
             raise
         finally:
-            if tracker is not None:
-                tracker.on_block = None
+            flush_span_log()
         return net
+
+    def _run_epoch(self, iterable, attr) -> None:
+        net = self.net
+        listeners = net.listeners
+        if self.epoch_start is not None:
+            self.epoch_start()
+        for l in listeners:
+            l.on_epoch_start(net, net.epoch)
+        buf: List = []
+        it = iter(iterable)
+        bi = -1
+        while True:
+            # the wait for the next batch; `etl` keeps its two clock
+            # reads, which also feed train_etl_ms
+            etl = span("fit.etl")
+            with etl:
+                ds = next(it, _END)
+                if ds is _END:
+                    etl.attrs["exhausted"] = True
+            if ds is _END:
+                break
+            bi += 1
+            if self.before_batch is not None:
+                ds = self.before_batch(bi, ds)
+                if ds is SKIP:
+                    continue
+                if ds is STOP:
+                    self.stopped = True
+                    break
+            fusible = (self.k > 1 and self.fused_step is not None
+                       and self.can_fuse(ds))
+            if fusible and buf and \
+                    batch_signature(buf[0][1]) != batch_signature(ds):
+                self._drain(buf)
+                buf = []
+            if fusible:
+                buf.append((bi, ds, etl.dur_ms))
+                if len(buf) == self.k:
+                    self._run_fused(buf)
+                    buf = []
+            else:
+                self._drain(buf)
+                buf = []
+                self._run_one(bi, ds, etl.dur_ms)
+        self._drain(buf)
+        if self.stopped:
+            return
+        for l in listeners:
+            l.on_epoch_end(net, net.epoch)
+        net.epoch += 1
+        if self.epoch_end is not None:
+            self.epoch_end()
+        # the ONE guaranteed materialization per epoch: score_ is a float
+        # at every epoch boundary without per-step syncs. Its span is the
+        # block boundary attribution infers device time from.
+        sync = span("fit.epoch_sync")
+        with sync:
+            net._loss_tracker.materialize()
+        if attr is not None:
+            attr.close_window(sync.start_ns, sync.end_ns)
 
     # ---------------------------------------------------------- helpers
     def _finish_epoch_trace(self, epoch: int, **attrs) -> None:
-        """Close the per-epoch trace root (None-safe; resets _rt)."""
+        """Close the per-epoch request trace (None-safe; resets _rt): one
+        `train.dispatch` span keyed (epoch, step-window) per `fit.dispatch`
+        span the store holds of this epoch, under the root. A window's
+        duration is the host ENQUEUE time, never a device wait. When the
+        comm ledger has priced this owner's compiled programs, each
+        window also carries the owner-level collective totals (comm_ops /
+        comm_bytes): host-side metadata from the watchdog."""
         rt, self._rt = self._rt, None
-        reqtrace.finish_root(rt, epoch=epoch, iteration=self.net.iteration,
-                             steps_per_dispatch=self.k, **attrs)
-
-    def _trace_window(self, bi_lo: int, bi_hi: int, dur_ms: float,
-                      fused: bool = False) -> None:
-        """Record one train.dispatch span keyed (epoch, step-window).
-
-        dur_ms is the host ENQUEUE time for the window — never a device
-        wait, so the span machinery stays sync-free. When the comm
-        ledger has priced this owner's compiled programs, the span also
-        carries the owner-level collective totals (comm_ops /
-        comm_bytes) — host-side metadata from the watchdog, never a
-        device read."""
-        rt = self._rt
         if rt is None:
             return
-        ep = self.net.epoch
-        attrs = dict(dur_ms=dur_ms, epoch=ep,
-                     window=f"{ep}:{bi_lo}-{bi_hi}",
-                     steps=bi_hi - bi_lo + 1, fused=fused)
+        store = get_span_store()
         comm = self._comm_totals()
-        if comm is not None:
-            attrs["comm_ops"] = comm["ops"]
-            attrs["comm_bytes"] = comm["wire_bytes"]
-        reqtrace.record_span(
-            rt.trace_id, "train.dispatch", parent_id=rt.span_id, **attrs)
+        for ev in store.events(self._rt_from):
+            if ev["name"] != "fit.dispatch":
+                continue
+            a = ev["attrs"]
+            lo, hi = a["batch"], a["batch"] + a["steps"] - 1
+            extra = {} if comm is None else {
+                "comm_ops": comm["ops"], "comm_bytes": comm["wire_bytes"]}
+            reqtrace.record_span(
+                rt.trace_id, "train.dispatch", parent_id=rt.span_id,
+                ts=ev["ts"], dur_ms=ev["dur_ms"], epoch=epoch,
+                window=f"{epoch}:{lo}-{hi}", steps=a["steps"],
+                fused=a["fused"], **extra)
+        reqtrace.finish_root(rt, epoch=epoch, iteration=self.net.iteration,
+                             steps_per_dispatch=self.k, **attrs)
 
     def _comm_totals(self) -> Optional[dict]:
         """Owner-level compiled-collective totals for the net's active
@@ -379,29 +370,33 @@ class TrainingExecutor:
                 named[field] = (v, lambda leaf: spec(leaf.ndim))
         check_dispatch_args(owner, named, witness=self._reshard)
 
+    def _run_one(self, bi, ds, etl_ms) -> None:
+        """One batch through the per-step path."""
+        if self._reshard is not None:
+            self._witness_batch(ds)
+        disp = span("fit.dispatch", iteration=self.net.iteration, batch=bi,
+                    steps=1, fused=False)
+        with disp:
+            loss = self.step(ds)
+        self._finish(bi, loss, etl_ms, disp.dur_ms)
+        if self.after_dispatch is not None:
+            self.after_dispatch(bi)
+
     def _drain(self, buf) -> None:
         """Flush a partial fusion buffer through the per-step path (a
         short tail would need its own K'-sized compile)."""
         for bi, ds, etl_ms in buf:
-            if self._reshard is not None:
-                self._witness_batch(ds)
-            t_d = time.perf_counter()
-            loss = self.step(ds)
-            dispatch_ms = (time.perf_counter() - t_d) * 1e3
-            self._trace_window(bi, bi, dispatch_ms)
-            self._finish(bi, loss, etl_ms, dispatch_ms)
-            if self.after_dispatch is not None:
-                self.after_dispatch(bi)
+            self._run_one(bi, ds, etl_ms)
 
     def _run_fused(self, buf) -> None:
         if self._reshard is not None:
             self._witness_batch(buf[0][1])
-        t_d = time.perf_counter()
-        losses = self.fused_step([ds for _, ds, _ in buf])
+        disp = span("fit.dispatch", iteration=self.net.iteration,
+                    batch=buf[0][0], steps=len(buf), fused=True)
+        with disp:
+            losses = self.fused_step([ds for _, ds, _ in buf])
         # one dispatch for K steps: attribute its enqueue cost evenly
-        dispatch_ms = (time.perf_counter() - t_d) * 1e3 / len(buf)
-        self._trace_window(buf[0][0], buf[-1][0],
-                           dispatch_ms * len(buf), fused=True)
+        dispatch_ms = disp.dur_ms / len(buf)
         for j, (bi, ds, etl_ms) in enumerate(buf):
             # losses[j] stays on device — indexing does not sync
             self._finish(bi, losses[j], etl_ms, dispatch_ms)
@@ -419,15 +414,11 @@ class TrainingExecutor:
         # host-side dispatch wall time per step: the training-side
         # series the sampler turns into train_dispatch_ms:p99
         self._dispatch_hist.observe(dispatch_ms)
-        t_h = time.perf_counter()
-        for l in net.listeners:
-            if hasattr(l, "set_etl_time"):
-                l.set_etl_time(etl_ms)
-            l.iteration_done(net, net.iteration, net.epoch,
-                             net._loss_tracker.peek())
-        if self.after_step is not None:
-            self.after_step(bi)
-        attr = self._attr
-        if attr is not None:
-            host_ms = (time.perf_counter() - t_h) * 1e3
-            attr.record_iteration(etl_ms, dispatch_ms, host_ms)
+        with span("fit.listeners"):
+            for l in net.listeners:
+                if hasattr(l, "set_etl_time"):
+                    l.set_etl_time(etl_ms)
+                l.iteration_done(net, net.iteration, net.epoch,
+                                 net._loss_tracker.peek())
+            if self.after_step is not None:
+                self.after_step(bi)

@@ -19,12 +19,21 @@ DEFAULT_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 def enable_compile_cache() -> str:
     """Return the cache directory in effect. Where
-    `JAX_COMPILATION_CACHE_DIR` is set JAX reads it itself and nothing is
-    set in code; otherwise the cache goes to `<checkout>/.jax_cache`."""
+    `JAX_COMPILATION_CACHE_DIR` is set JAX reads it itself and no
+    directory is set in code; otherwise the cache goes to
+    `<checkout>/.jax_cache`.
+
+    Debug locations are made part of the cache key. JAX 0.9.0 leaves them
+    out, so a program whose ops carry layer names (`jax.named_scope` in
+    the models' `_forward`) fetched the executable of the same program
+    compiled without them, and a device trace then named no layer (chip
+    run, PR 25). The price: an edit that moves lines in a traced file
+    makes the next run compile again."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR

@@ -410,19 +410,32 @@ class TestCompileCostProbe:
 # ---------------------------------------------------------- attribution
 class TestStepAttribution:
     def test_window_math_and_zero_step_skip(self, fresh_registry):
-        attr = StepAttribution(fresh_registry)
-        attr.record_iteration(etl_ms=1.0, dispatch_ms=2.0, host_ms=3.0)
-        attr.record_iteration(etl_ms=1.0, dispatch_ms=2.0, host_ms=3.0)
-        attr.on_device_block(block_ms=10.0)
+        # the reader sums the window's spans out of a store: two steps of
+        # etl 1 ms, dispatch 2 ms, listeners 3 ms, then a 10 ms sync
+        import threading
+        from deeplearning4j_tpu.observe.trace import SpanStore
+
+        store, me, ms = SpanStore(64), threading.current_thread().name, 10**6
+        attr = StepAttribution(fresh_registry, store)
+        t = attr._t0
+        for _ in range(2):
+            for name, dur in (("fit.etl", 1), ("fit.dispatch", 2),
+                              ("fit.listeners", 3)):
+                store.add((1, None, name, t, t + dur * ms, me,
+                           {"steps": 1} if name == "fit.dispatch" else {}))
+                t += dur * ms
+        store.add((1, None, "fit.etl", t, t, "another-thread", {}))
+        attr.close_window(t, t + 10 * ms)
         assert attr.windows == 1
         dev = attr.last_device_step_ms()
-        assert dev is not None and dev > 0
-        # device_total <= block + dispatch + host, split over 2 steps
-        assert dev <= (10.0 + 4.0 + 6.0) / 2 + 1e-6
-        # a re-read between windows (no steps) must not emit a window
-        attr.on_device_block(block_ms=5.0)
+        # min(block + dispatch + host, wall - etl) = min(20, 22 - 2) over 2
+        assert dev == pytest.approx(10.0, abs=0.5)
+        assert fresh_registry.histogram(
+            "train_step_attribution_ms", segment="host").sum == \
+            pytest.approx(6.0)
+        # a second sync with no steps since must not close a window
+        attr.close_window(t + 10 * ms, t + 15 * ms)
         assert attr.windows == 1
-        assert attr.snapshot()["open_window_steps"] == 0
 
     def test_fit_publishes_attribution_metrics(self, fresh_registry,
                                                fresh_flight):
@@ -439,10 +452,11 @@ class TestStepAttribution:
         segs = {m["labels"]["segment"]
                 for m in series["train_step_attribution_ms"]}
         assert segs == {"etl", "dispatch", "host", "device"}
-        # the window span reached the flight ring
-        assert any(e["kind"] == "span"
-                   and e["data"].get("name") == "fit.attribution_window"
-                   for e in fresh_flight.events())
+        # the block boundary it reads reached the flight ring: one
+        # fit.epoch_sync span an epoch
+        assert sum(e["kind"] == "span"
+                   and e["data"].get("name") == "fit.epoch_sync"
+                   for e in fresh_flight.events()) == 2
 
     def test_attribution_env_kill_switch(self, fresh_registry,
                                          monkeypatch):

@@ -9,6 +9,7 @@ import json
 import logging
 import re
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -299,11 +300,16 @@ class TestSpans:
         path = str(tmp_path / "spans.jsonl")
         observe.install_span_log(path)
         try:
-            observe.emit_manual_span("window", 100.0, 100.25, tag="t")
+            # bounds read elsewhere, on the span clock (nanoseconds)
+            t0 = time.perf_counter_ns()
+            observe.emit_manual_span("window", t0, t0 + 250_000_000,
+                                     tag="t")
         finally:
             observe.uninstall_span_log()
         (ev,) = read_spans(path)
-        assert ev["ts"] == 100.0 and ev["dur_ms"] == pytest.approx(250.0)
+        assert ev["start_ns"] == t0 and ev["dur_ms"] == pytest.approx(250.0)
+        assert abs(ev["ts"] - time.time()) < 5.0    # dated by the anchor
+        assert ev["attrs"] == {"tag": "t"}
 
     def test_spanlog_threads_never_interleave(self, tmp_path):
         path = str(tmp_path / "spans.jsonl")

@@ -147,3 +147,54 @@ def test_kernel_compiles_for_v5e(chip, name):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- every `pl.pallas_call` site names its kernel: the device trace and
+# its reduction find a kernel by that name after a refactor. Lowered for
+# the TPU platform (Mosaic runs at lowering), which needs no chip and no
+# described topology; nothing is compiled.
+conv_fused = importlib.import_module("deeplearning4j_tpu.ops.conv_fused")
+
+
+def _nesterov(shape=(512, 2048), dt=BF16):
+    def fwd(p, g, v, lr):
+        return update.nesterov_update(p, g, v, lr, interpret=False)
+
+    return fwd, [(shape, dt)] * 3 + [((), F32)]
+
+
+def _matmul_stats(m=1024, k=512, n=256):
+    return (lambda x, w: conv_fused.matmul_with_channel_stats(x, w),
+            [((m, k), BF16), ((k, n), BF16)])
+
+
+def _conv3_stats(b=8, hw=56, c=64):
+    return (lambda x, w: conv_fused.conv3x3_with_channel_stats(x, w),
+            [((b, hw, hw, c), BF16), ((3, 3, c, c), BF16)])
+
+
+KERNEL_NAMES = {
+    "lstm_fwd": "lstm_train_f32", "lstm_bwd": "lstm_train_f32",
+    "lstm_fwd_inference": "lstm_fwd_f32",
+    "flash_attention_fwd": "flash_fwd",
+    "flash_attention_bwd_dkdv": "flash_pallas_bwd",
+    "flash_attention_bwd_dq": "flash_pallas_bwd",
+    "banded_attention_fwd": "banded_fwd_gqa",
+    "banded_decode_attention": "slot_decode_bf16",
+    "paged_decode_attention": "paged_decode_bf16",
+    "fused_adam_update": "fused_adam_bf16",
+    "fused_nesterov_update": _nesterov,
+    "matmul_channel_stats": _matmul_stats,
+    "conv3x3_channel_stats": _conv3_stats,
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_NAMES))
+def test_pallas_site_names_its_kernel(kernel):
+    case = KERNEL_NAMES[kernel]
+    fn, shapes = (CASES[case] if isinstance(case, str) else case)()
+    with jax.enable_x64(False):
+        args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert f'kernel_name = "{kernel}"' in text
