@@ -25,7 +25,7 @@ import sys
 import time
 
 from benchmarks import (
-    compare, harness, trace_reduce, traffic_gen, xplane_schema,
+    compare, harness, span_reduce, trace_reduce, traffic_gen, xplane_schema,
 )
 
 
@@ -115,6 +115,12 @@ class FirstSteps(harness.Listener):
             self.delta = self._delta_norms(model.params_tree)
 
 
+def dl4j_trace_beacon(v):
+    """The clock link's program: jitted it is `trace_reduce.BEACON` in the
+    trace's `XLA Modules` line, and does no work to speak of."""
+    return v + 1
+
+
 class TraceWindow(harness.Listener):
     """The profiler over the window's first `seconds`. It is started just
     BEFORE the window (starting it stalls the host for a second or two,
@@ -122,22 +128,45 @@ class TraceWindow(harness.Listener):
     70% idle: chip runs, PR 24) and stopped from the fit loop's own thread.
     Device ops only. With the host tracer on, even at its lowest level,
     the runtime's per-chunk events of the host-side layout change of every
-    batch made a 330 MB trace and a dispatch of 0.4 s (chip run, PR 24)."""
+    batch made a 330 MB trace and a dispatch of 0.4 s (chip run, PR 24).
+
+    A device plane counts from the moment its tracer started, a
+    millisecond or two into opening the session, which no host clock read
+    gives (chip run, PR 25). So five beacons tie the trace's zero to the
+    program's span clock, `time.perf_counter_ns()`: runs of a trivial
+    program, each blocked on between two reads of that clock, three once
+    the session is open and two before it stops (the first of those drains
+    the device's queue, the second finds it idle). `span_reduce.clock_link`
+    reads `beacons_ns` against the runs' events. (After the program's
+    `utils/profiling.DeviceTrace`, which writes files; this keeps the
+    trace in memory.) Traced runs only: no untraced run pays for it."""
 
     def __init__(self, stream, seconds):
         self.stream, self.seconds = stream, seconds
         self.session, self.xspace, self.stop_s = None, None, None
+        self.beacons_ns = []
+
+    def _beacon(self, runs: int):
+        for _ in range(runs):
+            t0 = time.perf_counter_ns()
+            self._program(self._one).block_until_ready()
+            self.beacons_ns.append([t0, time.perf_counter_ns()])
 
     def start(self):
         import jax
+        import jax.numpy as jnp
         from jax._src.lib import _profiler
 
         jax.devices()           # the backend before the tracer, as JAX does
+        self._program = jax.jit(dl4j_trace_beacon)
+        self._one = jnp.zeros((8, 128), jnp.float32)
+        self._program(self._one).block_until_ready()   # compiled out here
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 0
         options.enable_hlo_proto = False
         self.session = _profiler.ProfilerSession(options)
+        self._beacon(3)
 
     def iteration_done(self, model, iteration, epoch, score):
         if self.session is not None and self.stream.t_first is not None \
@@ -146,6 +175,7 @@ class TraceWindow(harness.Listener):
 
     def stop(self):
         if self.session is not None:
+            self._beacon(2)
             t0 = time.perf_counter()
             self.xspace, self.session = self.session.stop(), None
             self.stop_s = time.perf_counter() - t0
@@ -333,6 +363,8 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
         "forward_macs_per_item": ready["forward_macs_per_item"],
         "setup_phases_s": phases,
         "item": config["item"], "peaks": peaks,
+        **({"tokens_per_item": config["tokens_per_item"]}
+           if "tokens_per_item" in config else {}),
         "device_kind": device["kind"], "platform": device["platform"],
         "memory_peak_bytes": device["memory_peak_bytes"],
         "memory_stats": {k: int(v) for k, v in
@@ -351,22 +383,34 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
                                             "unit": m["unit"]}
         return result
 
-    reduction = None
+    devices = [d.id for d in used]
+    space = reduction = clock = scopes = None
     if tracer.xspace:
         space = xplane_schema.xspace_class()()
         space.ParseFromString(tracer.xspace)
         reduction = trace_reduce.reduce_space(
-            space, devices=[d.id for d in used],
+            space, devices=devices,
             skip_s=float(traffic.get("trace_skip_s", 0.0)))
+        clock = span_reduce.clock_link(space, tracer.beacons_ns)
+        scopes = span_reduce.by_scope(space, devices)
         harness.say("trace", bytes=len(tracer.xspace),
-                    stop_s=tracer.stop_s, **{
+                    stop_s=tracer.stop_s, clock=clock, **{
                         k: reduction[k] for k in (
                             "window_s", "busy_s", "idle_share_worst",
-                            "category_s", "main_module", "main_module_runs_per_s")
+                            "category_s", "main_module", "main_module_runs",
+                            "main_module_runs_per_s", "main_module_period_ms")
                         if reduction is not None},
                     lines=trace_reduce.line_counts(space))
+    # What a reader gets: `trace`, the reduction (None where no op ran on
+    # a TPU); `xspace`, the parsed trace itself; `spans`, the subtree of
+    # the window's `fit` root out of the program's span store; `clock`,
+    # where the trace's zero lies on the spans' clock; `scopes`, device op
+    # time by `jax.named_scope`, summed once for every reader; `registry`
+    # and `run` as before.
     facts = {
-        "trace": reduction,
+        "trace": reduction, "xspace": space, "clock": clock,
+        "scopes": scopes,
+        "spans": span_reduce.window(span_reduce.program_spans()),
         "registry": {k: {"count": hist_after[k][0] - hist_before[k][0],
                          "sum": hist_after[k][1] - hist_before[k][1]}
                      for k in hist_after},
@@ -381,5 +425,31 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
     if reduction is not None:
         result["device"]["busy_s"] = reduction["busy_s"]
         result["device"]["window_s"] = reduction["window_s"]
-        result["breakdown"] = reduction["breakdown"]
+        result["breakdown"] = dict(
+            reduction["breakdown"],
+            **_by_layer_and_span(space, devices, facts, peaks))
     return result
+
+
+def _by_layer_and_span(space, devices, facts, peaks) -> dict:
+    """`layers`: the ten scopes with most device time, as
+    `span_reduce.layers` gives them (ms a step forward and backward,
+    achieved TFLOP/s and HBM GB/s, the nearer roof and the share of it).
+    `gaps`: the first chip's idle seconds since the window's `fit` span
+    began, by the innermost host span that covers each gap, where the
+    clocks are linked."""
+    _, runs = span_reduce.main_module(space, devices)
+    out = {"layers": span_reduce.layers(facts["scopes"], len(runs),
+                                        len(devices), peaks)}
+    fit = next((s for s in facts["spans"] if s["name"] == "fit"), None)
+    if facts["clock"] is not None and fit is not None:
+        # from the window's start on: the opening beacons ran before it
+        zero = facts["clock"]["zero_ns"]
+        start_ps = (fit["start_ns"] - zero) * 1000.0
+        gaps = span_reduce.gaps_by_span(
+            [[max(a, start_ps), b]
+             for a, b in span_reduce.idle_gaps(space, devices)
+             if b > start_ps], facts["spans"], zero)
+        out["gaps"] = [[name, row["s"]] for name, row in gaps.items()][
+            :span_reduce.TOP]
+    return out

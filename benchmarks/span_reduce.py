@@ -50,7 +50,7 @@ if __name__ == "__main__":
 
 from benchmarks import trace_reduce  # noqa: E402
 
-BEACON = "jit_dl4j_trace_beacon"
+BEACON = trace_reduce.BEACON
 ASYNC_COPY = ("copy-start", "copy-done")
 TOP = 10
 _WRAPPED = re.compile(r"^(?:transpose\()?jvp\((.*?)\)+$")
@@ -189,15 +189,22 @@ def clock_link(space, beacons_ns):
 
 
 # ------------------------------------------------------------- device ops
+def _scope_parts(tf_op: str):
+    """The parts of a `tf_op` below the jitted function (and a fused
+    scan's `while/body`): scopes, then the primitive."""
+    parts = [p for p in tf_op.rstrip(":").split("/") if p]
+    while parts and (parts[0].startswith(("jit(", "pjit("))
+                     or parts[0] in ("while", "body", "cond")):
+        parts = parts[1:]
+    return parts
+
+
 def parse_scope(tf_op: str):
     """(scope, direction) of an op's `tf_op`: the first `named_scope`
     under the jitted function, with `loss` kept beside an output layer's
     name, and 'backward' for ops of the transposed pass. (None, None) for
     an op outside every scope."""
-    parts = [p for p in tf_op.rstrip(":").split("/") if p]
-    while parts and (parts[0].startswith(("jit(", "pjit("))
-                     or parts[0] in ("while", "body", "cond")):
-        parts = parts[1:]           # the jitted function, a fused scan
+    parts = _scope_parts(tf_op)
     if len(parts) < 2:              # only the primitive is left
         return None, None
     first, direction = parts[0], "forward"
@@ -213,6 +220,22 @@ def parse_scope(tf_op: str):
     if len(parts) > 2 and parts[1] == "loss":
         first += "/loss"
     return first, direction
+
+
+def inner_scopes(tf_op: str):
+    """The `named_scope`s of an op's `tf_op` below its first one, outermost
+    first: where a `pl.pallas_call`'s `name=` lands (JAX puts it on the
+    name stack), as in `jit(step)/jvp(lstm1)/lstm_cell_fwd/pallas_call:`.
+    Transforms are unwrapped as `parse_scope` unwraps them; `loss` stays
+    with its output layer's name."""
+    out = []
+    for part in _scope_parts(tf_op)[1:-1]:
+        wrapped = _WRAPPED.match(part)
+        part = wrapped.group(1) if wrapped else part
+        if part and "(" not in part and part not in (
+                "loss", "while", "body", "cond"):
+            out.append(part)
+    return out
 
 
 def _varint(raw: bytes, i: int):
@@ -266,6 +289,8 @@ def scoped_events(plane):
                     or md.name.split(" = ")[0].lstrip("%"),
                     "category": str(stats.get("hlo_category", "")).lower(),
                     "scope": scope, "direction": direction,
+                    "inner": inner_scopes(str(stats.get("tf_op", "")))
+                    if scope else [],
                     "flops": float(stats.get("flops") or 0),
                     "bytes": float(stats.get("bytes_accessed") or 0),
                     "hbm_bytes": hbm_bytes(
@@ -307,29 +332,67 @@ def by_scope(space, devices=None):
     ops outside every scope, with their seconds by op name under `ops`;
     beacon runs are left out. An asynchronous copy (`copy-start`,
     `copy-done`) gives its time and not its bytes: the copy ran beside
-    other ops, and the event is the wait for it."""
+    other ops, and the event is the wait for it. A row also counts its
+    events (`n`) and keeps, under `inner`, the same sums for every
+    `named_scope` below the first (a `pl.pallas_call`'s `name=`)."""
+    def empty():
+        return {"s": 0.0, "forward_s": 0.0, "backward_s": 0.0, "n": 0,
+                "flops": 0.0, "bytes": 0.0, "hbm_bytes": 0.0}
+
     table = {}
     for _, plane in _device_planes(space, devices):
-        beacons = [(s, e) for s, e, name in trace_reduce.module_spans(plane)
-                   if name == BEACON]
-        events = [e for e in scoped_events(plane)
-                  if not any(s <= e["start"] < t for s, t in beacons)]
+        events = trace_reduce.outside_beacons(plane, scoped_events(plane))
         for row in trace_reduce.self_times(events):
-            cell = table.setdefault(row["scope"], {
-                "s": 0.0, "forward_s": 0.0, "backward_s": 0.0,
-                "flops": 0.0, "bytes": 0.0, "hbm_bytes": 0.0})
-            cell["s"] += row["self"] / 1e12
-            if row["direction"]:
-                cell[row["direction"] + "_s"] += row["self"] / 1e12
-            else:
+            cell = table.setdefault(row["scope"], empty())
+            cells = [cell] + [cell.setdefault("inner", {}).setdefault(
+                name, empty()) for name in row["inner"]]
+            for c in cells:
+                c["s"] += row["self"] / 1e12
+                c["n"] += 1
+                if row["direction"]:
+                    c[row["direction"] + "_s"] += row["self"] / 1e12
+            if not row["direction"]:
                 ops = cell.setdefault("ops", {})
                 ops[row["name"]] = ops.get(row["name"], 0.0) \
                     + row["self"] / 1e12
             if row["category"] in ASYNC_COPY:
                 continue    # its time is the wait alone, its bytes the copy's
-            for key in ("flops", "bytes", "hbm_bytes"):
-                cell[key] += row[key]
+            for c in cells:
+                for key in ("flops", "bytes", "hbm_bytes"):
+                    c[key] += row[key]
     return table
+
+
+def roofline_share(scopes, name, peaks, flops=None, hbm_bytes=None):
+    """A kernel's or a layer's share of its nearer roof, in %: the larger
+    of achieved FLOP/s over the bf16 peak and achieved HBM bytes/s over
+    the HBM peak, for the ops of `scopes` (a `by_scope` table) whose first
+    scope is `name` or that lie under an inner scope `name` (a
+    `pl.pallas_call(..., name=name)`). The operations and bytes are the
+    trace's own sums unless the caller gives `flops` and `hbm_bytes`, PER
+    CALL of the kernel (one device event each; the trace counts nothing
+    inside a custom call), from a function of shapes kept with the
+    benchmark. None where no such op ran or nothing was counted: never 0.
+    A share over 105% is refused: the operations or bytes are then counted
+    too high, or the time leaves out part of the work."""
+    rows = [scopes[name]] if name in scopes else []
+    rows += [row["inner"][name] for row in scopes.values()
+             if name in row.get("inner", {})]
+    seconds, calls = sum(r["s"] for r in rows), sum(r["n"] for r in rows)
+    if not seconds:
+        return None
+    total_flops = sum(r["flops"] for r in rows) if flops is None \
+        else flops * calls
+    total_hbm = sum(r["hbm_bytes"] for r in rows) if hbm_bytes is None \
+        else hbm_bytes * calls
+    share = 100.0 * max(total_flops / seconds / peaks["bf16_flops_per_s"],
+                        total_hbm / seconds / peaks["hbm_bytes_per_s"])
+    if share > 105.0:
+        raise ValueError(
+            f"{name}: {share:.1f}% of the roof over {seconds:.6f} s of "
+            f"{calls} device events: operations or bytes counted too "
+            f"high, or time left out")
+    return share or None
 
 
 def scope_shares(table):

@@ -33,6 +33,7 @@ from benchmarks import xplane_schema  # noqa: E402
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+BEACON = "jit_dl4j_trace_beacon"    # the clock link's program: no work
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute", "collective-broadcast")
 GAP_FLOOR_PS = 20_000_000      # 20 us: shorter gaps are launch latency
@@ -130,6 +131,14 @@ def module_spans(plane):
     return sorted(spans)
 
 
+def outside_beacons(plane, events) -> list:
+    """`events` of one device plane less those that ran inside a run of the
+    clock link's beacon program: a trace with beacons reads as one without."""
+    beacons = [(s, e) for s, e, name in module_spans(plane) if name == BEACON]
+    return [ev for ev in events
+            if not any(s <= ev["start"] < e for s, e in beacons)]
+
+
 def gap_name(start, end, spans) -> str:
     """What an idle gap lay in, as far as the device's own trace says:
     `inside <program>` if a program run spans it (the chip waited within a
@@ -151,18 +160,36 @@ def line_counts(space) -> dict:
             for plane in space.planes if any(l.events for l in plane.lines)}
 
 
-def main_module_runs(plane, t0, t1):
-    """(name, runs) of the program that took most of [t0, t1) on one
-    chip, counting the runs that started inside it: the train step."""
-    time_in, runs = {}, {}
+def main_module_starts(plane, t0, t1):
+    """(name, sorted starts) of the program that took most of [t0, t1) on
+    one chip, counting the runs that started inside it: the train step."""
+    time_in, starts = {}, {}
     for start, end, name in module_spans(plane):
-        if t0 <= start < t1:
+        if t0 <= start < t1 and name != BEACON:
             time_in[name] = time_in.get(name, 0) + end - start
-            runs[name] = runs.get(name, 0) + 1
-    if not runs:
-        return None, 0
+            starts.setdefault(name, []).append(start)
+    if not starts:
+        return None, []
     name = max(time_in, key=time_in.get)
-    return name, runs[name]
+    return name, sorted(starts[name])
+
+
+def runs_per_s(starts):
+    """Runs a second of a program from its runs' starts in picoseconds:
+    (runs - 1) over (last start - first start), which is exact for a
+    program that runs back to back. Counting the runs that START in a
+    window over the window's length counts one run too many (4.5% at 22
+    runs: chip runs, PR 25). None under two runs."""
+    if len(starts) < 2 or starts[-1] <= starts[0]:
+        return None
+    return (len(starts) - 1) / ((starts[-1] - starts[0]) / 1e12)
+
+
+def _periods_ms(starts):
+    """[shortest, median, longest] start-to-start time of a program's runs
+    on the cell's first chip, in ms: one slow step shows here."""
+    gaps = sorted((b - a) / 1e9 for a, b in zip(starts, starts[1:]))
+    return [gaps[0], gaps[len(gaps) // 2], gaps[-1]] if gaps else None
 
 
 def reduce_space(space, devices=None, skip_s: float = 0.0):
@@ -170,10 +197,13 @@ def reduce_space(space, devices=None, skip_s: float = 0.0):
 
     The window runs from the first op's start, plus `skip_s` (the
     pipeline's fill at the start of a `fit()`), to the last op's end on ANY
-    chip of the cell; ops are clipped to it. `busy_s` is the union of a chip's op intervals,
-    averaged over the chips; `idle_share_worst` is that of the idlest chip.
-    Seconds in `breakdown` are summed over the chips and divided by their
-    number.
+    chip of the cell; ops are clipped to it, and the clock link's beacon
+    runs (`BEACON`) are left out, so a trace with them reads as one
+    without. `busy_s` is the union of a chip's op intervals, averaged over
+    the chips; `idle_share_worst` is that of the idlest chip. Seconds in
+    `breakdown` are summed over the chips and divided by their number.
+    `main_module_runs_per_s` is `runs_per_s` of the train step's runs that
+    start in the window, mean over the chips.
     """
     per_device = {}
     for plane in space.planes:
@@ -182,7 +212,7 @@ def reduce_space(space, devices=None, skip_s: float = 0.0):
         ordinal = int(plane.name[len(DEVICE_PLANE):].split()[0])
         if devices is not None and ordinal not in devices:
             continue
-        events = list(device_events(plane))
+        events = outside_beacons(plane, device_events(plane))
         if events:
             per_device[ordinal] = events
     if not per_device:
@@ -202,7 +232,7 @@ def reduce_space(space, devices=None, skip_s: float = 0.0):
     window = t1 - t0
     if window <= 0 or not all(per_device.values()):
         return None
-    main = [main_module_runs(plane, t0, t1) for plane in space.planes
+    main = [main_module_starts(plane, t0, t1) for plane in space.planes
             if plane.name.startswith(DEVICE_PLANE)
             and int(plane.name[len(DEVICE_PLANE):].split()[0])
             in per_device]
@@ -228,6 +258,8 @@ def reduce_space(space, devices=None, skip_s: float = 0.0):
                 name = gap_name(e, s, spans.get(ordinal, []))
                 gaps[name] = gaps.get(name, 0) + (s - e)
     n = len(per_device)
+    rates = [r for r in (runs_per_s(starts) for _, starts in main)
+             if r is not None]
     worst = max(per_device, key=lambda d: window - busy[d])
 
     def top(table):
@@ -238,8 +270,10 @@ def reduce_space(space, devices=None, skip_s: float = 0.0):
         "devices": sorted(per_device),
         "window_s": window / 1e12,
         "main_module": main[0][0],
-        "main_module_runs_per_s": sum(n for _, n in main) / len(main)
-        / (window / 1e12),
+        "main_module_runs": sum(len(starts) for _, starts in main)
+        / len(main),
+        "main_module_runs_per_s": sum(rates) / len(rates) if rates else None,
+        "main_module_period_ms": _periods_ms(main[0][1]),
         "busy_s": sum(busy.values()) / n / 1e12,
         "idle_share_worst": 1.0 - busy[worst] / window,
         "busy_by_device_s": {str(d): busy[d] / 1e12 for d in busy},
