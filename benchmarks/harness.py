@@ -60,19 +60,29 @@ def enable_compile_cache() -> str:
     return path
 
 
-def init_in_one_program(net):
-    """`net.init()` traced into ONE compiled program. Run eagerly it is
-    some eighty small programs, and fetching each from the compile cache
-    cost 0.17 s: 14 s of every run's set-up (chip run, PR 24). The values
-    it makes are replaced by the benchmark's own weights anyway."""
+def init_in_one_program(net) -> dict:
+    """`net.init()` traced into ONE compiled program that gives the net
+    its layer state and its optimizer's state and NOT its parameters,
+    whose shapes it returns (`{vertex: {leaf: ShapeDtypeStruct}}`): the
+    benchmark places its own weights, and the net's own initial values,
+    alive beside them for a moment, were a second set of the model's size
+    on the device. Run eagerly `init()` is some eighty small programs, and
+    fetching each from the compile cache cost 0.17 s: 14 s of every run's
+    set-up (chip run, PR 24)."""
     import jax
+
+    shapes = {}
 
     def traced():
         net.init()
-        return net.params_tree, net.state_tree, net.updater_state
+        shapes.update(jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            net.params_tree))
+        return net.state_tree, net.updater_state
 
-    net.params_tree, net.state_tree, net.updater_state = jax.jit(traced)()
-    return net
+    net.state_tree, net.updater_state = jax.jit(traced)()
+    net.params_tree = None      # the trace's own: placed by the caller
+    return shapes
 
 
 def require_chips(chips: int):

@@ -1,7 +1,9 @@
 """`tokens_tiny`: the smallest token model, through the program's public
-API: an embedding, one tanh layer applied at every time step, and a
-softmax over the vocabulary scored with integer labels (`sparse_mcxent`).
-The rehearsal of the `token_stream` generator and the `adam` rule.
+API: an embedding, `hidden_layers` tanh layers (1 where the configuration
+names none) applied at every time step, and a softmax over the vocabulary
+scored with integer labels (`sparse_mcxent`). The rehearsal of the
+`token_stream` generator and the `adam` rule; wide and deep enough
+(`tests/configs/tokens_wide.json`) it is a model whose bytes are weights.
 
 The hidden layer is a `Convolution1DLayer` of kernel 1: the program's
 `DenseLayer` after a sequence layer gets only the last time step
@@ -34,9 +36,10 @@ def build(config: dict, seed: int):
             .list(EmbeddingSequenceLayer(n_in=config["vocabulary_held"],
                                          n_out=config["width"],
                                          activation="identity"),
-                  Convolution1DLayer(n_in=config["width"],
-                                     n_out=config["width"], kernel=1,
-                                     activation="tanh"),
+                  *[Convolution1DLayer(n_in=config["width"],
+                                       n_out=config["width"], kernel=1,
+                                       activation="tanh")
+                    for _ in range(config.get("hidden_layers", 1))],
                   RnnOutputLayer(n_in=config["width"],
                                  n_out=config["vocabulary_held"],
                                  activation="softmax", loss="sparse_mcxent"))

@@ -6,7 +6,8 @@ it and reads its JSON file: a chip belongs to one process at a time, and
 run this way the float32 reference may take the whole chip while
 `memory_peak_bytes` of the run stays the program's. It makes the weights
 and the first batches from the seed exactly as the runner does, follows
-the steps in `--mode` and writes losses and per-leaf norms.
+the steps in `--mode` and writes losses, per-leaf norms and its own peak of
+device memory.
 
     python benchmarks/reference_main.py \
         --config benchmarks/configs/resnet50.json \
@@ -90,9 +91,12 @@ def main(argv=None) -> int:
         out = reference_numbers(config, traffic, chips=args.chips,
                                 seed=args.seed, steps=args.steps,
                                 mode=args.mode)
+    import jax
     import numpy as np
 
     np.savez(args.out + ".npz", **out.pop("grad_sample"))
+    out["memory_peak_bytes"] = harness.device_facts(
+        jax.devices()[:args.chips])["memory_peak_bytes"]
     out["seconds"] = time.perf_counter() - t0
     out["phases"]["imports_and_cache"] = out["seconds"] - sum(
         out["phases"].values())

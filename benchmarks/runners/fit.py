@@ -98,21 +98,17 @@ def _stream(pool, *, steps=None, seconds=None):
 
 class FirstSteps(harness.Listener):
     """Reads, on the device and without a host sync, each warm-up step's
-    loss, the first gradient's per-leaf norms and samples out of the
-    optimizer's state after step one (how is the update rule's to say),
-    and the per-leaf norms of the parameters' change after the last step."""
+    loss, and the first gradient's per-leaf norms and samples out of the
+    optimizer's state after step one (how is the update rule's to say)."""
 
-    def __init__(self, steps, first_grad, delta_norms):
-        self.steps = steps
-        self._first_grad, self._delta_norms = first_grad, delta_norms
-        self.losses, self.grad, self.sample, self.delta = [], None, None, None
+    def __init__(self, first_grad):
+        self._first_grad = first_grad
+        self.losses, self.grad, self.sample = [], None, None
 
     def iteration_done(self, model, iteration, epoch, score):
         self.losses.append(score)
         if len(self.losses) == 1:
             self.grad, self.sample = self._first_grad(model.updater_state)
-        if len(self.losses) == self.steps:
-            self.delta = self._delta_norms(model.params_tree)
 
 
 def dl4j_trace_beacon(v):
@@ -184,32 +180,71 @@ class TraceWindow(harness.Listener):
         self.stop()
 
 
-def _place_weights(net, ref_params, dtype):
-    """Give the net the benchmark's weights, in the dtype it is trained
-    in, and return a copy of them as placed. Every leaf of the net must be
-    one the reference made."""
+def _rounded_on_host(made: dict, dtype) -> dict:
+    """The reference's float32 weights `made`, off the device and in the
+    dtype the net is trained in, as numpy arrays. Each leaf is rounded on
+    the host (numpy rounds to nearest even, as the device's cast does: the
+    same bits) and its device buffer dropped before the next, so the
+    device never holds a second copy and no program is compiled."""
     import jax
-    import jax.numpy as jnp
+    import numpy as np
 
-    full = {}
-    for name, leaves in net.params_tree.items():
-        made = ref_params.get(name, {})
-        if set(made) != set(leaves):
-            raise RuntimeError(f"vertex {name!r}: the program has leaves "
-                               f"{sorted(leaves)}, the reference {sorted(made)}")
-        for leaf, arr in leaves.items():
-            if made[leaf].shape != arr.shape:
-                raise RuntimeError(f"{name}/{leaf}: program "
-                                   f"{arr.shape}, reference {made[leaf].shape}")
-        full[name] = made
-    missing = set(ref_params) - set(full)
+    for arr in jax.tree_util.tree_leaves(made):
+        arr.copy_to_host_async()
+    rounded = {}
+    for name in list(made):
+        rounded[name] = {}
+        for leaf, arr in made.pop(name).items():
+            rounded[name][leaf] = np.asarray(arr).astype(dtype)
+            arr.delete()
+    return rounded
+
+
+def _place_weights(net, shapes: dict, weights: dict):
+    """Give the net the benchmark's `weights` (host arrays). Every leaf
+    of the net (`shapes`, as `harness.init_in_one_program` returns them)
+    must be one the reference made."""
+    import jax
+
+    missing = set(weights) - set(shapes)
     if missing:
         raise RuntimeError(f"the program lacks {sorted(missing)}")
-    rounded = jax.jit(lambda t: jax.tree_util.tree_map(
-        lambda a: a.astype(dtype), t))(full)
-    net.params_tree = rounded
-    # a copy: the step donates the net's own buffers
-    return jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t))(rounded)
+    for name, leaves in shapes.items():
+        made = weights.get(name, {})
+        if set(made) != set(leaves):
+            raise RuntimeError(
+                f"vertex {name!r}: the program has leaves {sorted(leaves)}, "
+                f"the reference {sorted(made)}")
+        for leaf, struct in leaves.items():
+            if made[leaf].shape != struct.shape:
+                raise RuntimeError(
+                    f"{name}/{leaf}: program {struct.shape}, "
+                    f"reference {made[leaf].shape}")
+    net.params_tree = jax.device_put(
+        {name: weights.get(name, {}) for name in shapes})
+
+
+def _delta_norms(params, initial) -> list:
+    """Per leaf, in `tree_leaves` order, the norm of (parameters now -
+    `initial`, the host copy of them as placed), read on the host a leaf
+    at a time: no second copy of the parameters visits the device. In
+    float32 a million elements at a time, summed in float64: whole-leaf
+    temporaries of VGG16's 103M-element kernel cost 2.8 s of set-up in
+    page faults (chip run, PR 28)."""
+    import jax
+    import numpy as np
+
+    def norm(now, was, chunk=1 << 20):
+        now, was = np.asarray(now).reshape(-1), was.reshape(-1)
+        total = 0.0
+        for i in range(0, now.size, chunk):
+            d = (now[i:i + chunk].astype(np.float32)
+                 - was[i:i + chunk].astype(np.float32))
+            total += float(np.sum(np.square(d), dtype=np.float64))
+        return float(np.sqrt(total))
+
+    return [norm(a, b) for a, b in zip(jax.tree_util.tree_leaves(params),
+                                       jax.tree_util.tree_leaves(initial))]
 
 
 def _histograms():
@@ -237,9 +272,13 @@ def prepare(cell: dict, seed: int, used, stamp=lambda name: None) -> dict:
                                config["updater"]["rule"] + ".py")
     global_batch = config["batch_per_chip"] * chips
 
-    net = harness.init_in_one_program(model.build(config, seed))
-    initial = _place_weights(net, ref_mod.init_params(seed, config),
-                             jnp.dtype(config["dtype"]))
+    # Of the model's size the device holds, in turn: the reference's
+    # float32 tree alone; nothing; the optimizer's state; that and the
+    # parameters. Never more than the step's own arguments.
+    initial = _rounded_on_host(ref_mod.init_params(seed, config),
+                               jnp.dtype(config["dtype"]))
+    net = model.build(config, seed)
+    _place_weights(net, harness.init_in_one_program(net), initial)
     stamp("net_and_weights")
     trainer = net
     if traffic.get("wrapper") == "ParallelWrapper":
@@ -257,18 +296,15 @@ def prepare(cell: dict, seed: int, used, stamp=lambda name: None) -> dict:
         g = rule.first_gradient(state, config["updater"])
         return follow.leaf_norms(g), follow.leaf_samples(g)
 
-    delta_of = jax.jit(lambda p, p0: follow.leaf_norms(
-        jax.tree_util.tree_map(
-            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-            p, p0)))
     names = follow.leaf_paths(initial)
-    first = FirstSteps(steps, first_grad, lambda p: delta_of(p, initial))
+    first = FirstSteps(first_grad)
     net.set_listeners(first)
     trainer.fit(_stream(pool, steps=steps), epochs=1)
     program = {
         "loss": [float(x) for x in first.losses],
         "grad_norm": dict(zip(names, map(float, np.asarray(first.grad)))),
-        "delta_norm": dict(zip(names, map(float, np.asarray(first.delta)))),
+        "delta_norm": dict(zip(names, _delta_norms(net.params_tree,
+                                                   initial))),
         "grad_sample": dict(zip(names, map(np.asarray, first.sample))),
     }
     stamp("first_steps")
@@ -286,7 +322,8 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
     reference, ref_wall = run_reference(cell, seed, steps, require_chip)
     harness.say("reference", seconds=ref_wall, loss=reference["loss"],
                 phases=reference.get("phases"),
-                xla_compiles=reference["xla_compiles"])
+                xla_compiles=reference["xla_compiles"],
+                memory_peak_bytes=reference["memory_peak_bytes"])
 
     import jax
     import numpy as np
@@ -299,11 +336,13 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
     peaks = harness.peaks_for(used[0].device_kind) if require_chip else None
     phases, t_phase = {}, [time.perf_counter()]
     phases["start_to_devices"] = t_phase[0] - t_start - ref_wall
+    peak_after = {}     # the process's peak so far, at each phase's end
 
     def stamp(name):
         now = time.perf_counter()
         phases[name] = now - t_phase[0]
         t_phase[0] = now
+        peak_after[name] = harness.device_facts(used)["memory_peak_bytes"]
 
     with harness.XlaLog() as xla_setup:
         ready = prepare(cell, seed, used, stamp)
@@ -322,6 +361,7 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
         tracer.start()
     net.set_listeners(*listeners)
     hist_before = _histograms()
+    peak_setup = harness.device_facts(used)["memory_peak_bytes"]
     t_window = time.perf_counter()
     setup_s = t_window - t_start - ref_wall
     with harness.XlaLog() as xla_window:
@@ -367,6 +407,8 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
            if "tokens_per_item" in config else {}),
         "device_kind": device["kind"], "platform": device["platform"],
         "memory_peak_bytes": device["memory_peak_bytes"],
+        "memory_peak_setup_bytes": peak_setup,
+        "memory_peak_after_phase_bytes": peak_after,
         "memory_stats": {k: int(v) for k, v in
                          (used[0].memory_stats() or {}).items()},
     }

@@ -5,7 +5,9 @@ on the chip; no test calls it):
     chiprun -- python3 benchmarks/tests/measure_limits.py \
         --workload resnet50_fit --seeds 12 --control-seeds 4
 
-In ONE process, at the cell's own size, for each seed: the float32
+(`--test-config tokens_wide` reads a rehearsal preset of
+`benchmarks/tests/configs/` on one chip under `fit_stream` instead of a
+cell.) In ONE process, at the cell's own size, for each seed: the float32
 reference, the control (the reference in float8 put in the program's
 place), the reference in bfloat16 for orientation, and the program's own
 first steps through the runner's `prepare()`. Prints, for every number
@@ -28,15 +30,25 @@ from benchmarks import run as bench_run  # noqa: E402
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", required=True)
+    which = ap.add_mutually_exclusive_group(required=True)
+    which.add_argument("--workload")
+    which.add_argument("--test-config")
     ap.add_argument("--seeds", type=int, default=12)
     ap.add_argument("--control-seeds", type=int, default=4)
     ap.add_argument("--first-seed", type=int, default=2 ** 31 + 11)
     ap.add_argument("--control-modes", default="float8,bfloat16",
                     help="float8 is the control; bfloat16 is orientation")
     args = ap.parse_args(argv)
-    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as fh:
-        cell = bench_run.load_cell(json.load(fh), args.workload)
+    import jax
+
+    if args.test_config:
+        from benchmarks.tests.helpers import tiny_cell
+
+        cell = tiny_cell(1, "fit_stream", args.test_config)
+        args.workload = args.test_config
+    else:
+        with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as fh:
+            cell = bench_run.load_cell(json.load(fh), args.workload)
     config, traffic = cell["config_data"], cell["traffic_data"]
     steps = int(traffic["warmup_steps"])
     harness.enable_compile_cache()
@@ -56,7 +68,14 @@ def main(argv=None) -> int:
     for seed in seeds:
         ready = runner.prepare(cell, seed, used)
         rows[seed]["program"] = ready["program"]
-        del ready
+        # a seed's net off the device before the next one's is built,
+        # whoever still holds the object: two do not fit where one fills
+        # the chip
+        net = ready["net"]
+        for leaf in jax.tree_util.tree_leaves(
+                (net.params_tree, net.updater_state, net.state_tree)):
+            leaf.delete()
+        del ready, net
         gc.collect()
     table = {}
     for seed, row in rows.items():

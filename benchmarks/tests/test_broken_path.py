@@ -31,19 +31,29 @@ def _multilayer_step(self, key):
     return step
 
 
-@pytest.mark.parametrize("config,net,broken,limits", [
-    ("resnet50_tiny", "computation_graph.ComputationGraph", _graph_step,
-     harness.load_json("limits", "resnet50_fit.json")),
-    ("tokens_tiny", "multilayer.MultiLayerNetwork", _multilayer_step,
-     LIMITS["tokens_tiny"])])
-def test_unchanged_state_is_not_correct(monkeypatch, config, net, broken,
-                                        limits):
+# by the configuration's `model`: the program's class that builds its train
+# step, and the step that does nothing
+BROKEN = {"resnet50": ("computation_graph.ComputationGraph", _graph_step),
+          "tokens_tiny": ("multilayer.MultiLayerNetwork", _multilayer_step)}
+
+
+def break_step(model: str, setattr_=setattr):
+    """Put the unchanged-state step under every net the configuration's
+    `model` builds from here on."""
     import importlib
 
-    module, cls = net.split(".")
-    monkeypatch.setattr(
-        getattr(importlib.import_module("deeplearning4j_tpu.models." + module),
-                cls), "_get_train_step", broken)
+    module, cls = BROKEN[model][0].split(".")
+    setattr_(getattr(importlib.import_module(
+        "deeplearning4j_tpu.models." + module), cls),
+        "_get_train_step", BROKEN[model][1])
+
+
+@pytest.mark.parametrize("config,limits", [
+    ("resnet50_tiny", harness.load_json("limits", "resnet50_fit.json")),
+    ("tokens_tiny", LIMITS["tokens_tiny"])])
+def test_unchanged_state_is_not_correct(monkeypatch, config, limits):
+    break_step(harness.load_json("tests", "configs", config + ".json")[
+        "model"], monkeypatch.setattr)
     result = bench_run.run_cell(
         tiny_cell(1, "fit_stream", config), seed=3, seconds=1.0, trace=False,
         require_chip=False, t_start=time.perf_counter(), limits=limits)

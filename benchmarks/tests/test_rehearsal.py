@@ -24,7 +24,8 @@ DEVICE_METRICS = {"device_idle_share.train", "peak_hbm_gib.train",
                   "scoped_op_time_share.train", "updater_time_share.train"}
 CELLS = [(1, "fit_stream", "resnet50_tiny"),
          (4, "fit_stream_dp", "resnet50_tiny"),
-         (1, "fit_stream", "tokens_tiny")]
+         (1, "fit_stream", "tokens_tiny"),
+         (1, "fit_stream", "tokens_tiny_deep")]     # the key `hidden_layers`
 
 
 def rehearse(chips: int, traffic: str, trace: int,
