@@ -353,8 +353,10 @@ class DevicePrefetchIterator(DataSetIterator):
     one is installed — each batch lands pre-sharded over the batch axis
     in ONE device_put — and to plain `jax.device_put` (single device)
     otherwise. The data-parallel trainer passes its spine's put
-    explicitly. ``transform(ds) -> ds`` is a host-side hook applied
-    before the put (e.g. padding to device-count divisible).
+    explicitly. ``transform(ds) -> ds`` is the data-parallel trainer's
+    host-side padding hook (to a device-count divisible batch), applied
+    before the put; nothing else uses it. A batch goes up in the dtype
+    the base iterator gave it: the consumer casts on the device.
     """
 
     def __init__(self, base: DataSetIterator, depth: int = 2,

@@ -377,7 +377,8 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         - the loss stays on device; ``score_`` materializes it lazily
           (``sync_every=N`` forces a float every N steps for listeners)
         - ``device_prefetch`` double-buffers the host→device transfer of
-          batch N+1 behind batch N's compute
+          batch N+1 behind batch N's compute; features travel in the
+          iterator's own dtype and are cast to the net's on the device
         - ``steps_per_dispatch=K`` (opt-in) fuses K same-shape batches
           into one `lax.scan` dispatch; tBPTT batches and non-SGD solvers
           fall back to per-step dispatch automatically
@@ -397,8 +398,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         it = as_iterator(data, labels, batch_size)
         if device_prefetch:
             it = DevicePrefetchIterator(
-                it, depth=max(2, int(steps_per_dispatch)),
-                transform=self._cast_batch)
+                it, depth=max(2, int(steps_per_dispatch)))
         self._loss_tracker.sync_every = int(sync_every)
         execu = TrainingExecutor(
             self,
@@ -414,16 +414,6 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         run_with_recovery(execu, plan, it, epochs)
         self.stopped_early = execu.stopped
         return self
-
-    def _cast_batch(self, ds: DataSet) -> DataSet:
-        """Pre-cast features to the model dtype so the prefetch transfer
-        carries the bytes the step actually consumes (bf16 nets ship half
-        the data)."""
-        f = ds.features
-        if hasattr(f, "dtype") and f.dtype != self.dtype:
-            ds = DataSet(np.asarray(f, self.dtype), ds.labels,
-                         ds.features_mask, ds.labels_mask)
-        return ds
 
     def _dispatch_batch(self, ds: DataSet):
         if self.conf.tbptt_fwd_length > 0 and ds.features.ndim == 3:

@@ -237,6 +237,52 @@ class TestDevicePrefetch:
         # after ONE consumer next(), the prefetcher has pulled ≥2 more
         assert sum(consumed) >= 3
 
+    @pytest.mark.parametrize("k", [1, 2], ids=["per_step", "fused"])
+    @pytest.mark.parametrize("feed", ["float32", "uint8", "bfloat16"])
+    def test_features_travel_in_the_iterators_dtype(self, feed, k):
+        """A bf16 net's features reach the dispatch as the iterator made
+        them (nothing is cast on the host) and train exactly as features
+        pre-cast to bf16 with numpy do."""
+        from deeplearning4j_tpu import observe
+        from deeplearning4j_tpu.observe.trace import get_span_store
+
+        rng = np.random.default_rng(3)
+        y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, 48)]
+        x = (rng.integers(0, 256, (48, 8)) if feed == "uint8"
+             else rng.standard_normal((48, 8))).astype(jnp.dtype(feed))
+
+        net = _mlp(dtype="bfloat16")
+        seen = []
+        step, fused = net._dispatch_batch, net._fused_dispatch
+
+        def spy_step(ds):
+            seen.append(ds.features)
+            return step(ds)
+
+        def spy_fused(batches):
+            seen.extend(b.features for b in batches)
+            return fused(batches)
+
+        net._dispatch_batch, net._fused_dispatch = spy_step, spy_fused
+        observe.get_flight()        # span recording on (the default)
+        store = get_span_store()
+        n0 = store.count
+        net.fit(x, y, epochs=1, batch_size=16, steps_per_dispatch=k)
+
+        assert len(seen) == 3 and net.iteration == 3
+        assert all(isinstance(f, jax.Array) and f.dtype == x.dtype
+                   for f in seen)
+        puts = [e for e in store.events(n0) if e["name"] == "data.put"]
+        assert [p["attrs"]["bytes"] for p in puts] == \
+            [x[:16].nbytes + y[:16].nbytes] * 3
+
+        ref = _mlp(dtype="bfloat16")
+        ref.fit(np.asarray(x, jnp.bfloat16), y, epochs=1, batch_size=16,
+                steps_per_dispatch=k)
+        assert all(p.dtype == jnp.bfloat16 for p in
+                   jax.tree_util.tree_leaves(net.params_tree))
+        assert _max_param_diff(net.params_tree, ref.params_tree) == 0.0
+
 
 # ------------------------------------------------- async iterator hygiene
 class _ExplodingIterator(DataSetIterator):
