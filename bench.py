@@ -1747,11 +1747,8 @@ def _kernels_main():
         banded_attention, banded_decode_attention, banded_reference,
         decode_reference,
     )
-    from deeplearning4j_tpu.ops.fused_update import (
-        adam_update, nesterov_update,
-    )
     from deeplearning4j_tpu.ops.kernel_defaults import (
-        banded_policy, decode_attention_policy, fused_update_policy,
+        banded_policy, decode_attention_policy,
     )
 
     on_tpu = jax.default_backend() == "tpu"
@@ -1830,32 +1827,6 @@ def _kernels_main():
             "banded": {"ms": _ms(dband, q1, ck, cv),
                        **_cost(dband, q1, ck, cv)},
         })
-
-    # fused optimizer update, one ~1M-element leaf
-    n = 1 << 20
-    key = jax.random.PRNGKey(7)
-    kp, kg = jax.random.split(key)
-    p = jax.random.normal(kp, (n,), jnp.float32)
-    g = jax.random.normal(kg, (n,), jnp.float32) * 1e-2
-    m = jnp.zeros((n,), jnp.float32)
-    vv = jnp.zeros((n,), jnp.float32)
-    lrbc = jnp.float32(1e-3)
-
-    def adam_xla(p, g, m, vv):
-        m2 = 0.9 * m + 0.1 * g
-        v2 = 0.999 * vv + 0.001 * g * g
-        return p - lrbc * m2 / (jnp.sqrt(v2) + 1e-8), m2, v2
-
-    adam_fused = lambda p, g, m, vv: adam_update(
-        p, g, m, vv, lrbc, interpret=interp)
-    upol = fused_update_policy("adam")
-    buckets.append({
-        "kind": "fused_update", "opt": "adam", "n": n, "policy": upol,
-        "xla": {"ms": _ms(adam_xla, p, g, m, vv),
-                **_cost(adam_xla, p, g, m, vv)},
-        "fused": {"ms": _ms(adam_fused, p, g, m, vv),
-                  **_cost(adam_fused, p, g, m, vv)},
-    })
 
     dev = jax.devices()[0]
     out = {

@@ -110,6 +110,10 @@ _SINK_BARE = frozenset({"b64encode", "b85encode"})
 #: without changing its donation contract — treat it as transparent.
 _TRANSPARENT_WRAPPERS = frozenset({"instrument"})
 
+#: `optim/step.jit_step(fn, ...)` is the trainers' one donating jit:
+#: (params, opt_state, states) of what it returns are donated.
+_DONATING_JITS: Dict[str, Tuple[int, ...]] = {"jit_step": (0, 1, 2)}
+
 
 def _donated_positions(call: ast.Call) -> Tuple[int, ...]:
     """donate_argnums=(0, 1) positions of a jit(...) call node."""
@@ -310,6 +314,9 @@ class _ShardAnalysis:
         if isinstance(value, ast.Call) \
                 and mc.fl.imports.is_jit_family(value.func):
             return _donated_positions(value), True
+        if isinstance(value, ast.Call) \
+                and _terminal(value.func) in _DONATING_JITS:
+            return _DONATING_JITS[_terminal(value.func)], True
         return None
 
     def _scan_binding_assign(self, fn: FunctionInfo, mc: _ModCtx,

@@ -14,6 +14,7 @@ computation, with multi-output loss = sum of per-output-layer losses
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -26,7 +27,9 @@ from deeplearning4j_tpu.data.iterators import (
 )
 from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
-from deeplearning4j_tpu.observe import donatemon
+from deeplearning4j_tpu.optim.step import (
+    jit_step, make_fused_step, make_train_step, stack_step_args,
+)
 from deeplearning4j_tpu.nn.graph import (
     ComputationGraphConfiguration, GraphVertex, LayerVertex,
     resolve_output_type,
@@ -34,7 +37,6 @@ from deeplearning4j_tpu.nn.graph import (
 from deeplearning4j_tpu.nn.layers.special import CenterLossOutputLayer
 from deeplearning4j_tpu.models.multilayer import (
     _check_decode_budget, _checkpointed, _dtype_of, _is_recurrent,
-    _normalize_grads,
 )
 from deeplearning4j_tpu.optim.listeners import TrainingListener
 from deeplearning4j_tpu.optim.updaters import NoOp, Updater, resolve_updater
@@ -249,70 +251,27 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
     def make_step_fn(self, tbptt: bool = False):
         """Pure (un-jitted) train-step fn for parallel trainers (see
         MultiLayerNetwork.make_step_fn)."""
-        return self._build_step(jit=False, tbptt=tbptt)
+        return make_train_step(
+            functools.partial(self._loss, train=True), self._vertex_updaters,
+            grad_norm=(self.conf.gradient_normalization,
+                       self.conf.gradient_normalization_threshold),
+            stateful=self._stateful,
+            carry_names=self._rnn_vertex_names if tbptt else None)
 
     def _get_train_step(self, key, tbptt: bool = False):
+        """The jitted step; `key` is (has_fmasks, has_lmasks)."""
         key = (key, tbptt)
         if key in self._jit_cache:
             return self._jit_cache[key]
-        fn = self._build_step(jit=True, tbptt=tbptt)
-        self._jit_cache[key] = fn
-        # read back through the cache: __setitem__ may have wrapped the
-        # callable in the watchdog's cost/comm probe, and returning the
-        # raw local lets the FIRST dispatch bypass the ledger
-        return self._jit_cache[key]
-
-    def _build_step(self, jit: bool, tbptt: bool = False):
-        mode = self.conf.gradient_normalization
-        thr = self.conf.gradient_normalization_threshold
-        updaters = self._vertex_updaters
-        stateful = self._stateful
-        rnn_names = self._rnn_vertex_names
-
-        def step_fn(params, opt_state, states, step, inputs, labels,
-                    fmasks, lmasks, rng, carries=None):
-            def loss_fn(p):
-                return self._loss(p, states, inputs, labels, fmasks, lmasks,
-                                  rng, train=True, carries=carries)
-
-            (loss, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            grads = _normalize_grads(grads, mode, thr)
-            new_params, new_opt = {}, {}
-            with jax.named_scope("updater"):
-                for name, u in updaters.items():
-                    # Whole-update seam (fused-kernel capable): see
-                    # MultiLayerNetwork._build_step.
-                    new_params[name], new_opt[name] = \
-                        u.update_with_params(grads[name], opt_state[name],
-                                             params[name], step)
-            persist = {
-                n: (new_states[n] if n in stateful else states.get(n, {}))
-                for n in states
-            }
-            if tbptt:
-                # Carry RNN state to the next chunk, gradients truncated at
-                # the chunk boundary (reference:
-                # `ComputationGraph.rnnUpdateStateWithTBPTTState`).
-                out_carries = {
-                    n: _tmap(jax.lax.stop_gradient, new_states[n])
-                    for n in rnn_names
-                }
-                return new_params, new_opt, persist, loss, out_carries
-            return new_params, new_opt, persist, loss
-
-        if not jit:
-            return step_fn
-        # donatemon.instrument is identity with DL4J_TPU_DONATEMON off;
-        # on, it witnesses the (params, opt_state, states) donation.
-        return donatemon.instrument(
-            jax.jit(step_fn, donate_argnums=(0, 1, 2)), (0, 1, 2),
-            name="ComputationGraph._step",
-            arg_names=("params", "opt_state", "states"))
+        return jit_step(self.make_step_fn(tbptt=tbptt),
+                        cache=self._jit_cache, key=key,
+                        name="ComputationGraph._step")
 
     # ---------------------------------------------------- data plumbing
-    def _to_dicts(self, ds: Union[DataSet, MultiDataSet], host: bool = False):
-        """Map a DataSet/MultiDataSet onto named inputs/outputs by order.
+    def _batch_args(self, ds: Union[DataSet, MultiDataSet],
+                    host: bool = False):
+        """A DataSet/MultiDataSet as the step's batch arguments: dicts of
+        named inputs/outputs by order, features in the net's dtype.
         `host=True` keeps leaves as numpy (multi-controller feeding: the
         caller lifts them into global arrays in one upload)."""
         asarray = np.asarray if host else jnp.asarray
@@ -384,7 +343,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
     def _fit_batch(self, ds: Union[DataSet, MultiDataSet]):
         """One training step; returns the loss as a DEVICE array on the
         SGD path (deferred sync — see LossTracker)."""
-        feats, labs, fmasks, lmasks = self._to_dicts(ds)
+        feats, labs, fmasks, lmasks = self._batch_args(ds)
         self.last_batch_size = next(iter(feats.values())).shape[0]
         if self.conf.optimization_algo != "stochastic_gradient_descent":
             from deeplearning4j_tpu.optim.solvers import fit_with_solver
@@ -415,59 +374,33 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         cache_key = ("fused", key, k)
         if cache_key in self._jit_cache:
             return self._jit_cache[cache_key]
-        base = self._build_step(jit=False, tbptt=False)
+        return jit_step(make_fused_step(self.make_step_fn()),
+                        cache=self._jit_cache, key=cache_key,
+                        name="ComputationGraph._fused_step")
 
-        def fused(params, opt_state, states, step0, rng, feats, labs, fms,
-                  lms):
-            # rng splits inside the scan carry — the same sequential
-            # `self._rng, k = split(self._rng)` chain as the K=1 path.
-            def body(carry, xs):
-                p, o, s, step, r = carry
-                f, l, fm, lm = xs
-                r, sub = jax.random.split(r)
-                new_p, new_o, persist, loss = base(
-                    p, o, s, step, f, l, fm, lm, sub, None)
-                return (new_p, new_o, persist, step + 1, r), loss
-
-            (params, opt_state, states, _, rng), losses = jax.lax.scan(
-                body, (params, opt_state, states, step0, rng),
-                (feats, labs, fms, lms))
-            return params, opt_state, states, rng, losses
-
-        fn = donatemon.instrument(
-            jax.jit(fused, donate_argnums=(0, 1, 2)), (0, 1, 2),
-            name="ComputationGraph._fused_step",
-            arg_names=("params", "opt_state", "states"))
-        self._jit_cache[cache_key] = fn
-        # read back through the cache (probe wrapping; see _get_train_step)
-        return self._jit_cache[cache_key]
-
-    def _fused_dispatch(self, batches: Sequence):
-        """K same-shape batches → one `lax.scan` dispatch → (K,) losses."""
-        # host=True keeps leaves as numpy when the batch is host-resident,
-        # so each tensor stacks on host and crosses to device ONCE; a
-        # prefetched (device-array) batch keeps the jnp path instead.
+    def _stacked_batch_args(self, batches: Sequence):
+        """K same-shape batches as the fused step's arguments, stacked on
+        a leading axis: on the host (numpy) when the batches are there, so
+        the caller's placement is each tensor's one transfer; a prefetched
+        (device-array) batch stacks on the device."""
         f0 = batches[0].features
         host = isinstance(
             f0[0] if hasattr(batches[0], "features_masks") else f0,
             np.ndarray)
-        conv = [self._to_dicts(b, host=host) for b in batches]
-        self.last_batch_size = next(iter(conv[0][0].values())).shape[0]
-        stack = ((lambda vs: jnp.asarray(np.stack(vs))) if host
-                 else jnp.stack)
+        return stack_step_args(
+            [self._batch_args(b, host=host) for b in batches])
 
-        def stk(idx):
-            head = conv[0][idx]
-            if head is None:
-                return None
-            return {n: stack([c[idx][n] for c in conv]) for n in head}
-
-        key = (conv[0][2] is not None, conv[0][3] is not None)
-        fn = self._get_fused_step(key, len(batches))
+    def _fused_dispatch(self, batches: Sequence):
+        """K same-shape batches → one `lax.scan` dispatch → (K,) losses."""
+        feats, labs, fms, lms = _tmap(
+            jnp.asarray, self._stacked_batch_args(batches))
+        self.last_batch_size = next(iter(feats.values())).shape[1]
+        fn = self._get_fused_step((fms is not None, lms is not None),
+                                  len(batches))
         (self.params_tree, self.updater_state, self.state_tree, self._rng,
          losses) = fn(self.params_tree, self.updater_state, self.state_tree,
                       np.int32(self.iteration), self._rng,
-                      stk(0), stk(1), stk(2), stk(3))
+                      feats, labs, fms, lms)
         return losses
 
     def _fit_tbptt(self, feats, labs, fmasks, lmasks) -> float:
@@ -665,7 +598,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
             step = 0
             for _ in range(epochs):
                 for ds in it:
-                    feats, _, _, _ = self._to_dicts(ds)
+                    feats, _, _, _ = self._batch_args(ds)
                     self._rng, k = jax.random.split(self._rng)
                     lp, opt, _ = pre_step(
                         self.params_tree, self.params_tree[name], opt,
@@ -693,7 +626,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         return outs[0] if len(outs) == 1 else outs
 
     def score(self, ds: Union[DataSet, MultiDataSet]) -> float:
-        feats, labs, fmasks, lmasks = self._to_dicts(ds)
+        feats, labs, fmasks, lmasks = self._batch_args(ds)
         loss, _ = self._loss(self.params_tree, self.state_tree, feats, labs,
                              fmasks, lmasks, rng=None, train=False)
         return float(loss)
@@ -760,7 +693,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         """Per-output metrics for multi-output graphs in ONE forward pass
         per batch: returns {output_name: Evaluation}. Accepts DataSet
         (single-output graphs) or MultiDataSet iterators (labels matched
-        to outputs by position, the _to_dicts ordering). RecordMetaData
+        to outputs by position, the _batch_args ordering). RecordMetaData
         from a meta-collecting iterator flows into every head's
         Prediction records. Reference: `nn/graph/ComputationGraph.java`
         evaluate family (single-output) — multi-output eval is a

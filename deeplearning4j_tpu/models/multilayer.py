@@ -18,6 +18,7 @@ the autodiff transpose.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -30,10 +31,12 @@ from deeplearning4j_tpu.data.iterators import (
     DataSetIterator, DevicePrefetchIterator, as_iterator,
 )
 from deeplearning4j_tpu.models.decode_state import DecodeState
-from deeplearning4j_tpu.observe import donatemon
 from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
 from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
+from deeplearning4j_tpu.optim.step import (
+    jit_step, make_fused_step, make_train_step, stack_step_args,
+)
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.recurrent import (
     BaseRecurrentLayer, Bidirectional, GravesBidirectionalLSTM, LastTimeStep,
@@ -45,7 +48,7 @@ from deeplearning4j_tpu.parallel.ring_attention import (
     SeqCtxJitCache, SeqCtxSolverCache,
 )
 from deeplearning4j_tpu.utils.pytrees import (
-    flatten_params, param_count, tree_norm, unflatten_params,
+    flatten_params, param_count, unflatten_params,
 )
 
 _tmap = jax.tree_util.tree_map
@@ -101,36 +104,6 @@ def _checkpointed(apply_fn, mask):
     return jax.checkpoint(
         lambda p, x, st, lr, _a=apply_fn:
         _a(p, x, state=st, train=True, rng=lr, mask=mask))
-
-
-def _normalize_grads(grads, mode: str, threshold: float):
-    """Gradient normalization/clipping per layer subtree.
-    Reference: `nn/conf/GradientNormalization.java` applied in BaseLayer."""
-    if mode == "none":
-        return grads
-    if mode == "clip_elementwise_absolute_value":
-        return _tmap(lambda g: jnp.clip(g, -threshold, threshold), grads)
-
-    def per_layer(sub):
-        if mode == "renormalize_l2_per_layer":
-            n = tree_norm(sub)
-            return _tmap(lambda g: g / jnp.maximum(n, 1e-8), sub)
-        if mode == "clip_l2_per_layer":
-            n = tree_norm(sub)
-            scale = jnp.minimum(1.0, threshold / jnp.maximum(n, 1e-8))
-            return _tmap(lambda g: g * scale, sub)
-        if mode == "renormalize_l2_per_param_type":
-            return {k: v / jnp.maximum(jnp.linalg.norm(jnp.ravel(v)), 1e-8)
-                    for k, v in sub.items()}
-        if mode == "clip_l2_per_param_type":
-            out = {}
-            for k, v in sub.items():
-                n = jnp.linalg.norm(jnp.ravel(v))
-                out[k] = v * jnp.minimum(1.0, threshold / jnp.maximum(n, 1e-8))
-            return out
-        raise ValueError(mode)
-
-    return {name: per_layer(sub) for name, sub in grads.items()}
 
 
 class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
@@ -289,17 +262,20 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         """The pure (un-jitted) train-step function — also consumed by the
         parallel trainers, which re-jit it with mesh shardings (DP/TP),
         the way the reference's ParallelWrapper wraps the same model fit."""
-        return self._build_step(( False, False, tbptt), jit=False)
+        return make_train_step(
+            functools.partial(self._loss, train=True), self._layer_updaters,
+            grad_norm=(self.conf.gradient_normalization,
+                       self.conf.gradient_normalization_threshold),
+            stateful=self._stateful,
+            carry_names=self._rnn_layer_names if tbptt else None)
 
     def _get_train_step(self, key):
+        """The jitted step; `key` is (has_fmask, has_lmask, tbptt)."""
         if key in self._jit_cache:
             return self._jit_cache[key]
-        fn = self._build_step(key, jit=True)
-        self._jit_cache[key] = fn
-        # read back through the cache: __setitem__ may have wrapped the
-        # callable in the watchdog's cost/comm probe, and returning the
-        # raw local lets the FIRST dispatch bypass the ledger
-        return self._jit_cache[key]
+        return jit_step(self.make_step_fn(tbptt=key[2]),
+                        cache=self._jit_cache, key=key,
+                        name="MultiLayerNetwork._step")
 
     @property
     def _rnn_layer_names(self):
@@ -316,53 +292,6 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
             self._decode_names_cache = [
                 l.name for l in self.layers if hasattr(l, "decode_carry")]
         return self._decode_names_cache
-
-    def _build_step(self, key, jit: bool):
-        has_fmask, has_lmask, tbptt = key[0], key[1], key[2]
-        mode = self.conf.gradient_normalization
-        thr = self.conf.gradient_normalization_threshold
-        updaters = self._layer_updaters
-        stateful = self._stateful
-        rnn_names = self._rnn_layer_names
-
-        def step_fn(params, opt_state, states, step, features, labels,
-                    fmask, lmask, rng, carries):
-            def loss_fn(p):
-                return self._loss(p, states, features, labels, fmask, lmask,
-                                  rng, train=True, carries=carries)
-
-            (loss, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            grads = _normalize_grads(grads, mode, thr)
-            new_params, new_opt = {}, {}
-            with jax.named_scope("updater"):
-                for name, u in updaters.items():
-                    # One seam for the whole read-modify-write: the
-                    # default is apply() + dtype-preserving subtract
-                    # exactly as before; Adam/Nesterovs may route through
-                    # the one-pass fused Pallas kernel
-                    # (ops/fused_update.py) when the measured policy
-                    # selects it.
-                    new_params[name], new_opt[name] = \
-                        u.update_with_params(grads[name], opt_state[name],
-                                             params[name], step)
-            persist = {
-                n: (new_states[n] if n in stateful else states.get(n, {}))
-                for n in states
-            }
-            out_carries = {
-                n: _tmap(jax.lax.stop_gradient, new_states[n]) for n in rnn_names
-            } if tbptt else {}
-            return new_params, new_opt, persist, loss, out_carries
-
-        if not jit:
-            return step_fn
-        # donatemon.instrument is identity with DL4J_TPU_DONATEMON off;
-        # on, it witnesses the (params, opt_state, states) donation.
-        return donatemon.instrument(
-            jax.jit(step_fn, donate_argnums=(0, 1, 2)), (0, 1, 2),
-            name="MultiLayerNetwork._step",
-            arg_names=("params", "opt_state", "states"))
 
     # ---------------------------------------------------------- fit API
     def fit(self, data, labels=None, *, epochs: int = 1, batch_size: int = 32,
@@ -431,33 +360,27 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         cache_key = ("fused", key, k)
         if cache_key in self._jit_cache:
             return self._jit_cache[cache_key]
-        base = self._build_step(key, jit=False)
+        return jit_step(make_fused_step(self.make_step_fn()),
+                        cache=self._jit_cache, key=cache_key,
+                        name="MultiLayerNetwork._fused_step")
 
-        def fused(params, opt_state, states, step0, rng, feats, labs, fms,
-                  lms):
-            # rng rides in the carry and splits INSIDE the scan — same
-            # `self._rng, k = split(self._rng)` chain as the K=1 path
-            # (bit-identical subkeys), but zero per-step host dispatches.
-            def body(carry, xs):
-                p, o, s, step, r = carry
-                f, l, fm, lm = xs
-                r, sub = jax.random.split(r)
-                new_p, new_o, persist, loss, _ = base(
-                    p, o, s, step, f, l, fm, lm, sub, None)
-                return (new_p, new_o, persist, step + 1, r), loss
+    def _batch_args(self, ds: DataSet, host: bool = False):
+        """A DataSet as the step's batch arguments `(features, labels,
+        fmask, lmask)`, features in the net's dtype (cast on the device
+        when they are there already). `host=True` keeps leaves as numpy:
+        the caller places them in one upload."""
+        asarray = np.asarray if host else jnp.asarray
+        opt = lambda a: None if a is None else asarray(a)
+        return (asarray(ds.features, self.dtype), opt(ds.labels),
+                opt(ds.features_mask), opt(ds.labels_mask))
 
-            (params, opt_state, states, _, rng), losses = jax.lax.scan(
-                body, (params, opt_state, states, step0, rng),
-                (feats, labs, fms, lms))
-            return params, opt_state, states, rng, losses
-
-        fn = donatemon.instrument(
-            jax.jit(fused, donate_argnums=(0, 1, 2)), (0, 1, 2),
-            name="MultiLayerNetwork._fused_step",
-            arg_names=("params", "opt_state", "states"))
-        self._jit_cache[cache_key] = fn
-        # read back through the cache (probe wrapping; see _get_train_step)
-        return self._jit_cache[cache_key]
+    def _stacked_batch_args(self, batches: List[DataSet]):
+        """K same-shape batches as the fused step's arguments, stacked on
+        a leading axis: on the host (numpy) when the batches are there, so
+        the caller's placement is each tensor's one transfer."""
+        host = isinstance(batches[0].features, np.ndarray)
+        return stack_step_args(
+            [self._batch_args(b, host=host) for b in batches])
 
     def _fused_dispatch(self, batches: List[DataSet]):
         """Run K stacked same-shape batches as ONE `lax.scan` dispatch.
@@ -469,24 +392,10 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         key = (first.features_mask is not None,
                first.labels_mask is not None, False)
         fn = self._get_fused_step(key, len(batches))
-
-        def stk(get, dtype=None):
-            vals = [get(b) for b in batches]
-            if vals[0] is None:
-                return None
-            if all(isinstance(v, np.ndarray) for v in vals):
-                # host-resident batches: one np.stack + ONE device transfer
-                # instead of K asarray dispatches + a device concat
-                return jnp.asarray(np.stack(vals), dtype)
-            return jnp.stack([jnp.asarray(v, dtype) for v in vals])
-
         (self.params_tree, self.updater_state, self.state_tree, self._rng,
          losses) = fn(self.params_tree, self.updater_state, self.state_tree,
                       np.int32(self.iteration), self._rng,
-                      stk(lambda b: b.features, self.dtype),
-                      stk(lambda b: b.labels),
-                      stk(lambda b: b.features_mask),
-                      stk(lambda b: b.labels_mask))
+                      *_tmap(jnp.asarray, self._stacked_batch_args(batches)))
         return losses
 
     def _split_rng(self):
@@ -524,23 +433,13 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
             # Solver.java builds the configured optimizer per fit call.
             from deeplearning4j_tpu.optim.solvers import fit_with_solver
 
-            return fit_with_solver(
-                self, jnp.asarray(ds.features, self.dtype),
-                None if ds.labels is None else jnp.asarray(ds.labels),
-                None if ds.features_mask is None
-                else jnp.asarray(ds.features_mask),
-                None if ds.labels_mask is None
-                else jnp.asarray(ds.labels_mask))
+            return fit_with_solver(self, *self._batch_args(ds))
         key = (ds.features_mask is not None, ds.labels_mask is not None, False)
         fn = self._get_train_step(key)
-        (self.params_tree, self.updater_state, self.state_tree, loss, _
+        (self.params_tree, self.updater_state, self.state_tree, loss, *_
          ) = fn(self.params_tree, self.updater_state, self.state_tree,
                 jnp.asarray(self.iteration, jnp.int32),
-                jnp.asarray(ds.features, self.dtype),
-                None if ds.labels is None else jnp.asarray(ds.labels),
-                None if ds.features_mask is None else jnp.asarray(ds.features_mask),
-                None if ds.labels_mask is None else jnp.asarray(ds.labels_mask),
-                self._split_rng(), None)
+                *self._batch_args(ds), self._split_rng(), None)
         # Deferred sync: the loss stays on device — LossTracker/score_
         # materializes it only on demand (async-dispatch contract).
         return loss
@@ -576,14 +475,15 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     None if ds.features_mask is None
                     else jnp.asarray(ds.features_mask[:, lo:t_lo]),
                     carries)
-            sl = lambda a: None if a is None else jnp.asarray(a[:, t_lo:hi])
+            sl = lambda a: None if a is None else a[:, t_lo:hi]
+            chunk = DataSet(sl(ds.features), sl(ds.labels),
+                            sl(ds.features_mask), sl(ds.labels_mask))
             (self.params_tree, self.updater_state, self.state_tree, loss,
              carries) = fn(
                 self.params_tree, self.updater_state, self.state_tree,
                 jnp.asarray(self.iteration, jnp.int32),
-                jnp.asarray(ds.features[:, t_lo:hi], self.dtype),
-                sl(ds.labels), sl(ds.features_mask), sl(ds.labels_mask),
-                self._split_rng(), carries if carries else None)
+                *self._batch_args(chunk), self._split_rng(),
+                carries if carries else None)
             losses.append(loss)
         self.last_batch_size = ds.num_examples()
         # Mean on device — one divide instead of len(losses) host syncs.

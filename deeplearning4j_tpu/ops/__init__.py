@@ -20,11 +20,6 @@ from deeplearning4j_tpu.ops.banded_attention import (
     banded_eligible,
     decode_eligible,
 )
-from deeplearning4j_tpu.ops.fused_update import (
-    adam_update,
-    fused_update_available,
-    nesterov_update,
-)
 
 __all__ = [
     "fused_lstm",
@@ -34,7 +29,4 @@ __all__ = [
     "banded_decode_attention",
     "banded_eligible",
     "decode_eligible",
-    "adam_update",
-    "fused_update_available",
-    "nesterov_update",
 ]

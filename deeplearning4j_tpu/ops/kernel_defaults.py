@@ -31,7 +31,6 @@ Policy, in order:
        DL4J_TPU_KV_DTYPE       = auto|native|int8|fp8 (KV-cache storage)
        DL4J_TPU_PREFIX_CACHE   = auto|on|off  (paged KV prefix reuse)
        DL4J_TPU_KV_PAGE        = int (KV page length; snapped to divisors)
-       DL4J_TPU_FUSED_UPDATE   = auto|fused|xla      (optimizer update)
   2. Shape eligibility: flash needs the TPU backend and 128-lane-tileable
      sequence lengths; otherwise dense.
   3. Memory necessity: when Tq*Tk >= DENSE_MAX_T^2 (default 8192^2) the
@@ -653,32 +652,6 @@ def prefix_cache_policy(page_len: Optional[int] = None, *,
     return paged("structural default: a warm prefix replaces its whole "
                  "prefill; admission-time bookkeeping costs the "
                  "steady-state window nothing")
-
-
-def fused_update_policy(kind: str) -> str:
-    """"fused" (one-pass Pallas read-modify-write) or "xla" for the
-    optimizer update of `kind` ("adam" | "nesterov"). Env hatch
-    DL4J_TPU_FUSED_UPDATE forces either way (force runs off-TPU via
-    interpret mode — the CPU integration seam); otherwise a winning
-    MEASURED["fused_update"][kind] row is required, with the XLA path
-    the conservative no-data default."""
-    forced = _env("DL4J_TPU_FUSED_UPDATE")
-    op = f"{kind}_update"
-    if forced == "xla":
-        record_dispatch(op, "xla")
-        return "xla"
-    if forced == "fused":
-        record_dispatch(op, "fused")
-        return "fused"
-    from deeplearning4j_tpu.ops.fused_update import fused_update_available
-
-    row = MEASURED.get("fused_update", {}).get(kind)
-    if (fused_update_available() and row is not None
-            and row["winner"] == "fused"):
-        record_dispatch(op, "fused")
-        return "fused"
-    record_dispatch(op, "xla")
-    return "xla"
 
 
 def lstm_policy(train: bool = True) -> str:

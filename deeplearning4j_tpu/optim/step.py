@@ -1,0 +1,161 @@
+"""The train step, built once for every trainer.
+
+`MultiLayerNetwork`, `ComputationGraph` and `ParallelWrapper` hand this
+module their loss and their updaters and get back the pure step
+(`make_train_step`), its K-step `lax.scan` window (`make_fused_step`) and
+the donating jit of either (`jit_step`). `optim/executor.py` drives what
+comes out. The models keep what is theirs: the forward pass, the loss, and
+turning a batch into the step's arguments.
+
+Reference parity: the solver step of
+`optimize/solvers/StochasticGradientDescent.java:58-98` (gradient, gradient
+normalization, updater, `StepFunction.step`) as one traced function.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu.observe import donatemon
+from deeplearning4j_tpu.utils.pytrees import tree_norm
+
+__all__ = ["make_train_step", "make_fused_step", "stack_step_args",
+           "jit_step", "normalize_grads"]
+
+_tmap = jax.tree_util.tree_map
+
+
+def normalize_grads(grads, mode: str, threshold: float):
+    """Gradient normalization/clipping per layer subtree.
+    Reference: `nn/conf/GradientNormalization.java` applied in BaseLayer."""
+    if mode == "none":
+        return grads
+    if mode == "clip_elementwise_absolute_value":
+        return _tmap(lambda g: jnp.clip(g, -threshold, threshold), grads)
+
+    def per_layer(sub):
+        if mode == "renormalize_l2_per_layer":
+            n = tree_norm(sub)
+            return _tmap(lambda g: g / jnp.maximum(n, 1e-8), sub)
+        if mode == "clip_l2_per_layer":
+            n = tree_norm(sub)
+            scale = jnp.minimum(1.0, threshold / jnp.maximum(n, 1e-8))
+            return _tmap(lambda g: g * scale, sub)
+        if mode == "renormalize_l2_per_param_type":
+            return {k: v / jnp.maximum(jnp.linalg.norm(jnp.ravel(v)), 1e-8)
+                    for k, v in sub.items()}
+        if mode == "clip_l2_per_param_type":
+            out = {}
+            for k, v in sub.items():
+                n = jnp.linalg.norm(jnp.ravel(v))
+                out[k] = v * jnp.minimum(1.0, threshold / jnp.maximum(n, 1e-8))
+            return out
+        raise ValueError(mode)
+
+    return {name: per_layer(sub) for name, sub in grads.items()}
+
+
+def make_train_step(loss_fn, updaters, *, grad_norm, stateful,
+                    carry_names=None):
+    """The pure (un-jitted) train step.
+
+    `loss_fn(params, states, features, labels, fmask, lmask, rng,
+    carries=...) -> (loss, new_states)` is the model's training loss;
+    features, labels and masks are arrays or dicts, as that loss takes
+    them. `updaters` maps
+    each top-level key of `params` to its `Updater`, `grad_norm` is the
+    configuration's `(mode, threshold)`, and `stateful` names the layers
+    whose new state persists (batch-norm statistics).
+
+    Returns `(params, opt_state, states, loss)` and, only with
+    `carry_names` (truncated BPTT), a fifth value: those layers' new states
+    with the gradient stopped, for the next chunk.
+    """
+    mode, threshold = grad_norm
+
+    def step_fn(params, opt_state, states, step, features, labels, fmask,
+                lmask, rng, carries=None):
+        def loss_of(p):
+            return loss_fn(p, states, features, labels, fmask, lmask, rng,
+                           carries=carries)
+
+        (loss, new_states), grads = jax.value_and_grad(
+            loss_of, has_aux=True)(params)
+        grads = normalize_grads(grads, mode, threshold)
+        new_params, new_opt = {}, {}
+        with jax.named_scope("updater"):
+            for name, u in updaters.items():
+                new_params[name], new_opt[name] = u.update_with_params(
+                    grads[name], opt_state[name], params[name], step)
+        persist = {
+            n: (new_states[n] if n in stateful else states.get(n, {}))
+            for n in states
+        }
+        if carry_names is None:
+            return new_params, new_opt, persist, loss
+        # Carry RNN state to the next chunk, gradients truncated at the
+        # chunk boundary (reference: `rnnUpdateStateWithTBPTTState`).
+        return new_params, new_opt, persist, loss, {
+            n: _tmap(jax.lax.stop_gradient, new_states[n])
+            for n in carry_names}
+
+    return step_fn
+
+
+def make_fused_step(step_fn):
+    """K train steps as ONE `lax.scan` over batches stacked on a leading
+    axis: `(params, opt_state, states, step0, rng, features, labels, fmask,
+    lmask) -> (params, opt_state, states, rng, losses)`."""
+
+    def fused(params, opt_state, states, step0, rng, feats, labs, fms, lms):
+        # rng rides in the carry and splits INSIDE the scan: the same
+        # `rng, sub = split(rng)` chain as K single dispatches (bit-identical
+        # subkeys), with no per-step host dispatch.
+        def body(carry, xs):
+            p, o, s, step, r = carry
+            f, l, fm, lm = xs
+            r, sub = jax.random.split(r)
+            new_p, new_o, persist, loss = step_fn(
+                p, o, s, step, f, l, fm, lm, sub)
+            return (new_p, new_o, persist, step + 1, r), loss
+
+        (params, opt_state, states, _, rng), losses = jax.lax.scan(
+            body, (params, opt_state, states, step0, rng),
+            (feats, labs, fms, lms))
+        return params, opt_state, states, rng, losses
+
+    return fused
+
+
+def stack_step_args(per_batch):
+    """K batches' step arguments `(features, labels, fmask, lmask)` as one
+    such tuple, every leaf stacked on a new leading axis: what the fused
+    step scans over. Leaves that are all numpy stack on the host, so the
+    caller's placement is each tensor's one transfer."""
+    def stack(*leaves):
+        if all(isinstance(v, np.ndarray) for v in leaves):
+            return np.stack(leaves)
+        return jnp.stack(leaves)
+
+    return _tmap(stack, *per_batch)
+
+
+def jit_step(fn, *, cache, key, name, in_shardings=None, out_shardings=None):
+    """Jit a step (or a window of steps) with its first three arguments
+    (params, opt_state, states) donated, and keep it in the owner's
+    `_jit_cache` under `key`. `name` is what `donatemon` calls it
+    (identity with DL4J_TPU_DONATEMON off; on, it witnesses the donation).
+    With shardings both ends are pinned: the donated buffers come back
+    where they were placed."""
+    placed = {} if in_shardings is None else {
+        "in_shardings": in_shardings, "out_shardings": out_shardings}
+    cache[key] = donatemon.instrument(
+        jax.jit(fn, donate_argnums=(0, 1, 2), **placed), (0, 1, 2),
+        name=name, arg_names=("params", "opt_state", "states"))
+    # read back through the cache: __setitem__ may have wrapped the
+    # callable in the watchdog's cost/comm probe, and returning the raw
+    # local lets the FIRST dispatch (often the only one in a short fit)
+    # bypass the ledger
+    return cache[key]

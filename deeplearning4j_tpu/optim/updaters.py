@@ -50,15 +50,10 @@ class Updater:
         raise NotImplementedError
 
     def update_with_params(self, grads, state, params, step):
-        """The whole optimizer step as ONE seam: returns (new_params,
-        new_state). The default composes `apply` with the subtraction the
-        step functions used to do inline, preserving dtypes identically
-        (schedules may promote to f32; params/state keep their configured
-        dtype for bf16 training and buffer donation). Adam/Nesterovs
-        override this to route through the one-pass fused Pallas kernel
-        (`ops/fused_update.py`) when `kernel_defaults.fused_update_policy`
-        says it wins — the seam exists so that choice is per-updater,
-        not per-model."""
+        """The whole optimizer step: `apply`, then the subtraction, and
+        returns (new_params, new_state) in the dtypes they came in
+        (schedules may promote to f32; bf16 training and buffer donation
+        rest on parameters and state keeping theirs)."""
         upd, st = self.apply(grads, state, params, step)
         new_params = _tmap(lambda a, b: a - b.astype(a.dtype), params, upd)
         new_state = _tmap(lambda n, o: n.astype(o.dtype), st, state)
@@ -66,26 +61,6 @@ class Updater:
 
     # learning-rate accessor shared by all (schedule-aware)
     lr = _lr
-
-
-def _fused_interpret() -> bool:
-    """An env-forced fused update off-TPU runs the kernel in interpret
-    mode (the CPU parity/integration seam; slow but exact)."""
-    return jax.default_backend() != "tpu"
-
-
-def _moments_replica_sharded() -> bool:
-    """Trace-time check: is an active sharding spine partitioning the
-    optimizer moments across the replica axis? The fused-update Pallas
-    kernels are slot-local (one contiguous buffer per leaf) — running
-    them over replica-sharded moments would force XLA to all-gather the
-    very state the spine just split, so the fused path defers to the
-    XLA update whenever the spine owns moment placement."""
-    from deeplearning4j_tpu.parallel.mesh import current_mesh_context
-
-    ctx = current_mesh_context()
-    return (ctx is not None and ctx.shard_opt_state
-            and ctx.data_size > 1)
 
 
 @register_serde
@@ -130,27 +105,6 @@ class Nesterovs(Updater):
         updates = _tmap(lambda vn, g: -(mu * vn - lr * g), v_new, grads)
         return updates, {"v": v_new}
 
-    def update_with_params(self, grads, state, params, step):
-        from deeplearning4j_tpu.ops.kernel_defaults import (
-            fused_update_policy,
-        )
-
-        if fused_update_policy("nesterov") != "fused" \
-                or _moments_replica_sharded():
-            return super().update_with_params(grads, state, params, step)
-        from deeplearning4j_tpu.ops.fused_update import nesterov_update
-
-        lr = jnp.asarray(self.lr(step), jnp.float32)
-        interp = _fused_interpret()
-        lp, treedef = jax.tree_util.tree_flatten(params)
-        lg = treedef.flatten_up_to(grads)
-        lv = treedef.flatten_up_to(state["v"])
-        outs = [nesterov_update(p, g, v, lr, momentum=self.momentum,
-                                interpret=interp)
-                for p, g, v in zip(lp, lg, lv)]
-        return (treedef.unflatten([o[0] for o in outs]),
-                {"v": treedef.unflatten([o[1] for o in outs])})
-
 
 @register_serde
 @dataclasses.dataclass(frozen=True)
@@ -175,33 +129,6 @@ class Adam(Updater):
         bc = jnp.sqrt(1.0 - b2**t) / (1.0 - b1**t)
         updates = _tmap(lambda m, v: lr * bc * m / (jnp.sqrt(v) + self.epsilon), m, v)
         return updates, {"m": m, "v": v}
-
-    def update_with_params(self, grads, state, params, step):
-        from deeplearning4j_tpu.ops.kernel_defaults import (
-            fused_update_policy,
-        )
-
-        if fused_update_policy("adam") != "fused" \
-                or _moments_replica_sharded():
-            return super().update_with_params(grads, state, params, step)
-        from deeplearning4j_tpu.ops.fused_update import adam_update
-
-        # Per-step scalars (schedule + bias correction) fold into ONE
-        # traced coefficient; the kernel does the per-element work.
-        t = jnp.asarray(step, jnp.float32) + 1.0
-        b1, b2 = self.beta1, self.beta2
-        lrbc = self.lr(step) * jnp.sqrt(1.0 - b2**t) / (1.0 - b1**t)
-        interp = _fused_interpret()
-        lp, treedef = jax.tree_util.tree_flatten(params)
-        lg = treedef.flatten_up_to(grads)
-        lm = treedef.flatten_up_to(state["m"])
-        lv = treedef.flatten_up_to(state["v"])
-        outs = [adam_update(p, g, m, v, lrbc, beta1=b1, beta2=b2,
-                            eps=self.epsilon, interpret=interp)
-                for p, g, m, v in zip(lp, lg, lm, lv)]
-        return (treedef.unflatten([o[0] for o in outs]),
-                {"m": treedef.unflatten([o[1] for o in outs]),
-                 "v": treedef.unflatten([o[2] for o in outs])})
 
 
 @register_serde

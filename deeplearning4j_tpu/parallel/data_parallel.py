@@ -27,9 +27,9 @@ from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.data.iterators import (
     DevicePrefetchIterator, as_iterator,
 )
-from deeplearning4j_tpu.observe import donatemon
 from deeplearning4j_tpu.optim.executor import TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import RecoveryPlan, run_with_recovery
+from deeplearning4j_tpu.optim.step import jit_step, make_fused_step
 from deeplearning4j_tpu.parallel.distributed import (
     put_global, put_global_batch,
 )
@@ -38,12 +38,6 @@ from deeplearning4j_tpu.parallel.mesh import (
 )
 from deeplearning4j_tpu.parallel.ring_attention import SeqCtxJitCache
 from deeplearning4j_tpu.parallel.sharding import ShardingRules
-
-
-def _is_graph(net) -> bool:
-    from deeplearning4j_tpu.models.computation_graph import ComputationGraph
-
-    return isinstance(net, ComputationGraph)
 
 
 class ParallelWrapper(SeqCtxJitCache):
@@ -90,7 +84,6 @@ class ParallelWrapper(SeqCtxJitCache):
         self.batch_axis = spine.batch_axis
         self.param_rules = spine.rules
         self.prefetch = prefetch_buffer
-        self._graph = _is_graph(net)
         self.last_batch_index = -1   # in-epoch position (elastic resume)
         self.stopped_early = False   # did the last fit() stop via stop_fn?
 
@@ -135,45 +128,19 @@ class ParallelWrapper(SeqCtxJitCache):
     def _get_step(self, key, example_args):
         if key in self._jit_cache:
             return self._jit_cache[key]
-        base = self.net.make_step_fn()
-        if self._graph:
-            # (params, opt, states, step, inputs, labels, fmasks, lmasks, rng)
-            _, _, _, _, feats, labs, fms, lms, _ = example_args
-            in_sh = (self._params_sh, self._opt_sh, self._rep, self._rep,
-                     self._batch_sharding_like(feats),
-                     self._batch_sharding_like(labs),
-                     self._batch_sharding_like(fms),
-                     self._batch_sharding_like(lms),
-                     self._rep)
-            # (params, opt, states, loss)
-            out_sh = (self._params_sh, self._opt_sh, self._rep, self._rep)
-        else:
-            # (params, opt, states, step, feats, labels, fm, lm, rng, carries)
-            _, _, _, _, feats, labs, fm, lm, _, _ = example_args
-            in_sh = (self._params_sh, self._opt_sh, self._rep, self._rep,
-                     self._batch_sharding_like(feats),
-                     self._batch_sharding_like(labs),
-                     self._batch_sharding_like(fm),
-                     self._batch_sharding_like(lm),
-                     self._rep, None)
-            # (params, opt, persist, loss, carries)
-            out_sh = (self._params_sh, self._opt_sh, self._rep, self._rep,
-                      None)
-        # out_shardings pin the donated params/opt buffers to their input
-        # placement — the moments stay replica-sharded through the update
-        # instead of silently re-replicating (the regression the perf
-        # gate's opt_state_shard_factor budget exists to catch).
-        fn = donatemon.instrument(
-            jax.jit(base, in_shardings=in_sh, out_shardings=out_sh,
-                    donate_argnums=(0, 1, 2)), (0, 1, 2),
-            name="ParallelWrapper._step",
-            arg_names=("params", "opt_state", "states"))
-        self._jit_cache[key] = fn
-        # read back through the cache: __setitem__ may have wrapped the
-        # callable in the watchdog's cost/comm probe, and returning the
-        # raw local would let the FIRST dispatch (often the only one in
-        # a short fit) bypass the ledger entirely
-        return self._jit_cache[key]
+        # (params, opt, states, step, features, labels, fmask, lmask, rng)
+        in_sh = (self._params_sh, self._opt_sh, self._rep, self._rep,
+                 *map(self._batch_sharding_like, example_args[4:8]),
+                 self._rep)
+        # (params, opt, states, loss): out_shardings pin the donated
+        # params/opt buffers to their input placement — the moments stay
+        # replica-sharded through the update instead of silently
+        # re-replicating (the regression the perf gate's
+        # opt_state_shard_factor budget exists to catch).
+        out_sh = (self._params_sh, self._opt_sh, self._rep, self._rep)
+        return jit_step(self.net.make_step_fn(), cache=self._jit_cache,
+                        key=key, name="ParallelWrapper._step",
+                        in_shardings=in_sh, out_shardings=out_sh)
 
     # -------------------------------------------------------------- fit
     def _pad_to_divisible(self, ds):
@@ -295,61 +262,37 @@ class ParallelWrapper(SeqCtxJitCache):
         self.stopped_early = execu.stopped  # authoritative for ElasticTrainer
         return net
 
-    def _put_batch(self, x):
-        """Multi-controller feed: lift this process's local slice into the
-        global batch array (concatenation over processes)."""
-        if x is None:
-            return None
-        if isinstance(x, dict):
-            return {k: self._put_batch(v) for k, v in x.items()}
-        return put_global_batch(x, self._batch_sharding_like(x))
+    def _put_batch(self, batch):
+        """Multi-controller feed: lift this process's local slice of every
+        leaf into the global batch array (concatenation over processes)."""
+        return jax.tree_util.tree_map(
+            lambda x: put_global_batch(x, self.spine.batch_sharding(x.ndim)),
+            batch)
+
+    @staticmethod
+    def _shape_key(kind, batch):
+        """Cache key of a step over `batch`: names and ranks, which the
+        in_shardings are built from."""
+        leaves, treedef = jax.tree_util.tree_flatten(batch)
+        return (kind, treedef, tuple(x.ndim for x in leaves))
 
     def _step(self, ds):
         net = self.net
         net._rng, k = jax.random.split(net._rng)
+        # Multi-controller: keep the local slice on host (numpy) so
+        # put_global_batch uploads once — no device round-trip.
+        batch = net._batch_args(ds, host=self._nproc > 1)
         if self._nproc > 1:
             step = put_global(np.int32(net.iteration), self._rep)
             k = put_global(k, self._rep)
+            batch = self._put_batch(batch)
         else:
             step = jnp.asarray(net.iteration, jnp.int32)
-        if self._graph:
-            feats, labs, fms, lms = net._to_dicts(ds, host=self._nproc > 1)
-            if self._nproc > 1:
-                feats, labs, fms, lms = (self._put_batch(feats),
-                                         self._put_batch(labs),
-                                         self._put_batch(fms),
-                                         self._put_batch(lms))
-            args = (net.params_tree, net.updater_state, net.state_tree, step,
-                    feats, labs, fms, lms, k)
-            key = ("g", tuple(sorted(feats)), tuple(sorted(labs)),
-                   fms is not None, lms is not None)
-            fn = self._get_step(key, args)
-            (net.params_tree, net.updater_state, net.state_tree, loss
-             ) = fn(*args)
-        else:
-            # Multi-controller: keep the local slice on host (numpy) so
-            # put_global_batch uploads once — no device round-trip.
-            conv = (lambda a, dt=None: np.asarray(a, dt)) if self._nproc > 1 \
-                else jnp.asarray
-            feats = conv(ds.features, net.dtype)
-            labs = None if ds.labels is None else conv(ds.labels)
-            fm = (None if ds.features_mask is None
-                  else conv(ds.features_mask))
-            lm = (None if ds.labels_mask is None
-                  else conv(ds.labels_mask))
-            if self._nproc > 1:
-                feats, labs, fm, lm = (self._put_batch(feats),
-                                       self._put_batch(labs),
-                                       self._put_batch(fm),
-                                       self._put_batch(lm))
-            args = (net.params_tree, net.updater_state, net.state_tree, step,
-                    feats, labs, fm, lm, k, None)
-            key = ("m", ds.features.ndim,
-                   0 if ds.labels is None else ds.labels.ndim,
-                   ds.features_mask is not None, ds.labels_mask is not None)
-            fn = self._get_step(key, args)
-            (net.params_tree, net.updater_state, net.state_tree, loss, _
-             ) = fn(*args)
+        args = (net.params_tree, net.updater_state, net.state_tree, step,
+                *batch, k)
+        fn = self._get_step(self._shape_key("step", batch), args)
+        (net.params_tree, net.updater_state, net.state_tree, loss, *_
+         ) = fn(*args)
         # Deferred sync: replicated device scalar; LossTracker materializes.
         return loss
 
@@ -359,142 +302,45 @@ class ParallelWrapper(SeqCtxJitCache):
         per-step host staging — fusion is single-controller only."""
         return self._nproc == 1
 
-    def _stacked_sharding_like(self, x):
+    def _stacked_sharding(self, ndim: int):
         """(K, batch, ...) stack: scan axis replicated, batch sharded."""
-        if x is None:
-            return None
-        if isinstance(x, dict):
-            return {k: self._stacked_sharding_like(v) for k, v in x.items()}
         return NamedSharding(
-            self.mesh, P(None, self.batch_axis, *([None] * (x.ndim - 2))))
-
-    def _put_stacked(self, x):
-        """Place a (K, batch, ...) stack with the scan axis replicated and
-        the batch axis sharded across the mesh."""
-        if x is None:
-            return None
-        if isinstance(x, dict):
-            return {k: self._put_stacked(v) for k, v in x.items()}
-        return jax.device_put(x, self._stacked_sharding_like(x))
+            self.mesh, P(None, self.batch_axis, *([None] * (ndim - 2))))
 
     def _get_fused_step(self, key, example_args):
         if key in self._jit_cache:
             return self._jit_cache[key]
-        k = key[1]
-        base = self.net.make_step_fn()
-        # rng rides in the scan carry and splits in-graph — the identical
-        # sequential `net._rng, r = split(net._rng)` chain as the unfused
-        # step, with no per-step host dispatch.
-        if self._graph:
-            def fused(params, opt_state, states, step0, rng, feats, labs,
-                      fms, lms):
-                def body(carry, xs):
-                    p, o, s, step, r = carry
-                    f, l, fm, lm = xs
-                    r, sub = jax.random.split(r)
-                    new_p, new_o, persist, loss = base(
-                        p, o, s, step, f, l, fm, lm, sub)
-                    return (new_p, new_o, persist, step + 1, r), loss
-
-                (params, opt_state, states, _, rng), losses = jax.lax.scan(
-                    body, (params, opt_state, states, step0, rng),
-                    (feats, labs, fms, lms))
-                return params, opt_state, states, rng, losses
-        else:
-            def fused(params, opt_state, states, step0, rng, feats, labs,
-                      fms, lms):
-                def body(carry, xs):
-                    p, o, s, step, r = carry
-                    f, l, fm, lm = xs
-                    r, sub = jax.random.split(r)
-                    new_p, new_o, persist, loss, _ = base(
-                        p, o, s, step, f, l, fm, lm, sub, None)
-                    return (new_p, new_o, persist, step + 1, r), loss
-
-                (params, opt_state, states, _, rng), losses = jax.lax.scan(
-                    body, (params, opt_state, states, step0, rng),
-                    (feats, labs, fms, lms))
-                return params, opt_state, states, rng, losses
-
         # Both ends of the K-step scan are pinned: the partitioner must
         # carry the replica-sharded moments through the whole window and
         # hand them back in place — without the explicit in_shardings it
         # re-replicates the carry and the donated moment buffers become
         # unusable (a reshard + 2x moment HBM per dispatch window).
         # (params, opt, states, step0, rng, feats, labs, fms, lms)
-        _, _, _, _, _, feats, labs, fms, lms = example_args
         in_sh = (self._params_sh, self._opt_sh, self._rep, self._rep,
                  self._rep,
-                 self._stacked_sharding_like(feats),
-                 self._stacked_sharding_like(labs),
-                 self._stacked_sharding_like(fms),
-                 self._stacked_sharding_like(lms))
+                 *jax.tree_util.tree_map(
+                     lambda x: self._stacked_sharding(x.ndim),
+                     example_args[5:9]))
         # (params, opt, states, rng, losses)
         out_sh = (self._params_sh, self._opt_sh, self._rep, self._rep,
                   self._rep)
-        fn = donatemon.instrument(
-            jax.jit(fused, in_shardings=in_sh, out_shardings=out_sh,
-                    donate_argnums=(0, 1, 2)), (0, 1, 2),
-            name="ParallelWrapper._fused_step",
-            arg_names=("params", "opt_state", "states"))
-        self._jit_cache[key] = fn
-        # read back through the cache (probe wrapping), as in _get_step
-        return self._jit_cache[key]
+        return jit_step(make_fused_step(self.net.make_step_fn()),
+                        cache=self._jit_cache, key=key,
+                        name="ParallelWrapper._fused_step",
+                        in_shardings=in_sh, out_shardings=out_sh)
 
     def _fused_step(self, batches):
         """K pre-sharded batches → one sharded `lax.scan` dispatch."""
         net = self.net
-        first = batches[0]
-        step0 = np.int32(net.iteration)
-        if self._graph:
-            f0 = first.features
-            host = isinstance(
-                f0[0] if hasattr(first, "features_masks") else f0,
-                np.ndarray)
-            conv = [net._to_dicts(b, host=host) for b in batches]
-            stack = (np.stack if host else jnp.stack)
-
-            def stk(idx):
-                head = conv[0][idx]
-                if head is None:
-                    return None
-                # host batches stack as numpy, so _put_stacked's
-                # device_put is the single host→device hop per tensor
-                return self._put_stacked(
-                    {n: stack([c[idx][n] for c in conv]) for n in head})
-
-            key = ("gf", len(batches), tuple(sorted(conv[0][0])),
-                   tuple(sorted(conv[0][1])),
-                   conv[0][2] is not None, conv[0][3] is not None)
-            args = (net.params_tree, net.updater_state, net.state_tree,
-                    step0, net._rng, stk(0), stk(1), stk(2), stk(3))
-            fn = self._get_fused_step(key, args)
-            (net.params_tree, net.updater_state, net.state_tree, net._rng,
-             losses) = fn(*args)
-        else:
-            def stk(get, dt=None):
-                vals = [get(b) for b in batches]
-                if vals[0] is None:
-                    return None
-                if all(isinstance(v, np.ndarray) for v in vals):
-                    arr = np.stack(vals)
-                    if dt is not None:
-                        arr = arr.astype(dt, copy=False)
-                else:
-                    arr = jnp.stack([jnp.asarray(v, dt) for v in vals])
-                return self._put_stacked(arr)
-
-            key = ("mf", len(batches), first.features.ndim,
-                   0 if first.labels is None else first.labels.ndim,
-                   first.features_mask is not None,
-                   first.labels_mask is not None)
-            args = (net.params_tree, net.updater_state, net.state_tree,
-                    step0, net._rng,
-                    stk(lambda b: b.features, net.dtype),
-                    stk(lambda b: b.labels),
-                    stk(lambda b: b.features_mask),
-                    stk(lambda b: b.labels_mask))
-            fn = self._get_fused_step(key, args)
-            (net.params_tree, net.updater_state, net.state_tree, net._rng,
-             losses) = fn(*args)
+        # host batches come stacked as numpy, so this device_put is the
+        # single host→device hop per tensor
+        stacked = jax.tree_util.tree_map(
+            lambda x: jax.device_put(x, self._stacked_sharding(x.ndim)),
+            net._stacked_batch_args(batches))
+        args = (net.params_tree, net.updater_state, net.state_tree,
+                np.int32(net.iteration), net._rng, *stacked)
+        fn = self._get_fused_step(
+            self._shape_key(("fused", len(batches)), stacked), args)
+        (net.params_tree, net.updater_state, net.state_tree, net._rng,
+         losses) = fn(*args)
         return losses

@@ -131,7 +131,7 @@ def main():
     assert net2.iteration == net.iteration
 
     # ComputationGraph DP across processes: dict-shaped batches flow
-    # through _to_dicts(host=True) + per-process global-batch assembly.
+    # through _batch_args(host=True) + per-process global-batch assembly.
     gnet = make_graph_net()
     DistributedTrainingMaster(mesh=make_mesh({"data": -1})).execute_training(
         gnet, x, y, batch_size=BATCH, epochs=1)

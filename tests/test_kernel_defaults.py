@@ -259,20 +259,6 @@ def test_decode_policy_record_flag_gates_counter(monkeypatch):
     assert c.value == v0 + 1
 
 
-def test_fused_update_policy(monkeypatch):
-    monkeypatch.setenv("DL4J_TPU_FUSED_UPDATE", "fused")
-    assert kd.fused_update_policy("adam") == "fused"
-    monkeypatch.setenv("DL4J_TPU_FUSED_UPDATE", "xla")
-    assert kd.fused_update_policy("adam") == "xla"
-    monkeypatch.delenv("DL4J_TPU_FUSED_UPDATE")
-    for kind in ("adam", "nesterov"):
-        row = kd.MEASURED.get("fused_update", {}).get(kind)
-        if row is None:
-            # no data: XLA is the conservative default (off-TPU the
-            # availability gate forces it regardless)
-            assert kd.fused_update_policy(kind) == "xla"
-
-
 def test_current_data_yields_dense_defaults(monkeypatch):
     """Regression pin for the r4 ADVICE finding: with the rows recorded
     today (flash loses everywhere measured), training and inference

@@ -1321,6 +1321,34 @@ class Net:
     assert "`self.params`" in findings[0].message
 
 
+def test_gl801_through_jit_step():
+    """The trainers' idiom since `optim/step.py`: the getter returns
+    `jit_step(...)`, which donates (params, opt_state, states), so a stale
+    read of one of them between the call and the rebind fires."""
+    src = """
+from deeplearning4j_tpu.optim.step import jit_step
+
+
+class Net:
+    def _get_train_step(self, key):
+        if key in self._jit_cache:
+            return self._jit_cache[key]
+        return jit_step(self.make_step_fn(), cache=self._jit_cache,
+                        key=key, name="Net._step")
+
+    def _fit_batch(self, x):
+        fn = self._get_train_step(0)
+        new_p, new_o, new_s, loss = fn(self.params, self.opt, self.states, x)
+        norm = self.opt             # stale: donated at position 1
+        self.params, self.opt, self.states = new_p, new_o, new_s
+        return norm, loss
+"""
+    findings = [f for f in lint_source(src, "pkg/net.py")
+                if f.rule == "GL801"]
+    assert len(findings) == 1
+    assert "`self.opt`" in findings[0].message
+
+
 def test_gl801_real_pipeline_clean_and_mutant_fires():
     """Regression pin for the audited tree: the shipped
     parallel/pipeline.py same-statement-rebind idiom is GL801-clean,

@@ -28,7 +28,6 @@ import pytest  # noqa: E402
 lstm = importlib.import_module("deeplearning4j_tpu.ops.lstm")
 flash = importlib.import_module("deeplearning4j_tpu.ops.attention")
 banded = importlib.import_module("deeplearning4j_tpu.ops.banded_attention")
-update = importlib.import_module("deeplearning4j_tpu.ops.fused_update")
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -119,13 +118,6 @@ def _decode(paged, cache_dtype, slots=8, cache=1024, page=128, heads=8,
     return fwd, shapes
 
 
-def _adam(shape=(512, 2048), dt=BF16):
-    def fwd(p, g, m, v, lrbc):
-        return update.adam_update(p, g, m, v, lrbc, interpret=False)
-
-    return fwd, [(shape, dt)] * 4 + [((), F32)]
-
-
 CASES = {
     "lstm_fwd_f32": lambda: _lstm(F32, train=False),
     "lstm_fwd_bf16": lambda: _lstm(BF16, train=False),
@@ -138,7 +130,6 @@ CASES = {
     "slot_decode_int8": lambda: _decode(False, I8),
     "paged_decode_bf16": lambda: _decode(True, BF16),
     "paged_decode_int8": lambda: _decode(True, I8),
-    "fused_adam_bf16": _adam,
 }
 
 
@@ -155,13 +146,6 @@ def test_kernel_compiles_for_v5e(chip, name):
 # the TPU platform (Mosaic runs at lowering), which needs no chip and no
 # described topology; nothing is compiled.
 conv_fused = importlib.import_module("deeplearning4j_tpu.ops.conv_fused")
-
-
-def _nesterov(shape=(512, 2048), dt=BF16):
-    def fwd(p, g, v, lr):
-        return update.nesterov_update(p, g, v, lr, interpret=False)
-
-    return fwd, [(shape, dt)] * 3 + [((), F32)]
 
 
 def _matmul_stats(m=1024, k=512, n=256):
@@ -183,8 +167,6 @@ KERNEL_NAMES = {
     "banded_attention_fwd": "banded_fwd_gqa",
     "banded_decode_attention": "slot_decode_bf16",
     "paged_decode_attention": "paged_decode_bf16",
-    "fused_adam_update": "fused_adam_bf16",
-    "fused_nesterov_update": _nesterov,
     "matmul_channel_stats": _matmul_stats,
     "conv3x3_channel_stats": _conv3_stats,
 }

@@ -11,8 +11,6 @@ this framework hand-writes kernels instead of trusting the compiler
       reference: windowed GQA, T in {1024, 2048, 4096}, w = T/8
   - ops/banded_attention.banded_decode_attention  vs  the dense masked
       einsum: single-query decode over [S, L, Hkv, Dh], L in {1024, 4096}
-  - ops/fused_update.{adam,nesterov}_update  vs  the XLA updater math:
-      one-pass read-modify-write, 16M-element leaves (HBM-bound)
   - ops/lstm.fused_lstm            vs  the lax.scan fallback
       forward and forward+backward
 
@@ -248,59 +246,6 @@ def bench_decode(cache_len, banded, block_l=512):
     return r
 
 
-# ---------------------------------------------------- fused optimizer step
-def bench_fused_update(opt, fused):
-    """One optimizer leaf update: the one-pass Pallas read-modify-write
-    vs the XLA expression the updaters build (same math, separate HBM
-    sweeps)."""
-    from deeplearning4j_tpu.ops.fused_update import (
-        adam_update, nesterov_update,
-    )
-    n = 1 << 24   # 16M f32 elements/tensor: decisively HBM-bound
-    key = jax.random.PRNGKey(4)
-    kp, kg = jax.random.split(key)
-    p = jax.random.normal(kp, (n,), jnp.float32)
-    g = jax.random.normal(kg, (n,), jnp.float32) * 1e-2
-    m = jnp.zeros((n,), jnp.float32)
-    v = jnp.zeros((n,), jnp.float32)
-    c = jnp.float32(1e-3)
-
-    if opt == "adam":
-        if fused:
-            def body(i, carry):
-                p, m, v = carry
-                return adam_update(p, g, m, v, c)
-        else:
-            def body(i, carry):
-                p, m, v = carry
-                m2 = 0.9 * m + 0.1 * g
-                v2 = 0.999 * v + 0.001 * g * g
-                return (p - c * m2 / (jnp.sqrt(v2) + 1e-8), m2, v2)
-        run = _loop(body, (p, m, v))
-        ntensors = 5   # read p,m,v + write m',v' dominate (g shared)
-    else:
-        if fused:
-            def body(i, carry):
-                p, v = carry
-                return nesterov_update(p, g, v, c)
-        else:
-            def body(i, carry):
-                p, v = carry
-                v2 = 0.9 * v - c * g
-                return (p + 0.9 * v2 - c * g, v2)
-        run = _loop(body, (p, v))
-        ntensors = 4
-
-    per_iter = _timed_per_iter(run)
-    bytes_moved = ntensors * n * 4
-    return {
-        "name": f"upd_{opt}_{'fused' if fused else 'xla'}",
-        "per_iter_ms": round(per_iter * 1e3, 3),
-        "gb_per_s": round(bytes_moved / per_iter / 1e9, 2),
-        "shape": f"n{n} f32",
-    }
-
-
 # ------------------------------------------------------------------ lstm
 def bench_lstm(train, fused):
     from deeplearning4j_tpu.ops.lstm import _cell, fused_lstm
@@ -394,10 +339,6 @@ def main():
         for banded in (False, True):
             jobs.append(("decode", functools.partial(
                 bench_decode, cache_len, banded)))
-    for opt in ("adam", "nesterov"):
-        for fused in (False, True):
-            jobs.append(("upd", functools.partial(
-                bench_fused_update, opt, fused)))
     for train in (False, True):
         for fused in (False, True):
             jobs.append(("lstm", functools.partial(bench_lstm, train,

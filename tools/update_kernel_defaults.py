@@ -14,11 +14,10 @@ Row-name grammar (kernel_bench.py):
     attn_t{T}_{fwd|train}_{flash|dense}[_bq{B}_bk{B}][_bwddense]
     battn_t{T}_w{W}_{fwd|train}_{banded|dense}[_bq{B}_bk{B}]
     dattn_l{L}_{banded|dense}[_bl{B}]
-    upd_{adam|nesterov}_{fused|xla}
     lstm_{fwd|train}_{fused|scan}
 Legacy flash rows without a block suffix or explicit fields were measured
 at the then-default 128x128 tiles with the pre-Pallas (dense-recompute)
-backward; they are read as such. The banded / decode / fused_update
+backward; they are read as such. The banded / decode
 sections are emitted only when their rows exist — build_table over a
 results file with none of them reproduces the pre-banded table exactly,
 which is what keeps the suite guard green until real measurements land.
@@ -45,7 +44,6 @@ _BATTN = re.compile(
     r"_(?P<kind>banded|dense)(?:_bq(?P<bq>\d+)_bk(?P<bk>\d+))?$")
 _DATTN = re.compile(
     r"^dattn_l(?P<l>\d+)_(?P<kind>banded|dense)(?:_bl(?P<bl>\d+))?$")
-_UPD = re.compile(r"^upd_(?P<opt>adam|nesterov)_(?P<kind>fused|xla)$")
 _LSTM = re.compile(r"^lstm_(?P<mode>fwd|train)_(?P<kind>fused|scan)$")
 
 
@@ -53,7 +51,6 @@ def build_table(rows: dict) -> dict:
     attn = {}   # mode -> T -> {dense_ms, flash candidates}
     banded = {}  # mode -> T -> {dense_ms, banded candidates}
     decode = {}  # L -> {dense_ms, banded candidates}
-    upd = {}    # opt -> {fused_ms, xla_ms}
     lstm = {}   # mode -> {fused_ms, scan_ms}
     devices = set()
     for name, row in rows.items():
@@ -87,11 +84,6 @@ def build_table(rows: dict) -> dict:
                     {"ms": row["per_iter_ms"],
                      "block_l": row.get("block_l") or (
                          int(m.group("bl")) if m.group("bl") else 512)})
-            continue
-        m = _UPD.match(name)
-        if m:
-            upd.setdefault(m.group("opt"), {})[
-                m.group("kind") + "_ms"] = row["per_iter_ms"]
             continue
         m = _ATTN.match(name)
         if m:
@@ -176,16 +168,6 @@ def build_table(rows: dict) -> dict:
         }
     if out_decode:
         table["decode"] = out_decode
-    out_upd = {}
-    for opt, d in sorted(upd.items()):
-        if "fused_ms" in d and "xla_ms" in d:
-            out_upd[opt] = {
-                "fused_ms": d["fused_ms"], "xla_ms": d["xla_ms"],
-                "winner": ("fused" if d["fused_ms"] < d["xla_ms"]
-                           else "xla"),
-            }
-    if out_upd:
-        table["fused_update"] = out_upd
     return table
 
 
