@@ -34,6 +34,9 @@ from deeplearning4j_tpu.nn.graph import (
     ComputationGraphConfiguration, GraphVertex, LayerVertex,
     resolve_output_type,
 )
+from deeplearning4j_tpu.nn.layers.convolution import (
+    defers_to_pool, record_deferred_pairs,
+)
 from deeplearning4j_tpu.nn.layers.special import CenterLossOutputLayer
 from deeplearning4j_tpu.models.multilayer import (
     _check_decode_budget, _checkpointed, _dtype_of, _is_recurrent,
@@ -146,18 +149,48 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
             ]
         return self._decode_names_cache
 
+    @property
+    def _pool_after(self) -> Dict[str, str]:
+        """Convolution vertex -> the max-pool vertex it defers its bias and
+        activation to (`defers_to_pool`): the pool is its ONLY consumer and
+        has no preprocessor, and the convolution is not a network output."""
+        if not hasattr(self, "_pool_after_cache"):
+            conf = self.conf
+            consumers: Dict[str, List[str]] = {}
+            for name, ins in conf.vertex_inputs.items():
+                for i in ins:
+                    consumers.setdefault(i, []).append(name)
+            self._pool_after_cache = {
+                name: cons[0] for name, cons in consumers.items()
+                if len(cons) == 1 and name not in conf.network_outputs
+                and isinstance(conf.vertices.get(name), LayerVertex)
+                and isinstance(conf.vertices[cons[0]], LayerVertex)
+                and conf.vertices[cons[0]].preprocessor is None
+                and defers_to_pool(conf.vertices[name].layer,
+                                   conf.vertices[cons[0]].layer)}
+        return self._pool_after_cache
+
     def _forward(self, params, states, inputs: Dict[str, Any], *, train, rng,
                  fmasks: Optional[Dict[str, Any]] = None,
                  carries: Optional[Dict[str, Any]] = None,
-                 stop_before: Optional[str] = None):
+                 stop_before: Optional[str] = None, collect: bool = False):
         """Fold over topological order. Returns (values, out_inputs, states)
         where out_inputs[name] is the input activation each output layer saw
         (needed for fused-loss score). `carries` override the stored state of
         recurrent vertices (tBPTT / rnnTimeStep statefulness — reference:
-        `ComputationGraph.rnnTimeStep` / `rnnUpdateStateWithTBPTTState`)."""
+        `ComputationGraph.rnnTimeStep` / `rnnUpdateStateWithTBPTTState`).
+
+        A convolution vertex whose only consumer is a max-pool
+        (`_pool_after`) adds its bias and activates on the pool's output,
+        under its own scope, and leaves no entry in `values`. Not with
+        `collect` (every vertex's own activation, as `MultiLayerNetwork.
+        feed_forward` gives) and not under `gradient_checkpointing`."""
         values: Dict[str, Any] = dict(inputs)
         out_inputs: Dict[str, Any] = {}
         new_states: Dict[str, Any] = {}
+        remat = train and self.conf.gradient_checkpointing
+        pool_after = {} if collect or remat else self._pool_after
+        tails = {}      # pool vertex -> (convolution vertex, its tail)
         for idx, name in enumerate(self.conf.topological_order):
             if name == stop_before:
                 break
@@ -196,8 +229,14 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                     y, new_st = v.layer.apply(
                         params[name], x, state=st, train=train, rng=lrng,
                         mask=mask)
-                elif (train and self.conf.gradient_checkpointing
-                      and isinstance(v, LayerVertex)):
+                elif name in pool_after and pool_after[name] != stop_before:
+                    x = ins[0]
+                    if v.preprocessor is not None:
+                        x = v.preprocessor.apply(x)
+                    y, tail = v.layer.split(params[name], x, train=train,
+                                            rng=lrng)
+                    tails[pool_after[name]], new_st = (name, tail), st
+                elif remat and isinstance(v, LayerVertex):
                     # remat this layer vertex in the backward pass; cheap
                     # parameterless vertices (merge/elementwise/...) are
                     # NOT wrapped — their outputs are checkpoint residuals
@@ -208,8 +247,15 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                     y, new_st = v.apply(
                         params[name], ins, state=st, train=train, rng=lrng,
                         mask=mask)
+            if name in tails:
+                conv, tail = tails[name]
+                with jax.named_scope(conv):
+                    y = tail(y)
+                del values[conv]
             values[name] = y
             new_states[name] = new_st
+        if not collect:
+            record_deferred_pairs(self, len(tails))
         return values, out_inputs, new_states
 
     # ------------------------------------------------------------- loss

@@ -38,6 +38,9 @@ from deeplearning4j_tpu.optim.step import (
     jit_step, make_fused_step, make_train_step, stack_step_args,
 )
 from deeplearning4j_tpu.nn.layers.base import Layer
+from deeplearning4j_tpu.nn.layers.convolution import (
+    defers_to_pool, record_deferred_pairs,
+)
 from deeplearning4j_tpu.nn.layers.recurrent import (
     BaseRecurrentLayer, Bidirectional, GravesBidirectionalLSTM, LastTimeStep,
 )
@@ -184,11 +187,19 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                  carries: Optional[Dict[str, Any]] = None,
                  collect: bool = False):
         """Run the stack; returns (final_out, out_layer_input, new_states,
-        activations?). Reference: `feedForward:752-858`."""
+        activations?). Reference: `feedForward:752-858`.
+
+        A convolution directly in front of a max-pool (`defers_to_pool`,
+        no preprocessor between) adds its bias and activates on the pool's
+        output, under its own scope. Not with `collect` (`feed_forward`
+        gets every layer's own activation) and not under
+        `gradient_checkpointing`, whose unit is one layer."""
         acts = []
         new_states = {}
         out_in = x
         n = len(self.layers)
+        remat = train and self.conf.gradient_checkpointing
+        tails = {}      # pool's index -> (convolution's name, its tail)
         for i, layer in enumerate(self.layers):
             # the layer's name on its device ops (and, as
             # `transpose(jvp(<name>))`, on its backward ops): debug
@@ -202,8 +213,13 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                 if carries is not None and layer.name in carries:
                     st = carries[layer.name]
                 lrng = None if rng is None else jax.random.fold_in(rng, i)
-                if (train and self.conf.gradient_checkpointing
-                        and not (layer.is_output_layer and i == n - 1)):
+                if (not collect and not remat and i + 1 < n
+                        and i + 1 not in self.conf.preprocessors
+                        and defers_to_pool(layer, self.layers[i + 1])):
+                    x, tail = layer.split(params[layer.name], x,
+                                          train=train, rng=lrng)
+                    tails[i + 1], new_st = (layer.name, tail), st
+                elif remat and not (layer.is_output_layer and i == n - 1):
                     # remat this layer's activations in the backward pass
                     # (memory ∝ depth → memory ∝ 1, +~33% FLOPs); the
                     # output layer is skipped — its input is retained for
@@ -214,9 +230,15 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     x, new_st = layer.apply(
                         params[layer.name], x, state=st, train=train,
                         rng=lrng, mask=fmask)
+            if i in tails:
+                name, tail = tails[i]
+                with jax.named_scope(name):
+                    x = tail(x)
             new_states[layer.name] = new_st
             if collect:
                 acts.append(x)
+        if not collect:
+            record_deferred_pairs(self, len(tails))
         return x, out_in, new_states, acts
 
     # ------------------------------------------------------------- loss

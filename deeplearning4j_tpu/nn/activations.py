@@ -152,6 +152,24 @@ _REGISTRY: Dict[str, Callable] = {
 }
 
 
+# Piecewise-linear and non-decreasing: such a function commutes with a max
+# (`max(f(a), f(b)) == f(max(a, b))`, in floating point too), and the only
+# ties it makes between unequal inputs are in its flat parts, where its
+# gradient is zero whichever of them a max-pool's backward picks. So a
+# convolution may apply one AFTER the max-pool that follows it
+# (`nn/layers/convolution.defers_to_pool`). `tanh`, `sigmoid` and the other
+# saturating ones commute forward as well, but in bfloat16 their rounded
+# outputs tie where the raw values do not and the gradient moves by 3 to
+# 10%; the rest (`gelu`, `swish`, `mish`, `softmax`) are not monotone.
+_MAX_POOL_COMMUTING: Dict[str, Callable] = {
+    "identity": identity,
+    "linear": identity,
+    "relu": relu,
+    "relu6": relu6,
+    "leakyrelu": leakyrelu,
+}
+
+
 class Activation:
     """Enum-like accessor mirroring DL4J's `Activation` enum surface."""
 
@@ -196,6 +214,21 @@ class Activation:
                 f"Unknown activation {name_or_fn!r}; known: {sorted(_REGISTRY)}"
             )
         return _REGISTRY[key]
+
+    @staticmethod
+    def commutes_with_max_pool(name: Union[str, Callable, None]) -> bool:
+        """True for the built-in piecewise-linear non-decreasing activations
+        (`identity`, `relu`, `relu6`, `leakyrelu` with a slope of 0 or
+        more), by NAME: never for a callable or a name registered over."""
+        if name is None:
+            return True
+        if not isinstance(name, str):
+            return False
+        base, _, arg = name.lower().partition(":")
+        fn = _MAX_POOL_COMMUTING.get(base)
+        if fn is None or _REGISTRY.get(base) is not fn:
+            return False
+        return not arg or (base == "leakyrelu" and float(arg) >= 0.0)
 
     @staticmethod
     def register(name: str, fn: Callable) -> None:

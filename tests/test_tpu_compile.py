@@ -221,9 +221,10 @@ def _compile(chip, fn, shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _stand_alone_statistics(text):
-    """Top-level fusions of the forward pass, not convolutions, that read a
-    whole activation and write only per-channel vectors."""
+def _stand_alone_statistics(text, whole=None, forward_only=True):
+    """Top-level fusions (of the forward pass, unless told otherwise), not
+    convolutions, that read a whole activation (`whole` positions; the
+    batch norm case's by default) and write only per-channel vectors."""
     bodies = dict(re.findall(r"^%(fused_computation[\w.]*) .*?\{\n(.*?)^\}",
                              text, re.M | re.S))
     entry = text[text.index("\nENTRY "):]
@@ -234,7 +235,7 @@ def _stand_alone_statistics(text):
         return [[int(d) for d in inner.split(",") if d]
                 for inner in re.findall(r"\w+\[([\d,]*)\]", shapes)]
 
-    whole = ACTIVATION[0] * ACTIVATION[1] * ACTIVATION[2]
+    whole = whole or ACTIVATION[0] * ACTIVATION[1] * ACTIVATION[2]
     found = []
     for name, shapes, operands, calls, meta in re.findall(
             r"^\s+(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) fusion\((.*?)\), "
@@ -245,7 +246,7 @@ def _stand_alone_statistics(text):
                 and any(len(d) == 4 and d[0] * d[1] * d[2] == whole
                         for d in reads)
                 and " convolution(" not in bodies[calls]
-                and "transpose(" not in meta):
+                and not (forward_only and "transpose(" in meta)):
             found.append(name)
     return found
 
@@ -261,3 +262,55 @@ def test_batch_norm_reads_its_activation_twice_not_five_times(chip):
     ratio = (new.cost_analysis()["bytes accessed"]
              / old.cost_analysis()["bytes accessed"])
     assert ratio <= 0.87, ratio
+
+
+# --- a convolution in front of a max-pool: its bias and ReLU run on the
+# pool's output (`nn/layers/convolution.defers_to_pool`). VGG16's first
+# pair and the convolution after it at the benchmark's batch, forward and
+# backward through `MultiLayerNetwork._forward`, against the same net with
+# the rule off: 19.73 against 27.54 GB, and the bias gradient is no longer
+# a reduction of its own over the full-resolution gradient.
+IMAGES = (256, 224, 224, 64)
+
+
+def _conv_pool_conv():
+    from deeplearning4j_tpu import InputType
+    from deeplearning4j_tpu.models import MultiLayerNetwork
+    from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.layers import ConvolutionLayer, SubsamplingLayer
+
+    def conv(n_out):
+        return ConvolutionLayer(n_out=n_out, kernel=(3, 3), activation="relu",
+                                convolution_mode="same")
+
+    net = MultiLayerNetwork(
+        NeuralNetConfiguration.builder().dtype("bfloat16")
+        .list(conv(64), SubsamplingLayer(pooling="max"), conv(128))
+        .set_input_type(InputType.convolutional(*IMAGES[1:])).build())
+
+    def loss(params, x):
+        y = net._forward(params, {}, x, train=True, rng=None)[0]
+        return jnp.sum(jnp.square(y.astype(F32)))
+
+    first, pool, last = (layer.name for layer in net.layers)
+    shapes = [{first: {"W": ((3, 3, 64, 64), BF16), "b": ((64,), BF16)},
+               pool: {},
+               last: {"W": ((3, 3, 64, 128), BF16), "b": ((128,), BF16)}},
+              (IMAGES, BF16)]
+    return jax.grad(loss, argnums=(0, 1)), shapes
+
+
+def test_bias_and_relu_run_after_the_max_pool(chip):
+    from deeplearning4j_tpu.models import multilayer
+
+    new = _compile(chip, *_conv_pool_conv())
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(multilayer, "defers_to_pool", lambda conv, pool: False)
+        old = _compile(chip, *_conv_pool_conv())
+    whole = IMAGES[0] * IMAGES[1] * IMAGES[2]
+    assert _stand_alone_statistics(old.as_text(), whole, forward_only=False)
+    assert not _stand_alone_statistics(new.as_text(), whole,
+                                       forward_only=False)
+    ratio = (new.cost_analysis()["bytes accessed"]
+             / old.cost_analysis()["bytes accessed"])
+    assert ratio <= 0.75, ratio
