@@ -28,7 +28,8 @@ from deeplearning4j_tpu.data.iterators import (
 from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
 from deeplearning4j_tpu.optim.step import (
-    jit_step, make_fused_step, make_train_step, stack_step_args,
+    as_features, jit_step, make_fused_step, make_train_step,
+    stack_step_args,
 )
 from deeplearning4j_tpu.nn.graph import (
     ComputationGraphConfiguration, GraphVertex, LayerVertex,
@@ -314,17 +315,29 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                         name="ComputationGraph._step")
 
     # ---------------------------------------------------- data plumbing
+    def _features(self, name: str, x, asarray=jnp.asarray):
+        """Input `name`'s features as the forward pass takes them
+        (`as_features`): ids into an embedding keep their dtype."""
+        if not hasattr(self, "_id_inputs"):
+            self._id_inputs = {
+                i for v, ins in self.conf.vertex_inputs.items() for i in ins
+                if getattr(getattr(self.conf.vertices[v], "layer", None),
+                           "TAKES_IDS", False)}
+        return as_features(x, self.dtype, asarray,
+                           ids=name in self._id_inputs)
+
     def _batch_args(self, ds: Union[DataSet, MultiDataSet],
                     host: bool = False):
         """A DataSet/MultiDataSet as the step's batch arguments: dicts of
-        named inputs/outputs by order, features in the net's dtype.
+        named inputs/outputs by order, real features in the net's dtype and
+        integer ids as they came.
         `host=True` keeps leaves as numpy (multi-controller feeding: the
         caller lifts them into global arrays in one upload)."""
         asarray = np.asarray if host else jnp.asarray
         ins = self.conf.network_inputs
         outs = self.conf.network_outputs
         if isinstance(ds, MultiDataSet):
-            feats = {n: asarray(f, self.dtype)
+            feats = {n: self._features(n, f, asarray)
                      for n, f in zip(ins, ds.features)}
             labs = {n: asarray(l) for n, l in zip(outs, ds.labels)}
             fmasks = {}
@@ -336,7 +349,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                 lmasks = {n: asarray(m) for n, m in
                           zip(outs, ds.labels_masks) if m is not None}
             return feats, labs, fmasks or None, lmasks or None
-        feats = {ins[0]: asarray(ds.features, self.dtype)}
+        feats = {ins[0]: self._features(ins[0], ds.features, asarray)}
         labs = {outs[0]: asarray(ds.labels)} if ds.labels is not None else {}
         fmasks = ({ins[0]: asarray(ds.features_mask)}
                   if ds.features_mask is not None else None)
@@ -527,7 +540,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         instead of corrupting each other's carries."""
         inputs = {}
         for n, x in zip(self.conf.network_inputs, xs):
-            x = jnp.asarray(x, self.dtype)
+            x = self._features(n, x)
             if x.ndim == 2:
                 x = x[:, None, :]
             inputs[n] = x
@@ -659,7 +672,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         output). Reference: `ComputationGraph.output(INDArray...)`."""
         if self.params_tree is None:
             raise RuntimeError("Network not initialized — call init() first")
-        inputs = {n: jnp.asarray(x, self.dtype)
+        inputs = {n: self._features(n, x)
                   for n, x in zip(self.conf.network_inputs, xs)}
         key = ("output", train, tuple(sorted(inputs)))
         if key not in self._jit_cache:

@@ -35,7 +35,8 @@ from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
 from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
 from deeplearning4j_tpu.optim.step import (
-    jit_step, make_fused_step, make_train_step, stack_step_args,
+    as_features, jit_step, make_fused_step, make_train_step,
+    stack_step_args,
 )
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.convolution import (
@@ -386,14 +387,21 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                         cache=self._jit_cache, key=cache_key,
                         name="MultiLayerNetwork._fused_step")
 
+    def _features(self, x, asarray=jnp.asarray):
+        """Features as the forward pass takes them (`as_features`): ids
+        into a net that starts with an embedding keep their dtype."""
+        return as_features(x, self.dtype, asarray,
+                           ids=self.layers[0].TAKES_IDS)
+
     def _batch_args(self, ds: DataSet, host: bool = False):
         """A DataSet as the step's batch arguments `(features, labels,
-        fmask, lmask)`, features in the net's dtype (cast on the device
-        when they are there already). `host=True` keeps leaves as numpy:
-        the caller places them in one upload."""
+        fmask, lmask)`, real features in the net's dtype (cast on the device
+        when they are there already), integer ids as they came.
+        `host=True` keeps leaves as numpy: the caller places them in one
+        upload."""
         asarray = np.asarray if host else jnp.asarray
         opt = lambda a: None if a is None else asarray(a)
-        return (asarray(ds.features, self.dtype), opt(ds.labels),
+        return (self._features(ds.features, asarray), opt(ds.labels),
                 opt(ds.features_mask), opt(ds.labels_mask))
 
     def _stacked_batch_args(self, batches: List[DataSet]):
@@ -436,7 +444,10 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         if it is None:
             return
         expect = it.shape(int(x.shape[0]))
-        if it.kind == "rnn" and it.timesteps is None:
+        if it.kind == "rnn" and it.size == 1 and x.ndim == 2:
+            # token ids as [B, T] under InputType.recurrent(1, T)
+            ok = it.timesteps in (None, x.shape[1])
+        elif it.kind == "rnn" and it.timesteps is None:
             ok = x.ndim == 3 and x.shape[-1] == it.size
         else:
             ok = tuple(x.shape) == tuple(expect)
@@ -493,7 +504,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
             if Lb < hi - lo:
                 t_lo = hi - Lb
                 carries = self._advance_carries(
-                    jnp.asarray(ds.features[:, lo:t_lo], self.dtype),
+                    self._features(ds.features[:, lo:t_lo]),
                     None if ds.features_mask is None
                     else jnp.asarray(ds.features_mask[:, lo:t_lo]),
                     carries)
@@ -542,12 +553,12 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                 return y
             self._jit_cache[key] = jax.jit(out_fn)
         return self._jit_cache[key](
-            self.params_tree, self.state_tree, jnp.asarray(x, self.dtype))
+            self.params_tree, self.state_tree, self._features(x))
 
     def feed_forward(self, x, train: bool = False) -> List[jax.Array]:
         """All per-layer activations. Reference: `feedForward:752`."""
         _, _, _, acts = self._forward(
-            self.params_tree, self.state_tree, jnp.asarray(x, self.dtype),
+            self.params_tree, self.state_tree, self._features(x),
             train=train, rng=None, collect=True)
         return acts
 
@@ -567,7 +578,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
             self._jit_cache[key] = jax.jit(score_fn)
         loss = self._jit_cache[key](
             self.params_tree, self.state_tree,
-            jnp.asarray(ds.features, self.dtype),
+            self._features(ds.features),
             None if ds.labels is None else jnp.asarray(ds.labels),
             None if ds.features_mask is None else jnp.asarray(ds.features_mask),
             None if ds.labels_mask is None else jnp.asarray(ds.labels_mask))
@@ -599,7 +610,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
             self._check_input(np.asarray(feats))
             idx = self._jit_cache[key](
                 self.params_tree, self.state_tree,
-                jnp.asarray(feats, self.dtype))
+                self._features(feats))
             return idx, getattr(self.layers[-1], "n_out", None)
 
         return Evaluation().evaluate_iterator(
@@ -667,7 +678,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         lock, so concurrent callers serialize instead of corrupting each
         other's carries (serving threads its carries through
         `session_step` arguments instead and never touches this state)."""
-        x = jnp.asarray(x, self.dtype)
+        x = self._features(x)
         if x.ndim == 2:
             x = x[:, None, :]
         stateful = set(self._rnn_layer_names) | set(self._decode_layer_names)
@@ -857,7 +868,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         One compiled program per (x.shape, active?, valid?) — the
         fixed-shape decode contract the recompile watchdog polices."""
         self._check_init()
-        x = jnp.asarray(x, self.dtype)
+        x = self._features(x)
         if x.ndim == 2:
             x = x[:, None, :]
         stateful = set(self._rnn_layer_names) | set(self._decode_layer_names)
@@ -1255,7 +1266,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     lp, opt, loss = pre_step(
                         self.params_tree[layer.name], opt,
                         jnp.asarray(step, jnp.int32),
-                        jnp.asarray(ds.features, self.dtype), self._split_rng())
+                        self._features(ds.features), self._split_rng())
                     self.params_tree[layer.name] = lp
                     step += 1
         return self

@@ -21,6 +21,7 @@ from deeplearning4j_tpu.nn.layers.convolution import (
 )
 from deeplearning4j_tpu.nn.layers.normalization import (
     BatchNormalization, LocalResponseNormalization, LayerNormalization,
+    RMSNormalization,
 )
 from deeplearning4j_tpu.nn.layers.pooling import GlobalPoolingLayer, PoolingType
 from deeplearning4j_tpu.nn.layers.recurrent import (
@@ -30,7 +31,9 @@ from deeplearning4j_tpu.nn.layers.recurrent import (
 from deeplearning4j_tpu.nn.layers.special import (
     FrozenLayer, CenterLossOutputLayer, VariationalAutoencoder, RBM,
 )
-from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
+from deeplearning4j_tpu.nn.layers.attention import (
+    MultiHeadAttention, SandwichTransformerBlock,
+)
 
 __all__ = [
     "Layer", "LAYER_REGISTRY",
@@ -42,9 +45,10 @@ __all__ = [
     "DepthwiseConvolution2DLayer", "Cropping2DLayer", "SpaceToDepthLayer",
     "FusedConvBNLayer",
     "BatchNormalization", "LocalResponseNormalization", "LayerNormalization",
+    "RMSNormalization",
     "GlobalPoolingLayer", "PoolingType",
     "LSTM", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn", "GRU",
     "RnnOutputLayer", "Bidirectional", "LastTimeStep",
     "FrozenLayer", "CenterLossOutputLayer", "VariationalAutoencoder", "RBM",
-    "MultiHeadAttention",
+    "MultiHeadAttention", "SandwichTransformerBlock",
 ]

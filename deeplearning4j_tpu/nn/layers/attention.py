@@ -45,6 +45,14 @@ def rope_rotate(x, positions, base: float = 10000.0):
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
 
 
+def rms_norm(x, gain, eps: float = 1e-5):
+    """x / sqrt(mean(x^2) + eps) * gain over the last axis; no centring,
+    no bias. The mean is taken in float32 where x is narrower."""
+    wide = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    ms = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(ms + eps).astype(x.dtype) * gain
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class MultiHeadAttention(Layer):
@@ -78,6 +86,12 @@ class MultiHeadAttention(Layer):
     # FIXED max_cache-slot ring buffer (Mistral's rolling KV cache):
     # slot = position % max_cache, so generation length is unbounded in
     # O(window) memory. Each step needs max_cache >= T + window - 1.
+    head_dim: Optional[int] = None    # None -> n_out // num_heads; set, the
+    # heads are that wide whatever n_out is (48 heads of 128 over 3072)
+    qk_norm: bool = False             # RMS norm of q and k over the head,
+    # one gain vector each for all heads, before the positions go on
+    output_gate: bool = False         # o * sigmoid(x Wg) before Wo
+    bias: bool = True                 # Wo's bias
 
     def infer_n_in(self, input_type: InputType):
         upd = {}
@@ -95,9 +109,13 @@ class MultiHeadAttention(Layer):
         return (self.num_kv_heads if self.num_kv_heads is not None
                 else self.num_heads)
 
+    @property
+    def _head_dim(self) -> int:
+        return self.head_dim or self.n_out // self.num_heads
+
     def _check_heads(self):
         H, Hkv = self.num_heads, self._kv_heads
-        if self.n_out % H:
+        if self.head_dim is None and self.n_out % H:
             raise ValueError(
                 f"n_out {self.n_out} not divisible by num_heads {H}")
         if not 1 <= Hkv <= H or H % Hkv:
@@ -118,16 +136,55 @@ class MultiHeadAttention(Layer):
                 raise ValueError(
                     f"rolling_cache: max_cache {self.max_cache} < window "
                     f"{self.window}; the buffer cannot hold the band")
-        dkv = self._kv_heads * (d // self.num_heads)
-        ks = jax.random.split(key, 4)
+        dh = self._head_dim
+        dq, dkv = self.num_heads * dh, self._kv_heads * dh
+        ks = jax.random.split(key, 5)
         winit = self._winit()
-        return {
-            "Wq": winit(ks[0], (self.n_in, d), dtype),
+        params = {
+            "Wq": winit(ks[0], (self.n_in, dq), dtype),
             "Wk": winit(ks[1], (self.n_in, dkv), dtype),
             "Wv": winit(ks[2], (self.n_in, dkv), dtype),
-            "Wo": winit(ks[3], (d, d), dtype),
-            "b": jnp.zeros((d,), dtype),
-        }, {}
+            "Wo": winit(ks[3], (dq, d), dtype),
+        }
+        if self.bias:
+            params["b"] = jnp.zeros((d,), dtype)
+        if self.output_gate:
+            params["Wg"] = winit(ks[4], (self.n_in, dq), dtype)
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((dh,), dtype)
+            params["k_norm"] = jnp.ones((dh,), dtype)
+        return params, {}
+
+    def _qkv(self, params, x):
+        """q [B, T, H, Dh], k and v [B, T, Hkv, Dh] (normed where
+        `qk_norm`, positions not yet on) and the output gate [B, T, H*Dh]
+        or None."""
+        B, T, _ = x.shape
+        dh = self._head_dim
+
+        def split(w, heads):
+            return (x @ w).reshape(B, T, heads, dh)
+
+        q = split(params["Wq"], self.num_heads)
+        k = split(params["Wk"], self._kv_heads)
+        v = split(params["Wv"], self._kv_heads)
+        if self.qk_norm:
+            q = rms_norm(q, params["q_norm"])
+            k = rms_norm(k, params["k_norm"])
+        gate = (jax.nn.sigmoid(x @ params["Wg"]) if self.output_gate
+                else None)
+        return q, k, v, gate
+
+    def _project_out(self, params, o, gate):
+        """[B, T, H, Dh] heads -> the layer's output, gated where the
+        layer has a gate."""
+        o = o.reshape(o.shape[0], o.shape[1], -1)
+        if gate is not None:
+            o = o * gate
+        y = o @ params["Wo"]
+        if self.bias:
+            y = y + params["b"]
+        return self._act(y)
 
     def decode_carry(self, batch: int, dtype=jnp.float32, *,
                      per_slot: bool = False, kv_dtype: str = None,
@@ -166,7 +223,7 @@ class MultiHeadAttention(Layer):
         addresses the monolithic slot layout). `pages` defaults to
         `batch * max_cache / page_len` — the same memory as the
         monolithic layout."""
-        Dh = self.n_out // self.num_heads
+        Dh = self._head_dim
         L = self.max_cache
         Hkv = self._kv_heads
         if per_slot and not self.causal:
@@ -247,7 +304,7 @@ class MultiHeadAttention(Layer):
         B, T, _ = x.shape
         H = self.num_heads
         Hkv = self._kv_heads
-        Dh = self.n_out // H
+        Dh = self._head_dim
         paged = "page_table" in state
         if paged:
             if self.rolling_cache:
@@ -285,12 +342,7 @@ class MultiHeadAttention(Layer):
                 f"KV cache overflow: pos {int(pos)} + step {T} > "
                 f"max_cache {L}; raise max_cache or clear state")
 
-        def split(w, heads):
-            return (x @ w).reshape(B, T, heads, Dh)
-
-        q = split(params["Wq"], H)
-        k = split(params["Wk"], Hkv)
-        v = split(params["Wv"], Hkv)
+        q, k, v, gate = self._qkv(params, x)
         if per_slot:
             valid = None if mask is None else (mask > 0)       # [B, T]
             n_new = (jnp.full(pos.shape, T, pos.dtype) if valid is None
@@ -513,39 +565,36 @@ class MultiHeadAttention(Layer):
             s = jnp.where(vb[:, None], s, -1e30)
             o = jnp.einsum("bhqk,bkhd->bqhd",
                            jax.nn.softmax(s, axis=-1), cv_a)
-        y = o.reshape(B, T, self.n_out) @ params["Wo"] + params["b"]
+        y = self._project_out(params, o, gate)
         new_state = {"cache_k": ck, "cache_v": cv, "pos": pos_new}
         if paged:
             new_state["page_table"] = pt
         if quant:
             new_state["scale_k"] = csk
             new_state["scale_v"] = csv
-        return self._act(y), new_state
+        return y, new_state
 
     def apply(self, params, x, *, state=None, train=False, rng=None, mask=None):
         if state is not None and "cache_k" in state:
             return self._decode(params, x, state, mask=mask)
-        B, T, _ = x.shape
-        H = self.num_heads
-        Hkv = self._kv_heads
-        Dh = self.n_out // H
-
-        def split(w, heads):
-            return (x @ w).reshape(B, T, heads, Dh)
-
-        q = split(params["Wq"], H)
-        k = split(params["Wk"], Hkv)
-        v = split(params["Wv"], Hkv)
+        q, k, v, gate = self._qkv(params, x)
         if self.rope:
-            positions = jnp.arange(T)
+            positions = jnp.arange(x.shape[1])
             q = rope_rotate(q, positions)
             k = rope_rotate(k, positions)
+        with jax.named_scope("attention_core"):
+            o = self._core(q, k, v, train=train, rng=rng, mask=mask)
+        return self._project_out(params, o, gate), state
+
+    def _core(self, q, k, v, *, train, rng, mask):
+        """softmax(q k^T / sqrt(Dh)) v over [B, T, H, Dh] queries and
+        [B, T, Hkv, Dh] keys and values, by the path the policies pick."""
+        T, H, Hkv = q.shape[1], self.num_heads, self._kv_heads
 
         def broadcast_kv(k, v):
-            # GQA fallback for the H-wide attention cores (ring, flash,
-            # dense): broadcast KV heads up to the query heads. The
-            # banded kernel never needs this — it consumes the native
-            # Hkv layout, which is where its decode-path HBM win lives.
+            # GQA fallback for the H-wide attention cores (ring, dense):
+            # broadcast KV heads up to the query heads. The banded and
+            # flash kernels read the native Hkv layout by `h // G`.
             if Hkv != H:
                 k = jnp.repeat(k, H // Hkv, axis=2)
                 v = jnp.repeat(v, H // Hkv, axis=2)
@@ -584,13 +633,14 @@ class MultiHeadAttention(Layer):
             )
 
             k, v = broadcast_kv(k, v)
-            o = ring_self_attention(q, k, v, seq_ctx.mesh,
-                                    axis=seq_ctx.axis, causal=self.causal)
-        elif self.window is not None and mask is None and not drop:
+            return ring_self_attention(q, k, v, seq_ctx.mesh,
+                                       axis=seq_ctx.axis, causal=self.causal)
+        if self.window is not None and mask is None and not drop:
             # Sliding window (no mask/dropout): the banded kernel serves
-            # this O(T·w) by grid construction, GQA-native. Banded-vs-
-            # dense is the measured policy's call (kernel_defaults.
-            # banded_policy; env hatch DL4J_TPU_ATTN=banded|dense).
+            # this O(T·w) by grid construction, forward and backward,
+            # GQA-native. Banded-vs-dense is the measured policy's call
+            # (kernel_defaults.banded_policy; env hatch
+            # DL4J_TPU_ATTN=banded|dense).
             from deeplearning4j_tpu.ops.kernel_defaults import (
                 banded_policy,
             )
@@ -601,44 +651,35 @@ class MultiHeadAttention(Layer):
                     banded_attention,
                 )
 
-                o = banded_attention(
+                return banded_attention(
                     q, k, v, self.window, self.causal, None, pol.block_q,
                     pol.block_k, jax.default_backend() != "tpu")
-            else:
-                k, v = broadcast_kv(k, v)
-                o = self._masked_attention(q, k, v, None, self.causal,
-                                           window=self.window)
-        elif mask is not None or drop:
+            k, v = broadcast_kv(k, v)
+            return self._masked_attention(q, k, v, None, self.causal,
+                                          window=self.window)
+        if mask is not None or drop:
             # Padding mask and attention-weight dropout need the dense
             # path (dropout perturbs the post-softmax weights, which
             # never materialize inside the fused kernels).
             k, v = broadcast_kv(k, v)
-            o = self._masked_attention(q, k, v, mask, self.causal,
-                                       dropout=drop, rng=rng,
-                                       window=self.window)
-        else:
-            k, v = broadcast_kv(k, v)
-            # Flash-vs-dense, tile config, and backward selection all come
-            # from the measured-winner policy (ops/kernel_defaults.py) —
-            # the kernel must have a recorded hardware row beating XLA
-            # dense at this mode/length, or dense memory pressure must
-            # make the O(T) path mandatory. Env hatches: DL4J_TPU_ATTN*.
-            from deeplearning4j_tpu.ops.kernel_defaults import (
-                attention_policy,
-            )
+            return self._masked_attention(q, k, v, mask, self.causal,
+                                          dropout=drop, rng=rng,
+                                          window=self.window)
+        # Flash-vs-dense, tile config, and backward selection all come
+        # from the measured-winner policy (ops/kernel_defaults.py) —
+        # the kernel must have a recorded hardware row beating XLA
+        # dense at this mode/length, or dense memory pressure must
+        # make the O(T) path mandatory. Env hatches: DL4J_TPU_ATTN*.
+        from deeplearning4j_tpu.ops.kernel_defaults import attention_policy
 
-            pol = attention_policy(T, train=train)
-            if pol.kind == "flash":
-                from deeplearning4j_tpu.ops.attention import flash_attention
+        pol = attention_policy(T, train=train)
+        if pol.kind == "flash":
+            from deeplearning4j_tpu.ops.attention import flash_attention
 
-                o = flash_attention(q, k, v, self.causal, None,
-                                    pol.block_q, pol.block_k, False,
-                                    pol.backward)
-            else:
-                o = attention(q, k, v, causal=self.causal)
-        o = o.reshape(B, T, self.n_out)
-        y = o @ params["Wo"] + params["b"]
-        return self._act(y), state
+            return flash_attention(q, k, v, self.causal, None, pol.block_q,
+                                   pol.block_k, False, pol.backward)
+        k, v = broadcast_kv(k, v)
+        return attention(q, k, v, causal=self.causal)
 
     @staticmethod
     def _masked_attention(q, k, v, mask, causal=False, dropout=0.0,
@@ -845,8 +886,7 @@ class TransformerEncoderBlock(Layer):
         g = params[f"{prefix}_g"]
         if self.norm == "rms":
             # no centering, no bias: one reduction sweep instead of two
-            ms = jnp.mean(x * x, axis=-1, keepdims=True)
-            return x * jax.lax.rsqrt(ms + 1e-5) * g
+            return rms_norm(x, g)
         mu = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.var(x, axis=-1, keepdims=True)
         return (x - mu) * jax.lax.rsqrt(var + 1e-5) * g \
@@ -890,3 +930,117 @@ class TransformerEncoderBlock(Layer):
             y = y @ params["ffn_w2"] + params["ffn_b2"]
         y = self._maybe_dropout(y, train, rng)
         return x + y, new_state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class SandwichTransformerBlock(Layer):
+    """A transformer block with a norm on both sides of each half:
+    `h = x + norm(attention(norm(x)))`, then `h + norm(ffn(norm(h)))`, all
+    four RMS norms with a gain and nothing else, no bias anywhere.
+
+    The attention half is a `MultiHeadAttention` with this block's options
+    handed through (GQA, `head_dim`, `qk_norm`, `output_gate`, `window`,
+    `rope`: a layer with `rope=False` sees no positions at all). The other
+    half is a SwiGLU of `ffn_width`, or, with `n_experts`, an
+    `ExpertFeedForward` (`parallel/moe.py`): a router over `n_experts`
+    with `moe_k` a token, of which this device holds `experts_held`
+    (first, count), experts and `n_shared` shared experts of
+    `expert_width`. Leaves: `ln1_g` to `ln4_g`, `attn_*`, and `ffn_w1`,
+    `ffn_w3`, `ffn_w2` or `moe_*`."""
+
+    CONSUMES = "rnn"   # [B, T, d] sequence activations
+
+    n_in: Optional[int] = None
+    num_heads: int = 4
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    qk_norm: bool = False
+    output_gate: bool = False
+    causal: bool = True
+    rope: bool = False
+    window: Optional[int] = None
+    max_cache: int = 1024
+    ffn_width: Optional[int] = None      # dense half; None -> 4 x n_in
+    n_experts: int = 0                   # 0 = dense SwiGLU; >0 = experts
+    experts_held: Optional[Any] = None   # (first, count); None -> all
+    moe_k: int = 2
+    expert_width: Optional[int] = None
+    n_shared: int = 0
+    score: str = "softmax"
+    selection_bias: bool = False
+    route_norm: bool = False
+    route_scale: float = 1.0
+    eps: float = 1e-5
+
+    def infer_n_in(self, input_type: InputType):
+        if self.n_in is None:
+            return dataclasses.replace(self, n_in=input_type.size)
+        return self
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def _sub(self):
+        d = self.n_in
+        attn = MultiHeadAttention(
+            n_in=d, n_out=d, num_heads=self.num_heads,
+            num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+            qk_norm=self.qk_norm, output_gate=self.output_gate, bias=False,
+            causal=self.causal, activation="identity",
+            weight_init=self.weight_init, max_cache=self.max_cache,
+            rope=self.rope, window=self.window)
+        moe = None
+        if self.n_experts > 0:
+            from deeplearning4j_tpu.parallel.moe import ExpertFeedForward
+
+            held = self.experts_held
+            moe = ExpertFeedForward(
+                n_in=d, width=self.expert_width, n_experts=self.n_experts,
+                held=None if held is None else tuple(held), k=self.moe_k,
+                score=self.score, selection_bias=self.selection_bias,
+                route_norm=self.route_norm, route_scale=self.route_scale,
+                n_shared=self.n_shared, weight_init=self.weight_init)
+        return attn, moe
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d = self.n_in
+        ks = jax.random.split(key, 5)
+        attn, moe = self._sub()
+        params = {f"ln{i}_g": jnp.ones((d,), dtype) for i in (1, 2, 3, 4)}
+        ap, _ = attn.init_params(ks[0], input_type, dtype)
+        params.update({f"attn_{k}": v for k, v in ap.items()})
+        if moe is None:
+            h = self.ffn_width or 4 * d
+            winit = self._winit()
+            params.update(ffn_w1=winit(ks[1], (d, h), dtype),
+                          ffn_w3=winit(ks[2], (d, h), dtype),
+                          ffn_w2=winit(ks[3], (h, d), dtype))
+            return params, {}
+        mp, state = moe.init_params(ks[4], input_type, dtype)
+        params.update({f"moe_{k}": v for k, v in mp.items()})
+        return params, state
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        return {"attn": self._sub()[0].decode_carry(batch, dtype, **kw)}
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        attn, moe = self._sub()
+        sub = lambda prefix: {k[len(prefix):]: v for k, v in params.items()
+                              if k.startswith(prefix)}
+        norm = lambda v, i: rms_norm(v, params[f"ln{i}_g"], self.eps)
+        carry = state.get("attn") if state else None
+        a, a_st = attn.apply(sub("attn_"), norm(x, 1), state=carry,
+                             train=train, rng=rng, mask=mask)
+        x = x + norm(a, 2)
+        h = norm(x, 3)
+        new_state = {} if carry is None else {"attn": a_st}
+        if moe is None:
+            with jax.named_scope("ffn"):
+                y = (jax.nn.silu(h @ params["ffn_w1"])
+                     * (h @ params["ffn_w3"])) @ params["ffn_w2"]
+        else:
+            y, counters = moe.apply(sub("moe_"), h, train=train, rng=rng)
+            new_state.update(counters)
+        return x + norm(y, 4), new_state

@@ -46,6 +46,10 @@ class Layer:
     builder defaults at build() time (reference: config cloning in
     `MultiLayerConfiguration.Builder`)."""
 
+    # the layer looks its input up as integer ids (an embedding): the
+    # models hand such an input on in its own integer dtype
+    TAKES_IDS = False
+
     name: Optional[str] = None
     activation: Optional[str] = None
     weight_init: Optional[str] = None

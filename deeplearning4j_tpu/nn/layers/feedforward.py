@@ -157,6 +157,8 @@ class EmbeddingLayer(Layer):
     On TPU the lookup is a gather (`jnp.take`), which XLA lowers natively —
     no one-hot matmul needed."""
 
+    TAKES_IDS = True
+
     n_in: Optional[int] = None    # vocab size
     n_out: Optional[int] = None
     has_bias: bool = True
@@ -193,9 +195,11 @@ class EmbeddingSequenceLayer(Layer):
     counterpart of reference EmbeddingSequenceLayer)."""
 
     CONSUMES = "rnn"   # sequence input — no RnnToFeedForward before it
+    TAKES_IDS = True
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None
+    scale: Optional[float] = None   # rows times this (sqrt(n_out), say)
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)
@@ -207,6 +211,8 @@ class EmbeddingSequenceLayer(Layer):
         if x.ndim == 3 and x.shape[-1] == 1:
             x = x[..., 0]  # [B, T, 1] token-id tensors (InputType.recurrent(1))
         emb = jnp.take(params["W"], x.astype(jnp.int32), axis=0)
+        if self.scale is not None:
+            emb = emb * jnp.asarray(self.scale, emb.dtype)
         return self._act(emb), state
 
 

@@ -186,3 +186,30 @@ class LayerNormalization(Layer):
         var = jnp.var(x, axis=-1, keepdims=True)
         y = (x - mean) / jnp.sqrt(var + self.eps)
         return self._act(y * params["gamma"] + params["beta"]), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RMSNormalization(Layer):
+    """x / sqrt(mean(x^2) + eps) * gamma over the trailing feature axis:
+    no centring and no shift (`nn/layers/attention.rms_norm`). The norm
+    before a language model's head."""
+
+    CONSUMES = "any"
+
+    n_out: Optional[int] = None
+    eps: float = 1e-5
+
+    def infer_n_in(self, input_type: InputType) -> "RMSNormalization":
+        if self.n_out is None:
+            feat = input_type.size if input_type.kind == "rnn" else input_type.flat_size()
+            return dataclasses.replace(self, n_out=feat)
+        return self
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        return {"gamma": jnp.ones((self.n_out,), dtype)}, {}
+
+    def apply(self, params, x, *, state=None, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.layers.attention import rms_norm
+
+        return self._act(rms_norm(x, params["gamma"], self.eps)), state

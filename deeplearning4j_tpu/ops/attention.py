@@ -134,6 +134,15 @@ def _fit_block(block: int, t: int) -> int:
     return block
 
 
+def _group(q, k) -> int:
+    """Query heads per KV head of folded [B·H, T, D] queries against
+    [B·Hkv, T, D] keys: row b of q reads row b // g of k and v."""
+    if q.shape[0] % k.shape[0]:
+        raise ValueError(f"{q.shape[0]} query rows over {k.shape[0]} KV "
+                         f"rows: Hkv must divide H")
+    return q.shape[0] // k.shape[0]
+
+
 def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
                block_k: int, interpret: bool, with_lse: bool = False):
     bh, tq, d = q.shape
@@ -142,6 +151,7 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
         raise ValueError(
             f"causal attention requires Tq == Tk (got {tq} vs {tk}); "
             "cross-attention is non-causal")
+    g = _group(q, k)
     block_q = _fit_block(block_q, tq)
     block_k = _fit_block(block_k, tk)
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
@@ -159,8 +169,9 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
         grid=(bh, tq // block_q, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            # GQA: query head b reads KV head b // g, never a copy of it
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0)),
         ],
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=tuple(out_shape) if with_lse else out_shape[0],
@@ -206,17 +217,19 @@ def _bwd_tile(q, k, v, do, lse_col, delta_col, qb, kb, bq, block_k, causal,
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
-                           scale: float):
-    """Grid = (batch·heads, K blocks, Q blocks): the K/V tile's gradient
-    accumulates in VMEM scratch across the innermost Q sweep."""
+                           scale: float, nq: int):
+    """Grid = (batch·KV heads, K blocks, group x Q blocks): the K/V tile's
+    gradient accumulates in VMEM scratch across the innermost sweep, which
+    takes the `nq` Q blocks of each query head of the group in turn."""
     kb = pl.program_id(1)
-    qb = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    qb = step % nq
     q = q_ref[0]
     bq = q.shape[0]
     block_k = k_ref.shape[1]
 
-    @pl.when(qb == 0)
+    @pl.when(step == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -238,7 +251,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] += jnp.dot(ds.astype(q.dtype).T, q,
                              preferred_element_type=jnp.float32, precision=prec)
 
-    @pl.when(qb == nq - 1)
+    @pl.when(step == last)
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -292,8 +305,10 @@ def _run_flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
     """
     bh, tq, d = q.shape
     tk = k.shape[1]
+    g = _group(q, k)
     block_q = _fit_block(block_q, tq)
     block_k = _fit_block(block_k, tk)
+    nq = tq // block_q
     lse = jnp.broadcast_to(lse[..., None], (bh, tq, _LSE_LANES))
     # Δ = rowsum(do · o): one cheap fused elementwise+reduce in XLA.
     delta2 = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -304,21 +319,24 @@ def _run_flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, block_q, _LSE_LANES),
                             lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    # dK/dV: K/V tile pinned (grid dim 1), Q swept (innermost dim 2)
-    q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0))
+    # dK/dV: K/V tile pinned (grid dim 1); the innermost dim sweeps the
+    # group's query heads and, within each, its Q blocks
+    q_spec_t = pl.BlockSpec((1, block_q, d),
+                            lambda b, j, i: (b * g + i // nq, i % nq, 0))
     row_spec_t = pl.BlockSpec((1, block_q, _LSE_LANES),
-                              lambda b, j, i: (b, i, 0))
+                              lambda b, j, i: (b * g + i // nq, i % nq, 0))
     kv_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, causal=causal, scale=scale),
+        functools.partial(_flash_bwd_dkdv_kernel, causal=causal, scale=scale,
+                          nq=nq),
         name="flash_attention_bwd_dkdv",
-        grid=(bh, tk // block_k, tq // block_q),
+        grid=(bh // g, tk // block_k, g * nq),
         in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
                   row_spec_t],
         out_specs=[kv_spec_t, kv_spec_t],
-        out_shape=[jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tk, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -390,8 +408,9 @@ def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, block_q: int = 512,
                     block_k: int = 512, interpret: bool = False,
                     backward: Optional[str] = None):
-    """Fused attention. q/k/v: [B, T, H, D] or [BH, T, D]; returns same
-    layout.
+    """Fused attention. q/k/v: [B, T, H, D] or [BH, T, D]; returns q's
+    layout. k and v may have fewer heads (GQA, Hkv dividing H): query
+    head h reads KV head h // (H // Hkv) in place, forward and backward.
 
     Residual memory of the forward is O(T) either way: the forward rule
     saves q/k/v/o and the per-row log-sum-exp. `backward` selects how
@@ -440,8 +459,11 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, backward, res,
                                     scale=s, block_q=block_q,
                                     block_k=block_k, interpret=interpret)
     else:
+        g = _group(q3, k3)
         _, vjp = jax.vjp(
-            lambda qq, kk, vv: _dense_attention(qq, kk, vv, causal, s),
+            lambda qq, kk, vv: _dense_attention(
+                qq, jnp.repeat(kk, g, axis=0), jnp.repeat(vv, g, axis=0),
+                causal, s),
             q3, k3, v3)
         dq, dk, dv = vjp(do3)
     return (_unfold3(dq, shape_q), _unfold3(dk, shape_k),

@@ -24,10 +24,14 @@ kernel family:
 
 Both kernels run under `interpret=True` on CPU (the parity suite in
 tests/test_banded_attention.py pins them against the layer's dense
-band-masked oracle). Backward: banded training shapes recompute through
-the dense band-masked reference (`banded_reference`) — the O(T²) scores
-exist transiently on the backward only; a blockwise Pallas backward is
-future work that `tools/roofline_report.py` exists to prioritize.
+band-masked oracle). Backward: blockwise over the band's tiles only, as
+`ops/attention.py`'s is over all of them. The forward rule saves the
+per-row log-sum-exp; a dQ kernel sweeps each Q block's `nkb` K blocks
+and a dK/dV kernel each K block's `nqb` Q blocks, scores recomputed a
+tile at a time, the whole GQA group's rows folded against one Hkv-wide
+KV tile so dK/dV sum over the group in the matmul itself. Nothing of
+size [T, T] exists in either direction. `banded_reference` stays as the
+oracle.
 
 Dispatch is NOT decided here: `kernel_defaults.banded_policy` owns the
 banded-vs-dense verdict under the measured-winner discipline (env hatch
@@ -46,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_LSE_LANES = 128   # lane width of a per-row statistic (TPU tiling)
 
 
 # --------------------------------------------------------------- reference
@@ -112,13 +117,19 @@ def _kb_first(i, *, nk: int, nkb: int, block_q: int, block_k: int,
     return jnp.clip(ub - (nkb - 1), 0, nk - nkb)
 
 
-def _banded_kernel(q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr, *,
-                   nk: int, window: int, causal: bool, scale: float):
+def _banded_kernel(q_ref, k_ref, v_ref, o_ref, *rest, nk: int, window: int,
+                   causal: bool, scale: float, with_lse: bool):
     """Grid = (batch·Hkv, Q blocks, band K blocks). Per Q block only the
     `nkb` K blocks the band can touch are visited; the online-softmax
     state rides VMEM scratch across that innermost sweep exactly as in
     `ops/attention._flash_kernel`. The query tile is the whole GQA group
-    ([G, Bq, Dh] folded to G·Bq rows) against one Hkv-wide KV tile."""
+    ([G, Bq, Dh] folded to G·Bq rows) against one Hkv-wide KV tile. With
+    `with_lse` the per-row log-sum-exp is emitted too, the residual the
+    blockwise backward recomputes score tiles from."""
+    if with_lse:
+        lse_ref, acc_scr, m_scr, l_scr = rest
+    else:
+        acc_scr, m_scr, l_scr = rest
     i = pl.program_id(1)
     j = pl.program_id(2)
     nkb = pl.num_programs(2)
@@ -149,14 +160,7 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr, *,
         qf = q.reshape(g * bq, d)
         s = jnp.dot(qf, k.T, preferred_element_type=jnp.float32,
                     precision=prec) * scale        # [G·Bq, Bk]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (g * bq, block_k), 0)
-        q_ids = i * bq + rows % bq                 # row r of group g -> q
-        k_ids = (kb * block_k
-                 + jax.lax.broadcasted_iota(jnp.int32, (g * bq, block_k), 1))
-        if causal:
-            vis = (k_ids <= q_ids) & (k_ids > q_ids - window)
-        else:
-            vis = (k_ids < q_ids + window) & (k_ids > q_ids - window)
+        vis = _visible(i, kb, g, bq, block_k, window, causal)
         s = jnp.where(vis, s, _NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -177,39 +181,81 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr, *,
     def _():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l).reshape(g, bq, d).astype(o_ref.dtype)
+        if with_lse:
+            # a row statistic broadcast over 128 lanes, the narrowest
+            # layout Mosaic takes (as `ops/attention._flash_kernel` does)
+            lse_ref[0] = jnp.broadcast_to(
+                m_scr[:] + jnp.log(l), (g * bq, _LSE_LANES)
+            ).reshape(g, bq, _LSE_LANES)
 
 
-def _run_banded(q, k, v, *, window: int, causal: bool, scale: float,
-                block_q: int, block_k: int, interpret: bool):
+def _visible(qb, kb, g: int, bq: int, block_k: int, window: int,
+             causal: bool):
+    """[G·Bq, Bk] mask of the band inside the tile (Q block `qb`, the
+    group's rows folded, against K block `kb`): one arithmetic for the
+    forward and both backward kernels."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (g * bq, block_k), 0)
+    q_ids = qb * bq + rows % bq                    # row r of group g -> q
+    k_ids = (kb * block_k
+             + jax.lax.broadcasted_iota(jnp.int32, (g * bq, block_k), 1))
+    if causal:
+        return (k_ids <= q_ids) & (k_ids > q_ids - window)
+    return (k_ids < q_ids + window) & (k_ids > q_ids - window)
+
+
+def _fold_heads(q, k, v):
+    """[B, T, H, Dh] -> [B·Hkv, G, T, Dh] and [B, T, Hkv, Dh] -> [B·Hkv,
+    T, Dh]; heads group as h = hkv·G + g, matching the layer's
+    `q.reshape(B, T, Hkv, G, Dh)` GQA grouping."""
     b, t, h, dh = q.shape
     hkv = k.shape[2]
-    g = h // hkv
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * hkv, t, dh)
+    return (q.transpose(0, 2, 1, 3).reshape(b * hkv, h // hkv, t, dh),
+            fold(k), fold(v))
+
+
+def _unfold_q(x, b: int):
+    """[B·Hkv, G, T, Dh] -> [B, T, H, Dh]."""
+    bh, g, t, dh = x.shape
+    return x.reshape(b, bh // b, g, t, dh).transpose(0, 3, 1, 2, 4) \
+        .reshape(b, t, bh // b * g, dh)
+
+
+def _unfold_kv(x, b: int):
+    """[B·Hkv, T, Dh] -> [B, T, Hkv, Dh]."""
+    bh, t, dh = x.shape
+    return x.reshape(b, bh // b, t, dh).transpose(0, 2, 1, 3)
+
+
+def _run_banded(q5, k3, v3, *, window: int, causal: bool, scale: float,
+                block_q: int, block_k: int, interpret: bool,
+                with_lse: bool = False):
+    """The forward over folded heads (`_fold_heads`): o [B·Hkv, G, T, Dh]
+    and, with `with_lse`, the rows' log-sum-exp [B·Hkv, G, T]."""
+    bh, g, t, dh = q5.shape
     block_q = _fit_block(block_q, t, interpret=interpret)
     block_k = _fit_block(block_k, t, interpret=interpret)
     nk, nkb = _band_geometry(t, window, causal, block_q, block_k)
-    # [B, T, H, Dh] -> [B·Hkv, G, T, Dh]; heads group as h = hkv·G + g,
-    # matching the layer's `q.reshape(B, T, Hkv, G, Dh)` GQA grouping.
-    q5 = q.transpose(0, 2, 1, 3).reshape(b, hkv, g, t, dh) \
-        .reshape(b * hkv, g, t, dh)
-    k3 = k.transpose(0, 2, 1, 3).reshape(b * hkv, t, dh)
-    v3 = v.transpose(0, 2, 1, 3).reshape(b * hkv, t, dh)
     kmap = functools.partial(_kb_first, nk=nk, nkb=nkb, block_q=block_q,
                              block_k=block_k, window=window, causal=causal)
-    o = pl.pallas_call(
+    q_spec = pl.BlockSpec((1, g, block_q, dh), lambda bb, i, j: (bb, 0, i, 0))
+    kv_spec = pl.BlockSpec((1, block_k, dh),
+                           lambda bb, i, j: (bb, kmap(i) + j, 0))
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct(q5.shape, q5.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, g, block_q, _LSE_LANES),
+                                      lambda bb, i, j: (bb, 0, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, g, t, _LSE_LANES),
+                                              jnp.float32))
+    out = pl.pallas_call(
         functools.partial(_banded_kernel, nk=nk, window=window,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale, with_lse=with_lse),
         name="banded_attention_fwd",
-        grid=(b * hkv, t // block_q, nkb),
-        in_specs=[
-            pl.BlockSpec((1, g, block_q, dh), lambda bb, i, j: (bb, 0, i, 0)),
-            pl.BlockSpec((1, block_k, dh),
-                         lambda bb, i, j: (bb, kmap(i) + j, 0)),
-            pl.BlockSpec((1, block_k, dh),
-                         lambda bb, i, j: (bb, kmap(i) + j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, g, block_q, dh),
-                               lambda bb, i, j: (bb, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hkv, g, t, dh), q.dtype),
+        grid=(bh, t // block_q, nkb),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs if with_lse else out_specs[0],
+        out_shape=out_shape if with_lse else out_shape[0],
         scratch_shapes=[
             pltpu.VMEM((g * block_q, dh), jnp.float32),
             pltpu.VMEM((g * block_q, 1), jnp.float32),
@@ -219,8 +265,198 @@ def _run_banded(q, k, v, *, window: int, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q5, k3, v3)
-    return o.reshape(b, hkv, g, t, dh).transpose(0, 3, 1, 2, 4) \
-        .reshape(b, t, h, dh)
+    if with_lse:
+        return out[0], out[1][..., 0]      # one lane: O(T) between passes
+    return out, None
+
+
+# ----------------------------------------------------- blockwise backward
+def _qb_geometry(t: int, window: int, causal: bool, block_q: int,
+                 block_k: int):
+    """`_band_geometry` seen from a K block: `nqb`, the number of Q blocks
+    whose rows can see any of its keys, again a function of the window and
+    the block sizes only."""
+    nq = t // block_q
+    span = block_k + window - 1 + (0 if causal else window - 1)
+    return nq, min(nq, (span + block_q - 1) // block_q + 1)
+
+
+def _qb_first(j, *, nq: int, nqb: int, block_q: int, block_k: int,
+              window: int, causal: bool):
+    """First Q block visited for K block `j`: the block of the first row
+    that can see the K block's first key; the `nqb` blocks from there
+    reach the last row that can see its last key."""
+    lo = j * block_k - (0 if causal else window - 1)
+    return jnp.clip(jnp.maximum(lo, 0) // block_q, 0, nq - nqb)
+
+
+def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qb, kb, *,
+              window: int, causal: bool, scale: float):
+    """One score tile again, from the saved log-sum-exp: p = exp(s - L)
+    inside the band, 0 outside, and ds = p * (do·vᵀ - Δ) * scale; also the
+    folded q and do rows the callers multiply them by."""
+    g, bq, d = q_ref.shape[1:]
+    block_k = k_ref.shape[1]
+    qf = q_ref[0].reshape(g * bq, d)
+    dof = do_ref[0].reshape(g * bq, d)
+    k, v = k_ref[0], v_ref[0]
+    prec = (jax.lax.Precision.HIGHEST if qf.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    s = jnp.dot(qf, k.T, preferred_element_type=jnp.float32,
+                precision=prec) * scale
+    vis = _visible(qb, kb, g, bq, block_k, window, causal)
+    lse = lse_ref[0].reshape(g * bq, _LSE_LANES)[:, 0:1]
+    delta = delta_ref[0].reshape(g * bq, _LSE_LANES)[:, 0:1]
+    p = jnp.where(vis, jnp.exp(jnp.where(vis, s, _NEG_INF) - lse), 0.0)
+    dp = jnp.dot(dof, v.T, preferred_element_type=jnp.float32,
+                 precision=prec)
+    return p, p * (dp - delta) * scale, qf, dof, prec
+
+
+def _tile_live(qb, kb, bq: int, block_k: int, window: int, causal: bool):
+    """Whether Q block `qb` and K block `kb` share any pair of the band."""
+    lo = qb * bq - window + 1
+    hi = (qb + 1) * bq - 1 + (0 if causal else window - 1)
+    return (kb * block_k <= hi) & (kb * block_k + block_k - 1 >= lo)
+
+
+def _banded_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dq_ref, dq_scr, *, nk: int, window: int,
+                          causal: bool, scale: float):
+    """Grid = (batch·Hkv, Q blocks, band K blocks), as the forward: the
+    group's dQ tile accumulates in VMEM scratch across the band."""
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    nkb = pl.num_programs(2)
+    g, bq, d = q_ref.shape[1:]
+    block_k = k_ref.shape[1]
+    kb = _kb_first(i, nk=nk, nkb=nkb, block_q=bq, block_k=block_k,
+                   window=window, causal=causal) + j
+
+    @pl.when(j == 0)
+    def _():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(_tile_live(i, kb, bq, block_k, window, causal))
+    def _():
+        _, ds, _, _, prec = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, kb,
+            window=window, causal=causal, scale=scale)
+        k = k_ref[0]
+        dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32,
+                             precision=prec)
+
+    @pl.when(j == nkb - 1)
+    def _():
+        dq_ref[0] = dq_scr[:].reshape(g, bq, d).astype(dq_ref.dtype)
+
+
+def _banded_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dk_ref, dv_ref, dk_scr, dv_scr, *, nq: int,
+                            window: int, causal: bool, scale: float):
+    """Grid = (batch·Hkv, K blocks, band Q blocks): the K/V tile's
+    gradient accumulates in VMEM scratch across the Q blocks that can see
+    it, summed over the GQA group by the folded rows' contraction."""
+    j = pl.program_id(1)
+    step = pl.program_id(2)
+    nqb = pl.num_programs(2)
+    bq = q_ref.shape[2]
+    block_k = k_ref.shape[1]
+    qb = _qb_first(j, nq=nq, nqb=nqb, block_q=bq, block_k=block_k,
+                   window=window, causal=causal) + step
+
+    @pl.when(step == 0)
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(_tile_live(qb, j, bq, block_k, window, causal))
+    def _():
+        p, ds, qf, dof, prec = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qb, j,
+            window=window, causal=causal, scale=scale)
+        dv_scr[:] += jnp.dot(p.astype(dof.dtype).T, dof,
+                             preferred_element_type=jnp.float32,
+                             precision=prec)
+        dk_scr[:] += jnp.dot(ds.astype(qf.dtype).T, qf,
+                             preferred_element_type=jnp.float32,
+                             precision=prec)
+
+    @pl.when(step == nqb - 1)
+    def _():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_block_q(block_q: int, g: int) -> int:
+    """The backward holds four [G·Bq, Bk] float32 tiles and two lane-wide
+    row statistics at once: G·Bq is kept to 1024 rows so that they fit the
+    default scoped VMEM with a six-wide group (six of 128)."""
+    while g * block_q > 1024 and block_q > 128:
+        block_q //= 2
+    return block_q
+
+
+def _run_banded_bwd(q5, k3, v3, o5, lse, do5, *, window: int, causal: bool,
+                    scale: float, block_q: int, block_k: int,
+                    interpret: bool):
+    """dq [B·Hkv, G, T, Dh], dk and dv [B·Hkv, T, Dh] from the O(T)
+    residuals (q, k, v, o, L), over the band's tiles only."""
+    bh, g, t, dh = q5.shape
+    block_q = _fit_block(_bwd_block_q(block_q, g), t, interpret=interpret)
+    block_k = _fit_block(block_k, t, interpret=interpret)
+    nk, nkb = _band_geometry(t, window, causal, block_q, block_k)
+    nq, nqb = _qb_geometry(t, window, causal, block_q, block_k)
+    geometry = dict(block_q=block_q, block_k=block_k, window=window,
+                    causal=causal)
+    kmap = functools.partial(_kb_first, nk=nk, nkb=nkb, **geometry)
+    qmap = functools.partial(_qb_first, nq=nq, nqb=nqb, **geometry)
+    lanes = lambda x: jnp.broadcast_to(x[..., None], x.shape + (_LSE_LANES,))
+    # Δ = rowsum(do · o): one fused elementwise and reduce in XLA
+    delta = jnp.sum(do5.astype(jnp.float32) * o5.astype(jnp.float32),
+                    axis=-1)
+    args = (q5, k3, v3, do5, lanes(lse), lanes(delta))
+    kernel_args = dict(window=window, causal=causal, scale=scale)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    def specs(q_index, kv_index):
+        q_spec = pl.BlockSpec((1, g, block_q, dh), q_index)
+        row_spec = pl.BlockSpec((1, g, block_q, _LSE_LANES), q_index)
+        kv_spec = pl.BlockSpec((1, block_k, dh), kv_index)
+        return q_spec, kv_spec, [q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                                 row_spec]
+
+    q_spec, _, in_specs = specs(lambda bb, i, j: (bb, 0, i, 0),
+                                lambda bb, i, j: (bb, kmap(i) + j, 0))
+    dq = pl.pallas_call(
+        functools.partial(_banded_bwd_dq_kernel, nk=nk, **kernel_args),
+        name="banded_attention_bwd_dq",
+        grid=(bh, nq, nkb),
+        in_specs=in_specs,
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+        scratch_shapes=[pltpu.VMEM((g * block_q, dh), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+    )(*args)
+    _, kv_spec, in_specs = specs(lambda bb, j, i: (bb, 0, qmap(j) + i, 0),
+                                 lambda bb, j, i: (bb, j, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_banded_bwd_dkdv_kernel, nq=nq, **kernel_args),
+        name="banded_attention_bwd_dkdv",
+        grid=(bh, nk, nqb),
+        in_specs=in_specs,
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
+                        pltpu.VMEM((block_k, dh), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+    )(*args)
+    return dq, dk, dv
 
 
 def banded_eligible(t: int, h: int, hkv: int, *, min_t: int = 256,
@@ -246,30 +482,36 @@ def banded_attention(q, k, v, window: int, causal: bool = True,
     q: [B, T, H, Dh]; k/v: [B, T, Hkv, Dh] with Hkv dividing H (Hkv == H
     is plain MHA). Causal visibility is `q - window < k <= q`;
     bidirectional is `|q - k| < window` — exactly the layer's dense band
-    semantics. Forward is O(T·w) compute/HBM by grid construction;
-    backward recomputes through the dense band-masked reference (scores
-    exist transiently on the backward only)."""
+    semantics. Forward and backward are O(T·w) in compute, HBM traffic
+    and memory by grid construction: the backward recomputes the band's
+    score tiles from the rows' saved log-sum-exp."""
     s = scale if scale is not None else q.shape[-1] ** -0.5
-    return _run_banded(q, k, v, window=window, causal=causal, scale=s,
-                       block_q=block_q, block_k=block_k,
+    o, _ = _run_banded(*_fold_heads(q, k, v), window=window, causal=causal,
+                       scale=s, block_q=block_q, block_k=block_k,
                        interpret=interpret)
+    return _unfold_q(o, q.shape[0])
 
 
 def _banded_fwd(q, k, v, window, causal, scale, block_q, block_k,
                 interpret):
-    out = banded_attention(q, k, v, window, causal, scale, block_q,
-                           block_k, interpret)
-    return out, (q, k, v)
+    s = scale if scale is not None else q.shape[-1] ** -0.5
+    q5, k3, v3 = _fold_heads(q, k, v)
+    o5, lse = _run_banded(q5, k3, v3, window=window, causal=causal, scale=s,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret, with_lse=True)
+    return _unfold_q(o5, q.shape[0]), (q5, k3, v3, o5, lse)
 
 
 def _banded_bwd(window, causal, scale, block_q, block_k, interpret, res,
                 do):
-    q, k, v = res
-    s = scale if scale is not None else q.shape[-1] ** -0.5
-    _, vjp = jax.vjp(
-        lambda qq, kk, vv: banded_reference(qq, kk, vv, window, causal, s),
-        q, k, v)
-    return vjp(do)
+    q5, k3, v3, o5, lse = res
+    b = do.shape[0]
+    s = scale if scale is not None else q5.shape[-1] ** -0.5
+    do5 = do.transpose(0, 2, 1, 3).reshape(q5.shape)
+    dq, dk, dv = _run_banded_bwd(
+        q5, k3, v3, o5, lse, do5, window=window, causal=causal, scale=s,
+        block_q=block_q, block_k=block_k, interpret=interpret)
+    return _unfold_q(dq, b), _unfold_kv(dk, b), _unfold_kv(dv, b)
 
 
 banded_attention.defvjp(_banded_fwd, _banded_bwd)

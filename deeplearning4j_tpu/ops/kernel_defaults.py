@@ -289,10 +289,20 @@ def banded_policy(t: int, h: int, hkv: int,
 
     Same lattice as `attention_policy`: env force, then shape capability,
     then memory necessity, then the measured verdict, with dense the
-    no-data default. Memory necessity applies to the FORWARD-only mode —
-    the banded backward recomputes through the dense band-masked
-    reference, so routing banded cannot relieve a training-shape [T, T]
-    hazard and must not claim to."""
+    no-data default. Memory necessity holds for training shapes as for
+    forward-only ones: the banded backward is blockwise over the band's
+    tiles (`ops/banded_attention._run_banded_bwd`), so where the dense
+    scores cannot exist (T 8,192 with 48 heads: 6 GiB a copy in bf16, and
+    the backward holds three) banded is the path in both directions.
+
+    What the chip showed for training at 8,192 (PR 33, the traced run of
+    the benchmark's `trinity_large_fit`: 48 query heads over 8 KV heads of
+    128, window 4,096, bf16, blocks 256 and the backward's Q block 128):
+    the forward 14.7 to 15.8 ms a call, the dK/dV kernel 15.0 ms, the
+    three calls together a third of the bf16 peak over the pairs the band
+    really has. There is no MEASURED row for it and there cannot be one:
+    a row is a head-to-head, and the dense contender does not fit the
+    chip at this shape (it asked the compiler for 18.7 GiB)."""
     forced = _env("DL4J_TPU_ATTN")
     blocks = _blocks_from_env()
     from deeplearning4j_tpu.ops.banded_attention import banded_eligible
@@ -324,8 +334,8 @@ def banded_policy(t: int, h: int, hkv: int,
         return banded(256, 256, "forced by DL4J_TPU_ATTN=banded")
     if not can:
         return dense(f"shape ineligible (t={t}, h={h}, hkv={hkv})")
-    if not train and _mem_hazard(t, t):
-        row = _best_measured_banded("fwd", t)
+    if _mem_hazard(t, t):
+        row = _best_measured_banded("train" if train else "fwd", t)
         bq, bk = (row["block_q"], row["block_k"]) if row else (256, 256)
         return banded(bq, bk,
                       f"memory necessity: T^2 >= {dense_max_t()}^2")

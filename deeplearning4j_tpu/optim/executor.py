@@ -126,6 +126,25 @@ def batch_signature(ds):
             _arr_sig(ds.features_mask), _arr_sig(ds.labels_mask))
 
 
+def _publish_routing_counters(net) -> None:
+    """The last step's routing counters of every expert layer
+    (`parallel/moe.ExpertFeedForward`: the `moe_*` scalars of its state)
+    out of the net's layer state into the gauges `<counter>{layer=}`.
+    Called where the epoch has just synchronised with the device; a net
+    without such a layer pays a walk over its state's keys."""
+    counters = {
+        name: {k: v for k, v in st.items() if k.startswith("moe_")}
+        for name, st in (getattr(net, "state_tree", None) or {}).items()
+        if isinstance(st, dict) and "moe_pairs_held" in st}
+    if counters:
+        import jax
+
+        # graft: allow-sync(the epoch has just synchronised; one read)
+        for name, values in jax.device_get(counters).items():
+            for key, value in values.items():
+                get_registry().gauge(key, layer=name).set(int(value))
+
+
 class TrainingExecutor:
     """The shared epoch/batch/listener loop with async-dispatch semantics.
 
@@ -306,6 +325,7 @@ class TrainingExecutor:
         sync = span("fit.epoch_sync")
         with sync:
             net._loss_tracker.materialize()
+        _publish_routing_counters(net)
         if attr is not None:
             attr.close_window(sync.start_ns, sync.end_ns)
 

@@ -22,7 +22,7 @@ from deeplearning4j_tpu.observe import donatemon
 from deeplearning4j_tpu.utils.pytrees import tree_norm
 
 __all__ = ["make_train_step", "make_fused_step", "stack_step_args",
-           "jit_step", "normalize_grads"]
+           "jit_step", "normalize_grads", "as_features"]
 
 _tmap = jax.tree_util.tree_map
 
@@ -127,6 +127,18 @@ def make_fused_step(step_fn):
         return params, opt_state, states, rng, losses
 
     return fused
+
+
+def as_features(a, dtype, asarray=jnp.asarray, ids: bool = False):
+    """A batch's features as the step takes them: in the net's `dtype`
+    (cast on the device when they are there already), except integers
+    into an input that looks ids up (`ids`: the consumer is an embedding),
+    which stay as they came. A bf16 net holds integers exactly only to
+    256, so ids cast to it fetch their neighbours' rows."""
+    kind = a.dtype if hasattr(a, "dtype") else np.asarray(a).dtype
+    if ids and np.issubdtype(kind, np.integer):
+        return asarray(a)
+    return asarray(a, dtype)
 
 
 def stack_step_args(per_batch):

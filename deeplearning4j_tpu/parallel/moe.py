@@ -16,12 +16,23 @@ TPU-first design (GShard/Switch-style, MXU-friendly):
   mean routed fraction, scaled by E); the layer reports it through the
   state pytree under "aux_loss" and the model runtimes add it to the score
   inside the differentiated loss closure.
+
+Two layers live here. `MoEFeedForward` is the one above: every expert on
+the mesh, a fixed capacity, tokens over it dropped; the `expert` mesh
+axis and its all_to_all are its reason to stay. `ExpertFeedForward` is
+one device's share of a large sparse model's expert layer: a router over
+all `n_experts`, of which this device holds `held`, the (token, expert)
+pairs sorted by expert, the rows of the experts held gathered, grouped
+matrix products over them (`jax.lax.ragged_dot`), and the results
+gathered back by token. No pair is dropped at any imbalance and no
+[N, E, C] tensor exists; what the absent experts would add is left out.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -313,3 +324,239 @@ class MoEFeedForward(Layer):
             y = y.reshape(b, t, d)
         return y, {"aux_loss": self.aux_weight * aux,
                    "overflow_frac": overflow}
+
+
+# ------------------------------------------- one device's share of experts
+def route(x, router, bias, *, k: int, score: str, route_norm: bool,
+          route_scale: float):
+    """Each token's `k` experts and their weights: scores over the
+    router's whole width in float32 (`score`: "sigmoid", each expert on
+    its own, or "softmax"), the `k` largest of score + `bias` (which
+    steers the choice only: no weight and no gradient comes of it), and
+    the chosen scores themselves, normalised to sum to one where
+    `route_norm`, times `route_scale`. Returns (experts [N, k] int32,
+    weights [N, k] float32)."""
+    logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"score must be 'sigmoid' or 'softmax', "
+                         f"got {score!r}")
+    choice = s if bias is None else s + jax.lax.stop_gradient(
+        bias.astype(jnp.float32))
+    _, experts = jax.lax.top_k(jax.lax.stop_gradient(choice), k)
+    weights = jnp.take_along_axis(s, experts, axis=-1)
+    if route_norm:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return experts.astype(jnp.int32), weights * route_scale
+
+
+@jax.custom_vjp
+def _take_rows(x, index, back):
+    """`x[index]`: the token rows of the first C sorted pairs (`index`
+    [C]). Its transpose is `_sum_rows`, so the cotangent is gathered too
+    and no scatter-add over rows is ever made."""
+    return jnp.take(x, index, axis=0)
+
+
+@jax.custom_vjp
+def _sum_rows(x, index, back):
+    """The sum over a token's k pairs of the pairs' rows: `sum_j
+    x_[back[:, j]]` with `x_` = `x` [C, d] and one row of zeros after it,
+    where `back` [N, k] holds each pair's place among the sorted pairs, C
+    for a pair that is not among the first C. The transpose of
+    `_take_rows`, a gather like it."""
+    padded = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+    return jnp.sum(jnp.take(padded, back, axis=0).astype(jnp.float32),
+                   axis=1).astype(x.dtype)
+
+
+_take_rows.defvjp(
+    lambda x, index, back: (_take_rows(x, index, back), (index, back)),
+    lambda res, g: (_sum_rows(g, *res), None, None))
+_sum_rows.defvjp(
+    lambda x, index, back: (_sum_rows(x, index, back), (index, back)),
+    lambda res, g: (_take_rows(g, *res), None, None))
+
+
+def _swiglu(x, w1, w3, w2, dot):
+    return dot(jax.nn.silu(dot(x, w1)) * dot(x, w3), w2)
+
+
+def _row_tiers(rows: int, share: float) -> Tuple[int, ...]:
+    """The row counts the routed products are compiled for: four times
+    what uniform routing sends to the experts held (`share` of all `rows`
+    pairs), twice and four times that, and all of them. A step runs the smallest
+    that holds the pairs that fell here, so no pair is ever dropped and
+    the work follows them, coarsely: on the chip a router's load on eight
+    of 256 experts read 0.4 to 2.1 times the uniform share from seed to
+    seed and batch to batch, and a tier that every other step crosses
+    makes the step's time a matter of the seed."""
+    up = lambda n: min(rows, -(-int(n) // 128) * 128)
+    first = up(4 * share * rows)
+    return tuple(sorted({first, up(2 * first), up(4 * first), rows}))
+
+
+def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
+                 n_experts: int):
+    """sum over a token's pairs whose expert is held of weight x
+    expert(token): x [N, d], experts and weights [N, k], the held experts'
+    SwiGLU kernels w1, w3 [count, d, f] and w2 [count, f, d], `first` the
+    published index of the first of them among the router's `n_experts`.
+    Returns (y [N, d], counters)."""
+    n, k = experts.shape
+    count, rows = w1.shape[0], n * k
+    with jax.named_scope("dispatch"):
+        local = experts.reshape(rows) - first
+        is_held = (local >= 0) & (local < count)
+        key = jnp.where(is_held, local, count)      # the rest sort last
+        order = jnp.argsort(key, stable=True)
+        place = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+        token = (order // k).astype(jnp.int32)      # pair p is token p // k
+        pair_weight = jnp.where(is_held, weights.reshape(rows), 0.0)[order]
+        sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                        dtype=jnp.int32)
+        n_held = jnp.sum(sizes)
+    tiers = _row_tiers(rows, count / n_experts)
+
+    def tier(c):
+        # under `jax.checkpoint`: what a tier keeps for its backward pass
+        # is its arguments, the same for every tier, so the one that runs
+        # writes no residuals of the others' sizes
+        @jax.checkpoint
+        def run(x, w1, w3, w2, token, place, pair_weight, sizes):
+            back = jnp.where(place < c, place, c)
+            # A row past the pairs held is no pair of ours. Left in no
+            # group, what a grouped product puts in it is undefined on the
+            # chip, in its result and in its cotangent alike (the CPU gives
+            # zeros), and a product's time there follows the rows that ARE
+            # in a group (a step read 10 ms longer at 6% of the pairs held
+            # than at 2%). So such rows are put at zero on both sides of
+            # every product and counted to the last expert held: they add
+            # nothing, and a tier costs what it costs whatever fell into it.
+            live = (jnp.arange(c) < jnp.sum(sizes))[:, None]
+            rows = lambda v: jnp.where(live, v, 0)
+            full = sizes.at[-1].add(
+                (c - jnp.sum(sizes)).astype(sizes.dtype))
+            with jax.named_scope("dispatch"):
+                taken = rows(_take_rows(x, token[:c], back))
+            with jax.named_scope("experts_held"):
+                out = _swiglu(taken, w1, w3, w2, lambda a, w: rows(
+                    jax.lax.ragged_dot(a, w, group_sizes=full)))
+            with jax.named_scope("combine"):
+                return _sum_rows(out * pair_weight[:c, None].astype(
+                    out.dtype), token[:c], back)
+        return run
+
+    which = jnp.sum(n_held > jnp.asarray(tiers[:-1], jnp.int32)) \
+        if len(tiers) > 1 else 0
+    args = (x, w1, w3, w2, token, place, pair_weight, sizes)
+    y = (jax.lax.switch(which, [tier(c) for c in tiers], *args)
+         if len(tiers) > 1 else tier(tiers[0])(*args))
+    taken = jnp.asarray(tiers, jnp.int32)[which]
+    return y, dict(zip(COUNTERS, (
+        jnp.asarray(rows, jnp.int32), n_held,
+        jnp.maximum(n_held - taken, 0), jnp.max(sizes), jnp.min(sizes))))
+
+
+# a step's routing counters, in an expert layer's state: the pairs the
+# router chose (tokens x k), those that fell on experts held, those of
+# them not computed (0: there is no capacity), and the largest and
+# smallest load of an expert held
+COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs_dropped",
+            "moe_load_max", "moe_load_min")
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class ExpertFeedForward(Layer):
+    """One device's share of a sparse model's expert layer (d -> d, no
+    residual inside): `shared(x) + sum over a token's k experts that are
+    held here of weight x expert(x)`, every expert a bias-free SwiGLU of
+    `width`. The router spans all `n_experts` (the published count);
+    `held` = (first, count) names the ones whose kernels this device has,
+    all of them where None. What the absent experts would add is left out:
+    on a mesh their devices add it, here nothing stands in for them.
+
+    Leaves: `router` [d, n_experts]; `bias` [n_experts] where
+    `selection_bias` (added to the scores for the choice only, its
+    gradient exactly zero: whoever balances load moves it between steps);
+    `w1`, `w3` [count, d, width], `w2` [count, width, d]; with `n_shared`
+    shared experts `shared_w1`, `shared_w3` [d, n_shared x width] and
+    `shared_w2`. State: the last step's `COUNTERS`, which `fit()`
+    publishes as gauges `<name>{layer=}` where an epoch synchronises.
+    Accepts [N, d] or [B, T, d]."""
+
+    CONSUMES = "any"
+
+    n_in: Optional[int] = None
+    width: Optional[int] = None
+    n_experts: int = 8
+    held: Optional[Tuple[int, int]] = None
+    k: int = 2
+    score: str = "softmax"
+    selection_bias: bool = False
+    route_norm: bool = False
+    route_scale: float = 1.0
+    n_shared: int = 0
+
+    def infer_n_in(self, input_type: InputType) -> "ExpertFeedForward":
+        if self.n_in is None:
+            return dataclasses.replace(self, n_in=input_type.size)
+        return self
+
+    @property
+    def _held(self) -> Tuple[int, int]:
+        first, count = self.held or (0, self.n_experts)
+        if not (0 <= first and 1 <= count
+                and first + count <= self.n_experts):
+            raise ValueError(f"held {self.held} lies outside the "
+                             f"{self.n_experts} experts")
+        return int(first), int(count)
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d = self.n_in or input_type.size
+        f = self.width or 4 * d
+        if not 1 <= self.k <= self.n_experts:
+            raise ValueError(f"k {self.k} of {self.n_experts} experts")
+        first, count = self._held
+        ks = jax.random.split(key, 7)
+        winit = self._winit()
+
+        def stack(key, shape):      # an expert's kernel from its own index
+            return jnp.stack([winit(jax.random.fold_in(key, first + i),
+                                    shape, dtype) for i in range(count)])
+
+        params = {"router": winit(ks[0], (d, self.n_experts), dtype),
+                  "w1": stack(ks[1], (d, f)), "w3": stack(ks[2], (d, f)),
+                  "w2": stack(ks[3], (f, d))}
+        if self.selection_bias:
+            params["bias"] = jnp.zeros((self.n_experts,), dtype)
+        if self.n_shared:
+            fs = self.n_shared * f
+            params.update(shared_w1=winit(ks[4], (d, fs), dtype),
+                          shared_w3=winit(ks[5], (d, fs), dtype),
+                          shared_w2=winit(ks[6], (fs, d), dtype))
+        return params, dict.fromkeys(COUNTERS, jnp.zeros((), jnp.int32))
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        tokens = x.reshape(-1, x.shape[-1])
+        with jax.named_scope("router"):
+            experts, weights = route(
+                tokens, params["router"], params.get("bias"), k=self.k,
+                score=self.score, route_norm=self.route_norm,
+                route_scale=self.route_scale)
+        y, counters = held_experts(
+            tokens, experts, weights.astype(tokens.dtype), params["w1"],
+            params["w3"], params["w2"], first=self._held[0],
+            n_experts=self.n_experts)
+        if self.n_shared:
+            with jax.named_scope("shared_expert"):
+                y = y + _swiglu(tokens, params["shared_w1"],
+                                params["shared_w3"], params["shared_w2"],
+                                jnp.dot)
+        return y.reshape(x.shape), counters

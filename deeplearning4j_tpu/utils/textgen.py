@@ -74,9 +74,15 @@ def _input_encoding(first_layer) -> str:
 
 
 def _encode(ids: np.ndarray, encoding: str, vocab: int) -> np.ndarray:
-    """ids: [B, T] -> model input [B, T, 1] or one-hot [B, T, V]."""
+    """ids: [B, T] -> model input [B, T, 1] (integers, as the embedding
+    looks them up) or one-hot [B, T, V]. An id outside the vocabulary is
+    refused, not folded back into it."""
+    if ids.size and (ids.min() < 0 or ids.max() >= vocab):
+        raise ValueError(
+            f"token ids must lie in [0, {vocab}); got {int(ids.min())} to "
+            f"{int(ids.max())}")
     if encoding == "ids":
-        return ids[..., None].astype(np.float32)
+        return ids[..., None].astype(np.int32)
     return np.eye(vocab, dtype=np.float32)[ids]
 
 
@@ -142,12 +148,11 @@ def generate(net, prompt_ids, n_tokens: int, *, temperature: float = 1.0,
                             top_p=top_p, greedy=greedy)
 
     penalize = repetition_penalty != 1.0
-    if penalize:
-        seen = np.zeros((B, vocab), dtype=bool)
-        np.put_along_axis(seen, prompt_ids.astype(np.int64) % vocab, True,
-                          axis=-1)
     net.rnn_clear_previous_state()
     out = _prefill(net, prompt_ids, encoding, vocab, prefill_chunk)
+    if penalize:    # the prefill has refused any id past the vocabulary
+        seen = np.zeros((B, vocab), dtype=bool)
+        np.put_along_axis(seen, prompt_ids.astype(np.int64), True, axis=-1)
     generated = np.empty((B, n_tokens), dtype=np.int64)
     for i in range(n_tokens):
         p = out[:, -1, :].astype(np.float64)

@@ -14,9 +14,10 @@ from __future__ import annotations
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.attention import (
-    PositionEmbeddingLayer, TransformerEncoderBlock,
+    PositionEmbeddingLayer, SandwichTransformerBlock, TransformerEncoderBlock,
 )
 from deeplearning4j_tpu.nn.layers.feedforward import EmbeddingSequenceLayer
+from deeplearning4j_tpu.nn.layers.normalization import RMSNormalization
 from deeplearning4j_tpu.nn.layers.recurrent import RnnOutputLayer
 from deeplearning4j_tpu.optim.updaters import Adam
 from deeplearning4j_tpu.zoo.base import ZooModel, register_zoo
@@ -125,3 +126,89 @@ class TextGenerationTransformer(ZooModel):
                                    loss="mcxent"))
                 .set_input_type(InputType.recurrent(1, t))
                 .build())
+
+
+@register_zoo
+class SparseSandwichTransformer(ZooModel):
+    """A causal language model of the `afmoe` family (Arcee Trinity),
+    built from the keys its published `config.json` has: sandwich-norm
+    blocks (`SandwichTransformerBlock`) whose attention is gated, GQA,
+    normed per head, over a sliding window with rotary positions where
+    `layer_types` says "sliding_attention" and over the whole causal
+    context with no positions at all where it says "full_attention"; the
+    first `num_dense_layers` blocks have a dense SwiGLU of
+    `intermediate_size`, the rest sigmoid-routed experts
+    (`num_experts`, `num_experts_per_tok`, `num_shared_experts`,
+    `moe_intermediate_size`, `route_norm`, `route_scale`) with a selection
+    bias. Embedding rows are scaled by sqrt(hidden_size) where
+    `mup_enabled`; the head is its own matrix behind a last RMS norm.
+
+    One device's share of a deployment is built with `experts_held`
+    (first, count): the experts of every expert layer whose kernels live
+    here, and `vocabulary_held`: the rows of the embedding and the head
+    that do. Token ids come as `[batch, time]` integers, labels as
+    integers (`sparse_mcxent`)."""
+
+    input_shape = (8192,)
+
+    def __init__(self, config: dict, *, timesteps: int = None,
+                 experts_held=None, vocabulary_held: int = None,
+                 dtype: str = "float32", gradient_checkpointing=False, **kw):
+        super().__init__(
+            num_classes=vocabulary_held or config["vocab_size"],
+            input_shape=(timesteps or self.input_shape[0],), **kw)
+        kinds = list(config["layer_types"])
+        if len(kinds) != config["num_hidden_layers"]:
+            raise ValueError(
+                f"layer_types has {len(kinds)} entries for "
+                f"{config['num_hidden_layers']} layers")
+        unknown = set(kinds) - {"sliding_attention", "full_attention"}
+        if unknown:
+            raise ValueError(f"layer_types {sorted(unknown)} not known")
+        self.config = dict(config)
+        self.experts_held = experts_held
+        self.dtype = dtype
+        self.gradient_checkpointing = gradient_checkpointing
+
+    def conf(self):
+        c, t = self.config, self.input_shape[0]
+        d = c["hidden_size"]
+        blocks = []
+        for i, kind in enumerate(c["layer_types"]):
+            sliding = kind == "sliding_attention"
+            sparse = i >= c["num_dense_layers"]
+            blocks.append(SandwichTransformerBlock(
+                num_heads=c["num_attention_heads"],
+                num_kv_heads=c["num_key_value_heads"],
+                head_dim=c["head_dim"], qk_norm=True, output_gate=True,
+                causal=True, rope=sliding,
+                window=c["sliding_window"] if sliding else None,
+                max_cache=t, eps=c["rms_norm_eps"],
+                ffn_width=c["intermediate_size"],
+                n_experts=c["num_experts"] if sparse else 0,
+                experts_held=self.experts_held,
+                moe_k=c["num_experts_per_tok"],
+                expert_width=c["moe_intermediate_size"],
+                n_shared=c["num_shared_experts"],
+                score=c.get("score_func", "sigmoid"), selection_bias=True,
+                route_norm=c["route_norm"], route_scale=c["route_scale"]))
+        if c.get("rope_theta", 10000) != 10000:
+            raise ValueError("rope_theta other than 10000 is not wired")
+        builder = (NeuralNetConfiguration.builder()
+                   .seed(self.seed)
+                   .updater(self.kw.get("updater", Adam(3e-4)))
+                   .activation("identity")
+                   .weight_init("xavier")
+                   .dtype(self.dtype))
+        if self.gradient_checkpointing:
+            builder = builder.gradient_checkpointing()
+        return (builder.list(
+            EmbeddingSequenceLayer(
+                n_in=self.num_classes, n_out=d, activation="identity",
+                scale=d ** 0.5 if c.get("mup_enabled") else None),
+            *blocks,
+            RMSNormalization(eps=c["rms_norm_eps"]),
+            RnnOutputLayer(n_out=self.num_classes, has_bias=False,
+                           activation="softmax", loss="sparse_mcxent"))
+            .set_input_type(InputType.recurrent(1, t))
+            .build())

@@ -63,9 +63,9 @@ class TestFullSeqParity:
                                    **TOL)
 
     def test_gradients_match_reference(self):
-        # custom_vjp routes the backward through the dense band-masked
-        # recompute; parity here proves the plumbing (residuals, GQA
-        # folding) — the forward parity above proves the kernel.
+        # custom_vjp routes the backward through the blockwise dQ and
+        # dK/dV kernels over the band's tiles; the dense band-masked
+        # reference under plain autodiff is the oracle.
         t, w, dh = 32, 8, 8
         q, k, v = _qkv(1, t, 4, 2, dh, seed=5)
 
@@ -81,6 +81,45 @@ class TestFullSeqParity:
         for g, r in zip(got, want):
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                        rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("t,w,bq,bk", [
+        (64, 24, 16, 16),     # window under T: most tiles lie off the band
+        (64, 24, 8, 32),      # Q and K blocks of different sizes
+        (64, 100, 16, 16),    # window over T: every tile is on the band
+        (48, 1, 16, 8),       # self only
+        (40, 17, 8, 8),       # T and w off every tile's edge
+    ])
+    def test_blockwise_backward_for_a_six_wide_group(self, causal, t, w,
+                                                     bq, bk):
+        # 48 query heads over 8 KV heads in small: 12 over 2, so the
+        # backward's tiles hold a group of six and dK/dV sum over it
+        dh = 8
+        q, k, v = _qkv(2, t, 12, 2, dh, seed=t + w)
+        do = jax.random.normal(jax.random.PRNGKey(99), q.shape)
+
+        def grads(attn):
+            return jax.grad(lambda q, k, v: (attn(q, k, v) * do).sum(),
+                            argnums=(0, 1, 2))(q, k, v)
+
+        got = grads(lambda q, k, v: banded_attention(
+            q, k, v, w, causal, None, bq, bk, interpret=True))
+        want = grads(lambda q, k, v: banded_reference(
+            q, k, v, w, causal, dh ** -0.5))
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), **TOL)
+
+    def test_backward_keeps_no_score_matrix(self):
+        # the residuals between the passes are q, k, v, o and one number a
+        # row; nothing [T, T] is traced in either direction
+        t, w = 256, 32
+        q, k, v = _qkv(1, t, 4, 2, 8)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: banded_attention(
+            q, k, v, w, True, None, 32, 32, interpret=False).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+        shapes = [tuple(var.aval.shape) for eqn in jaxpr.eqns
+                  for var in eqn.outvars]
+        assert not any(s[-2:] == (t, t) for s in shapes if len(s) >= 2)
 
     def test_multi_block_sweep(self):
         # the same answer regardless of tiling: block geometry must not

@@ -71,10 +71,12 @@ def _lstm(dt, train, T=128, B=64, H=512):
     return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if train else fwd), shapes
 
 
-def _flash(train, T=8192, heads=8, d=64, block=512):
+def _flash(train, T=8192, heads=8, d=64, block=512, kv_heads=None):
     # T 8192 is where the policy routes to flash for memory, with the
-    # Pallas backward (ops/kernel_defaults.attention_policy)
-    shapes = [((1, T, heads, d), BF16)] * 3
+    # Pallas backward (ops/kernel_defaults.attention_policy); with
+    # `kv_heads` the kernels read K/V by `h // G` in place
+    shapes = [((1, T, heads, d), BF16)] \
+        + [((1, T, kv_heads or heads, d), BF16)] * 2
 
     def fwd(q, k, v):
         return flash.flash_attention(q, k, v, True, None, block, block,
@@ -86,13 +88,18 @@ def _flash(train, T=8192, heads=8, d=64, block=512):
     return (jax.grad(loss, argnums=(0, 1, 2)) if train else fwd), shapes
 
 
-def _banded(T=2048, heads=8, kv_heads=2, d=64, window=512):
+def _banded(T=2048, heads=8, kv_heads=2, d=64, window=512, train=False,
+            batch=2):
     def fwd(q, k, v):
         return banded.banded_attention(q, k, v, window, True, None, 256,
                                        256, False)
 
-    return fwd, [((2, T, heads, d), BF16), ((2, T, kv_heads, d), BF16),
-                 ((2, T, kv_heads, d), BF16)]
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(F32).sum()
+
+    return (jax.grad(loss, argnums=(0, 1, 2)) if train else fwd), [
+        ((batch, T, heads, d), BF16), ((batch, T, kv_heads, d), BF16),
+        ((batch, T, kv_heads, d), BF16)]
 
 
 def _decode(paged, cache_dtype, slots=8, cache=1024, page=128, heads=8,
@@ -125,7 +132,15 @@ CASES = {
     "lstm_train_bf16": lambda: _lstm(BF16, train=True),
     "flash_fwd": lambda: _flash(train=False),
     "flash_pallas_bwd": lambda: _flash(train=True),
+    # 48 query heads of 128 over 8 KV heads at 8,192 tokens, window 4,096:
+    # the shapes a six-wide group folds into the backward's tiles
+    "flash_pallas_bwd_gqa": lambda: _flash(train=True, heads=48, d=128,
+                                           kv_heads=8),
     "banded_fwd_gqa": _banded,
+    "banded_train_gqa": lambda: _banded(train=True),
+    "banded_train_gqa_48_8": lambda: _banded(
+        T=8192, heads=48, kv_heads=8, d=128, window=4096, train=True,
+        batch=1),
     "slot_decode_bf16": lambda: _decode(False, BF16),
     "slot_decode_int8": lambda: _decode(False, I8),
     "paged_decode_bf16": lambda: _decode(True, BF16),
@@ -165,6 +180,8 @@ KERNEL_NAMES = {
     "flash_attention_bwd_dkdv": "flash_pallas_bwd",
     "flash_attention_bwd_dq": "flash_pallas_bwd",
     "banded_attention_fwd": "banded_fwd_gqa",
+    "banded_attention_bwd_dq": "banded_train_gqa",
+    "banded_attention_bwd_dkdv": "banded_train_gqa",
     "banded_decode_attention": "slot_decode_bf16",
     "paged_decode_attention": "paged_decode_bf16",
     "matmul_channel_stats": _matmul_stats,
