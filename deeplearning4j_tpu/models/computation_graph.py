@@ -41,6 +41,7 @@ from deeplearning4j_tpu.nn.layers.convolution import (
 from deeplearning4j_tpu.nn.layers.special import CenterLossOutputLayer
 from deeplearning4j_tpu.models.multilayer import (
     _check_decode_budget, _checkpointed, _dtype_of, _is_recurrent,
+    record_residuals_kept,
 )
 from deeplearning4j_tpu.optim.listeners import TrainingListener
 from deeplearning4j_tpu.optim.updaters import NoOp, Updater, resolve_updater
@@ -192,6 +193,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         remat = train and self.conf.gradient_checkpointing
         pool_after = {} if collect or remat else self._pool_after
         tails = {}      # pool vertex -> (convolution vertex, its tail)
+        kept = 0        # attention kernel calls whose residuals stay
         for idx, name in enumerate(self.conf.topological_order):
             if name == stop_before:
                 break
@@ -242,8 +244,9 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                     # parameterless vertices (merge/elementwise/...) are
                     # NOT wrapped — their outputs are checkpoint residuals
                     # anyway, so wrapping buys nothing and blocks CSE
-                    y, new_st = _checkpointed(v.apply, mask)(
+                    (y, new_st), named = _checkpointed(v.apply, mask)(
                         params[name], ins, st, lrng)
+                    kept += named
                 else:
                     y, new_st = v.apply(
                         params[name], ins, state=st, train=train, rng=lrng,
@@ -257,6 +260,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
             new_states[name] = new_st
         if not collect:
             record_deferred_pairs(self, len(tails))
+            record_residuals_kept(self, kept)
         return values, out_inputs, new_states
 
     # ------------------------------------------------------------- loss

@@ -46,6 +46,7 @@ from deeplearning4j_tpu.nn.layers.recurrent import (
     BaseRecurrentLayer, Bidirectional, GravesBidirectionalLSTM, LastTimeStep,
 )
 from deeplearning4j_tpu.nn.layers.special import CenterLossOutputLayer
+from deeplearning4j_tpu.observe.registry import get_registry
 from deeplearning4j_tpu.optim.listeners import TrainingListener
 from deeplearning4j_tpu.optim.updaters import NoOp, Updater, resolve_updater
 from deeplearning4j_tpu.parallel.ring_attention import (
@@ -103,11 +104,40 @@ def _check_decode_budget(model, decode_layers, t_step: int) -> None:
 def _checkpointed(apply_fn, mask):
     """Wrap one layer/vertex apply in jax.checkpoint for the TRAIN path
     (gradient_checkpointing): its activations are rematerialized in the
-    backward pass instead of stored. Shared by MultiLayerNetwork and
-    ComputationGraph so the remat semantics can't drift."""
-    return jax.checkpoint(
+    backward pass instead of stored, all but what a Pallas attention
+    kernel's backward reads of its forward, the kernel's output and its
+    rows' log-sum-exp (`ops/attention.RESIDUAL_NAMES`: one hidden-sized
+    tensor and T floats a head), which only a second run of the kernel
+    could remake. A layer that names nothing keeps nothing. Returns the
+    apply's result and how many kernel calls had the pair named. Shared by
+    MultiLayerNetwork and ComputationGraph so the remat semantics can't
+    drift."""
+    from deeplearning4j_tpu.ops.attention import (
+        RESIDUAL_NAMES, residuals_named,
+    )
+
+    remat = jax.checkpoint(
         lambda p, x, st, lr, _a=apply_fn:
-        _a(p, x, state=st, train=True, rng=lr, mask=mask))
+        _a(p, x, state=st, train=True, rng=lr, mask=mask),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES))
+
+    def apply(p, x, st, lr):
+        before = residuals_named()
+        out = remat(p, x, st, lr)
+        return out, residuals_named() - before
+
+    return apply
+
+
+def record_residuals_kept(model, kept: int) -> None:
+    """Gauge `attention_residuals_kept{model=<class>}`: how many attention
+    kernel calls of the forward pass that `model` traced last had their
+    output and log-sum-exp named inside a checkpointed layer (5 for the
+    benchmark's `trinity_large`, 0 without `gradient_checkpointing` or
+    without a Pallas attention forward under differentiation)."""
+    get_registry().gauge("attention_residuals_kept",
+                         model=type(model).__name__).set(kept)
 
 
 class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
@@ -201,6 +231,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         n = len(self.layers)
         remat = train and self.conf.gradient_checkpointing
         tails = {}      # pool's index -> (convolution's name, its tail)
+        kept = 0        # attention kernel calls whose residuals stay
         for i, layer in enumerate(self.layers):
             # the layer's name on its device ops (and, as
             # `transpose(jvp(<name>))`, on its backward ops): debug
@@ -222,11 +253,13 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     tails[i + 1], new_st = (layer.name, tail), st
                 elif remat and not (layer.is_output_layer and i == n - 1):
                     # remat this layer's activations in the backward pass
-                    # (memory ∝ depth → memory ∝ 1, +~33% FLOPs); the
-                    # output layer is skipped — its input is retained for
-                    # the loss anyway
-                    x, new_st = _checkpointed(layer.apply, fmask)(
+                    # (memory ∝ depth → the layers' inputs and, of an
+                    # attention kernel, its output and log-sum-exp; +~33%
+                    # FLOPs less that kernel's); the output layer is
+                    # skipped — its input is retained for the loss anyway
+                    (x, new_st), named = _checkpointed(layer.apply, fmask)(
                         params[layer.name], x, st, lrng)
+                    kept += named
                 else:
                     x, new_st = layer.apply(
                         params[layer.name], x, state=st, train=train,
@@ -240,6 +273,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                 acts.append(x)
         if not collect:
             record_deferred_pairs(self, len(tails))
+            record_residuals_kept(self, kept)
         return x, out_in, new_states, acts
 
     # ------------------------------------------------------------- loss

@@ -51,7 +51,8 @@ class MultiLayerConfiguration:
     # remat every layer's activations in the backward pass — trades
     # ~33% more FLOPs for O(depth) less activation memory (the
     # jax.checkpoint lever for deep nets / long context; TPU-native
-    # extension, no reference counterpart)
+    # extension, no reference counterpart); what stays on the device:
+    # `Builder.gradient_checkpointing`
     gradient_checkpointing: bool = False
     tbptt_fwd_length: int = 0       # 0 = no truncated BPTT
     tbptt_back_length: int = 0
@@ -158,7 +159,12 @@ class Builder:
 
     def gradient_checkpointing(self, v: bool = True) -> "Builder":
         """Rematerialize layer activations in the backward pass
-        (jax.checkpoint per layer/vertex) — memory for FLOPs."""
+        (jax.checkpoint per layer/vertex) — memory for FLOPs. What stays
+        on the device between the passes is each layer's input and, of a
+        Pallas attention kernel (flash, banded), its output and its rows'
+        log-sum-exp (`ops/attention.RESIDUAL_NAMES`: one hidden-sized
+        tensor and T floats a head), so the recomputed forward does not
+        run that kernel a second time."""
         self._grad_ckpt = v
         return self
 

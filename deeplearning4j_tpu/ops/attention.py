@@ -24,20 +24,59 @@ identity ds = p * (dp - Δ) with Δ = rowsum(do · o) precomputed by XLA.
 fallback/oracle path. The default (`backward=None`) resolves from the
 measured-winner table in `ops/kernel_defaults.py` — see that module for
 the dispatch policy and its env escape hatches.
+
+Under gradient checkpointing: the forward rules of this kernel and of
+`ops/banded_attention.py`'s name the kernel's own output and the one-lane
+L (`name_residuals`: the checkpoint names `attention_out` and
+`attention_lse`, `RESIDUAL_NAMES`), and a checkpointed layer's policy
+keeps exactly those (`models/multilayer._checkpointed`). They are what the
+two backward kernels read of the forward and what only a second run of the
+forward kernel could remake: one hidden-sized tensor and T floats a head.
+`flash_attention_with_lse` names nothing: under a ring that would keep
+every ring step's pair.
 """
 
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LSE_LANES = 128   # lane width for per-row statistics outputs (TPU tiling)
+
+# the checkpoint names of a forward kernel's output (in the layout its
+# backward reads) and of its rows' one-lane log-sum-exp
+RESIDUAL_NAMES = ("attention_out", "attention_lse")
+
+
+class _Named(threading.local):
+    calls = 0
+
+
+_named = _Named()
+
+
+def residuals_named() -> int:
+    """How many forward kernel calls this thread has traced so far with
+    `name_residuals`; a checkpointed layer reads it before and after its
+    own trace."""
+    return _named.calls
+
+
+def name_residuals(o, lse):
+    """`o` and `lse` under `RESIDUAL_NAMES`, for a forward rule whose
+    backward is the Pallas one. A no-op without a policy that keeps the
+    names; the primal output has to be made from the named `o`."""
+    _named.calls += 1
+    return (checkpoint_name(o, RESIDUAL_NAMES[0]),
+            checkpoint_name(lse, RESIDUAL_NAMES[1]))
 
 
 def _dense_attention(q, k, v, causal: bool, scale: float):
@@ -440,6 +479,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                          block_q=block_q, block_k=block_k,
                          interpret=interpret,
                          with_lse=(backward == "pallas"))
+    if lse is not None:
+        o3, lse = name_residuals(o3, lse)
     return _unfold3(o3, shape_q), (q3, k3, v3, o3, lse, shape_q, shape_k)
 
 

@@ -31,7 +31,10 @@ and a dK/dV kernel each K block's `nqb` Q blocks, scores recomputed a
 tile at a time, the whole GQA group's rows folded against one Hkv-wide
 KV tile so dK/dV sum over the group in the matmul itself. Nothing of
 size [T, T] exists in either direction. `banded_reference` stays as the
-oracle.
+oracle. The forward rule names the kernel's output and that log-sum-exp
+`attention_out` and `attention_lse` (`ops/attention.name_residuals`), so
+that a checkpointed layer keeps them and its recomputed forward does not
+run the kernel a second time.
 
 Dispatch is NOT decided here: `kernel_defaults.banded_policy` owns the
 banded-vs-dense verdict under the measured-winner discipline (env hatch
@@ -48,6 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops.attention import name_residuals
 
 _NEG_INF = -1e30
 _LSE_LANES = 128   # lane width of a per-row statistic (TPU tiling)
@@ -499,6 +504,7 @@ def _banded_fwd(q, k, v, window, causal, scale, block_q, block_k,
     o5, lse = _run_banded(q5, k3, v3, window=window, causal=causal, scale=s,
                           block_q=block_q, block_k=block_k,
                           interpret=interpret, with_lse=True)
+    o5, lse = name_residuals(o5, lse)
     return _unfold_q(o5, q.shape[0]), (q5, k3, v3, o5, lse)
 
 

@@ -331,3 +331,67 @@ def test_bias_and_relu_run_after_the_max_pool(chip):
     ratio = (new.cost_analysis()["bytes accessed"]
              / old.cost_analysis()["bytes accessed"])
     assert ratio <= 0.75, ratio
+
+
+# --- gradient checkpointing keeps an attention kernel's output and
+# log-sum-exp (`ops/attention.RESIDUAL_NAMES`): a window layer and a full
+# layer of `trinity_large_fit`'s dense block (8,192 tokens, 48 query heads
+# over 8 KV heads of 128, hidden 3072, window 4096), forward and backward
+# through `MultiLayerNetwork._forward` under `gradient_checkpointing`,
+# against the same net under the bare `jax.checkpoint`: each forward kernel
+# is in the program once, not twice, for one hidden-sized tensor a layer.
+TOKENS, HIDDEN = 8192, 3072
+
+
+def _two_attention_blocks(monkeypatch):
+    from deeplearning4j_tpu import InputType
+    from deeplearning4j_tpu.models import MultiLayerNetwork
+    from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.layers.attention import (
+        SandwichTransformerBlock,
+    )
+
+    # the policies ask which backend runs; the described chip is not it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def block(window):
+        return SandwichTransformerBlock(
+            num_heads=48, num_kv_heads=8, head_dim=128, qk_norm=True,
+            output_gate=True, causal=True, rope=window is not None,
+            window=window, max_cache=TOKENS, ffn_width=12288)
+
+    net = MultiLayerNetwork(
+        NeuralNetConfiguration.builder().dtype("bfloat16")
+        .gradient_checkpointing().list(block(4096), block(None))
+        .set_input_type(InputType.recurrent(HIDDEN, TOKENS)).build())
+
+    def loss(params, x):
+        y = net._forward(params, {}, x, train=True, rng=None)[0]
+        return jnp.sum(jnp.square(y.astype(F32)))
+
+    params = jax.tree_util.tree_map(
+        lambda leaf: (leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: net.init().params_tree))
+    return jax.grad(loss), [params, ((1, TOKENS, HIDDEN), BF16)]
+
+
+def _kernel_calls(compiled, kernel):
+    return len(re.findall(rf"custom-call\([^\n]*/{kernel}/pallas_call",
+                          compiled.as_text()))
+
+
+def test_checkpointed_attention_runs_its_forward_kernel_once(chip):
+    with pytest.MonkeyPatch.context() as mp:
+        new = _compile(chip, *_two_attention_blocks(mp))
+        mp.setattr(jax.checkpoint_policies, "save_only_these_names",
+                   lambda *names: None)
+        old = _compile(chip, *_two_attention_blocks(mp))
+    for kernel in ("banded_attention", "flash_attention"):
+        assert _kernel_calls(old, kernel + "_fwd") == 2     # the reader finds
+        assert _kernel_calls(new, kernel + "_fwd") == 1
+        for backward in ("_bwd_dq", "_bwd_dkdv"):
+            assert _kernel_calls(new, kernel + backward) == 1
+    grown = (new.memory_analysis().temp_size_in_bytes
+             - old.memory_analysis().temp_size_in_bytes)
+    # o [8192, 48 x 128] bf16 and lse [48, 8192] float32 a layer
+    assert grown <= 2 * 103e6, grown
