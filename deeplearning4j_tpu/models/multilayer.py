@@ -140,6 +140,16 @@ def record_residuals_kept(model, kept: int) -> None:
                          model=type(model).__name__).set(kept)
 
 
+def record_sparse_dense(model, dense: int) -> None:
+    """Gauge `sparse_layers_dense{model=<class>}`: how many layers with a
+    block selection the forward pass that `model` traced last ran as plain
+    causal attention because the sequence was no longer than their
+    `dense_len` (0 for the benchmark's `minicpm_sala` at 16,384 tokens,
+    and for a model with no such layer)."""
+    get_registry().gauge("sparse_layers_dense",
+                         model=type(model).__name__).set(dense)
+
+
 class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
     """Sequential network runtime over a MultiLayerConfiguration."""
 
@@ -232,6 +242,9 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         remat = train and self.conf.gradient_checkpointing
         tails = {}      # pool's index -> (convolution's name, its tail)
         kept = 0        # attention kernel calls whose residuals stay
+        from deeplearning4j_tpu.ops.sparse_attention import dense_runs
+
+        dense_before = dense_runs()
         for i, layer in enumerate(self.layers):
             # the layer's name on its device ops (and, as
             # `transpose(jvp(<name>))`, on its backward ops): debug
@@ -274,6 +287,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         if not collect:
             record_deferred_pairs(self, len(tails))
             record_residuals_kept(self, kept)
+            record_sparse_dense(self, dense_runs() - dense_before)
         return x, out_in, new_states, acts
 
     # ------------------------------------------------------------- loss

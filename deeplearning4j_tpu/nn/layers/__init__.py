@@ -32,7 +32,8 @@ from deeplearning4j_tpu.nn.layers.special import (
     FrozenLayer, CenterLossOutputLayer, VariationalAutoencoder, RBM,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
-    MultiHeadAttention, SandwichTransformerBlock,
+    LinearAttention, MultiHeadAttention, PreNormBlock,
+    SandwichTransformerBlock,
 )
 
 __all__ = [

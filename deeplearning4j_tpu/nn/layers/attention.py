@@ -53,6 +53,13 @@ def rms_norm(x, gain, eps: float = 1e-5):
     return x * jax.lax.rsqrt(ms + eps).astype(x.dtype) * gain
 
 
+# a step's selection counters, in a `sparse=` layer's state: the block
+# visits (token x KV group x block of keys) the selection kept, and those a
+# dense causal layer would make; `fit()` publishes them as gauges
+# `<name>{layer=}` where an epoch synchronises
+SPARSE_COUNTERS = ("sparse_blocks_kept", "sparse_blocks_causal")
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class MultiHeadAttention(Layer):
@@ -92,6 +99,12 @@ class MultiHeadAttention(Layer):
     # one gain vector each for all heads, before the positions go on
     output_gate: bool = False         # o * sigmoid(x Wg) before Wo
     bias: bool = True                 # Wo's bias
+    norm_eps: float = 1e-5            # of the q and k norms
+    sparse: Optional[Any] = None      # `ops.sparse_attention.BlockSelection`
+    # (or its values): past `dense_len` tokens every query and KV group
+    # reads `topk` blocks of keys chosen from scores over mean-pooled keys
+    # (InfLLM-V2), by the block-sparse kernels; at or under it the layer is
+    # plain causal attention. Training and scoring only: no decode.
 
     def infer_n_in(self, input_type: InputType):
         upd = {}
@@ -153,7 +166,23 @@ class MultiHeadAttention(Layer):
         if self.qk_norm:
             params["q_norm"] = jnp.ones((dh,), dtype)
             params["k_norm"] = jnp.ones((dh,), dtype)
-        return params, {}
+        if self._selection is None:
+            return params, {}
+        if not self.causal or self.window is not None or self.rope:
+            raise ValueError("block selection needs causal=True and goes "
+                             "with no window and no positions")
+        return params, {k: jnp.zeros((), jnp.int32)
+                        for k in SPARSE_COUNTERS}
+
+    @property
+    def _selection(self):
+        from deeplearning4j_tpu.ops.sparse_attention import BlockSelection
+
+        sel = self.sparse
+        if sel is None or isinstance(sel, BlockSelection):
+            return sel
+        return (BlockSelection(**sel) if isinstance(sel, dict)
+                else BlockSelection(*sel))
 
     def _qkv(self, params, x):
         """q [B, T, H, Dh], k and v [B, T, Hkv, Dh] (normed where
@@ -169,11 +198,18 @@ class MultiHeadAttention(Layer):
         k = split(params["Wk"], self._kv_heads)
         v = split(params["Wv"], self._kv_heads)
         if self.qk_norm:
-            q = rms_norm(q, params["q_norm"])
-            k = rms_norm(k, params["k_norm"])
+            q = rms_norm(q, params["q_norm"], self.norm_eps)
+            k = rms_norm(k, params["k_norm"], self.norm_eps)
         gate = (jax.nn.sigmoid(x @ params["Wg"]) if self.output_gate
                 else None)
         return q, k, v, gate
+
+    def _positioned(self, q, k):
+        """q and k with the rotary positions 0..T-1 on, where `rope`."""
+        if not self.rope:
+            return q, k
+        positions = jnp.arange(q.shape[1])
+        return rope_rotate(q, positions), rope_rotate(k, positions)
 
     def _project_out(self, params, o, gate):
         """[B, T, H, Dh] heads -> the layer's output, gated where the
@@ -223,6 +259,11 @@ class MultiHeadAttention(Layer):
         addresses the monolithic slot layout). `pages` defaults to
         `batch * max_cache / page_len` — the same memory as the
         monolithic layout."""
+        if self.sparse is not None:
+            raise NotImplementedError(
+                f"MultiHeadAttention {self.name!r} selects its key blocks "
+                f"(sparse=): decoding it needs the selection inside paged "
+                f"attention, which serving does not have yet")
         Dh = self._head_dim
         L = self.max_cache
         Hkv = self._kv_heads
@@ -578,13 +619,49 @@ class MultiHeadAttention(Layer):
         if state is not None and "cache_k" in state:
             return self._decode(params, x, state, mask=mask)
         q, k, v, gate = self._qkv(params, x)
-        if self.rope:
-            positions = jnp.arange(x.shape[1])
-            q = rope_rotate(q, positions)
-            k = rope_rotate(k, positions)
+        q, k = self._positioned(q, k)
+        sel = self._selection
+        if sel is not None:
+            o, counters = self._selected_core(q, k, v, sel, train=train,
+                                              rng=rng, mask=mask)
+            return (self._project_out(params, o, gate),
+                    {**(state or {}), **counters})
         with jax.named_scope("attention_core"):
             o = self._core(q, k, v, train=train, rng=rng, mask=mask)
         return self._project_out(params, o, gate), state
+
+    def _selected_core(self, q, k, v, sel, *, train, rng, mask):
+        """A layer with `sparse`: past `dense_len` tokens the selection
+        (scope `sparse_select`) and attention over the blocks it kept
+        (`sparse_attention_core`), else the dense causal core. Returns the
+        heads and the step's `SPARSE_COUNTERS`."""
+        from deeplearning4j_tpu.ops import sparse_attention as sp
+
+        B, T = q.shape[0], q.shape[1]
+        if T <= sel.dense_len:
+            sp.note_dense_run()
+            with jax.named_scope("attention_core"):
+                o = self._core(q, k, v, train=train, rng=rng, mask=mask)
+            causal = sp.causal_visits(B, self._kv_heads, T, sel.block_size)
+            return o, dict.fromkeys(SPARSE_COUNTERS,
+                                    jnp.asarray(causal, jnp.int32))
+        if mask is not None or (train and self.attn_dropout):
+            raise ValueError("block selection takes no padding mask and "
+                             "no attention dropout")
+        from deeplearning4j_tpu.ops.kernel_defaults import sparse_policy
+
+        with jax.named_scope("sparse_select"):
+            allow = sp.select_blocks(q, k, sel)
+            kept, causal = sp.selection_counts(allow, sel.block_size)
+        pol = sparse_policy(T, sel.block_size)
+        with jax.named_scope("sparse_attention_core"):
+            if pol.kind == "kernel":
+                o = sp.block_sparse_attention(
+                    q, k, v, allow, sel.block_size, None, pol.block_q,
+                    pol.block_k, False)
+            else:
+                o = sp.masked_attention(q, k, v, allow, sel.block_size)
+        return o, dict(zip(SPARSE_COUNTERS, (kept, causal)))
 
     def _core(self, q, k, v, *, train, rng, mask):
         """softmax(q k^T / sqrt(Dh)) v over [B, T, H, Dh] queries and
@@ -1044,3 +1121,132 @@ class SandwichTransformerBlock(Layer):
             y, counters = moe.apply(sub("moe_"), h, train=train, rng=rng)
             new_state.update(counters)
         return x + norm(y, 4), new_state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class LinearAttention(MultiHeadAttention):
+    """Decayed linear attention over [batch, time, features] (Lightning
+    Attention-2; the `lightning-attn` mixer of MiniCPM-SALA): `S_t =
+    lambda_h S_{t-1} + k_t^T v_t`, `o_t = q_t S_t / sqrt(Dh)`, no softmax.
+    The projections, the per-head q and k norms, the positions, the gate
+    and `Wo` are `MultiHeadAttention`'s; the core is
+    `ops/linear_attention.py`'s chunked scan under the scope
+    `linear_attention_core`. `lambda_h` is no parameter: head `h` of layer
+    `decay_layer` among `decay_layers` decays by
+    `exp(-2^(-8h/H) (1 - decay_layer / (decay_layers - 1) + 1e-5))`.
+    `output_norm`: an RMS norm with a gain (leaf `o_norm`) over the
+    concatenated heads before the gate. Training and scoring only."""
+
+    causal: bool = True
+    bias: bool = False
+    output_norm: bool = False
+    decay_layer: int = 0
+    decay_layers: int = 1
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        if not self.causal or self.window is not None or self.sparse:
+            raise ValueError("LinearAttention is causal and takes neither "
+                             "a window nor a block selection")
+        params, _ = super().init_params(key, input_type, dtype)
+        if self.output_norm:
+            params["o_norm"] = jnp.ones(
+                (self.num_heads * self._head_dim,), dtype)
+        return params, {}
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        raise NotImplementedError(
+            f"LinearAttention {self.name!r} has no decode carry yet: its "
+            f"[Dh, Dh] state a head wants snapshots in the session "
+            f"carries, which serving does not have")
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        if mask is not None:
+            raise ValueError("LinearAttention takes no padding mask")
+        from deeplearning4j_tpu.ops.linear_attention import (
+            decay_rates, linear_attention,
+        )
+
+        q, k, v, gate = self._qkv(params, x)
+        q, k = self._positioned(q, k)
+        with jax.named_scope("linear_attention_core"):
+            o = linear_attention(
+                q, k, v, decay_rates(self.num_heads, self.decay_layer,
+                                     self.decay_layers))
+        if self.output_norm:
+            o = rms_norm(o.reshape(o.shape[0], o.shape[1], -1),
+                         params["o_norm"], self.norm_eps).reshape(o.shape)
+        return self._project_out(params, o, gate), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class PreNormBlock(Layer):
+    """A pre-norm decoder block around any sequence mixer:
+    `h = x + r mixer(norm(x))`, then `h + r swiglu(norm(h))`, with
+    `r = residual_scale`, both norms RMS with a gain, a bias-free SwiGLU
+    of `ffn_width`. The mixer is a layer of its own (`MultiHeadAttention`
+    with whatever options, `LinearAttention`), given whole: the block
+    hands none of its options through. Leaves: `ln1_g`, `ln2_g`,
+    `mixer_*`, `ffn_w1`, `ffn_w3`, `ffn_w2`; the mixer's state is the
+    block's."""
+
+    CONSUMES = "rnn"   # [B, T, d] sequence activations
+
+    n_in: Optional[int] = None
+    mixer: Optional[Any] = None
+    ffn_width: Optional[int] = None      # None -> 4 x n_in
+    residual_scale: float = 1.0
+    eps: float = 1e-5
+
+    def infer_n_in(self, input_type: InputType):
+        if self.n_in is None:
+            return dataclasses.replace(self, n_in=input_type.size)
+        return self
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def _mixer(self):
+        if self.mixer is None:
+            raise ValueError("PreNormBlock needs a mixer layer")
+        d = self.n_in
+        return dataclasses.replace(
+            self.mixer, n_in=d, n_out=d, activation="identity",
+            weight_init=self.mixer.weight_init or self.weight_init,
+            name=self.mixer.name or f"{self.name}.mixer")
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d = self.n_in
+        ks = jax.random.split(key, 4)
+        params = {"ln1_g": jnp.ones((d,), dtype),
+                  "ln2_g": jnp.ones((d,), dtype)}
+        mp, state = self._mixer().init_params(ks[0], input_type, dtype)
+        params.update({f"mixer_{k}": v for k, v in mp.items()})
+        h = self.ffn_width or 4 * d
+        winit = self._winit()
+        params.update(ffn_w1=winit(ks[1], (d, h), dtype),
+                      ffn_w3=winit(ks[2], (d, h), dtype),
+                      ffn_w2=winit(ks[3], (h, d), dtype))
+        return params, state
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        return {"mixer": self._mixer().decode_carry(batch, dtype, **kw)}
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        mixer = self._mixer()
+        mp = {k[6:]: v for k, v in params.items() if k.startswith("mixer_")}
+        carry = state.get("mixer") if state else None
+        a, m_st = mixer.apply(
+            mp, rms_norm(x, params["ln1_g"], self.eps),
+            state=state if carry is None else carry, train=train, rng=rng,
+            mask=mask)
+        x = x + self.residual_scale * a
+        h = rms_norm(x, params["ln2_g"], self.eps)
+        with jax.named_scope("ffn"):
+            y = (jax.nn.silu(h @ params["ffn_w1"])
+                 * (h @ params["ffn_w3"])) @ params["ffn_w2"]
+        return (x + self.residual_scale * y,
+                (m_st or {}) if carry is None else {"mixer": m_st})

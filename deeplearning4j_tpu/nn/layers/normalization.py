@@ -199,6 +199,7 @@ class RMSNormalization(Layer):
 
     n_out: Optional[int] = None
     eps: float = 1e-5
+    scale: Optional[float] = None   # the normed rows times this
 
     def infer_n_in(self, input_type: InputType) -> "RMSNormalization":
         if self.n_out is None:
@@ -212,4 +213,7 @@ class RMSNormalization(Layer):
     def apply(self, params, x, *, state=None, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.nn.layers.attention import rms_norm
 
-        return self._act(rms_norm(x, params["gamma"], self.eps)), state
+        y = rms_norm(x, params["gamma"], self.eps)
+        if self.scale is not None:
+            y = y * jnp.asarray(self.scale, y.dtype)
+        return self._act(y), state

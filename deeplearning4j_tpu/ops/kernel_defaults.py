@@ -354,6 +354,46 @@ def banded_policy(t: int, h: int, hkv: int,
     return dense("no measured rows; conservative default")
 
 
+class SparsePolicy(NamedTuple):
+    kind: str            # "kernel" | "masked"
+    block_q: int
+    block_k: int
+    reason: str
+
+
+def sparse_policy(t: int, block_size: int) -> SparsePolicy:
+    """Kernels-vs-masked for attention over selected key blocks
+    (`ops/sparse_attention.py`). A layer comes here only past its
+    `dense_len`, where the masked path's [T, T] scores are a memory
+    hazard by construction, so there is nothing to measure against: the
+    kernels serve every shape they tile on a TPU, the dense masked
+    softmax the rest (small shapes off the chip, the tests').
+    `DL4J_TPU_ATTN=dense` forces the masked path as it forces the others.
+
+    Tiles: Q 256 x K 512 (8 blocks of 64 keys). What the chip showed
+    (PR 35, `minicpm_sala_fit`: 32 query heads over 2 KV heads of 128 at
+    16,384 tokens, bf16) is in PERF.md section 5."""
+    import jax
+
+    from deeplearning4j_tpu.ops.sparse_attention import sparse_eligible
+
+    bq, bk = min(256, t), min(512, t)
+
+    def verdict(kind, reason):
+        record_dispatch("sparse_attention", kind)
+        return SparsePolicy(kind, bq, bk, reason)
+
+    if _env("DL4J_TPU_ATTN") == "dense":
+        return verdict("masked", "forced by DL4J_TPU_ATTN=dense")
+    if jax.default_backend() != "tpu":
+        return verdict("masked", "no TPU: the dense masked softmax")
+    if not sparse_eligible(t, block_size, bq, bk):
+        return verdict("masked", f"shape ineligible (t={t}, blocks of "
+                                 f"{block_size})")
+    return verdict("kernel", "past dense_len the masked path's scores "
+                             "cannot exist")
+
+
 class DecodePolicy(NamedTuple):
     kind: str            # "banded" | "dense"
     block_l: int

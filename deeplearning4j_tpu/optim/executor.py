@@ -127,15 +127,20 @@ def batch_signature(ds):
 
 
 def _publish_routing_counters(net) -> None:
-    """The last step's routing counters of every expert layer
-    (`parallel/moe.ExpertFeedForward`: the `moe_*` scalars of its state)
-    out of the net's layer state into the gauges `<counter>{layer=}`.
-    Called where the epoch has just synchronised with the device; a net
-    without such a layer pays a walk over its state's keys."""
-    counters = {
-        name: {k: v for k, v in st.items() if k.startswith("moe_")}
-        for name, st in (getattr(net, "state_tree", None) or {}).items()
-        if isinstance(st, dict) and "moe_pairs_held" in st}
+    """The last step's counters of every layer that keeps some in its
+    state (`parallel/moe.ExpertFeedForward`'s routing: the `moe_*`
+    scalars; a `MultiHeadAttention` with a block selection: the
+    `sparse_blocks_*` scalars) out of the net's layer state into the
+    gauges `<counter>{layer=}`. Called where the epoch has just
+    synchronised with the device; a net without such a layer pays a walk
+    over its state's keys."""
+    counters = {}
+    for name, st in (getattr(net, "state_tree", None) or {}).items():
+        if isinstance(st, dict):
+            own = {k: v for k, v in st.items()
+                   if k.startswith(("moe_", "sparse_blocks_"))}
+            if own:
+                counters[name] = own
     if counters:
         import jax
 

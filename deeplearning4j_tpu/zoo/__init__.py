@@ -21,7 +21,8 @@ from deeplearning4j_tpu.zoo.inception import (
     GoogLeNet, InceptionResNetV1, FaceNetNN4Small2,
 )
 from deeplearning4j_tpu.zoo.transformer import (
-    SparseSandwichTransformer, TextGenerationTransformer,
+    HybridLinearSparseTransformer, SparseSandwichTransformer,
+    TextGenerationTransformer,
 )
 from deeplearning4j_tpu.zoo.pretrained import (
     PRETRAINED_CATALOG, PretrainedType, fetch_pretrained, load_pretrained,
@@ -35,5 +36,5 @@ __all__ = [
     "ZooModel", "ZOO_REGISTRY", "LeNet", "AlexNet", "SimpleCNN", "VGG16",
     "VGG19", "TextGenerationLSTM", "ResNet50", "GoogLeNet",
     "InceptionResNetV1", "FaceNetNN4Small2", "TextGenerationTransformer",
-    "SparseSandwichTransformer",
+    "SparseSandwichTransformer", "HybridLinearSparseTransformer",
 ]

@@ -14,7 +14,8 @@ from __future__ import annotations
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.attention import (
-    PositionEmbeddingLayer, SandwichTransformerBlock, TransformerEncoderBlock,
+    LinearAttention, MultiHeadAttention, PositionEmbeddingLayer,
+    PreNormBlock, SandwichTransformerBlock, TransformerEncoderBlock,
 )
 from deeplearning4j_tpu.nn.layers.feedforward import EmbeddingSequenceLayer
 from deeplearning4j_tpu.nn.layers.normalization import RMSNormalization
@@ -208,6 +209,110 @@ class SparseSandwichTransformer(ZooModel):
                 scale=d ** 0.5 if c.get("mup_enabled") else None),
             *blocks,
             RMSNormalization(eps=c["rms_norm_eps"]),
+            RnnOutputLayer(n_out=self.num_classes, has_bias=False,
+                           activation="softmax", loss="sparse_mcxent"))
+            .set_input_type(InputType.recurrent(1, t))
+            .build())
+
+
+@register_zoo
+class HybridLinearSparseTransformer(ZooModel):
+    """A causal language model of the `minicpm_sala` family (MiniCPM-SALA),
+    built from the keys its published `config.json` has: pre-norm blocks
+    (`PreNormBlock`) whose halves enter the residual stream times
+    `scale_depth / sqrt(mup_denominator)`, with a SwiGLU of
+    `intermediate_size` and, by `mixer_types`, one of two mixers.
+    "lightning-attn": decayed linear attention (`LinearAttention`:
+    `lightning_nh` heads of `lightning_head_dim`, q and k normed per head,
+    rotary positions where `lightning_use_rope`, an output norm and gate).
+    "minicpm4": gated GQA softmax attention with no positions
+    (`attn_use_rope` false) that, past `sparse_config.dense_len` tokens,
+    reads `topk` blocks of keys a query and KV group (InfLLM-V2;
+    `sparse_config` as MiniCPM4 publishes it, whose sizes are
+    `ops.sparse_attention.BlockSelection`'s defaults where the config has
+    none). Embedding rows are scaled by `scale_emb`;
+    the head is its own matrix behind a last RMS norm whose rows are
+    divided by `hidden_size / dim_model_base`.
+
+    The first pipeline stage's share of a deployment is built with
+    `layers_published`: `mixer_types` then lists this stage's layers
+    only, and a linear layer's decay follows its index in the whole
+    model. `vocabulary_held`: the rows of the embedding and the
+    head that live here. Token ids come as `[batch, time]` integers,
+    labels as integers (`sparse_mcxent`)."""
+
+    input_shape = (16384,)
+
+    def __init__(self, config: dict, *, timesteps: int = None,
+                 vocabulary_held: int = None, layers_published: int = None,
+                 dtype: str = "float32", gradient_checkpointing=False, **kw):
+        super().__init__(
+            num_classes=vocabulary_held or config["vocab_size"],
+            input_shape=(timesteps or self.input_shape[0],), **kw)
+        kinds = list(config["mixer_types"])
+        if len(kinds) != config["num_hidden_layers"]:
+            raise ValueError(
+                f"mixer_types has {len(kinds)} entries for "
+                f"{config['num_hidden_layers']} layers")
+        unknown = set(kinds) - {"minicpm4", "lightning-attn"}
+        if unknown:
+            raise ValueError(f"mixer_types {sorted(unknown)} not known")
+        if config.get("rope_theta", 10000) != 10000:
+            raise ValueError("rope_theta other than 10000 is not wired")
+        self.config = dict(config)
+        self.layers_published = layers_published or len(kinds)
+        self.dtype = dtype
+        self.gradient_checkpointing = gradient_checkpointing
+
+    def _mixer(self, kind: str, index: int):
+        from deeplearning4j_tpu.ops.sparse_attention import BlockSelection
+
+        c, t = self.config, self.input_shape[0]
+        eps = c["rms_norm_eps"]
+        if kind == "lightning-attn":
+            return LinearAttention(
+                num_heads=c["lightning_nh"], num_kv_heads=c["lightning_nkv"],
+                head_dim=c["lightning_head_dim"], qk_norm=c["qk_norm"],
+                norm_eps=eps, rope=c["lightning_use_rope"],
+                output_gate=c["use_output_gate"],
+                output_norm=c["use_output_norm"],
+                decay_layer=index,
+                decay_layers=self.layers_published)
+        sizes = {k: v for k, v in c.get("sparse_config", {}).items()
+                 if k in BlockSelection._fields}
+        return MultiHeadAttention(
+            num_heads=c["num_attention_heads"],
+            num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+            qk_norm=c["qk_norm"], norm_eps=eps, rope=c["attn_use_rope"],
+            output_gate=c["attn_use_output_gate"],
+            bias=c.get("attention_bias", False), causal=True, max_cache=t,
+            sparse=BlockSelection(**sizes))
+
+    def conf(self):
+        c, t = self.config, self.input_shape[0]
+        d = c["hidden_size"]
+        blocks = [
+            PreNormBlock(mixer=self._mixer(kind, i),
+                         ffn_width=c["intermediate_size"],
+                         residual_scale=(c["scale_depth"]
+                                         / c["mup_denominator"] ** 0.5),
+                         eps=c["rms_norm_eps"])
+            for i, kind in enumerate(c["mixer_types"])]
+        builder = (NeuralNetConfiguration.builder()
+                   .seed(self.seed)
+                   .updater(self.kw.get("updater", Adam(3e-4)))
+                   .activation("identity")
+                   .weight_init("xavier")
+                   .dtype(self.dtype))
+        if self.gradient_checkpointing:
+            builder = builder.gradient_checkpointing()
+        return (builder.list(
+            EmbeddingSequenceLayer(n_in=self.num_classes, n_out=d,
+                                   activation="identity",
+                                   scale=c["scale_emb"]),
+            *blocks,
+            RMSNormalization(eps=c["rms_norm_eps"],
+                             scale=c["dim_model_base"] / d),
             RnnOutputLayer(n_out=self.num_classes, has_bias=False,
                            activation="softmax", loss="sparse_mcxent"))
             .set_input_type(InputType.recurrent(1, t))

@@ -28,6 +28,7 @@ import pytest  # noqa: E402
 lstm = importlib.import_module("deeplearning4j_tpu.ops.lstm")
 flash = importlib.import_module("deeplearning4j_tpu.ops.attention")
 banded = importlib.import_module("deeplearning4j_tpu.ops.banded_attention")
+sparse = importlib.import_module("deeplearning4j_tpu.ops.sparse_attention")
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -125,7 +126,27 @@ def _decode(paged, cache_dtype, slots=8, cache=1024, page=128, heads=8,
     return fwd, shapes
 
 
+def _sparse(train, T=16384, heads=32, kv_heads=2, d=128):
+    # `minicpm_sala`'s selecting layer at the cell's size: selection and
+    # the three block-sparse kernels, Q tiles of 256 over K tiles of 512
+    sel = sparse.BlockSelection()
+
+    def fwd(q, k, v):
+        allow = sparse.select_blocks(q, k, sel)
+        return sparse.block_sparse_attention(q, k, v, allow, sel.block_size,
+                                             None, 256, 512, False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(F32).sum()
+
+    return (jax.grad(loss, argnums=(0, 1, 2)) if train else fwd), [
+        ((1, T, heads, d), BF16), ((1, T, kv_heads, d), BF16),
+        ((1, T, kv_heads, d), BF16)]
+
+
 CASES = {
+    "sparse_fwd_32_2": lambda: _sparse(train=False),
+    "sparse_train_32_2": lambda: _sparse(train=True),
     "lstm_fwd_f32": lambda: _lstm(F32, train=False),
     "lstm_fwd_bf16": lambda: _lstm(BF16, train=False),
     "lstm_train_f32": lambda: _lstm(F32, train=True),
@@ -184,6 +205,9 @@ KERNEL_NAMES = {
     "banded_attention_bwd_dkdv": "banded_train_gqa",
     "banded_decode_attention": "slot_decode_bf16",
     "paged_decode_attention": "paged_decode_bf16",
+    "sparse_attention_fwd": "sparse_fwd_32_2",
+    "sparse_attention_bwd_dq": "sparse_train_32_2",
+    "sparse_attention_bwd_dkdv": "sparse_train_32_2",
     "matmul_channel_stats": _matmul_stats,
     "conv3x3_channel_stats": _conv3_stats,
 }
@@ -395,3 +419,50 @@ def test_checkpointed_attention_runs_its_forward_kernel_once(chip):
              - old.memory_analysis().temp_size_in_bytes)
     # o [8192, 48 x 128] bf16 and lse [48, 8192] float32 a layer
     assert grown <= 2 * 103e6, grown
+
+
+# --- the benchmark's `minicpm_sala` step at the cell's own size (one
+# sequence of 16,384 ids, bf16, every layer checkpointed), built by the
+# benchmark's own model file: it fits the chip with room, each block-sparse
+# kernel is in it once (the forward's output and log-sum-exp are kept, not
+# remade), and nothing [heads, T, T] exists. The MLP's width is 16,384 too,
+# so a [16384, 16384] tensor of rank 2 is the MLP's and says nothing.
+def _minicpm_step(monkeypatch):
+    import json
+
+    from benchmarks import harness
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(harness.BENCH_DIR, "configs", "minicpm_sala.json"),
+              encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    net = harness.load_module("models", "minicpm_sala.py").build(cfg, 0)
+    shapes = jax.eval_shape(
+        lambda: (net.init().params_tree, net.updater_state, net.state_tree))
+    t = cfg["input_shape"][0]
+    spec = lambda tree: jax.tree_util.tree_map(
+        lambda leaf: (leaf.shape, leaf.dtype), tree)
+    return (net.make_step_fn(), [
+        *map(spec, shapes), ((), I32), ((1, t), I32), ((1, t), I32), None,
+        None, ((2,), jnp.uint32)], t)
+
+
+def test_minicpm_sala_step_fits_the_chip_with_nothing_t_by_t(chip):
+    with pytest.MonkeyPatch.context() as mp:
+        step, shapes, t = _minicpm_step(mp)
+        args = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(*s, sharding=chip), shapes,
+            is_leaf=lambda s: isinstance(s, tuple))
+        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            *args).compile()
+    memory = compiled.memory_analysis()
+    # parameters, moments and layer state are donated: all but the batch
+    assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
+            < 2 ** 20)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    text = compiled.as_text()
+    for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
+        assert _kernel_calls(compiled, "sparse_attention" + kernel) == 1
+    square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", text)
+    assert not square, sorted(set(square))[:5]
