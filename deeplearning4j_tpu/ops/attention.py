@@ -11,7 +11,23 @@ online-softmax statistics (running max m, normalizer l, accumulator) carried
 in VMEM scratch across the innermost K grid dimension, so neither the
 [T, T] score matrix nor the full K/V sequence ever sits in VMEM/HBM at
 once. Causal masking skips dead K blocks' FLOPs via block-index
-comparison.
+comparison, and their fetches by naming the last live block again.
+
+The online-softmax step is written once, here, for the three forward
+kernels (`_softmax_update`: this file's, `ops/banded_attention.py`'s and
+`ops/sparse_attention.py`'s). m and l are `[rows, 128]` float32, every lane
+of a row holding the row's value as the log-sum-exp output does, so that
+`s - m` and `acc * alpha` meet whole registers: kept `[rows, 1]` they took
+a register for eight values, were broadcast across lanes at every use, and
+cost the forward more than its two matrix products did. l's lanes gather
+the 128-lane groups of the weights and are summed across lanes once, at
+the sweep's end. Each forward picks its own tile from the policy's blocks
+(`_fwd_tile`: as many rows as a 3 MiB score tile allows over 512 keys,
+where a band's or a causal edge does not make that dearer). On a v5e, bf16,
+one call alone (PR 36, PERF.md section 6): this kernel over 48 query heads
+of 128 on 8 KV heads at 8,192 tokens 13.7 to 6.7 ms (tile 1,024 x 512),
+the banded one at a window of 4,096 14.7 to 5.3 (1,536 x 512), the sparse
+one at 32 heads and 16,384 tokens 50.0 to 26.7 (1,024 x 512).
 
 Backward (FlashAttention-2 style, `backward="pallas"`): the
 forward rule additionally saves the per-row log-sum-exp L = m + log(l)
@@ -95,6 +111,96 @@ def _prec(dtype):
             else jax.lax.Precision.DEFAULT)
 
 
+def _lanes(x, n: int):
+    """A `[rows, 128]` statistic, every lane of a row holding the row's
+    value, as `[rows, n]`."""
+    if n == _LSE_LANES:
+        return x
+    if n < _LSE_LANES:
+        return x[:, :n]
+    whole, rest = divmod(n, _LSE_LANES)
+    return jnp.concatenate([x] * whole + ([x[:, :rest]] if rest else []),
+                           axis=1)
+
+
+def _lane_sums(keys: int) -> bool:
+    """Whether a sweep of `keys` keys leaves the normalizer's lanes as
+    partial sums (whole 128-lane groups) or each as the row's sum."""
+    return keys % _LSE_LANES == 0
+
+
+def _softmax_scratch(rows: int, d: int) -> list:
+    """The online softmax's state for a tile of `rows` rows: accumulator,
+    running maximum, normalizer (`_softmax_init` and on)."""
+    return [pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, _LSE_LANES), jnp.float32),
+            pltpu.VMEM((rows, _LSE_LANES), jnp.float32)]
+
+
+def _softmax_init(acc_scr, m_scr, l_scr):
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+
+
+def _softmax_update(s, mask, v, acc_scr, m_scr, l_scr, prec):
+    """One step of the online softmax, the one all three forward kernels
+    make (`_flash_kernel`, `banded_attention._banded_kernel`,
+    `sparse_attention._fwd_kernel`): the scaled float32 scores `s`
+    [rows, keys] of one sweep of keys and their values `v` [keys, D] go
+    into the running maximum `m_scr`, normalizer `l_scr` (both
+    [rows, 128] float32) and accumulator `acc_scr` [rows, D].
+
+    `mask` [rows, keys] says which pairs the softmax has; None means all
+    of them, or that the caller has put -1e30 on the others and no row is
+    masked whole before its first live key. With a mask the weights are
+    zeroed outright and not only biased: a row none of whose keys has
+    come yet has m == -1e30, where exp(s - m) is exp(0) = 1 for every
+    masked entry (a banded grid's first block can be dead for a live row,
+    and a sparse row may list nothing in a visited tile).
+
+    The statistics are a lane wide, every lane of a row holding the row's
+    maximum, so `s - m` and `acc * alpha` meet whole registers and nothing
+    is broadcast across lanes at a use. Where the sweep is whole 128-lane
+    groups of keys the normalizer's lanes hold partial sums: the groups of
+    `p` are added lane for lane and `_softmax_finish` makes the one sum
+    across lanes, which leaves the row maximum as the step's one
+    cross-lane reduction. Any other width (interpret mode's odd blocks)
+    sums across lanes here and keeps the whole sum in every lane."""
+    keys, d = v.shape
+    if mask is not None:
+        s = jnp.where(mask, s, _NEG_INF)
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - _lanes(m_new, keys))
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    if _lane_sums(keys):
+        l_new = sum(p[:, c:c + _LSE_LANES]
+                    for c in range(0, keys, _LSE_LANES))
+    else:
+        l_new = jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[:] = m_new
+    l_scr[:] = l_scr[:] * alpha + l_new
+    acc_scr[:] = acc_scr[:] * _lanes(alpha, d) + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32,
+        precision=prec)
+
+
+def _softmax_finish(acc_scr, m_scr, l_scr, keys: int):
+    """The sweep's end: (o [rows, D] float32, lse [rows, 128] float32 with
+    the row's log-sum-exp in every lane, the narrowest layout Mosaic
+    takes for a row statistic: cf. MIN_BLOCK_SIZE in jax's in-tree TPU
+    flash kernel, which keeps its running statistics so too). `keys` is
+    the width of the sweeps `_softmax_update` saw."""
+    l = l_scr[:]
+    l = (jnp.sum(l, axis=-1, keepdims=True) if _lane_sums(keys)
+         else l[:, :1])
+    l = jnp.maximum(l, 1e-30)
+    return acc_scr[:] / l, m_scr[:] + jnp.log(l)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
                   scale: float, with_lse: bool):
     """Grid = (batch·heads, q blocks, K blocks): the K/V HBM→VMEM transfer
@@ -116,18 +222,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
 
     @pl.when(kb == 0)
     def _():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        _softmax_init(acc_scr, m_scr, l_scr)
 
-    # Causal: K blocks strictly above this Q block's last row are dead —
-    # skip their FLOPs (the DMA still happens; acceptable at Bk=128).
+    # Causal: K blocks strictly above this Q block's last row are dead:
+    # their FLOPs are skipped, and the index map fetches nothing new.
     relevant = (kb * block_k <= (qb + 1) * bq - 1) if causal else (kb >= 0)
 
     @pl.when(relevant)
     def _():
         k = k_ref[0]                              # [Bk, D]
-        v = v_ref[0]
         prec = _prec(q.dtype)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
                     precision=prec) * scale
@@ -136,27 +239,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
                      + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0))
             k_ids = (kb * block_k
                      + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1))
+            # key 0 is live for every row, so the bias alone will do
             s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-            precision=prec)
+        _softmax_update(s, None, v_ref[0], acc_scr, m_scr, l_scr, prec)
 
     @pl.when(kb == nk - 1)
     def _():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        o, lse = _softmax_finish(acc_scr, m_scr, l_scr, block_k)
+        o_ref[0] = o.astype(o_ref.dtype)
         if with_lse:
-            # Per-row scalar broadcast across a 128-lane last dim — the
-            # narrowest output layout Mosaic accepts for row statistics
-            # (cf. MIN_BLOCK_SIZE in jax's in-tree TPU flash kernel).
-            lse_ref[0] = jnp.broadcast_to(m_scr[:] + jnp.log(l),
-                                          (bq, _LSE_LANES))
+            lse_ref[0] = lse
 
 
 def _fit_block(block: int, t: int) -> int:
@@ -182,6 +274,97 @@ def _group(q, k) -> int:
     return q.shape[0] // k.shape[0]
 
 
+_FWD_ROWS = 2048            # rows of a forward tile at most, a folded group's too
+_FWD_KEYS = 512             # keys one update of the statistics covers at most
+_FWD_TILE_BYTES = 3 << 20   # a float32 score tile at most: 1,536 x 512
+# What a forward tile costs beside its pairs, in pairs (the kernels alone
+# on a v5e over tiles of 768 and 1,536 rows by 256 and 512 keys, PR 36:
+# PERF.md section 6): an update of a row's statistics as much as
+# `_UPDATE_KEYS` more keys of the row, a grid step as `_STEP_PAIRS` pairs.
+_UPDATE_KEYS = 80
+_STEP_PAIRS = 170_000
+
+
+def _fwd_tile(op: str, block_q: int, block_k: int, *, fold: int = 1,
+              interpret: bool = False, legal, tiles):
+    """(block_q, block_k) of a forward kernel, from the blocks the policy
+    passed (fitted to the sequence already), the `fold` query heads a
+    tile's rows hold of each token, and what the family says of a tile:
+    `legal(block_q, block_k)` (whole tiles, its own conditions) and
+    `tiles(block_q, block_k)`, how many tiles a head's grid computes.
+
+    The candidates are the passed blocks doubled any number of times, the
+    K block (the sweep of keys one update of the softmax statistics
+    covers) to `_FWD_KEYS` at most, the float32 score tile to
+    `_FWD_TILE_BYTES`; a Q block whose tile alone passes that is halved
+    first, down to 128 rows a head, as `banded_attention._bwd_block_q`
+    does for the backward. Of them the cheapest is taken, a tile costing
+    its pairs, `_UPDATE_KEYS` keys a row for the update and
+    `_STEP_PAIRS` for the grid step: so tiles grow until what they
+    compute past a band's or a causal edge outweighs the steps and
+    updates they save, and a narrow band keeps narrow tiles. In interpret
+    mode there is no step to save and the passed blocks are the tile
+    (any divisor of the sequence: the tests' odd shapes). Sets the gauge
+    `attention_fwd_tile{op=, field=rows|keys_per_update}` (trace time,
+    host side)."""
+    fits = lambda bq, bk: (fold * bq <= max(_FWD_ROWS, fold * block_q)
+                           and fold * bq * bk * 4 <= _FWD_TILE_BYTES)
+    while not fits(block_q, block_k) and block_q > 128 and not block_q % 2:
+        block_q //= 2
+
+    def cost(bq, bk):
+        return tiles(bq, bk) * (fold * bq * (bk + _UPDATE_KEYS)
+                                + _STEP_PAIRS)
+
+    def doubled(block, ok):
+        out = [block]
+        while ok(2 * out[-1]):
+            out.append(2 * out[-1])
+        return out
+
+    best = (block_q, block_k) if interpret else min(
+        ((bq, bk)
+         for bq in doubled(block_q, lambda bq: fits(bq, block_k)
+                           and legal(bq, block_k))
+         for bk in doubled(block_k, lambda bk: bk <= _FWD_KEYS
+                           and fits(bq, bk) and legal(bq, bk))),
+        key=lambda tile: cost(*tile))
+    from deeplearning4j_tpu.observe import get_registry
+
+    gauge = functools.partial(get_registry().gauge, "attention_fwd_tile",
+                              op=op)
+    gauge(field="rows").set(fold * best[0])
+    gauge(field="keys_per_update").set(best[1])
+    return best
+
+
+def _last_live(i, block_q: int, block_k: int):
+    """The last K block in which a causal Q block `i` has a key."""
+    return ((i + 1) * block_q - 1) // block_k
+
+
+def _causal_tiles(t: int, block_q: int, block_k: int) -> int:
+    """Tiles of the causal triangle over `t` tokens, the diagonal's whole."""
+    return sum(_last_live(i, block_q, block_k) + 1
+               for i in range(t // block_q))
+
+
+def _fwd_params(rows: int, keys: int, d: int, itemsize: int):
+    """Compiler parameters of a forward kernel with a [rows, keys] tile:
+    the grid's first two dimensions carry no state (Mosaic may run them
+    in any order and pipeline them), the K sweep carries the scratch. A
+    wide tile's float32 temporaries (scores, weights, the mask's iotas)
+    outgrow the default scoped VMEM of 16 MiB, of the chip's 128: the
+    limit is raised to what the tile needs."""
+    need = (8 * rows * keys * 4                 # the tile's temporaries
+            + 2 * 2 * (rows + 2 * keys) * d * itemsize   # q, k, v twice
+            + 3 * rows * max(d, _LSE_LANES) * 4)         # the scratch
+    limit = None if need <= (12 << 20) else min(need + (8 << 20), 100 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=limit)
+
+
 def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
                block_k: int, interpret: bool, with_lse: bool = False):
     bh, tq, d = q.shape
@@ -191,10 +374,23 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
             f"causal attention requires Tq == Tk (got {tq} vs {tk}); "
             "cross-attention is non-causal")
     g = _group(q, k)
-    block_q = _fit_block(block_q, tq)
-    block_k = _fit_block(block_k, tk)
+
+    def tiles(bq, bk):
+        return (_causal_tiles(tq, bq, bk) if causal
+                else (tq // bq) * (tk // bk))
+
+    block_q, block_k = _fwd_tile(
+        "flash_attention", _fit_block(block_q, tq), _fit_block(block_k, tk),
+        interpret=interpret, tiles=tiles,
+        legal=lambda bq, bk: tq % bq == 0 and tk % bk == 0)
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                with_lse=with_lse)
+    if causal:
+        # a dead step names the block before it again: nothing is fetched
+        kv_index = lambda b, i, j: (
+            b // g, jnp.minimum(j, _last_live(i, block_q, block_k)), 0)
+    else:
+        kv_index = lambda b, i, j: (b // g, j, 0)
     out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, tq, d), q.dtype)]
     if with_lse:
@@ -209,20 +405,13 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             # GQA: query head b reads KV head b // g, never a copy of it
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, d), kv_index),
         ],
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=tuple(out_shape) if with_lse else out_shape[0],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        # batch/Q-block dims have no cross-step state -> Mosaic may
-        # parallelize and pipeline them; the K sweep carries scratch.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=_softmax_scratch(block_q, d),
+        compiler_params=_fwd_params(block_q, block_k, d, q.dtype.itemsize),
         interpret=interpret,
     )(q, k, v)
     if with_lse:

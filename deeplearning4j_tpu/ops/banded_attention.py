@@ -22,6 +22,16 @@ kernel family:
   scalar-prefetched per-slot positions, so one compiled program serves
   every session position — the zero-recompile decode contract holds.
 
+The forward's online-softmax step is `ops/attention._softmax_update`, the
+one the flash and block-sparse forwards make (statistics a lane wide, the
+normalizer summed across lanes once a Q tile), and its tile its own
+(`ops/attention._fwd_tile`): at 48 query heads over 8 KV heads of 128,
+8,192 tokens and a window of 4,096 the six-wide group's 256 tokens
+(1,536 rows) against 512 keys, 5.3 ms a call on a v5e where Q 256 x K 256
+with `[rows, 1]` statistics took 14.7 (PR 36, PERF.md section 6); its
+grid's K extent is the most blocks a Q block really meets. The decode
+kernels keep their own single-query loops.
+
 Both kernels run under `interpret=True` on CPU (the parity suite in
 tests/test_banded_attention.py pins them against the layer's dense
 band-masked oracle). Backward: blockwise over the band's tiles only, as
@@ -52,10 +62,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.ops.attention import name_residuals
-
-_NEG_INF = -1e30
-_LSE_LANES = 128   # lane width of a per-row statistic (TPU tiling)
+from deeplearning4j_tpu.ops.attention import (
+    _LSE_LANES, _NEG_INF, _fwd_params, _fwd_tile, _prec, _softmax_finish,
+    _softmax_init, _softmax_scratch, _softmax_update, name_residuals,
+)
 
 
 # --------------------------------------------------------------- reference
@@ -126,11 +136,12 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, *rest, nk: int, window: int,
                    causal: bool, scale: float, with_lse: bool):
     """Grid = (batch·Hkv, Q blocks, band K blocks). Per Q block only the
     `nkb` K blocks the band can touch are visited; the online-softmax
-    state rides VMEM scratch across that innermost sweep exactly as in
-    `ops/attention._flash_kernel`. The query tile is the whole GQA group
-    ([G, Bq, Dh] folded to G·Bq rows) against one Hkv-wide KV tile. With
-    `with_lse` the per-row log-sum-exp is emitted too, the residual the
-    blockwise backward recomputes score tiles from."""
+    state rides VMEM scratch across that innermost sweep and is updated
+    once a K block by `ops/attention._softmax_update`, as in
+    `_flash_kernel`. The query tile is the whole GQA group ([G, Bq, Dh]
+    folded to G·Bq rows) against one Hkv-wide KV tile. With `with_lse`
+    the per-row log-sum-exp is emitted too, the residual the blockwise
+    backward recomputes score tiles from."""
     if with_lse:
         lse_ref, acc_scr, m_scr, l_scr = rest
     else:
@@ -146,52 +157,27 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, *rest, nk: int, window: int,
 
     @pl.when(j == 0)
     def _():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        _softmax_init(acc_scr, m_scr, l_scr)
 
     # A clamped band (first/last rows of the sequence) can hand this step
     # a K block fully outside the visible interval — skip its FLOPs.
-    lo = i * bq - window + 1
-    hi = (i + 1) * bq - 1 + (0 if causal else window - 1)
-    relevant = (kb * block_k <= hi) & (kb * block_k + block_k - 1 >= lo)
-
-    @pl.when(relevant)
+    @pl.when(_tile_live(i, kb, bq, block_k, window, causal))
     def _():
-        k = k_ref[0]                               # [Bk, Dh]
-        v = v_ref[0]
-        prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
-                else jax.lax.Precision.DEFAULT)
-        qf = q.reshape(g * bq, d)
-        s = jnp.dot(qf, k.T, preferred_element_type=jnp.float32,
+        prec = _prec(q.dtype)
+        s = jnp.dot(q.reshape(g * bq, d), k_ref[0].T,
+                    preferred_element_type=jnp.float32,
                     precision=prec) * scale        # [G·Bq, Bk]
-        vis = _visible(i, kb, g, bq, block_k, window, causal)
-        s = jnp.where(vis, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # Explicit zeroing, not just the -1e30 bias: a row whose visible
-        # band hasn't started yet has m_new == -1e30, where exp(s - m)
-        # would be exp(0) = 1 for every masked entry — fake weight the
-        # full-context kernel never sees (its first block is never fully
-        # dead for a live row; a banded grid's can be).
-        p = jnp.where(vis, jnp.exp(s - m_new), 0.0)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-            precision=prec)
+        # the mask and not only a bias: a row whose band has not started
+        # in this block is all masked (`_softmax_update`)
+        _softmax_update(s, _visible(i, kb, g, bq, block_k, window, causal),
+                        v_ref[0], acc_scr, m_scr, l_scr, prec)
 
     @pl.when(j == nkb - 1)
     def _():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).reshape(g, bq, d).astype(o_ref.dtype)
+        o, lse = _softmax_finish(acc_scr, m_scr, l_scr, block_k)
+        o_ref[0] = o.reshape(g, bq, d).astype(o_ref.dtype)
         if with_lse:
-            # a row statistic broadcast over 128 lanes, the narrowest
-            # layout Mosaic takes (as `ops/attention._flash_kernel` does)
-            lse_ref[0] = jnp.broadcast_to(
-                m_scr[:] + jnp.log(l), (g * bq, _LSE_LANES)
-            ).reshape(g, bq, _LSE_LANES)
+            lse_ref[0] = lse.reshape(g, bq, _LSE_LANES)
 
 
 def _visible(qb, kb, g: int, bq: int, block_k: int, window: int,
@@ -232,15 +218,34 @@ def _unfold_kv(x, b: int):
     return x.reshape(b, bh // b, t, dh).transpose(0, 2, 1, 3)
 
 
+def _live_blocks(i: int, block_q: int, block_k: int, t: int, window: int,
+                 causal: bool) -> int:
+    """How many K blocks hold a key that a row of Q block `i` sees."""
+    lo = max(i * block_q - window + 1, 0)
+    hi = min((i + 1) * block_q - 1 + (0 if causal else window - 1), t - 1)
+    return hi // block_k - lo // block_k + 1
+
+
 def _run_banded(q5, k3, v3, *, window: int, causal: bool, scale: float,
                 block_q: int, block_k: int, interpret: bool,
                 with_lse: bool = False):
     """The forward over folded heads (`_fold_heads`): o [B·Hkv, G, T, Dh]
     and, with `with_lse`, the rows' log-sum-exp [B·Hkv, G, T]."""
     bh, g, t, dh = q5.shape
-    block_q = _fit_block(block_q, t, interpret=interpret)
-    block_k = _fit_block(block_k, t, interpret=interpret)
-    nk, nkb = _band_geometry(t, window, causal, block_q, block_k)
+
+    def live(bq, bk):
+        return [_live_blocks(i, bq, bk, t, window, causal)
+                for i in range(t // bq)]
+
+    block_q, block_k = _fwd_tile(
+        "banded_attention", _fit_block(block_q, t, interpret=interpret),
+        _fit_block(block_k, t, interpret=interpret), fold=g,
+        interpret=interpret,
+        legal=lambda bq, bk: t % bq == 0 and t % bk == 0,
+        tiles=lambda bq, bk: sum(live(bq, bk)))
+    # the grid's K extent is the most blocks any Q block really meets,
+    # one under `_band_geometry`'s bound where the blocks line up
+    nk, nkb = t // block_k, max(live(block_q, block_k))
     kmap = functools.partial(_kb_first, nk=nk, nkb=nkb, block_q=block_q,
                              block_k=block_k, window=window, causal=causal)
     q_spec = pl.BlockSpec((1, g, block_q, dh), lambda bb, i, j: (bb, 0, i, 0))
@@ -261,13 +266,9 @@ def _run_banded(q5, k3, v3, *, window: int, causal: bool, scale: float,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
-        scratch_shapes=[
-            pltpu.VMEM((g * block_q, dh), jnp.float32),
-            pltpu.VMEM((g * block_q, 1), jnp.float32),
-            pltpu.VMEM((g * block_q, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=_softmax_scratch(g * block_q, dh),
+        compiler_params=_fwd_params(g * block_q, block_k, dh,
+                                    q5.dtype.itemsize),
         interpret=interpret,
     )(q5, k3, v3)
     if with_lse:

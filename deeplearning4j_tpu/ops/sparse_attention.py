@@ -35,6 +35,16 @@ relayout. Forward, dQ and dK/dV kernels recompute scores a tile at a time
 from the saved log-sum-exp; nothing `[T, T]` and no gathered copy of K or
 V exists. The forward names its output and log-sum-exp
 (`ops/attention.name_residuals`), so a checkpointed layer keeps them.
+
+The forward's online-softmax step is `ops/attention._softmax_update`, the
+flash and banded forwards' too. Its K tile is the caller's (the unit the
+walk skips by) and its Q tile its own (`ops/attention._fwd_tile`), with a
+walk of its own where that differs from the backward's: at the policy's
+256 x 512, 32 query heads and 16,384 tokens it takes 1,024 tokens a tile,
+26.7 ms a call on a v5e where 256 with `[rows, 1]` statistics took 50.0
+(PR 36, PERF.md section 6). A taller Q tile walks the union of more
+tokens' lists; on the benchmark's seeded weights that is every causal
+tile at either height.
 """
 
 from __future__ import annotations
@@ -49,7 +59,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.attention import (
-    _LSE_LANES, _NEG_INF, _fold3, _group, _prec, _unfold3, name_residuals,
+    _LSE_LANES, _NEG_INF, _causal_tiles, _fold3, _fwd_params, _fwd_tile,
+    _group, _prec, _softmax_finish, _softmax_init, _softmax_scratch,
+    _softmax_update, _unfold3, name_residuals,
 )
 
 _LANES = 128
@@ -239,35 +251,24 @@ def _fwd_kernel(visit_ref, fetch_ref, q_ref, k_ref, v_ref, a_ref, o_ref,
 
     @pl.when(kb == 0)
     def _():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        _softmax_init(acc_scr, m_scr, l_scr)
 
     @pl.when(visit_ref[flat] > 0)
     def _():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        q = q_ref[0]
         prec = _prec(q.dtype)
-        mask = _tile_mask(a_ref[0], qb, kb, bq, bk, block_size)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+        s = jnp.dot(q, k_ref[0].T, preferred_element_type=jnp.float32,
                     precision=prec) * scale
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a row with nothing listed in this tile (or yet) adds nothing
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-            precision=prec)
+        # the mask and not only a bias: a row may list nothing in this
+        # tile, or nothing yet (`ops/attention._softmax_update`)
+        _softmax_update(s, _tile_mask(a_ref[0], qb, kb, bq, bk, block_size),
+                        v_ref[0], acc_scr, m_scr, l_scr, prec)
 
     @pl.when(kb == nk - 1)
     def _():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(m_scr[:] + jnp.log(l),
-                                      (bq, _LSE_LANES))
+        o, lse = _softmax_finish(acc_scr, m_scr, l_scr, bk)
+        o_ref[0] = o.astype(o_ref.dtype)
+        lse_ref[0] = lse
 
 
 def _bwd_tile(q, k, v, do, a, lse_col, delta_col, qb, kb, block_size, scale):
@@ -410,12 +411,10 @@ def _run_fwd(q3, k3, v3, a3, walk, *, scale, block_size, block_q, block_k,
             num_scalar_prefetch=2, grid=(bh, nq, nk),
             in_specs=[q_spec, kv_spec, kv_spec, a_spec],
             out_specs=[q_spec, row_spec],
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                            pltpu.VMEM((block_q, 1), jnp.float32),
-                            pltpu.VMEM((block_q, 1), jnp.float32)]),
+            scratch_shapes=_softmax_scratch(block_q, d)),
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
                    jax.ShapeDtypeStruct((bh, t, _LSE_LANES), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(**_PARAMS),
+        compiler_params=_fwd_params(block_q, block_k, d, q3.dtype.itemsize),
         interpret=interpret,
     )(visit, fetch_k, q3, k3, v3, a3)
     return o, lse[..., 0]
@@ -507,10 +506,22 @@ def _sparse_fwd(q, k, v, allow, block_size, scale, block_q, block_k,
     k3, shape_k = _fold3(k)
     v3, _ = _fold3(v)
     a3 = _mask_operand(allow, q.dtype)
-    walk = _walk(allow.reshape(a3.shape[0], t, -1), block_q, block_k,
-                 block_size)
-    o3, lse = _run_fwd(q3, k3, v3, a3, walk, scale=s, block_size=block_size,
-                       block_q=block_q, block_k=block_k, interpret=interpret)
+    allow3 = allow.reshape(a3.shape[0], t, -1)
+    walk = _walk(allow3, block_q, block_k, block_size)
+    # The forward's own tile. The K tile stays the caller's: it is the
+    # unit the walk skips by, and what a selection lists is data. The Q
+    # tile may grow (its walk is then the union of more tokens' lists),
+    # priced over the causal triangle, the most a walk can visit.
+    fwd_q, fwd_k = _fwd_tile(
+        "sparse_attention", block_q, block_k, interpret=interpret,
+        legal=lambda bq, bk: bk == block_k and sparse_eligible(
+            t, block_size, bq, bk),
+        tiles=lambda bq, bk: _causal_tiles(t, bq, bk))
+    fwd_walk = (walk if (fwd_q, fwd_k) == (block_q, block_k)
+                else _walk(allow3, fwd_q, fwd_k, block_size))
+    o3, lse = _run_fwd(q3, k3, v3, a3, fwd_walk, scale=s,
+                       block_size=block_size, block_q=fwd_q, block_k=fwd_k,
+                       interpret=interpret)
     o3, lse = name_residuals(o3, lse)
     return (_unfold3(o3, shape_q),
             (q3, k3, v3, a3, walk, o3, lse, shape_q, shape_k))

@@ -90,7 +90,7 @@ def _flash(train, T=8192, heads=8, d=64, block=512, kv_heads=None):
 
 
 def _banded(T=2048, heads=8, kv_heads=2, d=64, window=512, train=False,
-            batch=2):
+            batch=2, dt=BF16):
     def fwd(q, k, v):
         return banded.banded_attention(q, k, v, window, True, None, 256,
                                        256, False)
@@ -99,8 +99,8 @@ def _banded(T=2048, heads=8, kv_heads=2, d=64, window=512, train=False,
         return fwd(q, k, v).astype(F32).sum()
 
     return (jax.grad(loss, argnums=(0, 1, 2)) if train else fwd), [
-        ((batch, T, heads, d), BF16), ((batch, T, kv_heads, d), BF16),
-        ((batch, T, kv_heads, d), BF16)]
+        ((batch, T, heads, d), dt), ((batch, T, kv_heads, d), dt),
+        ((batch, T, kv_heads, d), dt)]
 
 
 def _decode(paged, cache_dtype, slots=8, cache=1024, page=128, heads=8,
@@ -162,6 +162,15 @@ CASES = {
     "banded_train_gqa_48_8": lambda: _banded(
         T=8192, heads=48, kv_heads=8, d=128, window=4096, train=True,
         batch=1),
+    # `trinity_large_fit`'s two forward kernels alone, at the tile each
+    # picks for itself from the policies' blocks (`ops/attention._fwd_tile`)
+    "banded_fwd_gqa_48_8": lambda: _banded(
+        T=8192, heads=48, kv_heads=8, d=128, window=4096, batch=1),
+    "flash_fwd_gqa_48_8": lambda: _flash(train=False, heads=48, d=128,
+                                         kv_heads=8),
+    # float32 operands at the same tile pass the default scoped VMEM
+    "banded_fwd_gqa_48_8_f32": lambda: _banded(
+        T=8192, heads=48, kv_heads=8, d=128, window=4096, batch=1, dt=F32),
     "slot_decode_bf16": lambda: _decode(False, BF16),
     "slot_decode_int8": lambda: _decode(False, I8),
     "paged_decode_bf16": lambda: _decode(True, BF16),
@@ -175,6 +184,32 @@ def test_kernel_compiles_for_v5e(chip, name):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- the tile a forward kernel picked is published at trace time: the
+# gauge says which tile a cell's program really runs (`_fwd_tile`; the
+# cells' shapes, where the kernels alone were timed over the candidates)
+FORWARD_TILES = {
+    "banded_fwd_gqa_48_8": ("banded_attention", 6 * 256, 512),
+    "flash_fwd_gqa_48_8": ("flash_attention", 1024, 512),
+    "sparse_fwd_32_2": ("sparse_attention", 1024, 512),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FORWARD_TILES))
+def test_forward_kernel_publishes_its_tile(name):
+    from deeplearning4j_tpu.observe import get_registry
+
+    op, rows, keys = FORWARD_TILES[name]
+    fn, shapes = CASES[name]()
+    gauge = lambda field: get_registry().gauge("attention_fwd_tile", op=op,
+                                               field=field)
+    gauge("rows").set(0)
+    gauge("keys_per_update").set(0)
+    with jax.enable_x64(False):
+        jax.eval_shape(fn, *[jax.ShapeDtypeStruct(s, d) for s, d in shapes])
+    assert (gauge("rows").value, gauge("keys_per_update").value) == (rows,
+                                                                     keys)
 
 
 # --- every `pl.pallas_call` site names its kernel: the device trace and
