@@ -635,8 +635,7 @@ def _child_main():
             os.environ.get("BENCH_STEPS_PER_DISPATCH", "1")),
         "registry": _registry_snapshot(),
         # device-truth telemetry: one DeviceMonitor sample (HBM
-        # in-use/peak/limit on TPU; live-array counts everywhere) —
-        # attribution series ride in under "registry"
+        # in-use/peak/limit on TPU; live-array counts everywhere)
         "devices": _devices_summary(),
     }))
 
@@ -933,33 +932,6 @@ def _host_overhead_main():
     net.fit(feats, labs, batch_size=batch, epochs=2)
     tracked = net._loss_tracker
 
-    # steady-state cost of the device-truth telemetry itself (step-time
-    # attribution in the executor + span→flight ring), measured on the
-    # same real fit loop with the env kill-switch toggled — PERF_NOTES
-    # holds this to <2%
-    def fit_wall(attribution_on):
-        prev = os.environ.get("DL4J_TPU_ATTRIBUTION")
-        os.environ["DL4J_TPU_ATTRIBUTION"] = "1" if attribution_on else "0"
-        try:
-            net2 = build()
-            net2.fit(feats, labs, batch_size=batch, epochs=1)  # warm
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                net2.fit(feats, labs, batch_size=batch, epochs=4)
-                jax.block_until_ready(net2.params_tree)
-                best = min(best, time.perf_counter() - t0)
-            return best
-        finally:
-            if prev is None:
-                os.environ.pop("DL4J_TPU_ATTRIBUTION", None)
-            else:
-                os.environ["DL4J_TPU_ATTRIBUTION"] = prev
-
-    wall_on = fit_wall(True)
-    wall_off = fit_wall(False)
-    attribution_overhead_pct = (wall_on - wall_off) / wall_off * 100.0
-
     from deeplearning4j_tpu.observe.devicemon import device_memory_summary
     t0 = time.perf_counter()
     devices = device_memory_summary()
@@ -990,9 +962,6 @@ def _host_overhead_main():
                 tracked.host_syncs / max(1, tracked.updates), 6),
         },
         "telemetry": {
-            "fit_s_attribution_on": round(wall_on, 4),
-            "fit_s_attribution_off": round(wall_off, 4),
-            "attribution_overhead_pct": round(attribution_overhead_pct, 3),
             "devicemon_sample_ms": round(devicemon_sample_ms, 3),
         },
         "devices": devices,

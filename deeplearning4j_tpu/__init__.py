@@ -31,10 +31,13 @@ Top-level subpackages
 
 __version__ = "0.2.0"
 
-from deeplearning4j_tpu.nn.inputs import InputType
-from deeplearning4j_tpu.nn.activations import Activation
-from deeplearning4j_tpu.nn.losses import LossFunction
-from deeplearning4j_tpu.nn.initializers import WeightInit
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.deeplearning4j_tpu"):
+    from deeplearning4j_tpu.nn.inputs import InputType
+    from deeplearning4j_tpu.nn.activations import Activation
+    from deeplearning4j_tpu.nn.losses import LossFunction
+    from deeplearning4j_tpu.nn.initializers import WeightInit
 
 
 _LAZY = {

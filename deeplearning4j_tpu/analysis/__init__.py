@@ -27,29 +27,32 @@ Public API:
                                     `.graftlint-cache.json` (cache.py)
 """
 
-from deeplearning4j_tpu.analysis.baseline import (   # noqa: F401
-    apply_baseline, load_baseline, prune_baseline, write_baseline,
-)
-from deeplearning4j_tpu.analysis.cache import (      # noqa: F401
-    CACHE_FILE, lint_files_cached,
-)
-from deeplearning4j_tpu.analysis.callgraph import (  # noqa: F401
-    CallGraph, Program,
-)
-from deeplearning4j_tpu.analysis.engine import (     # noqa: F401
-    DEFAULT_HOT_PREFIXES, Finding, is_hot, lint_file, lint_files,
-    lint_paths, lint_source,
-)
-from deeplearning4j_tpu.analysis.locks import (      # noqa: F401
-    analyze_lock_paths, analyze_lock_program, analyze_lock_sources,
-)
-from deeplearning4j_tpu.analysis.rules import (      # noqa: F401
-    RULES, RULES_VERSION, RUNTIME_RULE_HINTS, Rule, runtime_hint,
-)
-from deeplearning4j_tpu.analysis.shardflow import (  # noqa: F401
-    analyze_shardflow_paths, analyze_shardflow_program,
-    analyze_shardflow_sources,
-)
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.analysis"):
+    from deeplearning4j_tpu.analysis.baseline import (   # noqa: F401
+        apply_baseline, load_baseline, prune_baseline, write_baseline,
+    )
+    from deeplearning4j_tpu.analysis.cache import (      # noqa: F401
+        CACHE_FILE, lint_files_cached,
+    )
+    from deeplearning4j_tpu.analysis.callgraph import (  # noqa: F401
+        CallGraph, Program,
+    )
+    from deeplearning4j_tpu.analysis.engine import (     # noqa: F401
+        DEFAULT_HOT_PREFIXES, Finding, is_hot, lint_file, lint_files,
+        lint_paths, lint_source,
+    )
+    from deeplearning4j_tpu.analysis.locks import (      # noqa: F401
+        analyze_lock_paths, analyze_lock_program, analyze_lock_sources,
+    )
+    from deeplearning4j_tpu.analysis.rules import (      # noqa: F401
+        RULES, RULES_VERSION, RUNTIME_RULE_HINTS, Rule, runtime_hint,
+    )
+    from deeplearning4j_tpu.analysis.shardflow import (  # noqa: F401
+        analyze_shardflow_paths, analyze_shardflow_program,
+        analyze_shardflow_sources,
+    )
 
 __all__ = [
     "CACHE_FILE", "CallGraph", "DEFAULT_HOT_PREFIXES", "Finding",

@@ -111,8 +111,10 @@ _SINK_BARE = frozenset({"b64encode", "b85encode"})
 _TRANSPARENT_WRAPPERS = frozenset({"instrument"})
 
 #: `optim/step.jit_step(fn, ...)` is the trainers' one donating jit:
-#: (params, opt_state, states) of what it returns are donated.
-_DONATING_JITS: Dict[str, Tuple[int, ...]] = {"jit_step": (0, 1, 2)}
+#: (params, opt_state, states) of what it returns are donated;
+#: `build_step(...)` is `jit_step` behind the step's construction.
+_DONATING_JITS: Dict[str, Tuple[int, ...]] = {"jit_step": (0, 1, 2),
+                                              "build_step": (0, 1, 2)}
 
 
 def _donated_positions(call: ast.Call) -> Tuple[int, ...]:

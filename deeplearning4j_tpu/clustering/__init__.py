@@ -12,9 +12,12 @@ structure (serving-time k-NN needs low-latency single queries, not
 throughput).
 """
 
-from deeplearning4j_tpu.clustering.kmeans import KMeansClustering
-from deeplearning4j_tpu.clustering.vptree import VPTree
-from deeplearning4j_tpu.clustering.kdtree import KDTree
-from deeplearning4j_tpu.clustering.tsne import BarnesHutTsne
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.clustering"):
+    from deeplearning4j_tpu.clustering.kmeans import KMeansClustering
+    from deeplearning4j_tpu.clustering.vptree import VPTree
+    from deeplearning4j_tpu.clustering.kdtree import KDTree
+    from deeplearning4j_tpu.clustering.tsne import BarnesHutTsne
 
 __all__ = ["KMeansClustering", "VPTree", "KDTree", "BarnesHutTsne"]

@@ -9,16 +9,19 @@ coding (`graph/models/deepwalk/GraphHuffman.java`), vector queries
 (`graph/models/loader/GraphVectorSerializer.java`).
 """
 
-from deeplearning4j_tpu.graph.api import (
-    Edge, Graph, NoEdgeHandling, Vertex, load_edge_list,
-    load_weighted_edge_list,
-)
-from deeplearning4j_tpu.graph.walks import (
-    Node2VecWalker, RandomWalker, WeightedWalker, generate_walks,
-)
-from deeplearning4j_tpu.graph.deepwalk import (
-    DeepWalk, GraphHuffman, Node2Vec,
-)
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.graph"):
+    from deeplearning4j_tpu.graph.api import (
+        Edge, Graph, NoEdgeHandling, Vertex, load_edge_list,
+        load_weighted_edge_list,
+    )
+    from deeplearning4j_tpu.graph.walks import (
+        Node2VecWalker, RandomWalker, WeightedWalker, generate_walks,
+    )
+    from deeplearning4j_tpu.graph.deepwalk import (
+        DeepWalk, GraphHuffman, Node2Vec,
+    )
 
 __all__ = [
     "Edge", "Graph", "NoEdgeHandling", "Vertex", "load_edge_list",

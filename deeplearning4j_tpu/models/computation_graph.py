@@ -28,8 +28,7 @@ from deeplearning4j_tpu.data.iterators import (
 from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
 from deeplearning4j_tpu.optim.step import (
-    as_features, jit_step, make_fused_step, make_train_step,
-    stack_step_args,
+    as_features, build_step, make_train_step, stack_step_args,
 )
 from deeplearning4j_tpu.nn.graph import (
     ComputationGraphConfiguration, GraphVertex, LayerVertex,
@@ -43,6 +42,8 @@ from deeplearning4j_tpu.models.multilayer import (
     _check_decode_budget, _checkpointed, _dtype_of, _is_recurrent,
     record_residuals_kept,
 )
+from deeplearning4j_tpu.observe.trace import span
+from deeplearning4j_tpu.observe.watchdog import listen_for_compiles
 from deeplearning4j_tpu.optim.listeners import TrainingListener
 from deeplearning4j_tpu.optim.updaters import NoOp, Updater, resolve_updater
 from deeplearning4j_tpu.models.decode_state import DecodeState
@@ -60,6 +61,9 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
     """DAG network runtime over a ComputationGraphConfiguration."""
 
     def __init__(self, conf: ComputationGraphConfiguration):
+        # from here on every compile of the process leaves `xla.*` spans
+        # (this net's `init()` and first `fit()` among them)
+        listen_for_compiles()
         self.conf = conf
         self.dtype = _dtype_of(conf.dtype)
         self.params_tree: Optional[Dict[str, Any]] = None
@@ -91,27 +95,31 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
 
     # ------------------------------------------------------------- init
     def init(self) -> "ComputationGraph":
-        key = jax.random.PRNGKey(self.conf.seed)
-        params, states = {}, {}
-        known = dict(self.conf.input_types)
-        for name in self.conf.topological_order:
-            v = self.conf.vertices[name]
-            in_types = [known[i] for i in self.conf.vertex_inputs[name]
-                        if i in known]
-            key, sub = jax.random.split(key)
-            p, s = v.init_params(sub, in_types, self.dtype)
-            params[name] = p
-            states[name] = s
-            if s:
-                self._stateful.add(name)
-            resolve_output_type(name, v, in_types,
-                                len(self.conf.vertex_inputs[name]), known)
-        self.params_tree = params
-        self.state_tree = states
-        self._build_updaters()
-        self.updater_state = {
-            n: u.init(params[n]) for n, u in self._vertex_updaters.items()
-        }
+        timed = span("net.init", model=type(self).__name__,
+                     layers=len(self.conf.topological_order))
+        with timed:
+            key = jax.random.PRNGKey(self.conf.seed)
+            params, states = {}, {}
+            known = dict(self.conf.input_types)
+            for name in self.conf.topological_order:
+                v = self.conf.vertices[name]
+                in_types = [known[i] for i in self.conf.vertex_inputs[name]
+                            if i in known]
+                key, sub = jax.random.split(key)
+                p, s = v.init_params(sub, in_types, self.dtype)
+                params[name] = p
+                states[name] = s
+                if s:
+                    self._stateful.add(name)
+                resolve_output_type(name, v, in_types,
+                                    len(self.conf.vertex_inputs[name]), known)
+            self.params_tree = params
+            self.state_tree = states
+            self._build_updaters()
+            self.updater_state = {
+                n: u.init(params[n]) for n, u in self._vertex_updaters.items()
+            }
+            timed.attrs["params"] = param_count(params)   # from shapes
         return self
 
     def _build_updaters(self):
@@ -314,9 +322,9 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         key = (key, tbptt)
         if key in self._jit_cache:
             return self._jit_cache[key]
-        return jit_step(self.make_step_fn(tbptt=tbptt),
-                        cache=self._jit_cache, key=key,
-                        name="ComputationGraph._step")
+        return build_step(
+            functools.partial(self.make_step_fn, tbptt=tbptt),
+            cache=self._jit_cache, key=key, name="ComputationGraph._step")
 
     # ---------------------------------------------------- data plumbing
     def _features(self, name: str, x, asarray=jnp.asarray):
@@ -437,9 +445,9 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         cache_key = ("fused", key, k)
         if cache_key in self._jit_cache:
             return self._jit_cache[cache_key]
-        return jit_step(make_fused_step(self.make_step_fn()),
-                        cache=self._jit_cache, key=cache_key,
-                        name="ComputationGraph._fused_step")
+        return build_step(self.make_step_fn, fused=True,
+                          cache=self._jit_cache, key=cache_key,
+                          name="ComputationGraph._fused_step")
 
     def _stacked_batch_args(self, batches: Sequence):
         """K same-shape batches as the fused step's arguments, stacked on

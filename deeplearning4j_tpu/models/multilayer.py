@@ -35,8 +35,7 @@ from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
 from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
 from deeplearning4j_tpu.optim.step import (
-    as_features, jit_step, make_fused_step, make_train_step,
-    stack_step_args,
+    as_features, build_step, make_train_step, stack_step_args,
 )
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.convolution import (
@@ -47,6 +46,8 @@ from deeplearning4j_tpu.nn.layers.recurrent import (
 )
 from deeplearning4j_tpu.nn.layers.special import CenterLossOutputLayer
 from deeplearning4j_tpu.observe.registry import get_registry
+from deeplearning4j_tpu.observe.trace import span
+from deeplearning4j_tpu.observe.watchdog import listen_for_compiles
 from deeplearning4j_tpu.optim.listeners import TrainingListener
 from deeplearning4j_tpu.optim.updaters import NoOp, Updater, resolve_updater
 from deeplearning4j_tpu.parallel.ring_attention import (
@@ -154,6 +155,9 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
     """Sequential network runtime over a MultiLayerConfiguration."""
 
     def __init__(self, conf: MultiLayerConfiguration):
+        # from here on every compile of the process leaves `xla.*` spans
+        # (this net's `init()` and first `fit()` among them)
+        listen_for_compiles()
         self.conf = conf
         self.layers: Tuple[Layer, ...] = conf.layers
         self.dtype = _dtype_of(conf.dtype)
@@ -188,26 +192,31 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
     # ------------------------------------------------------------- init
     def init(self) -> "MultiLayerNetwork":
         """Initialize params/state. Reference: `MultiLayerNetwork.init():446`."""
-        key = jax.random.PRNGKey(self.conf.seed)
-        params, states = {}, {}
-        it = self.conf.input_type
-        for i, layer in enumerate(self.layers):
-            if it is not None and i in self.conf.preprocessors:
-                it = self.conf.preprocessors[i].output_type(it)
-            key, sub = jax.random.split(key)
-            p, s = layer.init_params(sub, it, self.dtype)
-            params[layer.name] = p
-            states[layer.name] = s
-            if s:
-                self._stateful.add(layer.name)
-            if it is not None:
-                it = layer.output_type(it)
-        self.params_tree = params
-        self.state_tree = states
-        self._build_updaters()
-        self.updater_state = {
-            name: u.init(params[name]) for name, u in self._layer_updaters.items()
-        }
+        timed = span("net.init", model=type(self).__name__,
+                     layers=len(self.layers))
+        with timed:
+            key = jax.random.PRNGKey(self.conf.seed)
+            params, states = {}, {}
+            it = self.conf.input_type
+            for i, layer in enumerate(self.layers):
+                if it is not None and i in self.conf.preprocessors:
+                    it = self.conf.preprocessors[i].output_type(it)
+                key, sub = jax.random.split(key)
+                p, s = layer.init_params(sub, it, self.dtype)
+                params[layer.name] = p
+                states[layer.name] = s
+                if s:
+                    self._stateful.add(layer.name)
+                if it is not None:
+                    it = layer.output_type(it)
+            self.params_tree = params
+            self.state_tree = states
+            self._build_updaters()
+            self.updater_state = {
+                name: u.init(params[name])
+                for name, u in self._layer_updaters.items()
+            }
+            timed.attrs["params"] = param_count(params)   # from shapes
         return self
 
     def _build_updaters(self):
@@ -344,9 +353,9 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         """The jitted step; `key` is (has_fmask, has_lmask, tbptt)."""
         if key in self._jit_cache:
             return self._jit_cache[key]
-        return jit_step(self.make_step_fn(tbptt=key[2]),
-                        cache=self._jit_cache, key=key,
-                        name="MultiLayerNetwork._step")
+        return build_step(
+            functools.partial(self.make_step_fn, tbptt=key[2]),
+            cache=self._jit_cache, key=key, name="MultiLayerNetwork._step")
 
     @property
     def _rnn_layer_names(self):
@@ -431,9 +440,9 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         cache_key = ("fused", key, k)
         if cache_key in self._jit_cache:
             return self._jit_cache[cache_key]
-        return jit_step(make_fused_step(self.make_step_fn()),
-                        cache=self._jit_cache, key=cache_key,
-                        name="MultiLayerNetwork._fused_step")
+        return build_step(self.make_step_fn, fused=True,
+                          cache=self._jit_cache, key=cache_key,
+                          name="MultiLayerNetwork._fused_step")
 
     def _features(self, x, asarray=jnp.asarray):
         """Features as the forward pass takes them (`as_features`): ids

@@ -13,14 +13,17 @@ This is the HOST side: feeding, compressing, staging.
 
 from __future__ import annotations
 
-import ctypes
-import hashlib
-import os
-import subprocess
-import threading
-from typing import Optional, Tuple
+from deeplearning4j_tpu.observe.trace import span as _span
 
-import numpy as np
+with _span("import.native"):
+    import ctypes
+    import hashlib
+    import os
+    import subprocess
+    import threading
+    from typing import Optional, Tuple
+
+    import numpy as np
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "csrc")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")

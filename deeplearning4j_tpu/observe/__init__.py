@@ -22,10 +22,18 @@ every layer shares:
   loop's segments (`fit.etl` > `data.put`, `fit.dispatch`,
   `fit.listeners`, `fit.epoch_sync`) are timed once, by their spans;
   `utils/profiling.ProfilerListener` writes them beside a device trace
-  with what links the two clocks.
+  with what links the two clocks. Set-up is timed the same way, from
+  this package's import on: `import.<subpackage>` round each
+  `__init__.py`'s imports, `net.init`, `wrapper.init`, `step.build`.
 - `RecompileWatchdog` (`watchdog.py`) — counts every jit-cache compile
   across the per-model `_jit_cache` seams and warns once per model when
-  compiles cross a churn threshold (the classic silent 10x).
+  compiles cross a churn threshold (the classic silent 10x). Its
+  `listen_for_compiles` hears every compile of the process from
+  `jax.monitoring` and leaves JAX's timed regions as the spans
+  `xla.trace`, `xla.lower` and `xla.compile` (`fun_name`, `fetched`)
+  with the counter `xla_compiles_total{fetched=}` and the histogram
+  `xla_compile_ms`; its probe of a cached step's first call is the span
+  `compile.probe`, a child a leg.
 - `HostSyncMonitor` (`syncmon.py`) — opt-in runtime generalization of the
   test-only dispatch-depth guard: counts device→host materializations so
   `PerformanceListener` can report syncs/step in production.
@@ -72,50 +80,50 @@ The package imports only the stdlib (no jax) so the dump tool and the
 registry work anywhere; jax seams are bound lazily at install time.
 """
 
-from deeplearning4j_tpu.observe.registry import (
-    MetricsRegistry, get_registry, set_registry,
-)
-from deeplearning4j_tpu.observe.trace import (
-    SpanLog, emit_manual_span, get_span_store, install_span_log, read_spans,
-    span, tracing_enabled, uninstall_span_log,
-)
-from deeplearning4j_tpu.observe.watchdog import (
-    RecompileWatchdog, WatchedJitCache, get_watchdog, set_watchdog,
-)
-from deeplearning4j_tpu.observe.syncmon import HostSyncMonitor, current_monitor
-from deeplearning4j_tpu.observe.lockmon import (
-    LockWitness, MonitoredLock, get_witness, lockmon_enabled,
-    reset_witness,
-)
-from deeplearning4j_tpu.observe.donatemon import (
-    DonationWitness, UseAfterDonateError, donatemon_enabled,
-    get_donation_witness, instrument, reset_donation_witness,
-)
-from deeplearning4j_tpu.observe.commsmon import (
-    ReshardWitness, commsmon_enabled, get_reshard_witness,
-    parse_hlo_collectives, reset_reshard_witness, summarize_collectives,
-)
-from deeplearning4j_tpu.observe.flight import (
-    FlightRecorder, get_flight, latest_dump, read_dump, set_flight,
-)
-from deeplearning4j_tpu.observe.devicemon import (
-    DeviceMonitor, device_memory_summary, get_device_monitor,
-    maybe_start_monitor, set_device_monitor,
-)
-from deeplearning4j_tpu.observe.attribution import (
-    StepAttribution, attribution_enabled,
-)
-from deeplearning4j_tpu.observe.reqtrace import (
-    TraceContext, TraceStore, active_dispatch, begin_dispatch,
-    current_trace, end_dispatch, error_extra, error_trace, finish_root,
-    get_trace_store, new_trace, record_span, set_trace_store,
-)
-from deeplearning4j_tpu.observe.series import (
-    SeriesRing, SeriesSampler, SeriesStore, series_key,
-)
-from deeplearning4j_tpu.observe.slo import (
-    SLO, AnomalyWatch, SLOEngine, default_slos,
-)
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.observe"):
+    from deeplearning4j_tpu.observe.registry import (
+        MetricsRegistry, get_registry, set_registry,
+    )
+    from deeplearning4j_tpu.observe.trace import (
+        SpanLog, emit_manual_span, get_span_store, install_span_log, read_spans,
+        span, tracing_enabled, uninstall_span_log,
+    )
+    from deeplearning4j_tpu.observe.watchdog import (
+        RecompileWatchdog, WatchedJitCache, get_watchdog, set_watchdog,
+    )
+    from deeplearning4j_tpu.observe.syncmon import HostSyncMonitor, current_monitor
+    from deeplearning4j_tpu.observe.lockmon import (
+        LockWitness, MonitoredLock, get_witness, lockmon_enabled,
+        reset_witness,
+    )
+    from deeplearning4j_tpu.observe.donatemon import (
+        DonationWitness, UseAfterDonateError, donatemon_enabled,
+        get_donation_witness, instrument, reset_donation_witness,
+    )
+    from deeplearning4j_tpu.observe.commsmon import (
+        ReshardWitness, commsmon_enabled, get_reshard_witness,
+        parse_hlo_collectives, reset_reshard_witness, summarize_collectives,
+    )
+    from deeplearning4j_tpu.observe.flight import (
+        FlightRecorder, get_flight, latest_dump, read_dump, set_flight,
+    )
+    from deeplearning4j_tpu.observe.devicemon import (
+        DeviceMonitor, device_memory_summary, get_device_monitor,
+        maybe_start_monitor, set_device_monitor,
+    )
+    from deeplearning4j_tpu.observe.reqtrace import (
+        TraceContext, TraceStore, active_dispatch, begin_dispatch,
+        current_trace, end_dispatch, error_extra, error_trace, finish_root,
+        get_trace_store, new_trace, record_span, set_trace_store,
+    )
+    from deeplearning4j_tpu.observe.series import (
+        SeriesRing, SeriesSampler, SeriesStore, series_key,
+    )
+    from deeplearning4j_tpu.observe.slo import (
+        SLO, AnomalyWatch, SLOEngine, default_slos,
+    )
 
 __all__ = [
     "MetricsRegistry", "get_registry", "set_registry",
@@ -133,7 +141,6 @@ __all__ = [
     "FlightRecorder", "get_flight", "set_flight", "latest_dump", "read_dump",
     "DeviceMonitor", "device_memory_summary", "get_device_monitor",
     "maybe_start_monitor", "set_device_monitor",
-    "StepAttribution", "attribution_enabled",
     "TraceContext", "TraceStore", "get_trace_store", "set_trace_store",
     "new_trace", "finish_root", "record_span", "error_trace", "error_extra",
     "current_trace", "begin_dispatch", "active_dispatch", "end_dispatch",

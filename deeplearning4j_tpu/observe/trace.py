@@ -17,9 +17,11 @@ span safe in the training hot loop:
   anything else (a jax array too) is recorded as its type name, NOT its
   value, so no span holds a device buffer or forces a host sync.
 - Recording is on while a `SpanLog` is installed or the flight recorder
-  is enabled (the default; `DL4J_TPU_FLIGHT=0` turns it off). Off, a span
-  still reads the clock twice (callers take their durations from it) and
-  records nothing.
+  is enabled (the default; `DL4J_TPU_FLIGHT=0` turns it off). It is on
+  from this module's import, before anything asks for the recorder, so
+  that the package's own `import.*` spans and a `net.init` before the
+  first `fit()` are kept. Off, a span still reads the clock twice
+  (callers take their durations from it) and records nothing.
 
 The wall clock is read once per root span (`fit`): `SpanStore.anchor`
 pairs it with the span clock, which dates a written record (`ts`) and
@@ -34,6 +36,7 @@ from __future__ import annotations
 
 import itertools
 import json
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -45,10 +48,11 @@ _tls = threading.local()
 _active_log: Optional["SpanLog"] = None
 _install_lock = threading.Lock()
 
-# Set by observe/flight.py (`_set_flight_sink`) while the process-wide
-# flight recorder is enabled: spans are then recorded with no SpanLog
-# installed. This module never imports flight (no cycle).
-_flight_sink = None
+# Truthy while spans are recorded with no SpanLog installed: from import
+# by the flight recorder's own switch, then whatever observe/flight.py
+# sets (`_set_flight_sink`: the process-wide recorder while it is enabled,
+# None for a disabled one). This module never imports flight (no cycle).
+_flight_sink = os.environ.get("DL4J_TPU_FLIGHT", "1") != "0" or None
 
 _PLAIN = (str, int, float, bool, type(None))
 
@@ -186,6 +190,11 @@ def tracing_enabled() -> bool:
     return _active_log is not None
 
 
+def recording_enabled() -> bool:
+    """Are finished spans kept (a SpanLog or the flight switch)?"""
+    return _active_log is not None or _flight_sink is not None
+
+
 def _stack() -> List["span"]:
     """This thread's open spans, outermost first."""
     st = getattr(_tls, "stack", None)
@@ -250,7 +259,7 @@ def emit_manual_span(name: str, start_ns: int, end_ns: int, /,
     """Record a span whose bounds were read elsewhere, on the span clock
     (`time.perf_counter_ns()`): a profiler capture bracketed by listener
     callbacks, a window closed by a later event."""
-    if _active_log is None and _flight_sink is None:
+    if not recording_enabled():
         return
     st = _stack()
     _record((next(_ids), st[-1].span_id if st else None, name,

@@ -21,6 +21,18 @@ the watchdog:
 
 Counting costs one lock acquisition per COMPILE (not per step): compiles
 are rare by construction, so the watchdog is always on.
+
+The dicts see a model's programs only. `listen_for_compiles` (called when
+the first `WatchedJitCache` is made, while span recording is on) hears
+every compile of the process from `jax.monitoring` and turns JAX's three
+timed regions into spans on the span clock: `xla.trace`, `xla.lower` and
+`xla.compile` (compile or fetch from the persistent cache: `fetched`),
+each with JAX's `fun_name`, under whatever span is open on the thread
+(the first `fit.dispatch`, a `net.init`, a `data.put`); beside them the
+counter `xla_compiles_total{fetched=}` and the histogram `xla_compile_ms`.
+The watchdog's own probe of a first call is the span `compile.probe` with
+a child for each leg (`compile.probe.lower`, `.compile`, `.cost`,
+`.text`).
 """
 
 from __future__ import annotations
@@ -28,7 +40,12 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Dict, List, Optional
+
+from deeplearning4j_tpu.observe.trace import (
+    emit_manual_span, recording_enabled, span,
+)
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -319,37 +336,48 @@ def _cost_of(artifact) -> dict:
 
 
 def _record_lowered_cost(fn, specs, owner_tag, owner_class, key) -> None:
-    try:
-        spec_args, spec_kw = specs
-        lowered = fn.lower(*spec_args, **spec_kw)
-    except Exception as e:
-        note_cost_analysis_failure(
-            f"lowering cost analysis failed: {type(e).__name__}")
-        return
-    # the comm ledger rides the same lowering; a failed cost_analysis
-    # does not forfeit the collective walk (and vice versa)
-    compiled = None
-    if _comm_ledger_enabled():
+    """The probe of a cached step's first call, as the span
+    `compile.probe` with one child a leg: a second lowering of the step
+    (a second whole trace), its compile (the comm ledger's; served from
+    a cache where the dispatch's own compile filled one), XLA's cost
+    analysis of either, and the text of the compiled module with its
+    parse."""
+    with span("compile.probe", owner=owner_class):
         try:
-            compiled = lowered.compile()
+            spec_args, spec_kw = specs
+            with span("compile.probe.lower"):
+                lowered = fn.lower(*spec_args, **spec_kw)
         except Exception as e:
             note_cost_analysis_failure(
-                f"compiled-HLO comm walk failed: {type(e).__name__}")
-    try:
-        cost = _cost_of(lowered)
-        if not cost.get("flops") and compiled is not None:
-            # the TPU client prices compiled modules only: there
-            # Lowered.cost_analysis() is None
-            cost = _cost_of(compiled)
-        get_watchdog().record_cost(owner_tag, owner_class, key, {
-            "flops": float(cost.get("flops") or 0.0),
-            "bytes_accessed": float(cost.get("bytes accessed") or 0.0),
-        })
-    except Exception as e:
-        note_cost_analysis_failure(
-            f"lowering cost analysis failed: {type(e).__name__}")
-    if compiled is not None:
-        _record_compiled_comm(compiled, owner_tag, owner_class, key)
+                f"lowering cost analysis failed: {type(e).__name__}")
+            return
+        # the comm ledger rides the same lowering; a failed cost_analysis
+        # does not forfeit the collective walk (and vice versa)
+        compiled = None
+        if _comm_ledger_enabled():
+            try:
+                with span("compile.probe.compile"):
+                    compiled = lowered.compile()
+            except Exception as e:
+                note_cost_analysis_failure(
+                    f"compiled-HLO comm walk failed: {type(e).__name__}")
+        try:
+            with span("compile.probe.cost"):
+                cost = _cost_of(lowered)
+                if not cost.get("flops") and compiled is not None:
+                    # the TPU client prices compiled modules only: there
+                    # Lowered.cost_analysis() is None
+                    cost = _cost_of(compiled)
+            get_watchdog().record_cost(owner_tag, owner_class, key, {
+                "flops": float(cost.get("flops") or 0.0),
+                "bytes_accessed": float(cost.get("bytes accessed") or 0.0),
+            })
+        except Exception as e:
+            note_cost_analysis_failure(
+                f"lowering cost analysis failed: {type(e).__name__}")
+        if compiled is not None:
+            with span("compile.probe.text"):
+                _record_compiled_comm(compiled, owner_tag, owner_class, key)
 
 
 def _record_compiled_comm(compiled, owner_tag, owner_class, key) -> None:
@@ -435,6 +463,7 @@ class WatchedJitCache(dict):
     def __init__(self, owner=None, *, owner_tag: Optional[str] = None,
                  owner_class: Optional[str] = None):
         super().__init__()
+        listen_for_compiles()
         cls = owner_class or (type(owner).__name__ if owner is not None
                               else "unknown")
         self.owner_class = cls
@@ -462,9 +491,89 @@ class WatchedJitCache(dict):
             self[k] = v
 
 
+# ----------------------------------------------------- every XLA compile
+_XLA_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla.lower",
+    "/jax/core/compile/backend_compile_duration": "xla.compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_xla_tls = threading.local()
+_xla_listening = False
+_lock = threading.Lock()
+
+
+def _xla_open() -> list:
+    """This thread's open timed regions of JAX, outermost first:
+    [event, start on the span clock]."""
+    st = getattr(_xla_tls, "open", None)
+    if st is None:
+        st = _xla_tls.open = []
+    return st
+
+
+def _on_xla_start(event, value, **kw) -> None:
+    """JAX records a timed region's name as a scalar when the region
+    OPENS (`dispatch.LogElapsedTimeContextManager.__enter__`): the span's
+    start is read here, on the span clock."""
+    if event in _XLA_SPANS:
+        _xla_open().append((event, time.perf_counter_ns()))
+        _xla_tls.hit = False
+
+
+def _on_xla_event(event, **kw) -> None:
+    # fires inside the compile region, before its duration
+    if event == _CACHE_HIT:
+        _xla_tls.hit = True
+
+
+def _on_xla_duration(event, duration, **kw) -> None:
+    name = _XLA_SPANS.get(event)
+    if name is None:
+        return
+    end = time.perf_counter_ns()
+    st = _xla_open()
+    if st and st[-1][0] == event:
+        start = st.pop()[1]
+    else:       # the region opened before this listener was registered
+        start = end - int(duration * 1e9)
+    if st:
+        # inside another region of this thread: an inner jit's trace in
+        # its caller's, a lowering rule's traces in the lowering (a
+        # ResNet-50 `init()` makes 800 such). The outer span holds them.
+        return
+    attrs = {"fun_name": kw.get("fun_name")}
+    if name == "xla.compile":
+        fetched = attrs["fetched"] = bool(getattr(_xla_tls, "hit", False))
+        _xla_tls.hit = False
+        from deeplearning4j_tpu.observe.registry import get_registry
+        reg = get_registry()
+        reg.counter("xla_compiles_total",
+                    fetched="true" if fetched else "false").inc()
+        reg.histogram("xla_compile_ms").observe((end - start) / 1e6)
+    emit_manual_span(name, start, end, **attrs)
+
+
+def listen_for_compiles() -> None:
+    """Register the three callbacks above with `jax.monitoring`, once a
+    process and only while span recording is on (`DL4J_TPU_FLIGHT=0`
+    registers nothing). They run on compiles only, never per step. JAX is
+    imported here, not at this package's import."""
+    global _xla_listening
+    if _xla_listening or not recording_enabled():
+        return
+    with _lock:
+        if not _xla_listening:
+            import jax.monitoring as mon
+
+            mon.register_scalar_listener(_on_xla_start)
+            mon.register_event_listener(_on_xla_event)
+            mon.register_event_duration_secs_listener(_on_xla_duration)
+            _xla_listening = True
+
+
 # ------------------------------------------------------------ process-wide
 _default_watchdog = RecompileWatchdog()
-_lock = threading.Lock()
 
 
 def get_watchdog() -> RecompileWatchdog:

@@ -12,14 +12,17 @@ pure-XLA path elsewhere (mirroring the reference's helper-or-builtin
 dispatch, `ConvolutionLayer.java:67-77`).
 """
 
-from deeplearning4j_tpu.ops.lstm import fused_lstm, fused_lstm_available
-from deeplearning4j_tpu.ops.attention import flash_attention
-from deeplearning4j_tpu.ops.banded_attention import (
-    banded_attention,
-    banded_decode_attention,
-    banded_eligible,
-    decode_eligible,
-)
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.ops"):
+    from deeplearning4j_tpu.ops.lstm import fused_lstm, fused_lstm_available
+    from deeplearning4j_tpu.ops.attention import flash_attention
+    from deeplearning4j_tpu.ops.banded_attention import (
+        banded_attention,
+        banded_decode_attention,
+        banded_eligible,
+        decode_eligible,
+    )
 
 __all__ = [
     "fused_lstm",

@@ -31,9 +31,6 @@ from __future__ import annotations
 from typing import Any, Callable, List, Optional
 
 from deeplearning4j_tpu.observe import get_registry, reqtrace, span
-from deeplearning4j_tpu.observe.attribution import (
-    StepAttribution, attribution_enabled,
-)
 from deeplearning4j_tpu.observe.trace import flush_span_log, get_span_store
 from deeplearning4j_tpu.observe.commsmon import get_reshard_witness
 from deeplearning4j_tpu.observe.devicemon import maybe_start_monitor
@@ -160,9 +157,9 @@ class TrainingExecutor:
     (the wait for the next batch; the prefetch iterator's `data.put`
     spans are its children), `fit.dispatch`, `fit.listeners`, and once an
     epoch `fit.epoch_sync`. The `train_etl_ms` / `train_dispatch_ms`
-    histograms take their values from those spans' own clock reads;
-    `StepAttribution` and the sampled `train.epoch` request trace read
-    the span store when an epoch ends.
+    histograms take their values from those spans' own clock reads; the
+    sampled `train.epoch` request trace reads the span store when an
+    epoch ends.
 
     Hooks:
       step(ds) -> loss                one training step (device loss)
@@ -236,11 +233,6 @@ class TrainingExecutor:
         # recording on, so a crash dump carries this run from the start
         flight = get_flight()
         maybe_start_monitor()
-        attr = None
-        if attribution_enabled() and hasattr(net, "_loss_tracker"):
-            # PerformanceListener reads the inferred device step time
-            # (MFU denominator) from here
-            attr = net._attribution = StepAttribution(reg)
         try:
             with span("fit", epochs=epochs, start_epoch=start_epoch,
                       steps_per_dispatch=self.k):
@@ -254,7 +246,7 @@ class TrainingExecutor:
                     self._rt = reqtrace.new_trace("train.epoch")
                     self._rt_from = get_span_store().count
                     with span("fit.epoch", epoch=ep):
-                        self._run_epoch(iterable, attr)
+                        self._run_epoch(iterable)
                     if self.stopped:
                         self._finish_epoch_trace(ep, stopped=True)
                         break
@@ -273,7 +265,7 @@ class TrainingExecutor:
             flush_span_log()
         return net
 
-    def _run_epoch(self, iterable, attr) -> None:
+    def _run_epoch(self, iterable) -> None:
         net = self.net
         listeners = net.listeners
         if self.epoch_start is not None:
@@ -325,14 +317,11 @@ class TrainingExecutor:
         if self.epoch_end is not None:
             self.epoch_end()
         # the ONE guaranteed materialization per epoch: score_ is a float
-        # at every epoch boundary without per-step syncs. Its span is the
-        # block boundary attribution infers device time from.
-        sync = span("fit.epoch_sync")
-        with sync:
+        # at every epoch boundary without per-step syncs. Its span is how
+        # long the device took to drain what the host had queued.
+        with span("fit.epoch_sync"):
             net._loss_tracker.materialize()
         _publish_routing_counters(net)
-        if attr is not None:
-            attr.close_window(sync.start_ns, sync.end_ns)
 
     # ---------------------------------------------------------- helpers
     def _finish_epoch_trace(self, epoch: int, **attrs) -> None:

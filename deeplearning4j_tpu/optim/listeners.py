@@ -112,7 +112,6 @@ class PerformanceListener(TrainingListener):
         self.peak_flops = peak_flops
         self.last_mfu: Optional[float] = None
         self.last_step_ms: Optional[float] = None
-        self.last_device_step_ms: Optional[float] = None
         self.last_syncs_per_step: Optional[float] = None
         from deeplearning4j_tpu.observe import get_registry
 
@@ -141,30 +140,18 @@ class PerformanceListener(TrainingListener):
             self.last_step_ms = dt / n_batches * 1e3
             self._g_sps.set(self.last_samples_per_sec)
             self._g_step_ms.set(self.last_step_ms)
-            # measured device step time from the attribution window (the
-            # executor parks its StepAttribution on the model) — absent
-            # until a window has closed or when attribution is off
-            attr = getattr(model, "_attribution", None)
-            dev_ms = (attr.last_device_step_ms()
-                      if attr is not None else None)
-            self.last_device_step_ms = dev_ms
             msg = (f"iteration {iteration}: "
                    f"{self.last_samples_per_sec:.1f} samples/sec, "
                    f"{self.last_batches_per_sec:.2f} batches/sec, "
                    f"{self.last_step_ms:.1f} ms/step, "
                    f"ETL {self.last_etl_ms:.1f} ms")
-            if dev_ms:
-                msg += f", device {dev_ms:.2f} ms/step"
             if self.flops_per_step and self.peak_flops:
-                # MFU over MEASURED device time when attribution has it
-                # (wall time charges the device for host stalls); wall
-                # step time is the fallback denominator
-                step_s = dev_ms / 1e3 if dev_ms else dt / n_batches
-                self.last_mfu = (self.flops_per_step / step_s
+                # over the wall step time: a host stall reads as a slower
+                # device (a device trace gives the step itself)
+                self.last_mfu = (self.flops_per_step / (dt / n_batches)
                                  / self.peak_flops)
                 self._g_mfu.set(self.last_mfu)
-                msg += (f", MFU {self.last_mfu:.1%}"
-                        + (" (device)" if dev_ms else ""))
+                msg += f", MFU {self.last_mfu:.1%}"
             from deeplearning4j_tpu.observe import current_monitor
 
             mon = current_monitor()

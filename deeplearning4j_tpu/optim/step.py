@@ -3,7 +3,8 @@
 `MultiLayerNetwork`, `ComputationGraph` and `ParallelWrapper` hand this
 module their loss and their updaters and get back the pure step
 (`make_train_step`), its K-step `lax.scan` window (`make_fused_step`) and
-the donating jit of either (`jit_step`). `optim/executor.py` drives what
+the donating jit of either (`jit_step`); `build_step` is the three in a
+trainer's order, as one span `step.build`. `optim/executor.py` drives what
 comes out. The models keep what is theirs: the forward pass, the loss, and
 turning a batch into the step's arguments.
 
@@ -18,11 +19,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.observe import donatemon
+from deeplearning4j_tpu.observe import donatemon, span
 from deeplearning4j_tpu.utils.pytrees import tree_norm
 
 __all__ = ["make_train_step", "make_fused_step", "stack_step_args",
-           "jit_step", "normalize_grads", "as_features"]
+           "jit_step", "build_step", "normalize_grads", "as_features"]
 
 _tmap = jax.tree_util.tree_map
 
@@ -171,3 +172,16 @@ def jit_step(fn, *, cache, key, name, in_shardings=None, out_shardings=None):
     # local lets the FIRST dispatch (often the only one in a short fit)
     # bypass the ledger
     return cache[key]
+
+
+def build_step(make_step_fn, *, fused: bool = False, name, **jit_kw):
+    """What a trainer does for a step its `_jit_cache` lacks, as the span
+    `step.build`: the pure step from the net's `make_step_fn` (so
+    `make_train_step`), its K-step window where `fused`, and `jit_step`
+    with `jit_kw`. Host work only: nothing is traced or compiled before
+    the step's first call, whose `xla.trace`, `xla.lower` and
+    `xla.compile` spans follow this one."""
+    with span("step.build", step=name, fused=fused):
+        fn = make_step_fn()
+        return jit_step(make_fused_step(fn) if fused else fn, name=name,
+                        **jit_kw)

@@ -13,38 +13,41 @@ tensor/sequence parallelism as extra mesh axes, ring attention for long
 context, multi-host DCN initialization.
 """
 
-from deeplearning4j_tpu.parallel.mesh import (
-    MeshContext, MeshSpec, current_mesh_context, device_count,
-    local_device_count, make_mesh, set_mesh_context, use_mesh_context,
-)
-from deeplearning4j_tpu.parallel.data_parallel import ParallelWrapper
-from deeplearning4j_tpu.parallel.sharding import (
-    ShardingRules, shard_params, replicate, batch_sharding,
-    fsdp_rules, tensor_parallel_rules,
-)
-from deeplearning4j_tpu.parallel.inference import ParallelInference
-from deeplearning4j_tpu.parallel.distributed import initialize_distributed
-from deeplearning4j_tpu.parallel.pipeline import (
-    PipelineParallel, PipelinedNetwork, make_pipeline_fn,
-    make_pipeline_1f1b_fn, partition_for_pipeline, stack_stage_params,
-    split_microbatches,
-)
-from deeplearning4j_tpu.parallel.moe import (
-    MoEFeedForward, moe_ffn, top_k_gating, expert_sharding, expert_mesh,
-)
-from deeplearning4j_tpu.parallel.training_master import (
-    TrainingMaster, ParameterAveragingTrainingMaster,
-    DistributedTrainingMaster, PhaseStats, distributed_evaluate,
-    export_timeline_html,
-)
-from deeplearning4j_tpu.parallel.estimator import NetworkEstimator
-from deeplearning4j_tpu.parallel.checkpoint import ShardedCheckpointer
-from deeplearning4j_tpu.parallel.elastic import ElasticTrainer, PreemptionHandler
-from deeplearning4j_tpu.parallel.async_ps import AsyncParameterServer, AsyncTrainer
-from deeplearning4j_tpu.parallel.chaos import (
-    CheckpointIOFault, FailingIterator, InjectedFault, SigtermAtStep,
-    StallingIterator,
-)
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.parallel"):
+    from deeplearning4j_tpu.parallel.mesh import (
+        MeshContext, MeshSpec, current_mesh_context, device_count,
+        local_device_count, make_mesh, set_mesh_context, use_mesh_context,
+    )
+    from deeplearning4j_tpu.parallel.data_parallel import ParallelWrapper
+    from deeplearning4j_tpu.parallel.sharding import (
+        ShardingRules, shard_params, replicate, batch_sharding,
+        fsdp_rules, tensor_parallel_rules,
+    )
+    from deeplearning4j_tpu.parallel.inference import ParallelInference
+    from deeplearning4j_tpu.parallel.distributed import initialize_distributed
+    from deeplearning4j_tpu.parallel.pipeline import (
+        PipelineParallel, PipelinedNetwork, make_pipeline_fn,
+        make_pipeline_1f1b_fn, partition_for_pipeline, stack_stage_params,
+        split_microbatches,
+    )
+    from deeplearning4j_tpu.parallel.moe import (
+        MoEFeedForward, moe_ffn, top_k_gating, expert_sharding, expert_mesh,
+    )
+    from deeplearning4j_tpu.parallel.training_master import (
+        TrainingMaster, ParameterAveragingTrainingMaster,
+        DistributedTrainingMaster, PhaseStats, distributed_evaluate,
+        export_timeline_html,
+    )
+    from deeplearning4j_tpu.parallel.estimator import NetworkEstimator
+    from deeplearning4j_tpu.parallel.checkpoint import ShardedCheckpointer
+    from deeplearning4j_tpu.parallel.elastic import ElasticTrainer, PreemptionHandler
+    from deeplearning4j_tpu.parallel.async_ps import AsyncParameterServer, AsyncTrainer
+    from deeplearning4j_tpu.parallel.chaos import (
+        CheckpointIOFault, FailingIterator, InjectedFault, SigtermAtStep,
+        StallingIterator,
+    )
 
 __all__ = [
     "ShardedCheckpointer", "ElasticTrainer", "PreemptionHandler",

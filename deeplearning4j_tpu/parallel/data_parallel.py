@@ -29,7 +29,8 @@ from deeplearning4j_tpu.data.iterators import (
 )
 from deeplearning4j_tpu.optim.executor import TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import RecoveryPlan, run_with_recovery
-from deeplearning4j_tpu.optim.step import jit_step, make_fused_step
+from deeplearning4j_tpu.observe.trace import span
+from deeplearning4j_tpu.optim.step import build_step
 from deeplearning4j_tpu.parallel.distributed import (
     put_global, put_global_batch,
 )
@@ -74,36 +75,39 @@ class ParallelWrapper(SeqCtxJitCache):
                 f"optimization_algo={net.conf.optimization_algo!r} is a "
                 "full-batch single-device solver — fit the model directly")
         self.net = net
-        if spine is None:
-            spine = MeshContext(
-                mesh if mesh is not None else make_mesh(),
-                param_rules, batch_axis=batch_axis,
-                shard_opt_state=shard_opt_state)
-        self.spine = spine
-        self.mesh = spine.mesh
-        self.batch_axis = spine.batch_axis
-        self.param_rules = spine.rules
-        self.prefetch = prefetch_buffer
-        self.last_batch_index = -1   # in-epoch position (elastic resume)
-        self.stopped_early = False   # did the last fit() stop via stop_fn?
+        # mesh, shardings and the placement of the net's state on them
+        with span("wrapper.init", wrapper=type(self).__name__):
+            if spine is None:
+                spine = MeshContext(
+                    mesh if mesh is not None else make_mesh(),
+                    param_rules, batch_axis=batch_axis,
+                    shard_opt_state=shard_opt_state)
+            self.spine = spine
+            self.mesh = spine.mesh
+            self.batch_axis = spine.batch_axis
+            self.param_rules = spine.rules
+            self.prefetch = prefetch_buffer
+            self.last_batch_index = -1  # in-epoch position (elastic resume)
+            self.stopped_early = False  # did the last fit() stop via stop_fn?
 
-        self.data_size = spine.data_size
-        # Multi-controller: each process feeds a host-LOCAL slice of every
-        # batch; padding must make the local slice divide the local devices.
-        self._nproc = jax.process_count()
-        self._local_divisor = max(1, self.data_size // self._nproc)
+            self.data_size = spine.data_size
+            # Multi-controller: each process feeds a host-LOCAL slice of
+            # every batch; padding must make the local slice divide the
+            # local devices.
+            self._nproc = jax.process_count()
+            self._local_divisor = max(1, self.data_size // self._nproc)
 
-        self._rep = spine.replicated
-        self._params_sh = spine.param_shardings(net.params_tree)
-        self._opt_sh = spine.opt_shardings(
-            net.updater_state, self._moment_keys())
-        net.params_tree = jax.tree_util.tree_map(
-            put_global, net.params_tree, self._params_sh)
-        net.updater_state = jax.tree_util.tree_map(
-            put_global, net.updater_state, self._opt_sh)
-        if net.state_tree:
-            net.state_tree = jax.tree_util.tree_map(
-                lambda x: put_global(x, self._rep), net.state_tree)
+            self._rep = spine.replicated
+            self._params_sh = spine.param_shardings(net.params_tree)
+            self._opt_sh = spine.opt_shardings(
+                net.updater_state, self._moment_keys())
+            net.params_tree = jax.tree_util.tree_map(
+                put_global, net.params_tree, self._params_sh)
+            net.updater_state = jax.tree_util.tree_map(
+                put_global, net.updater_state, self._opt_sh)
+            if net.state_tree:
+                net.state_tree = jax.tree_util.tree_map(
+                    lambda x: put_global(x, self._rep), net.state_tree)
 
     # ------------------------------------------------------- shardings
     def _moment_keys(self):
@@ -138,9 +142,9 @@ class ParallelWrapper(SeqCtxJitCache):
         # re-replicating (the regression the perf gate's
         # opt_state_shard_factor budget exists to catch).
         out_sh = (self._params_sh, self._opt_sh, self._rep, self._rep)
-        return jit_step(self.net.make_step_fn(), cache=self._jit_cache,
-                        key=key, name="ParallelWrapper._step",
-                        in_shardings=in_sh, out_shardings=out_sh)
+        return build_step(self.net.make_step_fn, cache=self._jit_cache,
+                          key=key, name="ParallelWrapper._step",
+                          in_shardings=in_sh, out_shardings=out_sh)
 
     # -------------------------------------------------------------- fit
     def _pad_to_divisible(self, ds):
@@ -324,10 +328,10 @@ class ParallelWrapper(SeqCtxJitCache):
         # (params, opt, states, rng, losses)
         out_sh = (self._params_sh, self._opt_sh, self._rep, self._rep,
                   self._rep)
-        return jit_step(make_fused_step(self.net.make_step_fn()),
-                        cache=self._jit_cache, key=key,
-                        name="ParallelWrapper._fused_step",
-                        in_shardings=in_sh, out_shardings=out_sh)
+        return build_step(self.net.make_step_fn, fused=True,
+                          cache=self._jit_cache, key=key,
+                          name="ParallelWrapper._fused_step",
+                          in_shardings=in_sh, out_shardings=out_sh)
 
     def _fused_step(self, batches):
         """K pre-sharded batches → one sharded `lax.scan` dispatch."""

@@ -9,17 +9,20 @@ remote stats router/receiver
 `ui/module/remote/RemoteReceiverModule.java`).
 """
 
-from deeplearning4j_tpu.ui.storage import (
-    FileStatsStorage, InMemoryStatsStorage, Persistable, StatsStorage,
-    StatsStorageEvent, StatsStorageRouter,
-)
-from deeplearning4j_tpu.ui.stats import StatsListener
-from deeplearning4j_tpu.ui.server import RemoteStatsRouter, UIServer
-from deeplearning4j_tpu.ui.components import (
-    ChartHistogram, ChartHorizontalBar, ChartLine, ChartScatter,
-    ChartStackedArea, ChartTimeline, Component, ComponentDiv,
-    ComponentTable, ComponentText, DecoratorAccordion, Style,
-)
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.ui"):
+    from deeplearning4j_tpu.ui.storage import (
+        FileStatsStorage, InMemoryStatsStorage, Persistable, StatsStorage,
+        StatsStorageEvent, StatsStorageRouter,
+    )
+    from deeplearning4j_tpu.ui.stats import StatsListener
+    from deeplearning4j_tpu.ui.server import RemoteStatsRouter, UIServer
+    from deeplearning4j_tpu.ui.components import (
+        ChartHistogram, ChartHorizontalBar, ChartLine, ChartScatter,
+        ChartStackedArea, ChartTimeline, Component, ComponentDiv,
+        ComponentTable, ComponentText, DecoratorAccordion, Style,
+    )
 
 __all__ = [
     "FileStatsStorage", "InMemoryStatsStorage", "Persistable",

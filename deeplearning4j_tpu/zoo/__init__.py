@@ -12,23 +12,26 @@ environment has no egress, so absent files raise with the expected path
 instead of downloading.
 """
 
-from deeplearning4j_tpu.zoo.base import ZooModel, ZOO_REGISTRY
-from deeplearning4j_tpu.zoo.models import (
-    LeNet, AlexNet, SimpleCNN, VGG16, VGG19, TextGenerationLSTM,
-)
-from deeplearning4j_tpu.zoo.resnet import ResNet50
-from deeplearning4j_tpu.zoo.inception import (
-    GoogLeNet, InceptionResNetV1, FaceNetNN4Small2,
-)
-from deeplearning4j_tpu.zoo.transformer import (
-    HybridLinearSparseTransformer, SparseSandwichTransformer,
-    TextGenerationTransformer,
-)
-from deeplearning4j_tpu.zoo.pretrained import (
-    PRETRAINED_CATALOG, PretrainedType, fetch_pretrained, load_pretrained,
-    sniff_format,
-)
-from deeplearning4j_tpu.zoo.imagenet import ImageNetLabels
+from deeplearning4j_tpu.observe.trace import span as _span
+
+with _span("import.zoo"):
+    from deeplearning4j_tpu.zoo.base import ZooModel, ZOO_REGISTRY
+    from deeplearning4j_tpu.zoo.models import (
+        LeNet, AlexNet, SimpleCNN, VGG16, VGG19, TextGenerationLSTM,
+    )
+    from deeplearning4j_tpu.zoo.resnet import ResNet50
+    from deeplearning4j_tpu.zoo.inception import (
+        GoogLeNet, InceptionResNetV1, FaceNetNN4Small2,
+    )
+    from deeplearning4j_tpu.zoo.transformer import (
+        HybridLinearSparseTransformer, SparseSandwichTransformer,
+        TextGenerationTransformer,
+    )
+    from deeplearning4j_tpu.zoo.pretrained import (
+        PRETRAINED_CATALOG, PretrainedType, fetch_pretrained, load_pretrained,
+        sniff_format,
+    )
+    from deeplearning4j_tpu.zoo.imagenet import ImageNetLabels
 
 __all__ = [
     "PRETRAINED_CATALOG", "PretrainedType", "fetch_pretrained",

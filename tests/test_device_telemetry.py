@@ -2,8 +2,8 @@
 (CPU memory_stats() is None), HBM warn-once via fake devices,
 FlightRecorder ring eviction + crash dumps (valid JSON with the
 triggering exception and a device-memory sample), the /devices and
-/flight serving endpoints, the compile-cost probe at the jit-cache
-seam, and step-time attribution end-to-end through a real fit().
+/flight serving endpoints, and the compile-cost probe at the jit-cache
+seam.
 """
 
 import json
@@ -16,7 +16,7 @@ import pytest
 
 from deeplearning4j_tpu.observe import (
     DeviceMonitor, FlightRecorder, MetricsRegistry, RecompileWatchdog,
-    StepAttribution, get_flight, set_flight, set_registry, set_watchdog,
+    get_flight, set_flight, set_registry, set_watchdog,
 )
 from deeplearning4j_tpu.observe.devicemon import (
     device_memory_summary, maybe_start_monitor, set_device_monitor,
@@ -405,83 +405,3 @@ class TestCompileCostProbe:
         got = cache.setdefault("k", fn)
         assert isinstance(got, _CostProbe)
         assert cache.setdefault("k", None) is got
-
-
-# ---------------------------------------------------------- attribution
-class TestStepAttribution:
-    def test_window_math_and_zero_step_skip(self, fresh_registry):
-        # the reader sums the window's spans out of a store: two steps of
-        # etl 1 ms, dispatch 2 ms, listeners 3 ms, then a 10 ms sync
-        import threading
-        from deeplearning4j_tpu.observe.trace import SpanStore
-
-        store, me, ms = SpanStore(64), threading.current_thread().name, 10**6
-        attr = StepAttribution(fresh_registry, store)
-        t = attr._t0
-        for _ in range(2):
-            for name, dur in (("fit.etl", 1), ("fit.dispatch", 2),
-                              ("fit.listeners", 3)):
-                store.add((1, None, name, t, t + dur * ms, me,
-                           {"steps": 1} if name == "fit.dispatch" else {}))
-                t += dur * ms
-        store.add((1, None, "fit.etl", t, t, "another-thread", {}))
-        attr.close_window(t, t + 10 * ms)
-        assert attr.windows == 1
-        dev = attr.last_device_step_ms()
-        # min(block + dispatch + host, wall - etl) = min(20, 22 - 2) over 2
-        assert dev == pytest.approx(10.0, abs=0.5)
-        assert fresh_registry.histogram(
-            "train_step_attribution_ms", segment="host").sum == \
-            pytest.approx(6.0)
-        # a second sync with no steps since must not close a window
-        attr.close_window(t + 10 * ms, t + 15 * ms)
-        assert attr.windows == 1
-
-    def test_fit_publishes_attribution_metrics(self, fresh_registry,
-                                               fresh_flight):
-        net = _net()
-        x, y = _data()
-        net.fit(x, y, epochs=2, batch_size=16)
-        attr = getattr(net, "_attribution", None)
-        assert attr is not None
-        # epoch-end materialization closes >=1 window on a device loss
-        assert attr.windows >= 1
-        assert attr.last_device_step_ms() is not None
-        series = fresh_registry.snapshot()["series"]
-        assert "train_device_step_ms" in series
-        segs = {m["labels"]["segment"]
-                for m in series["train_step_attribution_ms"]}
-        assert segs == {"etl", "dispatch", "host", "device"}
-        # the block boundary it reads reached the flight ring: one
-        # fit.epoch_sync span an epoch
-        assert sum(e["kind"] == "span"
-                   and e["data"].get("name") == "fit.epoch_sync"
-                   for e in fresh_flight.events()) == 2
-
-    def test_attribution_env_kill_switch(self, fresh_registry,
-                                         monkeypatch):
-        monkeypatch.setenv("DL4J_TPU_ATTRIBUTION", "0")
-        net = _net()
-        x, y = _data(n=32)
-        net.fit(x, y, epochs=1, batch_size=16)
-        assert getattr(net, "_attribution", None) is None
-        series = fresh_registry.snapshot()["series"]
-        assert "train_step_attribution_ms" not in series
-
-    def test_performance_listener_reports_device_time(self,
-                                                      fresh_registry):
-        from deeplearning4j_tpu.optim.listeners import (
-            PerformanceListener,
-        )
-
-        msgs = []
-        pl = PerformanceListener(frequency=2, report=msgs.append,
-                                 flops_per_step=1e6, peak_flops=1e12)
-        net = _net()
-        net.set_listeners(pl)
-        x, y = _data(n=96)
-        net.fit(x, y, epochs=3, batch_size=16)
-        assert pl.last_mfu is not None and pl.last_mfu > 0
-        # after the first epoch boundary, reports carry measured device
-        # time and MFU switches to the device denominator
-        assert any("device" in m and "MFU" in m for m in msgs)
