@@ -22,7 +22,7 @@ a register for eight values, were broadcast across lanes at every use, and
 cost the forward more than its two matrix products did. l's lanes gather
 the 128-lane groups of the weights and are summed across lanes once, at
 the sweep's end. Each forward picks its own tile from the policy's blocks
-(`_fwd_tile`: as many rows as a 3 MiB score tile allows over 512 keys,
+(`_pick_tile`: as many rows as a 3 MiB score tile allows over 512 keys,
 where a band's or a causal edge does not make that dearer). On a v5e, bf16,
 one call alone (PR 36, PERF.md section 6): this kernel over 48 query heads
 of 128 on 8 KV heads at 8,192 tokens 13.7 to 6.7 ms (tile 1,024 x 512),
@@ -41,6 +41,24 @@ fallback/oracle path. The default (`backward=None`) resolves from the
 measured-winner table in `ops/kernel_defaults.py` — see that module for
 the dispatch policy and its env escape hatches.
 
+The backward's tile is written once, here, for the six backward kernels
+of the three families (`_bwd_scores`, `_dq_step`, `_dkdv_step`). dQ
+computes it query-major, [rows, keys], and reads the log-sum-exp and Δ
+as the [rows, 128] operands they are; dK/dV computes it key-major,
+[keys, rows] from k·qᵀ and v·doᵀ, so that p and ds are born in the
+orientation dV += p·do and dK += ds·q contract over and nothing is
+transposed a tile, with the two statistics as [1, rows] rows. A tile on
+which every pair is visible builds no mask (`_on_tiles`), a dead step of
+the causal grid names the nearest live tile again and fetches nothing,
+and each kernel picks its own tile by the forward's rule with its own
+limits (`_pick_tile`, `_TILE_COST`). On a v5e, bf16, one call alone at
+the shapes above (PR 39, PERF.md section 6; host clock, delta and the
+statistics' layouts included): dQ / dK/dV of this family 13.2 / 17.4 to
+9.6 / 10.5 ms (tile 1,024 x 1,024 where the policy's 512 x 512 was the
+tile), the banded pair 9.9 / 16.5 to 7.7 / 8.9 (1,536 x 512 from 768 x
+256), the sparse pair 41.4 / 55.1 to 31.1 / 35.5 (1,024 x 512 from
+256 x 512).
+
 Under gradient checkpointing: the forward rules of this kernel and of
 `ops/banded_attention.py`'s name the kernel's own output and the one-lane
 L (`name_residuals`: the checkpoint names `attention_out` and
@@ -56,7 +74,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -235,12 +253,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
                     precision=prec) * scale
         if causal:
-            q_ids = (qb * bq
-                     + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0))
-            k_ids = (kb * block_k
-                     + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1))
             # key 0 is live for every row, so the bias alone will do
-            s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
+            s = jnp.where(_causal_mask(qb, kb, bq, block_k, False), s,
+                          _NEG_INF)
         _softmax_update(s, None, v_ref[0], acc_scr, m_scr, l_scr, prec)
 
     @pl.when(kb == nk - 1)
@@ -274,47 +289,67 @@ def _group(q, k) -> int:
     return q.shape[0] // k.shape[0]
 
 
-_FWD_ROWS = 2048            # rows of a forward tile at most, a folded group's too
-_FWD_KEYS = 512             # keys one update of the statistics covers at most
-_FWD_TILE_BYTES = 3 << 20   # a float32 score tile at most: 1,536 x 512
-# What a forward tile costs beside its pairs, in pairs (the kernels alone
-# on a v5e over tiles of 768 and 1,536 rows by 256 and 512 keys, PR 36:
-# PERF.md section 6): an update of a row's statistics as much as
-# `_UPDATE_KEYS` more keys of the row, a grid step as `_STEP_PAIRS` pairs.
-_UPDATE_KEYS = 80
-_STEP_PAIRS = 170_000
+class _TileCost(NamedTuple):
+    """What `_pick_tile` holds a kernel's tile to, and what the tile costs
+    beside its pairs, in pairs."""
+
+    rows: int          # rows of a tile at most, a folded group's too
+    keys: int          # keys of a tile at most
+    tile_bytes: int    # a float32 [rows, keys] tile at most
+    update_keys: int   # a row's bookkeeping a tile, as so many more keys
+    step_pairs: int    # a grid step, live or dead, as so many pairs
 
 
-def _fwd_tile(op: str, block_q: int, block_k: int, *, fold: int = 1,
-              interpret: bool = False, legal, tiles):
-    """(block_q, block_k) of a forward kernel, from the blocks the policy
-    passed (fitted to the sequence already), the `fold` query heads a
-    tile's rows hold of each token, and what the family says of a tile:
-    `legal(block_q, block_k)` (whole tiles, its own conditions) and
-    `tiles(block_q, block_k)`, how many tiles a head's grid computes.
+# The forward (the kernels alone on a v5e over tiles of 768 and 1,536 rows
+# by 256 and 512 keys, PR 36: PERF.md section 6): 512 keys one update of
+# the statistics covers at most, a float32 score tile of 1,536 x 512; an
+# update of a row's statistics costs as much as 80 more keys of the row, a
+# grid step as 170,000 pairs. The backward kernels (the six alone over
+# nine tiles each, PR 39: PERF.md section 6) update nothing a row, so a
+# sweep of 1,024 keys costs them nothing beside its pairs; a grid step
+# costs dQ as 65,000 to 69,000 pairs and dK/dV as 45,000 to 51,000, and a
+# float32 tile of 1,024 x 1,024 is the largest that paid.
+_TILE_COST = {
+    "fwd": _TileCost(2048, 512, 3 << 20, 80, 170_000),
+    "bwd": _TileCost(2048, 1024, 4 << 20, 0, 60_000),
+}
 
-    The candidates are the passed blocks doubled any number of times, the
-    K block (the sweep of keys one update of the softmax statistics
-    covers) to `_FWD_KEYS` at most, the float32 score tile to
-    `_FWD_TILE_BYTES`; a Q block whose tile alone passes that is halved
-    first, down to 128 rows a head, as `banded_attention._bwd_block_q`
-    does for the backward. Of them the cheapest is taken, a tile costing
-    its pairs, `_UPDATE_KEYS` keys a row for the update and
-    `_STEP_PAIRS` for the grid step: so tiles grow until what they
-    compute past a band's or a causal edge outweighs the steps and
-    updates they save, and a narrow band keeps narrow tiles. In interpret
-    mode there is no step to save and the passed blocks are the tile
-    (any divisor of the sequence: the tests' odd shapes). Sets the gauge
-    `attention_fwd_tile{op=, field=rows|keys_per_update}` (trace time,
-    host side)."""
-    fits = lambda bq, bk: (fold * bq <= max(_FWD_ROWS, fold * block_q)
-                           and fold * bq * bk * 4 <= _FWD_TILE_BYTES)
+
+def _pick_tile(op: str, kernel: str, block_q: int, block_k: int, *,
+               fold: int = 1, interpret: bool = False, legal, tiles,
+               steps=None):
+    """(block_q, block_k) of the kernel `kernel` ("fwd", "dq" or "dkdv")
+    of the family `op`, from the blocks the policy passed (fitted to the
+    sequence already), the `fold` query heads a tile's rows hold of each
+    token, and what the family says of a tile: `legal(block_q, block_k)`
+    (whole tiles, its own conditions), `tiles(block_q, block_k)`, how many
+    tiles a head's grid computes, and `steps(block_q, block_k)`, how many
+    steps that grid has, dead ones too (a grid is a rectangle; the
+    forward's rule was fitted without them and leaves them out).
+
+    The candidates are the passed blocks doubled any number of times, up
+    to the kernel's `_TILE_COST`: its most keys, its most rows and its
+    largest float32 [rows, keys] tile; a Q block whose tile alone passes
+    that is halved first, down to 128 rows a head. Of them the cheapest is
+    taken, a tile costing its pairs, `update_keys` keys a row and
+    `step_pairs` for each grid step: so tiles grow until what they compute
+    past a band's or a causal edge outweighs the steps (and the forward's
+    updates) they save, and a narrow band keeps narrow tiles. In interpret
+    mode there is no step to save and the passed blocks are the tile (any
+    divisor of the sequence: the tests' odd shapes). Sets the gauge
+    `attention_fwd_tile{op=, field=rows|keys_per_update}` or
+    `attention_bwd_tile{op=, kernel=dq|dkdv, field=rows|keys}` (trace
+    time, host side)."""
+    limit = _TILE_COST["fwd" if kernel == "fwd" else "bwd"]
+    steps = steps or tiles
+    fits = lambda bq, bk: (fold * bq <= max(limit.rows, fold * block_q)
+                           and fold * bq * bk * 4 <= limit.tile_bytes)
     while not fits(block_q, block_k) and block_q > 128 and not block_q % 2:
         block_q //= 2
 
     def cost(bq, bk):
-        return tiles(bq, bk) * (fold * bq * (bk + _UPDATE_KEYS)
-                                + _STEP_PAIRS)
+        return (tiles(bq, bk) * fold * bq * (bk + limit.update_keys)
+                + steps(bq, bk) * limit.step_pairs)
 
     def doubled(block, ok):
         out = [block]
@@ -326,16 +361,37 @@ def _fwd_tile(op: str, block_q: int, block_k: int, *, fold: int = 1,
         ((bq, bk)
          for bq in doubled(block_q, lambda bq: fits(bq, block_k)
                            and legal(bq, block_k))
-         for bk in doubled(block_k, lambda bk: bk <= _FWD_KEYS
+         for bk in doubled(block_k, lambda bk: bk <= limit.keys
                            and fits(bq, bk) and legal(bq, bk))),
         key=lambda tile: cost(*tile))
     from deeplearning4j_tpu.observe import get_registry
 
-    gauge = functools.partial(get_registry().gauge, "attention_fwd_tile",
-                              op=op)
+    if kernel == "fwd":
+        gauge = functools.partial(get_registry().gauge,
+                                  "attention_fwd_tile", op=op)
+        gauge(field="keys_per_update").set(best[1])
+    else:
+        gauge = functools.partial(get_registry().gauge,
+                                  "attention_bwd_tile", op=op, kernel=kernel)
+        gauge(field="keys").set(best[1])
     gauge(field="rows").set(fold * best[0])
-    gauge(field="keys_per_update").set(best[1])
     return best
+
+
+def _publish_bwd_steps(op: str, kernel: str, heads: int, steps: int,
+                       live: int, interior: int):
+    """Gauge `attention_bwd_steps{op=, kernel=, kind=interior|edge|dead}`:
+    the grid a backward kernel builds for one call, from the geometry
+    (trace time, host side). `steps`, `live` and `interior` are one head's:
+    all steps of its grid, those that compute a tile, and those of them
+    whose tile is wholly visible and builds no mask."""
+    from deeplearning4j_tpu.observe import get_registry
+
+    gauge = functools.partial(get_registry().gauge, "attention_bwd_steps",
+                              op=op, kernel=kernel)
+    gauge(kind="interior").set(heads * interior)
+    gauge(kind="edge").set(heads * (live - interior))
+    gauge(kind="dead").set(heads * (steps - live))
 
 
 def _last_live(i, block_q: int, block_k: int):
@@ -349,16 +405,23 @@ def _causal_tiles(t: int, block_q: int, block_k: int) -> int:
                for i in range(t // block_q))
 
 
-def _fwd_params(rows: int, keys: int, d: int, itemsize: int):
-    """Compiler parameters of a forward kernel with a [rows, keys] tile:
-    the grid's first two dimensions carry no state (Mosaic may run them
-    in any order and pipeline them), the K sweep carries the scratch. A
-    wide tile's float32 temporaries (scores, weights, the mask's iotas)
-    outgrow the default scoped VMEM of 16 MiB, of the chip's 128: the
-    limit is raised to what the tile needs."""
-    need = (8 * rows * keys * 4                 # the tile's temporaries
-            + 2 * 2 * (rows + 2 * keys) * d * itemsize   # q, k, v twice
-            + 3 * rows * max(d, _LSE_LANES) * 4)         # the scratch
+def _tile_params(rows: int, keys: int, d: int, itemsize: int,
+                 backward: bool = False):
+    """Compiler parameters of a kernel with a [rows, keys] tile: the
+    grid's first two dimensions carry no state (Mosaic may run them in any
+    order and pipeline them), the sweep carries the scratch. A wide tile's
+    float32 temporaries (scores, weights, the mask's iotas; the backward's
+    dP and dS too) outgrow the default scoped VMEM of 16 MiB, of the
+    chip's 128: the limit is raised to what the tile needs."""
+    if backward:
+        need = (12 * rows * keys * 4            # s, p, dP, dS, mask, casts
+                + 2 * (3 * rows + 4 * keys) * d * itemsize  # q, do, k, v
+                + 2 * 2 * rows * _LSE_LANES * 4             # two statistics
+                + (rows + 2 * keys) * max(d, _LSE_LANES) * 4)   # scratch
+    else:
+        need = (8 * rows * keys * 4             # the tile's temporaries
+                + 2 * 2 * (rows + 2 * keys) * d * itemsize  # q, k, v twice
+                + 3 * rows * max(d, _LSE_LANES) * 4)        # the scratch
     limit = None if need <= (12 << 20) else min(need + (8 << 20), 100 << 20)
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -379,9 +442,9 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
         return (_causal_tiles(tq, bq, bk) if causal
                 else (tq // bq) * (tk // bk))
 
-    block_q, block_k = _fwd_tile(
-        "flash_attention", _fit_block(block_q, tq), _fit_block(block_k, tk),
-        interpret=interpret, tiles=tiles,
+    block_q, block_k = _pick_tile(
+        "flash_attention", "fwd", _fit_block(block_q, tq),
+        _fit_block(block_k, tk), interpret=interpret, tiles=tiles,
         legal=lambda bq, bk: tq % bq == 0 and tk % bk == 0)
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                with_lse=with_lse)
@@ -411,7 +474,7 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=tuple(out_shape) if with_lse else out_shape[0],
         scratch_shapes=_softmax_scratch(block_q, d),
-        compiler_params=_fwd_params(block_q, block_k, d, q.dtype.itemsize),
+        compiler_params=_tile_params(block_q, block_k, d, q.dtype.itemsize),
         interpret=interpret,
     )(q, k, v)
     if with_lse:
@@ -423,24 +486,97 @@ def _run_flash(q, k, v, *, causal: bool, scale: float, block_q: int,
 
 
 # ----------------------------------------------------- blockwise backward
-def _bwd_tile(q, k, v, do, lse_col, delta_col, qb, kb, bq, block_k, causal,
-              scale):
-    """Shared score-tile rematerialization for both backward kernels:
-    p = exp(s - L) row-wise, ds = p * (do·vᵀ - Δ) * scale."""
+_NT = (((1,), (1,)), ((), ()))    # both operands contract their last axis
+_STAT_ROWS = 8    # sublanes of a row statistic laid along lanes ([8, T])
+
+def _stat_lanes(x):
+    """[..., T] -> [..., T, 128]: a row statistic as the dQ kernels read
+    it, every lane of a row holding the row's value."""
+    return jnp.broadcast_to(x[..., None], x.shape + (_LSE_LANES,))
+
+
+def _stat_rows(x):
+    """[..., T] -> [..., 8, T]: a row statistic as the dK/dV kernels read
+    it, the rows along lanes (a key-major tile's columns are the rows)."""
+    return jnp.broadcast_to(x[..., None, :],
+                            x.shape[:-1] + (_STAT_ROWS, x.shape[-1]))
+
+
+def _bwd_scores(q, k, v, do, lse, delta, mask, scale: float,
+                key_major: bool):
+    """One score tile again from the saved log-sum-exp, the one every
+    backward kernel of the three families makes: p = exp(s - L) on the
+    pairs `mask` has (None: all of them, an interior tile), 0 on the
+    others, and ds = p * (do·vᵀ - Δ) * scale, both float32.
+
+    Query-major (dQ): [rows, keys], with `lse` and `delta` the [rows, 128]
+    operands they are, a lane wide. Key-major (dK/dV): [keys, rows] from
+    k·qᵀ and v·doᵀ, so that p and ds are born in the orientation that
+    dV += p·do and dK += ds·q contract over and nothing is transposed a
+    tile; `lse` and `delta` are then [1, rows], broadcast down sublanes."""
+    dot = functools.partial(
+        jax.lax.dot_general, dimension_numbers=_NT,
+        preferred_element_type=jnp.float32, precision=_prec(q.dtype))
+    if key_major:
+        s, dp = dot(k, q) * scale, dot(v, do)
+    else:
+        s, dp = dot(q, k) * scale, dot(do, v)
+        lse, delta = _lanes(lse, k.shape[0]), _lanes(delta, k.shape[0])
+    p = jnp.exp(s - lse)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    return p, p * (dp - delta) * scale
+
+
+def _dq_step(dq_scr, q, k, v, do, lse, delta, mask, scale: float):
+    """dQ += ds·k for one tile: q, do [rows, D], k, v [keys, D], `lse` and
+    `delta` [rows, 128], `mask` [rows, keys] or None."""
+    _, ds = _bwd_scores(q, k, v, do, lse, delta, mask, scale, False)
+    dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
+                         preferred_element_type=jnp.float32,
+                         precision=_prec(k.dtype))
+
+
+def _dkdv_step(dk_scr, dv_scr, q, k, v, do, lse, delta, mask, scale: float):
+    """dV += pᵀ·do and dK += dsᵀ·q for one tile, computed key-major:
+    `lse` and `delta` [1, rows], `mask` [keys, rows] or None."""
     prec = _prec(q.dtype)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                precision=prec) * scale
-    if causal:
-        q_ids = (qb * bq
-                 + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0))
-        k_ids = (kb * block_k
-                 + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1))
-        s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
-    p = jnp.exp(s - lse_col)                       # [Bq, Bk] f32
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32,
-                 precision=prec)
-    ds = p * (dp - delta_col) * scale
-    return p, ds
+    p, ds = _bwd_scores(q, k, v, do, lse, delta, mask, scale, True)
+    dv_scr[:] += jnp.dot(p.astype(do.dtype), do,
+                         preferred_element_type=jnp.float32, precision=prec)
+    dk_scr[:] += jnp.dot(ds.astype(q.dtype), q,
+                         preferred_element_type=jnp.float32, precision=prec)
+
+
+def _on_tiles(step, live=None, interior=None):
+    """Run `step(masked)` on a grid step's tile: not at all on a dead
+    tile, bare (`masked` False) on an interior one, where every pair is
+    visible, and with the mask built on an edge tile. `live` None: every
+    tile is interior (no mask exists); `interior` None: none is."""
+    if live is None:
+        step(False)
+    elif interior is None:
+        pl.when(live)(lambda: step(True))
+    else:
+        pl.when(live & interior)(lambda: step(False))
+        pl.when(live & jnp.logical_not(interior))(lambda: step(True))
+
+
+def _causal_mask(qb, kb, bq: int, bk: int, key_major: bool):
+    """The causal triangle inside Q block `qb` x K block `kb`: [bq, bk],
+    or [bk, bq] key-major."""
+    shape = (bk, bq) if key_major else (bq, bk)
+    q_ids = qb * bq + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                               int(key_major))
+    k_ids = kb * bk + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                               int(not key_major))
+    return q_ids >= k_ids
+
+
+def _causal_kind(qb, kb, bq: int, bk: int):
+    """(live, interior) of a causal tile: it has a key at or before its
+    last row; all its keys are at or before its first row."""
+    return kb * bk <= (qb + 1) * bq - 1, (kb + 1) * bk - 1 <= qb * bq
 
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -451,35 +587,23 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     takes the `nq` Q blocks of each query head of the group in turn."""
     kb = pl.program_id(1)
     step = pl.program_id(2)
-    last = pl.num_programs(2) - 1
     qb = step % nq
-    q = q_ref[0]
-    bq = q.shape[0]
-    block_k = k_ref.shape[1]
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(step == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
+    def tile(masked):
+        mask = _causal_mask(qb, kb, bq, bk, True) if masked else None
+        _dkdv_step(dk_scr, dv_scr, q_ref[0], k_ref[0], v_ref[0], do_ref[0],
+                   lse_ref[0, :1], delta_ref[0, :1], mask, scale)
+
     # Causal: Q blocks entirely above this K block's first row are dead.
-    relevant = ((qb + 1) * bq - 1 >= kb * block_k) if causal else (qb >= 0)
+    _on_tiles(tile, *(_causal_kind(qb, kb, bq, bk) if causal else ()))
 
-    @pl.when(relevant)
-    def _():
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        prec = _prec(q.dtype)
-        p, ds = _bwd_tile(q, k, v, do, lse_ref[0, :, 0:1],
-                          delta_ref[0, :, 0:1], qb, kb, bq, block_k,
-                          causal, scale)
-        dv_scr[:] += jnp.dot(p.astype(do.dtype).T, do,
-                             preferred_element_type=jnp.float32, precision=prec)
-        dk_scr[:] += jnp.dot(ds.astype(q.dtype).T, q,
-                             preferred_element_type=jnp.float32, precision=prec)
-
-    @pl.when(step == last)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -491,30 +615,20 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     accumulates in VMEM scratch across the innermost K sweep."""
     qb = pl.program_id(1)
     kb = pl.program_id(2)
-    nk = pl.num_programs(2)
-    q = q_ref[0]
-    bq = q.shape[0]
-    block_k = k_ref.shape[1]
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(kb == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    relevant = (kb * block_k <= (qb + 1) * bq - 1) if causal else (kb >= 0)
+    def tile(masked):
+        mask = _causal_mask(qb, kb, bq, bk, False) if masked else None
+        _dq_step(dq_scr, q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
+                 delta_ref[0], mask, scale)
 
-    @pl.when(relevant)
-    def _():
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        prec = _prec(q.dtype)
-        _, ds = _bwd_tile(q, k, v, do, lse_ref[0, :, 0:1],
-                          delta_ref[0, :, 0:1], qb, kb, bq, block_k,
-                          causal, scale)
-        dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32, precision=prec)
+    _on_tiles(tile, *(_causal_kind(qb, kb, bq, bk) if causal else ()))
 
-    @pl.when(kb == nk - 1)
+    @pl.when(kb == pl.num_programs(2) - 1)
     def _():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -525,66 +639,95 @@ def _run_flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
     """Blockwise dq/dk/dv from O(T) residuals (q, k, v, o, L).
 
     `lse` is the narrow [BH, Tq] log-sum-exp saved by the forward; both
-    row stats are re-broadcast here to the lane-wide layout the kernels
-    read. `dlse` (optional, [BH, Tq]) is the cotangent of the emitted
-    log-sum-exp when the caller exposes it as an output (ring attention's
-    merge does): since dL/ds_ij = p_ij, it folds into the softmax-vjp
-    identity as a shift on Δ — ds = p * (dp - (Δ - dL)).
+    row stats are laid out here as each kernel reads them (`_stat_lanes`,
+    `_stat_rows`). `dlse` (optional, [BH, Tq]) is the cotangent of the
+    emitted log-sum-exp when the caller exposes it as an output (ring
+    attention's merge does): since dL/ds_ij = p_ij, it folds into the
+    softmax-vjp identity as a shift on Δ: ds = p * (dp - (Δ - dL)).
+
+    Each kernel takes its own tile from the passed blocks (`_pick_tile`:
+    dQ sweeps keys, dK/dV sweeps rows). A dead step of a causal grid names
+    the nearest live tile again, so that it fetches nothing.
     """
     bh, tq, d = q.shape
     tk = k.shape[1]
     g = _group(q, k)
-    block_q = _fit_block(block_q, tq)
-    block_k = _fit_block(block_k, tk)
-    nq = tq // block_q
-    lse = jnp.broadcast_to(lse[..., None], (bh, tq, _LSE_LANES))
+
+    def tiles(bq, bk):
+        return (_causal_tiles(tq, bq, bk) if causal
+                else (tq // bq) * (tk // bk))
+
+    def pick(kernel):
+        bq, bk = _pick_tile(
+            "flash_attention", kernel, _fit_block(block_q, tq),
+            _fit_block(block_k, tk), interpret=interpret, tiles=tiles,
+            steps=lambda bq, bk: (tq // bq) * (tk // bk),
+            legal=lambda bq, bk: tq % bq == 0 and tk % bk == 0)
+        nq, nk = tq // bq, tk // bk
+        # a Q block's K blocks that lie wholly at or before its first row
+        interior = (sum(min(nk, (i * bq + 1) // bk) for i in range(nq))
+                    if causal else nq * nk)
+        _publish_bwd_steps("flash_attention", kernel, bh, nq * nk,
+                           tiles(bq, bk), interior)
+        return bq, bk, nq, nk
+
     # Δ = rowsum(do · o): one cheap fused elementwise+reduce in XLA.
-    delta2 = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                     axis=-1, keepdims=True)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
-        delta2 = delta2 - dlse.astype(jnp.float32)[..., None]
-    delta = jnp.broadcast_to(delta2, (bh, tq, _LSE_LANES))
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, block_q, _LSE_LANES),
-                            lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0))
+        delta = delta - dlse.astype(jnp.float32)
+    params = functools.partial(_tile_params, d=d, itemsize=q.dtype.itemsize,
+                               backward=True)
+
     # dK/dV: K/V tile pinned (grid dim 1); the innermost dim sweeps the
     # group's query heads and, within each, its Q blocks
-    q_spec_t = pl.BlockSpec((1, block_q, d),
-                            lambda b, j, i: (b * g + i // nq, i % nq, 0))
-    row_spec_t = pl.BlockSpec((1, block_q, _LSE_LANES),
-                              lambda b, j, i: (b * g + i // nq, i % nq, 0))
-    kv_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    bq, bk, nq, nk = pick("dkdv")
+    # head and Q block of step i; causal: no earlier than the first Q block
+    # with a row at or after K block j's first key
+    head = lambda b, i: b * g + i // nq
+    block = lambda j, i: (jnp.maximum(i % nq, j * bk // bq) if causal
+                          else i % nq)
+    q_spec = pl.BlockSpec((1, bq, d),
+                          lambda b, j, i: (head(b, i), block(j, i), 0))
+    row_spec = pl.BlockSpec((1, _STAT_ROWS, bq),
+                            lambda b, j, i: (head(b, i), 0, block(j, i)))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, causal=causal, scale=scale,
                           nq=nq),
         name="flash_attention_bwd_dkdv",
-        grid=(bh // g, tk // block_k, g * nq),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
-                  row_spec_t],
-        out_specs=[kv_spec_t, kv_spec_t],
+        grid=(bh // g, nk, g * nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params(bq, bk),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, _stat_rows(lse), _stat_rows(delta))
+
+    bq, bk, nq, nk = pick("dq")
+    if causal:
+        kv_index = lambda b, i, j: (
+            b // g, jnp.minimum(j, _last_live(i, bq, bk)), 0)
+    else:
+        kv_index = lambda b, i, j: (b // g, j, 0)
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+    row_spec = pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, bk, d), kv_index)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale),
         name="flash_attention_bwd_dq",
-        grid=(bh, tq // block_q, tk // block_k),
+        grid=(bh, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=params(bq, bk),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, _stat_lanes(lse), _stat_lanes(delta))
     return dq, dk, dv
 
 

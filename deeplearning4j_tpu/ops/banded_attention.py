@@ -25,7 +25,7 @@ kernel family:
 The forward's online-softmax step is `ops/attention._softmax_update`, the
 one the flash and block-sparse forwards make (statistics a lane wide, the
 normalizer summed across lanes once a Q tile), and its tile its own
-(`ops/attention._fwd_tile`): at 48 query heads over 8 KV heads of 128,
+(`ops/attention._pick_tile`): at 48 query heads over 8 KV heads of 128,
 8,192 tokens and a window of 4,096 the six-wide group's 256 tokens
 (1,536 rows) against 512 keys, 5.3 ms a call on a v5e where Q 256 x K 256
 with `[rows, 1]` statistics took 14.7 (PR 36, PERF.md section 6); its
@@ -36,15 +36,24 @@ Both kernels run under `interpret=True` on CPU (the parity suite in
 tests/test_banded_attention.py pins them against the layer's dense
 band-masked oracle). Backward: blockwise over the band's tiles only, as
 `ops/attention.py`'s is over all of them. The forward rule saves the
-per-row log-sum-exp; a dQ kernel sweeps each Q block's `nkb` K blocks
-and a dK/dV kernel each K block's `nqb` Q blocks, scores recomputed a
-tile at a time, the whole GQA group's rows folded against one Hkv-wide
-KV tile so dK/dV sum over the group in the matmul itself. Nothing of
-size [T, T] exists in either direction. `banded_reference` stays as the
-oracle. The forward rule names the kernel's output and that log-sum-exp
-`attention_out` and `attention_lse` (`ops/attention.name_residuals`), so
-that a checkpointed layer keeps them and its recomputed forward does not
-run the kernel a second time.
+per-row log-sum-exp; a dQ kernel sweeps each Q block's K blocks and a
+dK/dV kernel each K block's Q blocks, scores recomputed a tile at a time
+by the one backward tile function of the three families
+(`ops/attention._dq_step`, `_dkdv_step`: dK/dV key-major, so that nothing
+is transposed a tile), the whole GQA group's rows folded against one
+Hkv-wide KV tile so dK/dV sum over the group in the matmul itself.
+Nothing of size [T, T] exists in either direction. Each backward kernel
+picks its own tile from the policy's blocks (`_pick_tile`), its grid's
+inner extent is the most tiles any pinned block really meets, and a
+tile wholly inside the band builds no mask (`_tile_interior`): at the
+shapes above both take 1,536 rows x 512 keys, where seven of a Q
+block's nine or ten tiles are interior, dQ 7.7 and dK/dV 8.9 ms a call
+on a v5e where 768 x 256 with the tile transposed twice a step took 9.9
+and 16.5 (PR 39, PERF.md section 6; host clock). `banded_reference`
+stays as the oracle. The forward rule names the kernel's output and that
+log-sum-exp `attention_out` and `attention_lse`
+(`ops/attention.name_residuals`), so that a checkpointed layer keeps
+them and its recomputed forward does not run the kernel a second time.
 
 Dispatch is NOT decided here: `kernel_defaults.banded_policy` owns the
 banded-vs-dense verdict under the measured-winner discipline (env hatch
@@ -63,8 +72,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.attention import (
-    _LSE_LANES, _NEG_INF, _fwd_params, _fwd_tile, _prec, _softmax_finish,
-    _softmax_init, _softmax_scratch, _softmax_update, name_residuals,
+    _LSE_LANES, _NEG_INF, _STAT_ROWS, _dkdv_step, _dq_step,
+    _on_tiles, _pick_tile, _prec, _publish_bwd_steps, _softmax_finish,
+    _softmax_init, _softmax_scratch, _softmax_update, _stat_lanes,
+    _stat_rows, _tile_params, name_residuals,
 )
 
 
@@ -107,26 +118,16 @@ def _fit_block(block: int, t: int, *, interpret: bool) -> int:
     return block
 
 
-def _band_geometry(t: int, window: int, causal: bool, block_q: int,
-                   block_k: int):
-    """Static band geometry: `nkb`, the number of K blocks any single Q
-    block can intersect, is a function of window/block sizes ONLY — this
-    is the T·w contract, enforced by making the grid's K extent `nkb`
-    instead of `T // block_k`."""
-    nk = t // block_k
-    span = block_q + window - 1 + (0 if causal else window - 1)
-    nkb = min(nk, (span + block_k - 1) // block_k + 1)
-    return nk, nkb
-
-
 def _kb_first(i, *, nk: int, nkb: int, block_q: int, block_k: int,
               window: int, causal: bool):
     """First K block visited for Q block `i` (shared by the BlockSpec
     index_map and the in-kernel mask arithmetic, so they can never
     disagree). The last needed block is `ub` = the block holding the
     band's rightmost visible key for the block's last row; the window of
-    `nkb` blocks ending there always covers the leftmost too (nkb bounds
-    the intersection count by construction)."""
+    `nkb` blocks ending there always covers the leftmost too (`nkb` is
+    the most K blocks any Q block meets, `_live_blocks`: a function of
+    the window and the block sizes, not of T, which is the T·w
+    contract)."""
     hi = (i + 1) * block_q - 1 + (0 if causal else window - 1)
     ub = jnp.minimum(hi // block_k, nk - 1)
     return jnp.clip(ub - (nkb - 1), 0, nk - nkb)
@@ -181,14 +182,15 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, *rest, nk: int, window: int,
 
 
 def _visible(qb, kb, g: int, bq: int, block_k: int, window: int,
-             causal: bool):
+             causal: bool, key_major: bool = False):
     """[G·Bq, Bk] mask of the band inside the tile (Q block `qb`, the
-    group's rows folded, against K block `kb`): one arithmetic for the
-    forward and both backward kernels."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (g * bq, block_k), 0)
+    group's rows folded, against K block `kb`), or [Bk, G·Bq] key-major:
+    one arithmetic for the forward and both backward kernels."""
+    shape = (block_k, g * bq) if key_major else (g * bq, block_k)
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, int(key_major))
     q_ids = qb * bq + rows % bq                    # row r of group g -> q
     k_ids = (kb * block_k
-             + jax.lax.broadcasted_iota(jnp.int32, (g * bq, block_k), 1))
+             + jax.lax.broadcasted_iota(jnp.int32, shape, int(not key_major)))
     if causal:
         return (k_ids <= q_ids) & (k_ids > q_ids - window)
     return (k_ids < q_ids + window) & (k_ids > q_ids - window)
@@ -237,14 +239,14 @@ def _run_banded(q5, k3, v3, *, window: int, causal: bool, scale: float,
         return [_live_blocks(i, bq, bk, t, window, causal)
                 for i in range(t // bq)]
 
-    block_q, block_k = _fwd_tile(
-        "banded_attention", _fit_block(block_q, t, interpret=interpret),
+    block_q, block_k = _pick_tile(
+        "banded_attention", "fwd",
+        _fit_block(block_q, t, interpret=interpret),
         _fit_block(block_k, t, interpret=interpret), fold=g,
         interpret=interpret,
         legal=lambda bq, bk: t % bq == 0 and t % bk == 0,
         tiles=lambda bq, bk: sum(live(bq, bk)))
-    # the grid's K extent is the most blocks any Q block really meets,
-    # one under `_band_geometry`'s bound where the blocks line up
+    # the grid's K extent is the most blocks any Q block really meets
     nk, nkb = t // block_k, max(live(block_q, block_k))
     kmap = functools.partial(_kb_first, nk=nk, nkb=nkb, block_q=block_q,
                              block_k=block_k, window=window, causal=causal)
@@ -267,8 +269,8 @@ def _run_banded(q5, k3, v3, *, window: int, causal: bool, scale: float,
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
         scratch_shapes=_softmax_scratch(g * block_q, dh),
-        compiler_params=_fwd_params(g * block_q, block_k, dh,
-                                    q5.dtype.itemsize),
+        compiler_params=_tile_params(g * block_q, block_k, dh,
+                                     q5.dtype.itemsize),
         interpret=interpret,
     )(q5, k3, v3)
     if with_lse:
@@ -277,14 +279,13 @@ def _run_banded(q5, k3, v3, *, window: int, causal: bool, scale: float,
 
 
 # ----------------------------------------------------- blockwise backward
-def _qb_geometry(t: int, window: int, causal: bool, block_q: int,
-                 block_k: int):
-    """`_band_geometry` seen from a K block: `nqb`, the number of Q blocks
-    whose rows can see any of its keys, again a function of the window and
-    the block sizes only."""
-    nq = t // block_q
-    span = block_k + window - 1 + (0 if causal else window - 1)
-    return nq, min(nq, (span + block_q - 1) // block_q + 1)
+def _live_q_blocks(j: int, block_q: int, block_k: int, t: int, window: int,
+                   causal: bool) -> int:
+    """`_live_blocks` seen from K block `j`: how many Q blocks hold a row
+    that sees one of its keys."""
+    lo = max(j * block_k - (0 if causal else window - 1), 0)
+    hi = min((j + 1) * block_k - 1 + window - 1, t - 1)
+    return hi // block_q - lo // block_q + 1
 
 
 def _qb_first(j, *, nq: int, nqb: int, block_q: int, block_k: int,
@@ -296,34 +297,21 @@ def _qb_first(j, *, nq: int, nqb: int, block_q: int, block_k: int,
     return jnp.clip(jnp.maximum(lo, 0) // block_q, 0, nq - nqb)
 
 
-def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qb, kb, *,
-              window: int, causal: bool, scale: float):
-    """One score tile again, from the saved log-sum-exp: p = exp(s - L)
-    inside the band, 0 outside, and ds = p * (do·vᵀ - Δ) * scale; also the
-    folded q and do rows the callers multiply them by."""
-    g, bq, d = q_ref.shape[1:]
-    block_k = k_ref.shape[1]
-    qf = q_ref[0].reshape(g * bq, d)
-    dof = do_ref[0].reshape(g * bq, d)
-    k, v = k_ref[0], v_ref[0]
-    prec = (jax.lax.Precision.HIGHEST if qf.dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)
-    s = jnp.dot(qf, k.T, preferred_element_type=jnp.float32,
-                precision=prec) * scale
-    vis = _visible(qb, kb, g, bq, block_k, window, causal)
-    lse = lse_ref[0].reshape(g * bq, _LSE_LANES)[:, 0:1]
-    delta = delta_ref[0].reshape(g * bq, _LSE_LANES)[:, 0:1]
-    p = jnp.where(vis, jnp.exp(jnp.where(vis, s, _NEG_INF) - lse), 0.0)
-    dp = jnp.dot(dof, v.T, preferred_element_type=jnp.float32,
-                 precision=prec)
-    return p, p * (dp - delta) * scale, qf, dof, prec
-
-
 def _tile_live(qb, kb, bq: int, block_k: int, window: int, causal: bool):
     """Whether Q block `qb` and K block `kb` share any pair of the band."""
     lo = qb * bq - window + 1
     hi = (qb + 1) * bq - 1 + (0 if causal else window - 1)
     return (kb * block_k <= hi) & (kb * block_k + block_k - 1 >= lo)
+
+
+def _tile_interior(qb, kb, bq: int, block_k: int, window: int, causal: bool):
+    """Whether every pair of Q block `qb` and K block `kb` is in the band:
+    its last key is visible to its first row, its first key to its last
+    row. Such a tile builds no mask (`ops/attention._on_tiles`)."""
+    first_row, last_row = qb * bq, (qb + 1) * bq - 1
+    first_key, last_key = kb * block_k, (kb + 1) * block_k - 1
+    return ((last_key <= first_row + (0 if causal else window - 1))
+            & (first_key > last_row - window))
 
 
 def _banded_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -338,20 +326,21 @@ def _banded_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     block_k = k_ref.shape[1]
     kb = _kb_first(i, nk=nk, nkb=nkb, block_q=bq, block_k=block_k,
                    window=window, causal=causal) + j
+    at = (i, kb, bq, block_k, window, causal)
 
     @pl.when(j == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_tile_live(i, kb, bq, block_k, window, causal))
-    def _():
-        _, ds, _, _, prec = _bwd_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, kb,
-            window=window, causal=causal, scale=scale)
-        k = k_ref[0]
-        dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32,
-                             precision=prec)
+    def tile(masked):
+        mask = (_visible(i, kb, g, bq, block_k, window, causal)
+                if masked else None)
+        _dq_step(dq_scr, q_ref[0].reshape(g * bq, d), k_ref[0], v_ref[0],
+                 do_ref[0].reshape(g * bq, d),
+                 lse_ref[0].reshape(g * bq, _LSE_LANES),
+                 delta_ref[0].reshape(g * bq, _LSE_LANES), mask, scale)
+
+    _on_tiles(tile, _tile_live(*at), _tile_interior(*at))
 
     @pl.when(j == nkb - 1)
     def _():
@@ -363,31 +352,35 @@ def _banded_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             window: int, causal: bool, scale: float):
     """Grid = (batch·Hkv, K blocks, band Q blocks): the K/V tile's
     gradient accumulates in VMEM scratch across the Q blocks that can see
-    it, summed over the GQA group by the folded rows' contraction."""
+    it, summed over the GQA group by the folded rows' contraction. The
+    tile is key-major (`ops/attention._dkdv_step`): the group's row
+    statistics come as [G, 8, Bq] and are laid side by side, [1, G·Bq]."""
     j = pl.program_id(1)
     step = pl.program_id(2)
     nqb = pl.num_programs(2)
-    bq = q_ref.shape[2]
+    g, bq, d = q_ref.shape[1:]
     block_k = k_ref.shape[1]
     qb = _qb_first(j, nq=nq, nqb=nqb, block_q=bq, block_k=block_k,
                    window=window, causal=causal) + step
+    at = (qb, j, bq, block_k, window, causal)
 
     @pl.when(step == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(_tile_live(qb, j, bq, block_k, window, causal))
-    def _():
-        p, ds, qf, dof, prec = _bwd_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qb, j,
-            window=window, causal=causal, scale=scale)
-        dv_scr[:] += jnp.dot(p.astype(dof.dtype).T, dof,
-                             preferred_element_type=jnp.float32,
-                             precision=prec)
-        dk_scr[:] += jnp.dot(ds.astype(qf.dtype).T, qf,
-                             preferred_element_type=jnp.float32,
-                             precision=prec)
+    def stat(ref):
+        return jnp.concatenate([ref[0, head, :1] for head in range(g)],
+                               axis=1)
+
+    def tile(masked):
+        mask = (_visible(qb, j, g, bq, block_k, window, causal, True)
+                if masked else None)
+        _dkdv_step(dk_scr, dv_scr, q_ref[0].reshape(g * bq, d), k_ref[0],
+                   v_ref[0], do_ref[0].reshape(g * bq, d), stat(lse_ref),
+                   stat(delta_ref), mask, scale)
+
+    _on_tiles(tile, _tile_live(*at), _tile_interior(*at))
 
     @pl.when(step == nqb - 1)
     def _():
@@ -395,73 +388,91 @@ def _banded_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_block_q(block_q: int, g: int) -> int:
-    """The backward holds four [G·Bq, Bk] float32 tiles and two lane-wide
-    row statistics at once: G·Bq is kept to 1024 rows so that they fit the
-    default scoped VMEM with a six-wide group (six of 128)."""
-    while g * block_q > 1024 and block_q > 128:
-        block_q //= 2
-    return block_q
-
-
 def _run_banded_bwd(q5, k3, v3, o5, lse, do5, *, window: int, causal: bool,
                     scale: float, block_q: int, block_k: int,
                     interpret: bool):
     """dq [B·Hkv, G, T, Dh], dk and dv [B·Hkv, T, Dh] from the O(T)
-    residuals (q, k, v, o, L), over the band's tiles only."""
+    residuals (q, k, v, o, L), over the band's tiles only. Each kernel
+    takes its own tile from the passed blocks (`ops/attention._pick_tile`)
+    and its grid's inner extent is the most tiles any block really meets."""
     bh, g, t, dh = q5.shape
-    block_q = _fit_block(_bwd_block_q(block_q, g), t, interpret=interpret)
-    block_k = _fit_block(block_k, t, interpret=interpret)
-    nk, nkb = _band_geometry(t, window, causal, block_q, block_k)
-    nq, nqb = _qb_geometry(t, window, causal, block_q, block_k)
-    geometry = dict(block_q=block_q, block_k=block_k, window=window,
-                    causal=causal)
-    kmap = functools.partial(_kb_first, nk=nk, nkb=nkb, **geometry)
-    qmap = functools.partial(_qb_first, nq=nq, nqb=nqb, **geometry)
-    lanes = lambda x: jnp.broadcast_to(x[..., None], x.shape + (_LSE_LANES,))
+    band = (t, window, causal)
+
+    def live_k(bq, bk):
+        return [_live_blocks(i, bq, bk, *band) for i in range(t // bq)]
+
+    def live_q(bq, bk):
+        return [_live_q_blocks(j, bq, bk, *band) for j in range(t // bk)]
+
+    def pick(kernel, outer, live):
+        """The kernel's tile, the extents of its grid's two last
+        dimensions (`outer(bq, bk)` pinned blocks by the most live blocks
+        one of them sweeps) and the geometry its index maps read."""
+        bq, bk = _pick_tile(
+            "banded_attention", kernel,
+            _fit_block(block_q, t, interpret=interpret),
+            _fit_block(block_k, t, interpret=interpret), fold=g,
+            interpret=interpret,
+            legal=lambda bq, bk: t % bq == 0 and t % bk == 0,
+            tiles=lambda bq, bk: sum(live(bq, bk)),
+            steps=lambda bq, bk: outer(bq, bk) * max(live(bq, bk)))
+        swept = live(bq, bk)
+        interior = sum(
+            bool(_tile_interior(i, kb, bq, bk, window, causal))
+            for i in range(t // bq) for kb in range(t // bk)
+            if _tile_live(i, kb, bq, bk, window, causal))
+        _publish_bwd_steps("banded_attention", kernel, bh,
+                           outer(bq, bk) * max(swept), sum(swept), interior)
+        return bq, bk, max(swept), dict(block_q=bq, block_k=bk,
+                                        window=window, causal=causal)
+
     # Δ = rowsum(do · o): one fused elementwise and reduce in XLA
     delta = jnp.sum(do5.astype(jnp.float32) * o5.astype(jnp.float32),
                     axis=-1)
-    args = (q5, k3, v3, do5, lanes(lse), lanes(delta))
     kernel_args = dict(window=window, causal=causal, scale=scale)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    params = functools.partial(_tile_params, d=dh,
+                               itemsize=q5.dtype.itemsize, backward=True)
 
-    def specs(q_index, kv_index):
-        q_spec = pl.BlockSpec((1, g, block_q, dh), q_index)
-        row_spec = pl.BlockSpec((1, g, block_q, _LSE_LANES), q_index)
-        kv_spec = pl.BlockSpec((1, block_k, dh), kv_index)
-        return q_spec, kv_spec, [q_spec, kv_spec, kv_spec, q_spec, row_spec,
-                                 row_spec]
-
-    q_spec, _, in_specs = specs(lambda bb, i, j: (bb, 0, i, 0),
-                                lambda bb, i, j: (bb, kmap(i) + j, 0))
+    bq, bk, nkb, geometry = pick("dq", lambda bq, bk: t // bq, live_k)
+    nk = t // bk
+    kmap = functools.partial(_kb_first, nk=nk, nkb=nkb, **geometry)
+    q_spec = pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, i, 0))
+    row_spec = pl.BlockSpec((1, g, bq, _LSE_LANES),
+                            lambda bb, i, j: (bb, 0, i, 0))
+    kv_spec = pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, kmap(i) + j, 0))
     dq = pl.pallas_call(
         functools.partial(_banded_bwd_dq_kernel, nk=nk, **kernel_args),
         name="banded_attention_bwd_dq",
-        grid=(bh, nq, nkb),
-        in_specs=in_specs,
+        grid=(bh, t // bq, nkb),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
-        scratch_shapes=[pltpu.VMEM((g * block_q, dh), jnp.float32)],
-        compiler_params=params,
+        scratch_shapes=[pltpu.VMEM((g * bq, dh), jnp.float32)],
+        compiler_params=params(g * bq, bk),
         interpret=interpret,
-    )(*args)
-    _, kv_spec, in_specs = specs(lambda bb, j, i: (bb, 0, qmap(j) + i, 0),
-                                 lambda bb, j, i: (bb, j, 0))
+    )(q5, k3, v3, do5, _stat_lanes(lse), _stat_lanes(delta))
+
+    bq, bk, nqb, geometry = pick("dkdv", lambda bq, bk: t // bk, live_q)
+    nq = t // bq
+    qmap = functools.partial(_qb_first, nq=nq, nqb=nqb, **geometry)
+    q_spec = pl.BlockSpec((1, g, bq, dh),
+                          lambda bb, j, i: (bb, 0, qmap(j) + i, 0))
+    row_spec = pl.BlockSpec((1, g, _STAT_ROWS, bq),
+                            lambda bb, j, i: (bb, 0, 0, qmap(j) + i))
+    kv_spec = pl.BlockSpec((1, bk, dh), lambda bb, j, i: (bb, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_banded_bwd_dkdv_kernel, nq=nq, **kernel_args),
         name="banded_attention_bwd_dkdv",
-        grid=(bh, nk, nqb),
-        in_specs=in_specs,
+        grid=(bh, t // bk, nqb),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
-                        pltpu.VMEM((block_k, dh), jnp.float32)],
-        compiler_params=params,
+        scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
+                        pltpu.VMEM((bk, dh), jnp.float32)],
+        compiler_params=params(g * bq, bk),
         interpret=interpret,
-    )(*args)
+    )(q5, k3, v3, do5, _stat_rows(lse), _stat_rows(delta))
     return dq, dk, dv
 
 

@@ -297,13 +297,15 @@ def banded_policy(t: int, h: int, hkv: int,
 
     What the chip showed for training at 8,192 (`trinity_large_fit`: 48
     query heads over 8 KV heads of 128, window 4,096, bf16; PERF.md
-    sections 5 and 6): with these blocks of 256 the backward's Q block is
-    128 (dQ 9.0 ms a call, dK/dV 13.9: PR 34) and the forward's tile, which
-    it picks for itself from them (`ops/attention._fwd_tile`), is the
-    group's 256 tokens by 512 keys: 5.3 ms a call since PR 36, where it
-    took 14.7. There is no MEASURED row for it and there cannot be one: a
-    row is a head-to-head, and the dense contender does not fit the chip
-    at this shape (it asked the compiler for 18.7 GiB)."""
+    sections 5 and 6): these blocks of 256 are where each of the three
+    kernels starts, and each picks its own tile from them
+    (`ops/attention._pick_tile`): the group's 256 tokens (1,536 rows) by
+    512 keys in all three. The forward takes 5.3 ms a call since PR 36,
+    where it took 14.7; dQ 7.7 and dK/dV 8.9 since PR 39, where a Q block
+    of 128 took 9.9 and 16.5 (host clock). There is no MEASURED row for
+    it and there cannot be one: a row is a head-to-head, and the dense
+    contender does not fit the chip at this shape (it asked the compiler
+    for 18.7 GiB)."""
     forced = _env("DL4J_TPU_ATTN")
     blocks = _blocks_from_env()
     from deeplearning4j_tpu.ops.banded_attention import banded_eligible
@@ -371,12 +373,13 @@ def sparse_policy(t: int, block_size: int) -> SparsePolicy:
     softmax the rest (small shapes off the chip, the tests').
     `DL4J_TPU_ATTN=dense` forces the masked path as it forces the others.
 
-    Tiles: Q 256 x K 512 (8 blocks of 64 keys) for the backward kernels
-    and the walk's unit; the forward keeps the K tile and picks its own Q
-    tile (`ops/attention._fwd_tile`: 1,024 tokens at `minicpm_sala_fit`'s
-    32 query heads over 2 KV heads of 128 and 16,384 tokens, bf16, where a
-    call takes 26.7 ms since PR 36 and took 50.0; PERF.md sections 5 and
-    6)."""
+    Tiles: K 512 (8 blocks of 64 keys) is the walk's unit and every
+    kernel's K tile; Q 256 is where each kernel's own Q tile starts
+    (`ops/attention._pick_tile`: 1,024 tokens in all three at
+    `minicpm_sala_fit`'s 32 query heads over 2 KV heads of 128 and 16,384
+    tokens, bf16, where a forward call takes 26.7 ms since PR 36 and took
+    50.0, dQ and dK/dV 31.1 and 35.5 since PR 39 and took 41.4 and 55.1;
+    PERF.md sections 5 and 6)."""
     import jax
 
     from deeplearning4j_tpu.ops.sparse_attention import sparse_eligible

@@ -37,14 +37,18 @@ V exists. The forward names its output and log-sum-exp
 (`ops/attention.name_residuals`), so a checkpointed layer keeps them.
 
 The forward's online-softmax step is `ops/attention._softmax_update`, the
-flash and banded forwards' too. Its K tile is the caller's (the unit the
-walk skips by) and its Q tile its own (`ops/attention._fwd_tile`), with a
-walk of its own where that differs from the backward's: at the policy's
-256 x 512, 32 query heads and 16,384 tokens it takes 1,024 tokens a tile,
-26.7 ms a call on a v5e where 256 with `[rows, 1]` statistics took 50.0
-(PR 36, PERF.md section 6). A taller Q tile walks the union of more
-tokens' lists; on the benchmark's seeded weights that is every causal
-tile at either height.
+flash and banded forwards' too, and the backward's tile theirs
+(`ops/attention._dq_step`, `_dkdv_step`; dK/dV key-major, the mask's
+spread product with it, `_tile_mask`). The K tile is the caller's (the
+unit the walk skips by) and each kernel's Q tile its own
+(`ops/attention._pick_tile`, `_kernel_tiles`), with a walk of its own
+where it differs: at the policy's 256 x 512, 32 query heads and 16,384
+tokens all three take 1,024 tokens a tile and share one walk; on a v5e a
+forward call takes 26.7 ms where 256 with `[rows, 1]` statistics took
+50.0 (PR 36, PERF.md section 6), dQ and dK/dV 31.1 and 35.5 ms where 256
+with the tile transposed twice a step took 41.4 and 55.1 (PR 39, host
+clock). A taller Q tile walks the union of more tokens' lists; on the
+benchmark's seeded weights that is every causal tile at either height.
 """
 
 from __future__ import annotations
@@ -59,9 +63,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.attention import (
-    _LSE_LANES, _NEG_INF, _causal_tiles, _fold3, _fwd_params, _fwd_tile,
-    _group, _prec, _softmax_finish, _softmax_init, _softmax_scratch,
-    _softmax_update, _unfold3, name_residuals,
+    _LSE_LANES, _NEG_INF, _NT, _STAT_ROWS, _causal_mask, _causal_tiles,
+    _dkdv_step, _dq_step, _fold3, _group, _on_tiles, _pick_tile, _prec,
+    _publish_bwd_steps, _softmax_finish, _softmax_init, _softmax_scratch,
+    _softmax_update, _stat_lanes, _stat_rows, _tile_params, _unfold3,
+    name_residuals,
 )
 
 _LANES = 128
@@ -223,23 +229,27 @@ def sparse_eligible(t: int, block_size: int, block_q: int,
             and block_k % block_size == 0 and _LANES % bpt == 0)
 
 
-def _tile_mask(a, qb, kb, bq, bk, block_size):
-    """[bq, bk] bool: key c of K tile `kb` is visible to row r of Q tile
-    `qb` iff the row lists the key's block and the key is not ahead of
-    it. `a` is the rows' [bq, 128] slice of the mask (0/1 in the
-    kernel's dtype), in which this tile's blocks start at lane
+def _tile_mask(a, qb, kb, bq, bk, block_size, key_major: bool = False):
+    """[bq, bk] bool ([bk, bq] key-major): key c of K tile `kb` is visible
+    to row r of Q tile `qb` iff the row lists the key's block and the key
+    is not ahead of it. `a` is the rows' [bq, 128] slice of the mask (0/1
+    in the kernel's dtype), in which this tile's blocks start at lane
     `(kb * bpt) % 128`."""
     bpt = bk // block_size
     shift = block_size.bit_length() - 1
     off = (kb * bpt) % _LANES
-    lane = jax.lax.broadcasted_iota(jnp.int32, (_LANES, bk), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (_LANES, bk), 1)
-    spread = lane == off + jnp.right_shift(col, shift)
-    listed = jnp.dot(a, spread.astype(a.dtype),
-                     preferred_element_type=jnp.float32) > 0.5
-    q_ids = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_ids = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return listed & (q_ids >= k_ids)
+    # spread[lane, c] (key-major: [c, lane]): lane holds key c's block
+    spread_shape = (bk, _LANES) if key_major else (_LANES, bk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, spread_shape, int(key_major))
+    col = jax.lax.broadcasted_iota(jnp.int32, spread_shape,
+                                   int(not key_major))
+    spread = (lane == off + jnp.right_shift(col, shift)).astype(a.dtype)
+    if key_major:
+        listed = jax.lax.dot_general(spread, a, _NT,
+                                     preferred_element_type=jnp.float32)
+    else:
+        listed = jnp.dot(a, spread, preferred_element_type=jnp.float32)
+    return (listed > 0.5) & _causal_mask(qb, kb, bq, bk, key_major)
 
 
 def _fwd_kernel(visit_ref, fetch_ref, q_ref, k_ref, v_ref, a_ref, o_ref,
@@ -271,36 +281,22 @@ def _fwd_kernel(visit_ref, fetch_ref, q_ref, k_ref, v_ref, a_ref, o_ref,
         lse_ref[0] = lse
 
 
-def _bwd_tile(q, k, v, do, a, lse_col, delta_col, qb, kb, block_size, scale):
-    bq, bk = q.shape[0], k.shape[0]
-    prec = _prec(q.dtype)
-    mask = _tile_mask(a, qb, kb, bq, bk, block_size)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                precision=prec) * scale
-    p = jnp.where(mask, jnp.exp(jnp.where(mask, s, _NEG_INF) - lse_col), 0.0)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32, precision=prec)
-    return p, p * (dp - delta_col) * scale
-
-
 def _bwd_dq_kernel(visit_ref, fetch_ref, q_ref, k_ref, v_ref, a_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dq_scr, *, scale, g, nq, nk,
                    block_size):
     b, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     flat = ((b // g) * nq + qb) * nk + kb
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(kb == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(visit_ref[flat] > 0)
-    def _():
-        k = k_ref[0]
-        _, ds = _bwd_tile(q_ref[0], k, v_ref[0], do_ref[0], a_ref[0],
-                          lse_ref[0, :, 0:1], delta_ref[0, :, 0:1], qb, kb,
-                          block_size, scale)
-        dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32,
-                             precision=_prec(k.dtype))
+    # no tile is interior: which blocks a row lists is data
+    _on_tiles(lambda masked: _dq_step(
+        dq_scr, q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
+        delta_ref[0], _tile_mask(a_ref[0], qb, kb, bq, bk, block_size),
+        scale), visit_ref[flat] > 0)
 
     @pl.when(kb == nk - 1)
     def _():
@@ -312,29 +308,23 @@ def _bwd_dkdv_kernel(visit_ref, fetch_ref, q_ref, k_ref, v_ref, a_ref,
                      dv_scr, *, scale, nq, nk, block_size):
     """Grid (batch x KV heads, K tiles, group x Q tiles): the K tile's
     gradient gathers in scratch over the Q tiles of each of the group's
-    query heads that list it."""
+    query heads that list it, the tile key-major
+    (`ops/attention._dkdv_step`)."""
     b, kb, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     qb = step % nq
     flat = (b * nq + qb) * nk + kb
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(step == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(visit_ref[flat] > 0)
-    def _():
-        q, do = q_ref[0], do_ref[0]
-        prec = _prec(q.dtype)
-        p, ds = _bwd_tile(q, k_ref[0], v_ref[0], do, a_ref[0],
-                          lse_ref[0, :, 0:1], delta_ref[0, :, 0:1], qb, kb,
-                          block_size, scale)
-        dv_scr[:] += jnp.dot(p.astype(do.dtype).T, do,
-                             preferred_element_type=jnp.float32,
-                             precision=prec)
-        dk_scr[:] += jnp.dot(ds.astype(q.dtype).T, q,
-                             preferred_element_type=jnp.float32,
-                             precision=prec)
+    _on_tiles(lambda masked: _dkdv_step(
+        dk_scr, dv_scr, q_ref[0], k_ref[0], v_ref[0], do_ref[0],
+        lse_ref[0, :1], delta_ref[0, :1],
+        _tile_mask(a_ref[0], qb, kb, bq, bk, block_size, True), scale),
+        visit_ref[flat] > 0)
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _():
@@ -392,9 +382,6 @@ def _specs(d, g, nq, nk, block_q, block_k, block_size):
     return q_spec, kv_spec, a_spec, row_spec
 
 
-_PARAMS = dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-
 def _run_fwd(q3, k3, v3, a3, walk, *, scale, block_size, block_q, block_k,
              interpret):
     bh, t, d = q3.shape
@@ -414,26 +401,28 @@ def _run_fwd(q3, k3, v3, a3, walk, *, scale, block_size, block_q, block_k,
             scratch_shapes=_softmax_scratch(block_q, d)),
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
                    jax.ShapeDtypeStruct((bh, t, _LSE_LANES), jnp.float32)],
-        compiler_params=_fwd_params(block_q, block_k, d, q3.dtype.itemsize),
+        compiler_params=_tile_params(block_q, block_k, d, q3.dtype.itemsize),
         interpret=interpret,
     )(visit, fetch_k, q3, k3, v3, a3)
     return o, lse[..., 0]
 
 
-def _run_bwd(q3, k3, v3, a3, walk, o3, lse, do3, *, scale, block_size,
-             block_q, block_k, interpret):
+def _run_bwd(q3, k3, v3, a3, walks, o3, lse, do3, *, scale, block_size,
+             tiles, interpret):
+    """dq, dk, dv from the residuals. `tiles` is `_kernel_tiles`'s, each
+    kernel's own tile, and `walks` the dQ and the dK/dV kernel's walk,
+    each made for its tile."""
     bh, t, d = q3.shape
     g = _group(q3, k3)
-    nq, nk, bpt = t // block_q, t // block_k, block_k // block_size
-    visit, fetch_k, fetch_q = walk
-    lse = jnp.broadcast_to(lse[..., None], (bh, t, _LSE_LANES))
-    delta = jnp.broadcast_to(
-        jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1,
-                keepdims=True), (bh, t, _LSE_LANES))
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1)
+    params = functools.partial(_tile_params, d=d,
+                               itemsize=q3.dtype.itemsize, backward=True)
 
+    (block_q, block_k), (visit, fetch_k, _) = tiles["dq"], walks[0]
+    nq, nk = t // block_q, t // block_k
     q_spec, kv_spec, a_spec, row_spec = _specs(d, g, nq, nk, block_q,
                                                block_k, block_size)
-    params = pltpu.CompilerParams(**_PARAMS)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, g=g, nq=nq, nk=nk,
                           block_size=block_size),
@@ -445,11 +434,15 @@ def _run_bwd(q3, k3, v3, a3, walk, o3, lse, do3, *, scale, block_size,
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-        compiler_params=params, interpret=interpret,
-    )(visit, fetch_k, q3, k3, v3, a3, do3, lse, delta)
+        compiler_params=params(block_q, block_k), interpret=interpret,
+    )(visit, fetch_k, q3, k3, v3, a3, do3, _stat_lanes(lse),
+      _stat_lanes(delta))
 
     # dK/dV: b runs over KV heads; step s is Q tile s % nq of the group's
     # query head s // nq
+    (block_q, block_k), (visit, _, fetch_q) = tiles["dkdv"], walks[1]
+    nq, nk, bpt = t // block_q, t // block_k, block_k // block_size
+
     def qat(b, j, s, f):
         return f[(b * nq + s % nq) * nk + j]
 
@@ -457,8 +450,8 @@ def _run_bwd(q3, k3, v3, a3, walk, o3, lse, do3, *, scale, block_size,
         (1, block_q, d),
         lambda b, j, s, v, f: (b * g + s // nq, qat(b, j, s, f), 0))
     row_spec_t = pl.BlockSpec(
-        (1, block_q, _LSE_LANES),
-        lambda b, j, s, v, f: (b * g + s // nq, qat(b, j, s, f), 0))
+        (1, _STAT_ROWS, block_q),
+        lambda b, j, s, v, f: (b * g + s // nq, 0, qat(b, j, s, f)))
     kv_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, s, v, f: (b, j, 0))
     a_spec_t = pl.BlockSpec(
         (1, block_q, _LANES),
@@ -476,9 +469,40 @@ def _run_bwd(q3, k3, v3, a3, walk, o3, lse, do3, *, scale, block_size,
                             pltpu.VMEM((block_k, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        compiler_params=params, interpret=interpret,
-    )(visit, fetch_q, q3, k3, v3, a3, do3, lse, delta)
+        compiler_params=params(block_q, block_k), interpret=interpret,
+    )(visit, fetch_q, q3, k3, v3, a3, do3, _stat_rows(lse),
+      _stat_rows(delta))
     return dq, dk, dv
+
+
+def _kernel_tiles(t: int, heads: int, block_size: int, block_q: int,
+                  block_k: int, interpret: bool) -> dict:
+    """{"fwd" | "dq" | "dkdv": (block_q, block_k)}: each kernel's own
+    tile (`ops/attention._pick_tile`). The K tile stays the caller's: it
+    is the unit the walk skips by, and what a selection lists is data.
+    The Q tile may grow, priced over the causal triangle, the most a walk
+    can visit; its walk is then the union of more tokens' lists, so a
+    backward kernel's grows no taller than the forward's (which its
+    score tile's size stops first) and the three share one walk. The
+    backward's gauge `attention_bwd_steps` counts the causal triangle as
+    its edge steps: no tile is interior, and how many a walk skips is
+    data."""
+    out = {}
+    for kernel in ("fwd", "dq", "dkdv"):
+        tallest = out["fwd"][0] if out else t
+        out[kernel] = tile = _pick_tile(
+            "sparse_attention", kernel, block_q, block_k,
+            interpret=interpret,
+            legal=lambda bq, bk: (bk == block_k and bq <= tallest
+                                  and sparse_eligible(t, block_size, bq, bk)),
+            tiles=lambda bq, bk: _causal_tiles(t, bq, bk),
+            steps=None if kernel == "fwd" else (
+                lambda bq, bk: (t // bq) * (t // bk)))
+        if kernel != "fwd":
+            _publish_bwd_steps(
+                "sparse_attention", kernel, heads,
+                (t // tile[0]) * (t // tile[1]), _causal_tiles(t, *tile), 0)
+    return out
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -507,34 +531,30 @@ def _sparse_fwd(q, k, v, allow, block_size, scale, block_q, block_k,
     v3, _ = _fold3(v)
     a3 = _mask_operand(allow, q.dtype)
     allow3 = allow.reshape(a3.shape[0], t, -1)
-    walk = _walk(allow3, block_q, block_k, block_size)
-    # The forward's own tile. The K tile stays the caller's: it is the
-    # unit the walk skips by, and what a selection lists is data. The Q
-    # tile may grow (its walk is then the union of more tokens' lists),
-    # priced over the causal triangle, the most a walk can visit.
-    fwd_q, fwd_k = _fwd_tile(
-        "sparse_attention", block_q, block_k, interpret=interpret,
-        legal=lambda bq, bk: bk == block_k and sparse_eligible(
-            t, block_size, bq, bk),
-        tiles=lambda bq, bk: _causal_tiles(t, bq, bk))
-    fwd_walk = (walk if (fwd_q, fwd_k) == (block_q, block_k)
-                else _walk(allow3, fwd_q, fwd_k, block_size))
-    o3, lse = _run_fwd(q3, k3, v3, a3, fwd_walk, scale=s,
-                       block_size=block_size, block_q=fwd_q, block_k=fwd_k,
-                       interpret=interpret)
+    # one walk a distinct tile; the backward kernels' ride as residuals
+    tiles = _kernel_tiles(t, q3.shape[0], block_size, block_q, block_k,
+                          interpret)
+    made = {tile: _walk(allow3, *tile, block_size)
+            for tile in set(tiles.values())}
+    o3, lse = _run_fwd(q3, k3, v3, a3, made[tiles["fwd"]], scale=s,
+                       block_size=block_size, block_q=tiles["fwd"][0],
+                       block_k=tiles["fwd"][1], interpret=interpret)
     o3, lse = name_residuals(o3, lse)
+    walks = made[tiles["dq"]], made[tiles["dkdv"]]
     return (_unfold3(o3, shape_q),
-            (q3, k3, v3, a3, walk, o3, lse, shape_q, shape_k))
+            (q3, k3, v3, a3, walks, o3, lse, shape_q, shape_k))
 
 
 def _sparse_bwd(block_size, scale, block_q, block_k, interpret, res, do):
-    q3, k3, v3, a3, walk, o3, lse, shape_q, shape_k = res
-    t = q3.shape[1]
+    q3, k3, v3, a3, walks, o3, lse, shape_q, shape_k = res
+    bh, t, _ = q3.shape
     s = scale if scale is not None else q3.shape[-1] ** -0.5
     do3, _ = _fold3(do)
-    dq, dk, dv = _run_bwd(q3, k3, v3, a3, walk, o3, lse, do3, scale=s,
-                          block_size=block_size, block_q=min(block_q, t),
-                          block_k=min(block_k, t), interpret=interpret)
+    tiles = _kernel_tiles(t, bh, block_size, min(block_q, t),
+                          min(block_k, t), interpret)
+    dq, dk, dv = _run_bwd(q3, k3, v3, a3, walks, o3, lse, do3, scale=s,
+                          block_size=block_size, tiles=tiles,
+                          interpret=interpret)
     return (_unfold3(dq, shape_q), _unfold3(dk, shape_k),
             _unfold3(dv, shape_k), None)
 

@@ -3,7 +3,7 @@ each of the three forward kernels that call it, in interpret mode against
 the family's oracle: output and log-sum-exp (the backward kernels read
 the second), float32 and bf16, at shapes that reach each branch of the
 update. In interpret mode the passed blocks are the tile; the tile the
-forward picks for itself on the chip (`_fwd_tile`) is arithmetic, checked
+forward picks for itself on the chip (`_pick_tile`) is arithmetic, checked
 at the cells' shapes at the end.
 """
 
@@ -235,7 +235,7 @@ def test_a_row_with_no_key_yet_adds_nothing():
 
 
 # --- the tile the forward picks on the chip: name -> (family's call of
-# `_fwd_tile`, the tile). The first three are the benchmark's cells
+# `_pick_tile`, the tile). The first three are the benchmark's cells
 # (`trinity_large_fit`'s window and full layers, `minicpm_sala_fit`'s
 # selecting layer), where the kernels alone were timed over the candidates
 # (PERF.md section 6, PR 36).

@@ -128,7 +128,8 @@ def _decode(paged, cache_dtype, slots=8, cache=1024, page=128, heads=8,
 
 def _sparse(train, T=16384, heads=32, kv_heads=2, d=128):
     # `minicpm_sala`'s selecting layer at the cell's size: selection and
-    # the three block-sparse kernels, Q tiles of 256 over K tiles of 512
+    # the three block-sparse kernels, each at the Q tile it picks for
+    # itself from the policy's 256 over K tiles of 512
     sel = sparse.BlockSelection()
 
     def fwd(q, k, v):
@@ -154,7 +155,9 @@ CASES = {
     "flash_fwd": lambda: _flash(train=False),
     "flash_pallas_bwd": lambda: _flash(train=True),
     # 48 query heads of 128 over 8 KV heads at 8,192 tokens, window 4,096:
-    # the shapes a six-wide group folds into the backward's tiles
+    # the shapes a six-wide group folds into the backward's tiles, each
+    # backward kernel at the tile it picks for itself
+    # (`ops/attention._pick_tile`)
     "flash_pallas_bwd_gqa": lambda: _flash(train=True, heads=48, d=128,
                                            kv_heads=8),
     "banded_fwd_gqa": _banded,
@@ -163,7 +166,7 @@ CASES = {
         T=8192, heads=48, kv_heads=8, d=128, window=4096, train=True,
         batch=1),
     # `trinity_large_fit`'s two forward kernels alone, at the tile each
-    # picks for itself from the policies' blocks (`ops/attention._fwd_tile`)
+    # picks for itself from the policies' blocks (`ops/attention._pick_tile`)
     "banded_fwd_gqa_48_8": lambda: _banded(
         T=8192, heads=48, kv_heads=8, d=128, window=4096, batch=1),
     "flash_fwd_gqa_48_8": lambda: _flash(train=False, heads=48, d=128,
@@ -171,6 +174,9 @@ CASES = {
     # float32 operands at the same tile pass the default scoped VMEM
     "banded_fwd_gqa_48_8_f32": lambda: _banded(
         T=8192, heads=48, kv_heads=8, d=128, window=4096, batch=1, dt=F32),
+    "banded_train_gqa_48_8_f32": lambda: _banded(
+        T=8192, heads=48, kv_heads=8, d=128, window=4096, train=True,
+        batch=1, dt=F32),
     "slot_decode_bf16": lambda: _decode(False, BF16),
     "slot_decode_int8": lambda: _decode(False, I8),
     "paged_decode_bf16": lambda: _decode(True, BF16),
@@ -187,7 +193,7 @@ def test_kernel_compiles_for_v5e(chip, name):
 
 
 # --- the tile a forward kernel picked is published at trace time: the
-# gauge says which tile a cell's program really runs (`_fwd_tile`; the
+# gauge says which tile a cell's program really runs (`_pick_tile`; the
 # cells' shapes, where the kernels alone were timed over the candidates)
 FORWARD_TILES = {
     "banded_fwd_gqa_48_8": ("banded_attention", 6 * 256, 512),
@@ -210,6 +216,47 @@ def test_forward_kernel_publishes_its_tile(name):
         jax.eval_shape(fn, *[jax.ShapeDtypeStruct(s, d) for s, d in shapes])
     assert (gauge("rows").value, gauge("keys_per_update").value) == (rows,
                                                                      keys)
+
+
+# --- and so is the tile each backward kernel picked, beside the grid it
+# builds (`attention_bwd_tile`, `attention_bwd_steps`): name -> (op, the dQ
+# kernel's rows and keys, the dK/dV kernel's)
+BACKWARD_TILES = {
+    "banded_train_gqa_48_8": ("banded_attention", (6 * 256, 512),
+                              (6 * 256, 512)),
+    "banded_train_gqa_48_8_f32": ("banded_attention", (6 * 256, 512),
+                                  (6 * 256, 512)),
+    "flash_pallas_bwd_gqa": ("flash_attention", (1024, 1024), (1024, 1024)),
+    "sparse_train_32_2": ("sparse_attention", (1024, 512), (1024, 512)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BACKWARD_TILES))
+def test_backward_kernel_publishes_its_tile(name):
+    from deeplearning4j_tpu.observe import get_registry
+
+    op, *tiles = BACKWARD_TILES[name]
+    fn, shapes = CASES[name]()
+    registry = get_registry()
+    tile = lambda kernel, field: registry.gauge(
+        "attention_bwd_tile", op=op, kernel=kernel, field=field)
+    steps = lambda kernel: [registry.gauge(
+        "attention_bwd_steps", op=op, kernel=kernel, kind=kind)
+        for kind in ("interior", "edge", "dead")]
+    for kernel in ("dq", "dkdv"):
+        for gauge in [tile(kernel, "rows"), tile(kernel, "keys"),
+                      *steps(kernel)]:
+            gauge.set(0)
+    with jax.enable_x64(False):
+        jax.eval_shape(fn, *[jax.ShapeDtypeStruct(s, d) for s, d in shapes])
+    for kernel, want in zip(("dq", "dkdv"), tiles):
+        assert (tile(kernel, "rows").value,
+                tile(kernel, "keys").value) == want, kernel
+        interior, edge, dead = (g.value for g in steps(kernel))
+        assert edge > 0 and dead > 0, (kernel, interior, edge, dead)
+        # a band's and a causal triangle's tiles are mostly interior; a
+        # block selection's never
+        assert (interior > edge) == (op != "sparse_attention"), kernel
 
 
 # --- every `pl.pallas_call` site names its kernel: the device trace and
