@@ -32,7 +32,7 @@ from deeplearning4j_tpu.nn.layers.special import (
     FrozenLayer, CenterLossOutputLayer, VariationalAutoencoder, RBM,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
-    LinearAttention, MultiHeadAttention, PreNormBlock,
+    LatentAttention, LinearAttention, MultiHeadAttention, PreNormBlock,
     SandwichTransformerBlock,
 )
 

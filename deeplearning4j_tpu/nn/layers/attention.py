@@ -11,6 +11,7 @@ runs sequence-parallel without code changes — the attention core is swapped.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import jax
@@ -21,7 +22,7 @@ from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 from deeplearning4j_tpu.parallel.ring_attention import attention
 
 
-def rope_rotate(x, positions, base: float = 10000.0):
+def rope_rotate(x, positions, base: float = 10000.0, inv_freq=None):
     """Rotary position embedding (RoPE): rotate [B, T, H, Dh] per-head
     pairs by position-dependent angles. Attention scores between rotated
     q/k depend only on RELATIVE distance, so there is no learned
@@ -30,12 +31,20 @@ def rope_rotate(x, positions, base: float = 10000.0):
 
     `positions` is [T] (one stream, or all rows at the same offset) or
     [B, T] (per-row offsets — the slot-indexed decode path, where each
-    session in the batch sits at its own absolute position)."""
+    session in the batch sits at its own absolute position). `inv_freq`,
+    `Dh // 2` frequencies, takes the place of `base ** (-i / half)`
+    (a scaled rope: `yarn_inv_freq`)."""
     dh = x.shape[-1]
     if dh % 2:
         raise ValueError(f"RoPE needs an even head dim, got {dh}")
     half = dh // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
+        if freqs.shape != (half,):
+            raise ValueError(f"{freqs.shape[0]} frequencies for {half} "
+                             f"pairs")
     ang = positions.astype(jnp.float32)[..., None] * freqs
     if ang.ndim == 2:                  # [T, half] -> [1, T, half]
         ang = ang[None]
@@ -51,6 +60,42 @@ def rms_norm(x, gain, eps: float = 1e-5):
     wide = x.astype(jnp.promote_types(x.dtype, jnp.float32))
     ms = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(ms + eps).astype(x.dtype) * gain
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, scaling: dict) -> tuple:
+    """The `dim // 2` rotary frequencies of a YaRN-scaled rope
+    (`rope_scaling` with `type` "yarn": `factor`, `beta_fast`,
+    `beta_slow`, `original_max_position_embeddings`): pair `i` keeps
+    `base^(-2i/dim)` below the pair that turns `beta_fast` times over the
+    original length, is divided by `factor` above the one that turns
+    `beta_slow` times, and goes linearly from one to the other between."""
+    half, span = dim // 2, scaling["original_max_position_embeddings"]
+
+    def pair_of(turns):
+        return (dim * math.log(span / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_of(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(scaling["beta_slow"])), dim - 1)
+    out = []
+    for i in range(half):
+        ramp = min(max((i - low) / max(high - low, 1e-3), 0.0), 1.0)
+        f = base ** (-2.0 * i / dim)
+        out.append(f * (1.0 - ramp) + f / scaling["factor"] * ramp)
+    return tuple(out)
+
+
+def yarn_factors(scaling: dict) -> tuple:
+    """(what multiplies cos and sin, what multiplies the softmax scale) of
+    a YaRN-scaled rope: `mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)` and `mscale(factor, mscale_all_dim) ** 2`."""
+    every = _yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0))
+    one = _yarn_mscale(scaling["factor"], scaling.get("mscale", 1))
+    return one / every, every * every
 
 
 # a step's selection counters, in a `sparse=` layer's state: the block
@@ -1182,21 +1227,180 @@ class LinearAttention(MultiHeadAttention):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class LatentAttention(Layer):
+    """Multi-head latent attention over [batch, time, features] (MLA, the
+    attention of the `deepseek_v2` family), causal, no bias anywhere:
+
+        c_q           = norm(x Wqa; q_norm)                 [T, q_lora_rank]
+        [q_nope|q_pe] = c_q Wqb, a head at a time           [T, H, Dn | Dr]
+        [c_kv | k_pe] = x Wkva                              [T, kv_lora | Dr]
+        [k_nope | v]  = norm(c_kv; kv_norm) Wkvb, a head at a time
+        s_h[i, j]     = (q_nope_h[i] . k_nope_h[j]
+                         + rope(q_pe_h)[i] . rope(k_pe)[j]) * scale,  j <= i
+        y             = concat_h(softmax_j(s_h) v_h) Wo
+
+    The rope key `k_pe` is one for all heads and is not normed. The
+    positions are rotary over the `Dr` lanes at `rope_theta`, with
+    `rope_scaling` (type "yarn") at `yarn_inv_freq`'s frequencies, and
+    `scale = (Dn + Dr)^-0.5` times `yarn_factors`' second. A head's pairs
+    are its halves `(x1, x2)`, as `rope_rotate` takes them.
+
+    `num_heads` is the published count; `heads_held` = (first, count)
+    names the heads whose slices of `Wqb`, `Wkvb` and `Wo` this device
+    has, all of them where None. A head's slices are initialised from its
+    own published index, so the shares of one layer add up: what the
+    absent heads would add to `y` is left out, as on a mesh their devices
+    add it (`Wo`'s all-reduce). `Wqa`, `Wkva` and the two norms are whole
+    on every device.
+
+    The core runs under the scope `latent_attention_core`
+    (`ops/latent_attention.py`'s kernels where
+    `kernel_defaults.latent_policy` says so, else its dense form),
+    everything else under `latent_projections`. Training and scoring
+    only."""
+
+    CONSUMES = "rnn"
+
+    n_in: Optional[int] = None
+    n_out: Optional[int] = None       # model dim (defaults to n_in)
+    num_heads: int = 4
+    heads_held: Optional[Any] = None  # (first, count); None -> all
+    q_lora_rank: int = 64
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[Any] = None
+    norm_eps: float = 1e-6
+
+    def infer_n_in(self, input_type: InputType):
+        upd = {}
+        if self.n_in is None:
+            upd["n_in"] = input_type.size
+        if self.n_out is None:
+            upd["n_out"] = upd.get("n_in", self.n_in)
+        return dataclasses.replace(self, **upd) if upd else self
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_out, input_type.timesteps)
+
+    @property
+    def _held(self):
+        first, count = self.heads_held or (0, self.num_heads)
+        if not (0 <= first and 1 <= count
+                and first + count <= self.num_heads):
+            raise ValueError(f"heads_held {self.heads_held} lies outside "
+                             f"the {self.num_heads} heads")
+        return int(first), int(count)
+
+    @property
+    def _rope(self):
+        """(frequencies or None, factor on cos and sin, softmax scale)."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        scaling = self.rope_scaling
+        if scaling is None:
+            return None, 1.0, scale
+        if scaling.get("type") != "yarn":
+            raise ValueError(f"rope_scaling type {scaling.get('type')!r} is "
+                             f"not known (\"yarn\" is)")
+        amplitude, sharper = yarn_factors(scaling)
+        return (yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta,
+                              scaling), amplitude, scale * sharper)
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        first, count = self._held
+        self._rope      # an unknown scaling is refused here
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        ks = jax.random.split(key, 5)
+        winit = self._winit()
+
+        def heads(key, shape, axis):   # a head's slice from its own index
+            return jnp.concatenate(
+                [winit(jax.random.fold_in(key, first + i), shape, dtype)
+                 for i in range(count)], axis=axis)
+
+        return {
+            "Wqa": winit(ks[0], (self.n_in, self.q_lora_rank), dtype),
+            "q_norm": jnp.ones((self.q_lora_rank,), dtype),
+            "Wqb": heads(ks[1], (self.q_lora_rank, dn + dr), 1),
+            "Wkva": winit(ks[2], (self.n_in, self.kv_lora_rank + dr), dtype),
+            "kv_norm": jnp.ones((self.kv_lora_rank,), dtype),
+            "Wkvb": heads(ks[3], (self.kv_lora_rank, dn + dv), 1),
+            "Wo": heads(ks[4], (dv, self.n_out), 0),
+        }, {}
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        raise NotImplementedError(
+            f"LatentAttention {self.name!r} has no decode carry yet: its "
+            f"cache is the compressed latent and the rope key, "
+            f"kv_lora_rank + qk_rope_head_dim a token, which serving's "
+            f"pages do not hold")
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        if mask is not None:
+            raise ValueError("LatentAttention takes no padding mask")
+        from deeplearning4j_tpu.ops.kernel_defaults import latent_policy
+        from deeplearning4j_tpu.ops.latent_attention import (
+            dense_latent_attention, latent_attention,
+        )
+
+        B, T, _ = x.shape
+        H = self._held[1]
+        dn, rank = self.qk_nope_head_dim, self.kv_lora_rank
+        freqs, amplitude, scale = self._rope
+        positions = jnp.arange(T)
+
+        def rope(a):
+            a = rope_rotate(a, positions, self.rope_theta, freqs)
+            return a if amplitude == 1.0 else a * amplitude
+
+        with jax.named_scope("latent_projections"):
+            c_q = rms_norm(x @ params["Wqa"], params["q_norm"],
+                           self.norm_eps)
+            q = (c_q @ params["Wqb"]).reshape(B, T, H, -1)
+            q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
+            kva = x @ params["Wkva"]
+            k_rope = rope(kva[:, :, None, rank:])[:, :, 0]
+            c_kv = rms_norm(kva[..., :rank], params["kv_norm"],
+                            self.norm_eps)
+            kv = (c_kv @ params["Wkvb"]).reshape(B, T, H, -1)
+            k_nope, v = kv[..., :dn], kv[..., dn:]
+        pol = latent_policy(T)
+        with jax.named_scope("latent_attention_core"):
+            if pol.kind == "kernel":
+                o = latent_attention(q, k_nope, k_rope, v, scale,
+                                     pol.block_q, pol.block_k, False)
+            else:
+                o = dense_latent_attention(q, k_nope, k_rope, v, scale)
+        with jax.named_scope("latent_projections"):
+            y = o.reshape(B, T, -1) @ params["Wo"]
+        return self._act(y), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class PreNormBlock(Layer):
     """A pre-norm decoder block around any sequence mixer:
-    `h = x + r mixer(norm(x))`, then `h + r swiglu(norm(h))`, with
-    `r = residual_scale`, both norms RMS with a gain, a bias-free SwiGLU
-    of `ffn_width`. The mixer is a layer of its own (`MultiHeadAttention`
-    with whatever options, `LinearAttention`), given whole: the block
-    hands none of its options through. Leaves: `ln1_g`, `ln2_g`,
-    `mixer_*`, `ffn_w1`, `ffn_w3`, `ffn_w2`; the mixer's state is the
-    block's."""
+    `h = x + r mixer(norm(x))`, then `h + r F(norm(h))`, with
+    `r = residual_scale`, both norms RMS with a gain, and F a bias-free
+    SwiGLU of `ffn_width`. The mixer is a layer of its own
+    (`MultiHeadAttention` with whatever options, `LinearAttention`,
+    `LatentAttention`), given whole: the block hands none of its options
+    through. With `ffn` F is that layer in the SwiGLU's place, given
+    whole too (a `parallel/moe.ExpertFeedForward`: one device's share of
+    an expert layer). Leaves: `ln1_g`, `ln2_g`, `mixer_*`, and `ffn_w1`,
+    `ffn_w3`, `ffn_w2` or, with `ffn`, `moe_*`; the mixer's state and
+    `ffn`'s (its routing counters) are the block's."""
 
     CONSUMES = "rnn"   # [B, T, d] sequence activations
 
     n_in: Optional[int] = None
     mixer: Optional[Any] = None
     ffn_width: Optional[int] = None      # None -> 4 x n_in
+    ffn: Optional[Any] = None            # a layer in the SwiGLU's place
     residual_scale: float = 1.0
     eps: float = 1e-5
 
@@ -1217,6 +1421,12 @@ class PreNormBlock(Layer):
             weight_init=self.mixer.weight_init or self.weight_init,
             name=self.mixer.name or f"{self.name}.mixer")
 
+    def _ffn(self):
+        return dataclasses.replace(
+            self.ffn, n_in=self.n_in,
+            weight_init=self.ffn.weight_init or self.weight_init,
+            name=self.ffn.name or f"{self.name}.ffn")
+
     def init_params(self, key, input_type, dtype=jnp.float32):
         d = self.n_in
         ks = jax.random.split(key, 4)
@@ -1224,6 +1434,10 @@ class PreNormBlock(Layer):
                   "ln2_g": jnp.ones((d,), dtype)}
         mp, state = self._mixer().init_params(ks[0], input_type, dtype)
         params.update({f"mixer_{k}": v for k, v in mp.items()})
+        if self.ffn is not None:
+            fp, counters = self._ffn().init_params(ks[1], input_type, dtype)
+            params.update({f"moe_{k}": v for k, v in fp.items()})
+            return params, {**state, **counters}
         h = self.ffn_width or 4 * d
         winit = self._winit()
         params.update(ffn_w1=winit(ks[1], (d, h), dtype),
@@ -1245,8 +1459,14 @@ class PreNormBlock(Layer):
             mask=mask)
         x = x + self.residual_scale * a
         h = rms_norm(x, params["ln2_g"], self.eps)
-        with jax.named_scope("ffn"):
-            y = (jax.nn.silu(h @ params["ffn_w1"])
-                 * (h @ params["ffn_w3"])) @ params["ffn_w2"]
-        return (x + self.residual_scale * y,
-                (m_st or {}) if carry is None else {"mixer": m_st})
+        new_state = (m_st or {}) if carry is None else {"mixer": m_st}
+        if self.ffn is not None:
+            y, counters = self._ffn().apply(
+                {k[4:]: v for k, v in params.items()
+                 if k.startswith("moe_")}, h, train=train, rng=rng)
+            new_state = {**new_state, **counters}
+        else:
+            with jax.named_scope("ffn"):
+                y = (jax.nn.silu(h @ params["ffn_w1"])
+                     * (h @ params["ffn_w3"])) @ params["ffn_w2"]
+        return x + self.residual_scale * y, new_state

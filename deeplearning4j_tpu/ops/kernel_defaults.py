@@ -401,6 +401,43 @@ def sparse_policy(t: int, block_size: int) -> SparsePolicy:
                              "cannot exist")
 
 
+class LatentPolicy(NamedTuple):
+    kind: str            # "kernel" | "dense"
+    block_q: int
+    block_k: int
+    reason: str
+
+
+def latent_policy(t: int) -> LatentPolicy:
+    """Kernels-vs-dense for latent attention's core
+    (`ops/latent_attention.py`). No other kernel computes it (a query 192
+    wide against values of 128, one rope key for all heads), and its
+    dense form makes [heads, T, T] scores, so there is one verdict: the
+    kernels serve every sequence they tile on a TPU, the dense form the
+    rest (off the chip, the tests' odd lengths). `DL4J_TPU_ATTN=dense`
+    forces the dense form as it forces the others.
+
+    Tiles: 512 x 512 is where each kernel's own tile starts
+    (`ops/attention._pick_tile`: 1,024 x 512 forward and 1,024 x 1,024 in
+    both backward kernels at `deepseek_v2_fit`'s 32 heads and 8,192
+    tokens, as the flash kernels take at the same length)."""
+    import jax
+
+    from deeplearning4j_tpu.ops.latent_attention import latent_eligible
+
+    def verdict(kind, reason):
+        record_dispatch("latent_attention", kind)
+        return LatentPolicy(kind, min(512, t), min(512, t), reason)
+
+    if _env("DL4J_TPU_ATTN") == "dense":
+        return verdict("dense", "forced by DL4J_TPU_ATTN=dense")
+    if jax.default_backend() != "tpu":
+        return verdict("dense", "no TPU: the dense form")
+    if not latent_eligible(t):
+        return verdict("dense", f"shape ineligible (t={t})")
+    return verdict("kernel", "no other kernel computes it")
+
+
 class DecodePolicy(NamedTuple):
     kind: str            # "banded" | "dense"
     block_l: int

@@ -126,7 +126,8 @@ def batch_signature(ds):
 def _publish_routing_counters(net) -> None:
     """The last step's counters of every layer that keeps some in its
     state (`parallel/moe.ExpertFeedForward`'s routing: the `moe_*`
-    scalars; a `MultiHeadAttention` with a block selection: the
+    scalars, `moe_tokens_held` of a layer that routes by groups among
+    them; a `MultiHeadAttention` with a block selection: the
     `sparse_blocks_*` scalars) out of the net's layer state into the
     gauges `<counter>{layer=}`. Called where the epoch has just
     synchronised with the device; a net without such a layer pays a walk

@@ -328,14 +328,19 @@ class MoEFeedForward(Layer):
 
 # ------------------------------------------- one device's share of experts
 def route(x, router, bias, *, k: int, score: str, route_norm: bool,
-          route_scale: float):
+          route_scale: float, n_group: int = 1, topk_group: int = 1):
     """Each token's `k` experts and their weights: scores over the
     router's whole width in float32 (`score`: "sigmoid", each expert on
     its own, or "softmax"), the `k` largest of score + `bias` (which
     steers the choice only: no weight and no gradient comes of it), and
     the chosen scores themselves, normalised to sum to one where
-    `route_norm`, times `route_scale`. Returns (experts [N, k] int32,
-    weights [N, k] float32)."""
+    `route_norm`, times `route_scale`. With `n_group` > 1 the choice has
+    two stages (group-limited greedy, under the scope `group_select`):
+    the experts lie in `n_group` groups of consecutive indices, a token
+    keeps the `topk_group` groups whose best expert scores highest (of
+    equal ones the lower group) and chooses its `k` among those groups'
+    experts alone (of equal ones the lower expert). Returns (experts
+    [N, k] int32, weights [N, k] float32)."""
     logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
     if score == "sigmoid":
         s = jax.nn.sigmoid(logits)
@@ -346,7 +351,20 @@ def route(x, router, bias, *, k: int, score: str, route_norm: bool,
                          f"got {score!r}")
     choice = s if bias is None else s + jax.lax.stop_gradient(
         bias.astype(jnp.float32))
-    _, experts = jax.lax.top_k(jax.lax.stop_gradient(choice), k)
+    choice = jax.lax.stop_gradient(choice)
+    if n_group > 1:
+        n, e = choice.shape
+        if e % n_group or not 1 <= topk_group <= n_group \
+                or k > topk_group * (e // n_group):
+            raise ValueError(f"{e} experts in {n_group} groups, "
+                             f"{topk_group} kept, {k} chosen")
+        with jax.named_scope("group_select"):
+            best = jnp.max(choice.reshape(n, n_group, e // n_group), axis=-1)
+            _, kept = jax.lax.top_k(best, topk_group)           # [N, kept]
+            keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+            choice = jnp.where(jnp.repeat(keep, e // n_group, axis=1),
+                               choice, 0.0)
+    _, experts = jax.lax.top_k(choice, k)
     weights = jnp.take_along_axis(s, experts, axis=-1)
     if route_norm:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
@@ -468,6 +486,10 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
 # smallest load of an expert held
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs_dropped",
             "moe_load_max", "moe_load_min")
+# and, of a layer that routes by groups, the tokens with at least one pair
+# on an expert held: what the exchange would send this device, which is
+# what group limits exist to bound
+TOKENS_HELD = "moe_tokens_held"
 
 
 @register_layer
@@ -481,13 +503,17 @@ class ExpertFeedForward(Layer):
     all of them where None. What the absent experts would add is left out:
     on a mesh their devices add it, here nothing stands in for them.
 
+    With `n_group` > 1 a token chooses its `k` inside the `topk_group`
+    groups of experts whose best score is highest (`route`).
+
     Leaves: `router` [d, n_experts]; `bias` [n_experts] where
     `selection_bias` (added to the scores for the choice only, its
     gradient exactly zero: whoever balances load moves it between steps);
     `w1`, `w3` [count, d, width], `w2` [count, width, d]; with `n_shared`
     shared experts `shared_w1`, `shared_w3` [d, n_shared x width] and
-    `shared_w2`. State: the last step's `COUNTERS`, which `fit()`
-    publishes as gauges `<name>{layer=}` where an epoch synchronises.
+    `shared_w2`. State: the last step's `COUNTERS` and, where it routes
+    by groups, `TOKENS_HELD`, which `fit()` publishes as gauges
+    `<name>{layer=}` where an epoch synchronises.
     Accepts [N, d] or [B, T, d]."""
 
     CONSUMES = "any"
@@ -502,6 +528,8 @@ class ExpertFeedForward(Layer):
     route_norm: bool = False
     route_scale: float = 1.0
     n_shared: int = 0
+    n_group: int = 1
+    topk_group: int = 1
 
     def infer_n_in(self, input_type: InputType) -> "ExpertFeedForward":
         if self.n_in is None:
@@ -540,7 +568,8 @@ class ExpertFeedForward(Layer):
             params.update(shared_w1=winit(ks[4], (d, fs), dtype),
                           shared_w3=winit(ks[5], (d, fs), dtype),
                           shared_w2=winit(ks[6], (fs, d), dtype))
-        return params, dict.fromkeys(COUNTERS, jnp.zeros((), jnp.int32))
+        names = COUNTERS + ((TOKENS_HELD,) if self.n_group > 1 else ())
+        return params, dict.fromkeys(names, jnp.zeros((), jnp.int32))
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None):
@@ -549,11 +578,17 @@ class ExpertFeedForward(Layer):
             experts, weights = route(
                 tokens, params["router"], params.get("bias"), k=self.k,
                 score=self.score, route_norm=self.route_norm,
-                route_scale=self.route_scale)
+                route_scale=self.route_scale, n_group=self.n_group,
+                topk_group=self.topk_group)
+        first, count = self._held
         y, counters = held_experts(
             tokens, experts, weights.astype(tokens.dtype), params["w1"],
-            params["w3"], params["w2"], first=self._held[0],
+            params["w3"], params["w2"], first=first,
             n_experts=self.n_experts)
+        if self.n_group > 1:
+            here = (experts >= first) & (experts < first + count)
+            counters[TOKENS_HELD] = jnp.sum(jnp.any(here, axis=-1),
+                                            dtype=jnp.int32)
         if self.n_shared:
             with jax.named_scope("shared_expert"):
                 y = y + _swiglu(tokens, params["shared_w1"],

@@ -24,8 +24,8 @@ with _span("import.zoo"):
         GoogLeNet, InceptionResNetV1, FaceNetNN4Small2,
     )
     from deeplearning4j_tpu.zoo.transformer import (
-        HybridLinearSparseTransformer, SparseSandwichTransformer,
-        TextGenerationTransformer,
+        HybridLinearSparseTransformer, LatentSparseTransformer,
+        SparseSandwichTransformer, TextGenerationTransformer,
     )
     from deeplearning4j_tpu.zoo.pretrained import (
         PRETRAINED_CATALOG, PretrainedType, fetch_pretrained, load_pretrained,
@@ -40,4 +40,5 @@ __all__ = [
     "VGG19", "TextGenerationLSTM", "ResNet50", "GoogLeNet",
     "InceptionResNetV1", "FaceNetNN4Small2", "TextGenerationTransformer",
     "SparseSandwichTransformer", "HybridLinearSparseTransformer",
+    "LatentSparseTransformer",
 ]

@@ -14,8 +14,9 @@ from __future__ import annotations
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.attention import (
-    LinearAttention, MultiHeadAttention, PositionEmbeddingLayer,
-    PreNormBlock, SandwichTransformerBlock, TransformerEncoderBlock,
+    LatentAttention, LinearAttention, MultiHeadAttention,
+    PositionEmbeddingLayer, PreNormBlock, SandwichTransformerBlock,
+    TransformerEncoderBlock,
 )
 from deeplearning4j_tpu.nn.layers.feedforward import EmbeddingSequenceLayer
 from deeplearning4j_tpu.nn.layers.normalization import RMSNormalization
@@ -313,6 +314,104 @@ class HybridLinearSparseTransformer(ZooModel):
             *blocks,
             RMSNormalization(eps=c["rms_norm_eps"],
                              scale=c["dim_model_base"] / d),
+            RnnOutputLayer(n_out=self.num_classes, has_bias=False,
+                           activation="softmax", loss="sparse_mcxent"))
+            .set_input_type(InputType.recurrent(1, t))
+            .build())
+
+
+@register_zoo
+class LatentSparseTransformer(ZooModel):
+    """A causal language model of the `deepseek_v2` family, built from the
+    keys its published `config.json` has: pre-norm blocks (`PreNormBlock`)
+    whose mixer is multi-head latent attention (`LatentAttention`:
+    `q_lora_rank`, `kv_lora_rank`, `qk_nope_head_dim`, `qk_rope_head_dim`,
+    `v_head_dim`, rotary positions at `rope_theta` under `rope_scaling`,
+    type "yarn") and whose other half is a dense SwiGLU of
+    `intermediate_size` in the first `first_k_dense_replace` blocks and,
+    after them, softmax-routed experts (`parallel/moe.ExpertFeedForward`:
+    `n_routed_experts` of `moe_intermediate_size`, `num_experts_per_tok` a
+    token chosen inside the `topk_group` best of `n_group` groups,
+    weights not normalised unless `norm_topk_prob`, times
+    `routed_scaling_factor`, beside `n_shared_experts` shared ones). The
+    head is its own matrix behind a last RMS norm. The balance losses and
+    the paper's token dropping are not built.
+
+    One device's share of a deployment is built with `heads_held` (first,
+    count): the attention heads of every layer whose slices live here,
+    `experts_held` (first, count): the routed experts of every expert
+    layer whose kernels do, and `vocabulary_held`: the rows of the
+    embedding and the head that do. Token ids come as `[batch, time]`
+    integers, labels as integers (`sparse_mcxent`)."""
+
+    input_shape = (8192,)
+
+    def __init__(self, config: dict, *, timesteps: int = None,
+                 heads_held=None, experts_held=None,
+                 vocabulary_held: int = None, dtype: str = "float32",
+                 gradient_checkpointing=False, **kw):
+        super().__init__(
+            num_classes=vocabulary_held or config["vocab_size"],
+            input_shape=(timesteps or self.input_shape[0],), **kw)
+        for key, known in (("topk_method", ("group_limited_greedy",
+                                            "greedy")),
+                           ("scoring_func", ("softmax",))):
+            if config[key] not in known:
+                raise ValueError(f"{key} {config[key]!r} is not known "
+                                 f"({', '.join(known)} are)")
+        scaling = config.get("rope_scaling")
+        if scaling is not None and scaling.get("type") != "yarn":
+            raise ValueError(f"rope_scaling type {scaling.get('type')!r} is "
+                             f"not known (yarn is)")
+        if config.get("moe_layer_freq", 1) != 1:
+            raise ValueError("moe_layer_freq other than 1 is not wired")
+        self.config = dict(config)
+        self.heads_held = heads_held
+        self.experts_held = experts_held
+        self.dtype = dtype
+        self.gradient_checkpointing = gradient_checkpointing
+
+    def conf(self):
+        from deeplearning4j_tpu.parallel.moe import ExpertFeedForward
+
+        c, t = self.config, self.input_shape[0]
+        d, eps = c["hidden_size"], c["rms_norm_eps"]
+        mixer = LatentAttention(
+            num_heads=c["num_attention_heads"], heads_held=self.heads_held,
+            q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+            qk_nope_head_dim=c["qk_nope_head_dim"],
+            qk_rope_head_dim=c["qk_rope_head_dim"],
+            v_head_dim=c["v_head_dim"], rope_theta=float(c["rope_theta"]),
+            rope_scaling=c.get("rope_scaling"), norm_eps=eps)
+        grouped = c["topk_method"] == "group_limited_greedy"
+        experts = ExpertFeedForward(
+            width=c["moe_intermediate_size"], n_experts=c["n_routed_experts"],
+            held=None if self.experts_held is None
+            else tuple(self.experts_held),
+            k=c["num_experts_per_tok"], score=c["scoring_func"],
+            route_norm=c["norm_topk_prob"],
+            route_scale=c["routed_scaling_factor"],
+            n_shared=c["n_shared_experts"],
+            n_group=c["n_group"] if grouped else 1,
+            topk_group=c["topk_group"] if grouped else 1)
+        blocks = [
+            PreNormBlock(mixer=mixer, ffn_width=c["intermediate_size"],
+                         ffn=None if i < c["first_k_dense_replace"]
+                         else experts, eps=eps)
+            for i in range(c["num_hidden_layers"])]
+        builder = (NeuralNetConfiguration.builder()
+                   .seed(self.seed)
+                   .updater(self.kw.get("updater", Adam(3e-4)))
+                   .activation("identity")
+                   .weight_init("xavier")
+                   .dtype(self.dtype))
+        if self.gradient_checkpointing:
+            builder = builder.gradient_checkpointing()
+        return (builder.list(
+            EmbeddingSequenceLayer(n_in=self.num_classes, n_out=d,
+                                   activation="identity"),
+            *blocks,
+            RMSNormalization(eps=eps),
             RnnOutputLayer(n_out=self.num_classes, has_bias=False,
                            activation="softmax", loss="sparse_mcxent"))
             .set_input_type(InputType.recurrent(1, t))

@@ -29,6 +29,7 @@ lstm = importlib.import_module("deeplearning4j_tpu.ops.lstm")
 flash = importlib.import_module("deeplearning4j_tpu.ops.attention")
 banded = importlib.import_module("deeplearning4j_tpu.ops.banded_attention")
 sparse = importlib.import_module("deeplearning4j_tpu.ops.sparse_attention")
+latent = importlib.import_module("deeplearning4j_tpu.ops.latent_attention")
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -145,7 +146,26 @@ def _sparse(train, T=16384, heads=32, kv_heads=2, d=128):
         ((1, T, kv_heads, d), BF16)]
 
 
+def _latent(train, T=8192, heads=32, nope=128, rope=64, value=128):
+    # `deepseek_v2`'s attention core at the cell's size: 32 heads held, a
+    # query of 192 lanes against values of 128, one rope key for all
+    # heads; each kernel at the tile it picks for itself from the policy's
+    # 512 x 512
+    def fwd(q, k_nope, k_rope, v):
+        return latent.latent_attention(q, k_nope, k_rope, v, 0.114721, 512,
+                                       512, False)
+
+    def loss(*args):
+        return fwd(*args).astype(F32).sum()
+
+    return (jax.grad(loss, argnums=(0, 1, 2, 3)) if train else fwd), [
+        ((1, T, heads, nope + rope), BF16), ((1, T, heads, nope), BF16),
+        ((1, T, rope), BF16), ((1, T, heads, value), BF16)]
+
+
 CASES = {
+    "latent_fwd_32": lambda: _latent(train=False),
+    "latent_train_32": lambda: _latent(train=True),
     "sparse_fwd_32_2": lambda: _sparse(train=False),
     "sparse_train_32_2": lambda: _sparse(train=True),
     "lstm_fwd_f32": lambda: _lstm(F32, train=False),
@@ -199,6 +219,7 @@ FORWARD_TILES = {
     "banded_fwd_gqa_48_8": ("banded_attention", 6 * 256, 512),
     "flash_fwd_gqa_48_8": ("flash_attention", 1024, 512),
     "sparse_fwd_32_2": ("sparse_attention", 1024, 512),
+    "latent_fwd_32": ("latent_attention", 1024, 512),
 }
 
 
@@ -228,6 +249,7 @@ BACKWARD_TILES = {
                                   (6 * 256, 512)),
     "flash_pallas_bwd_gqa": ("flash_attention", (1024, 1024), (1024, 1024)),
     "sparse_train_32_2": ("sparse_attention", (1024, 512), (1024, 512)),
+    "latent_train_32": ("latent_attention", (1024, 1024), (1024, 1024)),
 }
 
 
@@ -290,6 +312,9 @@ KERNEL_NAMES = {
     "sparse_attention_fwd": "sparse_fwd_32_2",
     "sparse_attention_bwd_dq": "sparse_train_32_2",
     "sparse_attention_bwd_dkdv": "sparse_train_32_2",
+    "latent_attention_fwd": "latent_fwd_32",
+    "latent_attention_bwd_dq": "latent_train_32",
+    "latent_attention_bwd_dkdv": "latent_train_32",
     "matmul_channel_stats": _matmul_stats,
     "conv3x3_channel_stats": _conv3_stats,
 }
@@ -547,4 +572,50 @@ def test_minicpm_sala_step_fits_the_chip_with_nothing_t_by_t(chip):
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "sparse_attention" + kernel) == 1
     square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", text)
+    assert not square, sorted(set(square))[:5]
+
+
+# --- the benchmark's `deepseek_v2` step at the cell's own size (one
+# sequence of 8,192 ids, bf16, every layer checkpointed, 32 of 128 heads
+# and 8 of 160 experts held), built by the benchmark's own model file: it
+# fits the chip with room, each latent-attention kernel is in it once a
+# layer (the forward's output and log-sum-exp are kept, not remade), and
+# nothing [heads, T, T] exists.
+def _deepseek_step(monkeypatch):
+    import json
+
+    from benchmarks import harness
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(harness.BENCH_DIR, "configs", "deepseek_v2.json"),
+              encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    net = harness.load_module("models", "deepseek_v2.py").build(cfg, 0)
+    shapes = jax.eval_shape(
+        lambda: (net.init().params_tree, net.updater_state, net.state_tree))
+    t = cfg["input_shape"][0]
+    spec = lambda tree: jax.tree_util.tree_map(
+        lambda leaf: (leaf.shape, leaf.dtype), tree)
+    return (net.make_step_fn(), [
+        *map(spec, shapes), ((), I32), ((1, t), I32), ((1, t), I32), None,
+        None, ((2,), jnp.uint32)], cfg)
+
+
+def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
+    with pytest.MonkeyPatch.context() as mp:
+        step, shapes, cfg = _deepseek_step(mp)
+        args = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(*s, sharding=chip), shapes,
+            is_leaf=lambda s: isinstance(s, tuple))
+        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            *args).compile()
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
+            < 2 ** 20)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 13 * 2 ** 30)
+    layers, t = cfg["num_hidden_layers"], cfg["input_shape"][0]
+    for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
+        assert _kernel_calls(compiled, "latent_attention" + kernel) == layers
+    square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", compiled.as_text())
     assert not square, sorted(set(square))[:5]
