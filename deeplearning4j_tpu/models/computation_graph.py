@@ -201,7 +201,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
         remat = train and self.conf.gradient_checkpointing
         pool_after = {} if collect or remat else self._pool_after
         tails = {}      # pool vertex -> (convolution vertex, its tail)
-        kept = 0        # attention kernel calls whose residuals stay
+        kept = np.zeros(2, int)     # named residuals that stay
         for idx, name in enumerate(self.conf.topological_order):
             if name == stop_before:
                 break

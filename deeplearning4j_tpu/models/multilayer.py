@@ -105,40 +105,66 @@ def _check_decode_budget(model, decode_layers, t_step: int) -> None:
 def _checkpointed(apply_fn, mask):
     """Wrap one layer/vertex apply in jax.checkpoint for the TRAIN path
     (gradient_checkpointing): its activations are rematerialized in the
-    backward pass instead of stored, all but what a Pallas attention
-    kernel's backward reads of its forward, the kernel's output and its
-    rows' log-sum-exp (`ops/attention.RESIDUAL_NAMES`: one hidden-sized
-    tensor and T floats a head), which only a second run of the kernel
-    could remake. A layer that names nothing keeps nothing. Returns the
-    apply's result and how many kernel calls had the pair named. Shared by
-    MultiLayerNetwork and ComputationGraph so the remat semantics can't
-    drift."""
+    backward pass instead of stored, all but the values named with one of
+    `ops/attention.KEPT_NAMES`, which the policy keeps from the forward
+    pass to the backward:
+
+    - what a Pallas attention kernel's backward reads of its forward, the
+      kernel's output and its rows' log-sum-exp (`RESIDUAL_NAMES`: one
+      hidden-sized tensor and T floats a head), which only a second run of
+      the kernel could remake;
+    - what a block's recomputation would remake only to read it again
+      (`BLOCK_RESIDUAL_NAMES`): both halves' outputs of a
+      `SandwichTransformerBlock`, whose norms read them (two `[T, d]`
+      tensors a block), the stream between the halves of a `PreNormBlock`
+      (one), and a sparse `MultiHeadAttention`'s block selection
+      (`[B, KV heads, T, blocks]` bools). Kept, the product that made each
+      (`Wo`, the feed-forward's or the shared expert's down-projection,
+      the routed experts' tier, the selection) is dead in the recomputed
+      forward and JAX leaves it out; the gradients are the same numbers.
+
+    A layer that names nothing keeps nothing. Returns the apply's result
+    and the pair (kernel calls whose pair was named, block values named).
+    Shared by MultiLayerNetwork and ComputationGraph so the remat
+    semantics can't drift."""
     from deeplearning4j_tpu.ops.attention import (
-        RESIDUAL_NAMES, residuals_named,
+        KEPT_NAMES, block_residuals_named, residuals_named,
     )
 
     remat = jax.checkpoint(
         lambda p, x, st, lr, _a=apply_fn:
         _a(p, x, state=st, train=True, rng=lr, mask=mask),
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES))
+        policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+
+    def tally():
+        return np.array([residuals_named(), block_residuals_named()])
 
     def apply(p, x, st, lr):
-        before = residuals_named()
+        before = tally()
         out = remat(p, x, st, lr)
-        return out, residuals_named() - before
+        return out, tally() - before
 
     return apply
 
 
-def record_residuals_kept(model, kept: int) -> None:
-    """Gauge `attention_residuals_kept{model=<class>}`: how many attention
-    kernel calls of the forward pass that `model` traced last had their
-    output and log-sum-exp named inside a checkpointed layer (5 for the
-    benchmark's `trinity_large`, 0 without `gradient_checkpointing` or
-    without a Pallas attention forward under differentiation)."""
-    get_registry().gauge("attention_residuals_kept",
-                         model=type(model).__name__).set(kept)
+def record_residuals_kept(model, kept) -> None:
+    """`kept` is the sum of `_checkpointed`'s pairs over the forward pass
+    that `model` traced last. Gauge
+    `attention_residuals_kept{model=<class>}`: how many attention kernel
+    calls had their output and log-sum-exp named inside a checkpointed
+    layer (5 for the benchmark's `trinity_large`, 0 without
+    `gradient_checkpointing` or without a Pallas attention forward under
+    differentiation). Gauge `block_residuals_kept{model=<class>}`: how
+    many values the blocks named inside checkpointed layers
+    (`ops/attention.name_block_residual`: 10 for `trinity_large`, two a
+    block; 5 for `deepseek_v2`, the stream of each block; 5 for
+    `minicpm_sala`, four streams and one selection; 0 without
+    `gradient_checkpointing`). Both are set at trace time and read by no
+    benchmark metric."""
+    for name, value in zip(("attention_residuals_kept",
+                            "block_residuals_kept"), kept):
+        get_registry().gauge(name, model=type(model).__name__).set(
+            int(value))
 
 
 def record_sparse_dense(model, dense: int) -> None:
@@ -250,7 +276,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         n = len(self.layers)
         remat = train and self.conf.gradient_checkpointing
         tails = {}      # pool's index -> (convolution's name, its tail)
-        kept = 0        # attention kernel calls whose residuals stay
+        kept = np.zeros(2, int)     # named residuals that stay
         from deeplearning4j_tpu.ops.sparse_attention import dense_runs
 
         dense_before = dense_runs()
@@ -275,10 +301,10 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     tails[i + 1], new_st = (layer.name, tail), st
                 elif remat and not (layer.is_output_layer and i == n - 1):
                     # remat this layer's activations in the backward pass
-                    # (memory ∝ depth → the layers' inputs and, of an
-                    # attention kernel, its output and log-sum-exp; +~33%
-                    # FLOPs less that kernel's); the output layer is
-                    # skipped — its input is retained for the loss anyway
+                    # (memory ∝ depth → the layers' inputs and what they
+                    # name, `_checkpointed`; +~33% FLOPs less what the
+                    # names keep out); the output layer is skipped — its
+                    # input is retained for the loss anyway
                     (x, new_st), named = _checkpointed(layer.apply, fmask)(
                         params[layer.name], x, st, lrng)
                     kept += named

@@ -160,11 +160,19 @@ class Builder:
     def gradient_checkpointing(self, v: bool = True) -> "Builder":
         """Rematerialize layer activations in the backward pass
         (jax.checkpoint per layer/vertex) — memory for FLOPs. What stays
-        on the device between the passes is each layer's input and, of a
-        Pallas attention kernel (flash, banded), its output and its rows'
-        log-sum-exp (`ops/attention.RESIDUAL_NAMES`: one hidden-sized
-        tensor and T floats a head), so the recomputed forward does not
-        run that kernel a second time."""
+        on the device between the passes is each layer's input and what
+        the layers name with `ops/attention.KEPT_NAMES`: of a Pallas
+        attention kernel its output and its rows' log-sum-exp (one
+        hidden-sized tensor and T floats a head), so the recomputed
+        forward does not run that kernel a second time; and of a block
+        what its recomputation would remake only to read it again, so
+        that the product which made it is left out: two `[B, T, d]`
+        tensors a `SandwichTransformerBlock` (the outputs of both
+        halves: 2 x B x T x d x the dtype's bytes a block), one a
+        `PreNormBlock` (the stream between its halves), and a sparse
+        `MultiHeadAttention`'s block selection (B x KV heads x T x
+        blocks bytes). A model that checkpoints for memory pays those on
+        top of the layers' inputs; the gradients are the same numbers."""
         self._grad_ckpt = v
         return self
 

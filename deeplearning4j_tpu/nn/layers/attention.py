@@ -695,8 +695,13 @@ class MultiHeadAttention(Layer):
                              "no attention dropout")
         from deeplearning4j_tpu.ops.kernel_defaults import sparse_policy
 
+        from deeplearning4j_tpu.ops.attention import name_block_residual
+
         with jax.named_scope("sparse_select"):
-            allow = sp.select_blocks(q, k, sel)
+            # named: a checkpointed layer keeps the choice (bools) and its
+            # recomputed forward does not choose again
+            allow = name_block_residual(sp.select_blocks(q, k, sel),
+                                        "block_selection")
             kept, causal = sp.selection_counts(allow, sel.block_size)
         pol = sparse_policy(T, sel.block_size)
         with jax.named_scope("sparse_attention_core"):
@@ -1069,7 +1074,12 @@ class SandwichTransformerBlock(Layer):
     with `moe_k` a token, of which this device holds `experts_held`
     (first, count), experts and `n_shared` shared experts of
     `expert_width`. Leaves: `ln1_g` to `ln4_g`, `attn_*`, and `ffn_w1`,
-    `ffn_w3`, `ffn_w2` or `moe_*`."""
+    `ffn_w3`, `ffn_w2` or `moe_*`.
+
+    Under `gradient_checkpointing` the block keeps both halves' outputs
+    (two `[B, T, d]` tensors) beside its input: the norm after each reads
+    it, and the recomputed forward then leaves out the product that made
+    it (`ops/attention.name_block_residual`)."""
 
     CONSUMES = "rnn"   # [B, T, d] sequence activations
 
@@ -1148,6 +1158,8 @@ class SandwichTransformerBlock(Layer):
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None):
+        from deeplearning4j_tpu.ops.attention import name_block_residual
+
         attn, moe = self._sub()
         sub = lambda prefix: {k[len(prefix):]: v for k, v in params.items()
                               if k.startswith(prefix)}
@@ -1155,7 +1167,10 @@ class SandwichTransformerBlock(Layer):
         carry = state.get("attn") if state else None
         a, a_st = attn.apply(sub("attn_"), norm(x, 1), state=carry,
                              train=train, rng=rng, mask=mask)
-        x = x + norm(a, 2)
+        # both halves' outputs are named: the norm after each reads it, so
+        # a checkpointed block that did not keep them would run `Wo`, the
+        # down-projection and the experts' tier again only to have them
+        x = x + norm(name_block_residual(a, "sublayer_out"), 2)
         h = norm(x, 3)
         new_state = {} if carry is None else {"attn": a_st}
         if moe is None:
@@ -1165,6 +1180,7 @@ class SandwichTransformerBlock(Layer):
         else:
             y, counters = moe.apply(sub("moe_"), h, train=train, rng=rng)
             new_state.update(counters)
+        y = name_block_residual(y, "sublayer_out")
         return x + norm(y, 4), new_state
 
 
@@ -1393,7 +1409,12 @@ class PreNormBlock(Layer):
     whole too (a `parallel/moe.ExpertFeedForward`: one device's share of
     an expert layer). Leaves: `ln1_g`, `ln2_g`, `mixer_*`, and `ffn_w1`,
     `ffn_w3`, `ffn_w2` or, with `ffn`, `moe_*`; the mixer's state and
-    `ffn`'s (its routing counters) are the block's."""
+    `ffn`'s (its routing counters) are the block's.
+
+    Under `gradient_checkpointing` the block keeps `h` (one `[B, T, d]`
+    tensor) beside its input: the second norm reads it, and the recomputed
+    forward then leaves out the mixer's last product
+    (`ops/attention.name_block_residual`)."""
 
     CONSUMES = "rnn"   # [B, T, d] sequence activations
 
@@ -1450,6 +1471,8 @@ class PreNormBlock(Layer):
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None):
+        from deeplearning4j_tpu.ops.attention import name_block_residual
+
         mixer = self._mixer()
         mp = {k[6:]: v for k, v in params.items() if k.startswith("mixer_")}
         carry = state.get("mixer") if state else None
@@ -1457,7 +1480,11 @@ class PreNormBlock(Layer):
             mp, rms_norm(x, params["ln1_g"], self.eps),
             state=state if carry is None else carry, train=train, rng=rng,
             mask=mask)
-        x = x + self.residual_scale * a
+        # the stream between the halves is named: the second norm reads
+        # it, and a checkpointed block that did not keep it would run the
+        # mixer's last product again only to have it
+        x = name_block_residual(x + self.residual_scale * a,
+                                "residual_stream")
         h = rms_norm(x, params["ln2_g"], self.eps)
         new_state = (m_st or {}) if carry is None else {"mixer": m_st}
         if self.ffn is not None:

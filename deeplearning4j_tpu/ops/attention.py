@@ -63,7 +63,8 @@ Under gradient checkpointing: the forward rules of this kernel and of
 `ops/banded_attention.py`'s name the kernel's own output and the one-lane
 L (`name_residuals`: the checkpoint names `attention_out` and
 `attention_lse`, `RESIDUAL_NAMES`), and a checkpointed layer's policy
-keeps exactly those (`models/multilayer._checkpointed`). They are what the
+keeps those (`models/multilayer._checkpointed`, which reads `KEPT_NAMES`:
+the pair, and what the blocks name themselves). They are what the
 two backward kernels read of the forward and what only a second run of the
 forward kernel could remake: one hidden-sized tensor and T floats a head.
 `flash_attention_with_lse` names nothing: under a ring that would keep
@@ -88,10 +89,21 @@ _LSE_LANES = 128   # lane width for per-row statistics outputs (TPU tiling)
 # the checkpoint names of a forward kernel's output (in the layout its
 # backward reads) and of its rows' one-lane log-sum-exp
 RESIDUAL_NAMES = ("attention_out", "attention_lse")
+# and of what a block names (`name_block_residual`): a sub-layer's output
+# that a norm reads, the stream between a pre-norm block's halves, and a
+# block selection. Each is made by a sub-layer's LAST product (or by a
+# choice with no backward) and read again by the recomputation only as a
+# value: kept, what made it is dead there
+BLOCK_RESIDUAL_NAMES = ("sublayer_out", "residual_stream",
+                        "block_selection")
+# every name a checkpointed layer's policy keeps
+# (`models/multilayer._checkpointed`): the one list
+KEPT_NAMES = RESIDUAL_NAMES + BLOCK_RESIDUAL_NAMES
 
 
 class _Named(threading.local):
     calls = 0
+    blocks = 0
 
 
 _named = _Named()
@@ -102,6 +114,25 @@ def residuals_named() -> int:
     `name_residuals`; a checkpointed layer reads it before and after its
     own trace."""
     return _named.calls
+
+
+def block_residuals_named() -> int:
+    """How many values this thread has traced so far with
+    `name_block_residual`; read as `residuals_named` is."""
+    return _named.blocks
+
+
+def name_block_residual(x, name: str):
+    """`x` under `name`, one of `BLOCK_RESIDUAL_NAMES`, where a block
+    makes a value that its recomputation would make again only to read
+    it: bit for bit the same value, held from the forward pass to the
+    backward instead (one hidden-sized tensor, or a selection's bools).
+    A no-op without a policy that keeps the names; what follows has to
+    be made from the named `x`."""
+    if name not in BLOCK_RESIDUAL_NAMES:
+        raise ValueError(f"{name!r} is not in {BLOCK_RESIDUAL_NAMES}")
+    _named.blocks += 1
+    return checkpoint_name(x, name)
 
 
 def name_residuals(o, lse):

@@ -443,7 +443,14 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
     def tier(c):
         # under `jax.checkpoint`: what a tier keeps for its backward pass
         # is its arguments, the same for every tier, so the one that runs
-        # writes no residuals of the others' sizes
+        # writes no residuals of the others' sizes. That holds inside a
+        # checkpointed layer too, so this checkpoint stays there, and the
+        # tier's backward runs its forward again: three grouped products
+        # forward, three here, six backward. A layer's own recomputation
+        # runs the switch once more only where something reads the
+        # layer's OUTPUT as a value (a norm after it): the block that has
+        # one names that output (`ops/attention.name_block_residual`), the
+        # layer's policy keeps it, and JAX drops the dead run
         @jax.checkpoint
         def run(x, w1, w3, w2, token, place, pair_weight, sizes):
             back = jnp.where(place < c, place, c)
