@@ -203,6 +203,29 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         # version was an unlocked shared-state mutation)
         self._decode_state = DecodeState()
         self._solvers: Dict[Any, Any] = {}      # full-batch solver cache
+        # a head with `tied_to`: its name -> the layer whose W it reads
+        self._tied: Dict[str, str] = {
+            l.name: self._tied_target(l) for l in self.layers
+            if getattr(l, "tied_to", None) is not None}
+
+    def _tied_target(self, layer) -> str:
+        names = [l.name for l in self.layers]
+        tied = layer.tied_to
+        if isinstance(tied, int) and 0 <= tied < len(names):
+            tied = names[tied]
+        if tied not in names or tied == layer.name:
+            raise ValueError(f"{layer.name}: tied_to {layer.tied_to!r} "
+                             f"names no other layer of {names}")
+        return tied
+
+    def _params_of(self, params, layer):
+        """The layer's own leaves and, for a head with `tied_to`, the
+        named layer's `W` transposed beside them: one leaf in the tree,
+        read twice."""
+        own = params[layer.name]
+        if layer.name not in self._tied:
+            return own
+        return {**own, "W": params[self._tied[layer.name]]["W"].T}
 
     @property
     def score_(self) -> Optional[float]:
@@ -235,6 +258,16 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     self._stateful.add(layer.name)
                 if it is not None:
                     it = layer.output_type(it)
+            for layer in self.layers:
+                if layer.name not in self._tied:
+                    continue
+                source = self._tied[layer.name]
+                w = params[source].get("W")
+                if w is None or w.shape != (layer.n_out, layer.n_in):
+                    raise ValueError(
+                        f"{layer.name} is tied to {source}, whose W is "
+                        f"{None if w is None else w.shape}, not "
+                        f"{(layer.n_out, layer.n_in)}")
             self.params_tree = params
             self.state_tree = states
             self._build_updaters()
@@ -306,12 +339,12 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     # names keep out); the output layer is skipped — its
                     # input is retained for the loss anyway
                     (x, new_st), named = _checkpointed(layer.apply, fmask)(
-                        params[layer.name], x, st, lrng)
+                        self._params_of(params, layer), x, st, lrng)
                     kept += named
                 else:
                     x, new_st = layer.apply(
-                        params[layer.name], x, state=st, train=train,
-                        rng=lrng, mask=fmask)
+                        self._params_of(params, layer), x, state=st,
+                        train=train, rng=lrng, mask=fmask)
             if i in tails:
                 name, tail = tails[i]
                 with jax.named_scope(name):
@@ -347,8 +380,8 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                 )
                 new_states[out_layer.name] = cstate
             else:
-                score = out_layer.score(params[out_layer.name], out_in,
-                                        labels, score_mask)
+                score = out_layer.score(self._params_of(params, out_layer),
+                                        out_in, labels, score_mask)
         with jax.named_scope("regularization"):
             reg = sum(
                 layer.regularization(params[layer.name])

@@ -33,7 +33,7 @@ from deeplearning4j_tpu.nn.layers.special import (
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     LatentAttention, LinearAttention, MultiHeadAttention, PreNormBlock,
-    SandwichTransformerBlock,
+    SandwichTransformerBlock, SelectiveStateSpace,
 )
 
 __all__ = [
@@ -51,5 +51,6 @@ __all__ = [
     "LSTM", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn", "GRU",
     "RnnOutputLayer", "Bidirectional", "LastTimeStep",
     "FrozenLayer", "CenterLossOutputLayer", "VariationalAutoencoder", "RBM",
-    "MultiHeadAttention", "SandwichTransformerBlock",
+    "MultiHeadAttention", "SandwichTransformerBlock", "LinearAttention",
+    "LatentAttention", "PreNormBlock", "SelectiveStateSpace",
 ]

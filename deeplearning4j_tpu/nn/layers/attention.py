@@ -54,11 +54,19 @@ def rope_rotate(x, positions, base: float = 10000.0, inv_freq=None):
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
 
 
-def rms_norm(x, gain, eps: float = 1e-5):
+def rms_norm(x, gain, eps: float = 1e-5, axis_name=None):
     """x / sqrt(mean(x^2) + eps) * gain over the last axis; no centring,
-    no bias. The mean is taken in float32 where x is narrower."""
+    no bias. The mean is taken in float32 where x is narrower. With
+    `axis_name` the last axis is one device's share of the lanes and the
+    mean runs over all of them: the sum of squares and the lane count are
+    summed over that mesh axis."""
     wide = x.astype(jnp.promote_types(x.dtype, jnp.float32))
-    ms = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+    if axis_name is None:
+        ms = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+    else:
+        ms = (jax.lax.psum(jnp.sum(jnp.square(wide), axis=-1, keepdims=True),
+                           axis_name)
+              / jax.lax.psum(x.shape[-1], axis_name))
     return x * jax.lax.rsqrt(ms + eps).astype(x.dtype) * gain
 
 
@@ -145,6 +153,9 @@ class MultiHeadAttention(Layer):
     output_gate: bool = False         # o * sigmoid(x Wg) before Wo
     bias: bool = True                 # Wo's bias
     norm_eps: float = 1e-5            # of the q and k norms
+    softmax_scale: Optional[float] = None   # None -> head_dim ** -0.5; a
+    # given one (`attention_multiplier`) through the flash kernel, the ring
+    # and the dense core; windows, masks, dropout and selections refuse it
     sparse: Optional[Any] = None      # `ops.sparse_attention.BlockSelection`
     # (or its values): past `dense_len` tokens every query and KV group
     # reads `topk` blocks of keys chosen from scores over mean-pooled keys
@@ -185,6 +196,11 @@ class MultiHeadAttention(Layer):
         self._check_heads()
         if self.window is not None and self.window < 1:
             raise ValueError(f"window must be >= 1, got {self.window}")
+        if self.softmax_scale is not None and (self.window is not None
+                                               or self.sparse is not None):
+            raise ValueError("a given softmax_scale goes with neither a "
+                             "window nor a block selection: their kernels "
+                             "derive the scale from the head size")
         if self.rolling_cache:
             if self.window is None or not self.causal:
                 raise ValueError(
@@ -429,6 +445,9 @@ class MultiHeadAttention(Layer):
                 f"max_cache {L}; raise max_cache or clear state")
 
         q, k, v, gate = self._qkv(params, x)
+        if self.softmax_scale is not None:
+            # the decode cores divide by sqrt(Dh): the given scale rides q
+            q = q * jnp.asarray(self.softmax_scale * math.sqrt(Dh), q.dtype)
         if per_slot:
             valid = None if mask is None else (mask > 0)       # [B, T]
             n_new = (jnp.full(pos.shape, T, pos.dtype) if valid is None
@@ -714,7 +733,8 @@ class MultiHeadAttention(Layer):
         return o, dict(zip(SPARSE_COUNTERS, (kept, causal)))
 
     def _core(self, q, k, v, *, train, rng, mask):
-        """softmax(q k^T / sqrt(Dh)) v over [B, T, H, Dh] queries and
+        """softmax(q k^T / sqrt(Dh)) v (or times `softmax_scale`, where
+        that is given) over [B, T, H, Dh] queries and
         [B, T, Hkv, Dh] keys and values, by the path the policies pick."""
         T, H, Hkv = q.shape[1], self.num_heads, self._kv_heads
 
@@ -761,7 +781,8 @@ class MultiHeadAttention(Layer):
 
             k, v = broadcast_kv(k, v)
             return ring_self_attention(q, k, v, seq_ctx.mesh,
-                                       axis=seq_ctx.axis, causal=self.causal)
+                                       axis=seq_ctx.axis, causal=self.causal,
+                                       scale=self.softmax_scale)
         if self.window is not None and mask is None and not drop:
             # Sliding window (no mask/dropout): the banded kernel serves
             # this O(T·w) by grid construction, forward and backward,
@@ -791,7 +812,8 @@ class MultiHeadAttention(Layer):
             k, v = broadcast_kv(k, v)
             return self._masked_attention(q, k, v, mask, self.causal,
                                           dropout=drop, rng=rng,
-                                          window=self.window)
+                                          window=self.window,
+                                          scale=self.softmax_scale)
         # Flash-vs-dense, tile config, and backward selection all come
         # from the measured-winner policy (ops/kernel_defaults.py) —
         # the kernel must have a recorded hardware row beating XLA
@@ -803,16 +825,19 @@ class MultiHeadAttention(Layer):
         if pol.kind == "flash":
             from deeplearning4j_tpu.ops.attention import flash_attention
 
-            return flash_attention(q, k, v, self.causal, None, pol.block_q,
-                                   pol.block_k, False, pol.backward)
+            return flash_attention(q, k, v, self.causal, self.softmax_scale,
+                                   pol.block_q, pol.block_k, False,
+                                   pol.backward)
         k, v = broadcast_kv(k, v)
-        return attention(q, k, v, causal=self.causal)
+        return attention(q, k, v, causal=self.causal,
+                         scale=self.softmax_scale)
 
     @staticmethod
     def _masked_attention(q, k, v, mask, causal=False, dropout=0.0,
-                          rng=None, window=None):
+                          rng=None, window=None, scale=None):
         d = q.shape[-1]
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+        s = s / jnp.sqrt(d) if scale is None else s * scale
         bias = jnp.zeros((), s.dtype)
         if mask is not None:
             bias = jnp.where(mask[:, None, None, :] > 0, 0.0, -1e30)
@@ -1394,6 +1419,157 @@ class LatentAttention(Layer):
         with jax.named_scope("latent_projections"):
             y = o.reshape(B, T, -1) @ params["Wo"]
         return self._act(y), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class SelectiveStateSpace(Layer):
+    """A Mamba-2 mixer over [batch, time, features] (the `mamba` layers of
+    the `granitemoehybrid` family), causal, no bias but the convolution's.
+    With P = `head_dim`, N = `state_size`, G = `n_groups`, `a` the input:
+
+        [z | xBC | dt] = a W_in        z [T, H P], xBC [T, H P + 2 G N], dt [T, H]
+        xBC = silu(conv(xBC) + b_conv)     causal, depthwise, `conv_kernel`
+                                           taps (t - K + 1 .. t, the last
+                                           tap on the token itself)
+        x [T, H, P], B [T, G, N], C [T, G, N] = split(xBC)
+        dt = softplus(dt + dt_bias);  A_h = -exp(A_log_h)
+        S_t = exp(dt_t,h A_h) S_{t-1} + dt_t,h x_t,h B_t^T     [P, N] a head
+        y_t,h = S_t C_t + D_h x_t,h
+        out = norm(y * silu(z); norm) W_out    the RMS norm over all lanes
+
+    `num_heads` is the published count; `heads_held` = (first, count)
+    names the heads whose z, x and dt columns of `in_proj`, x channels of
+    the convolution, `dt_bias`, `A_log`, `D`, lanes of `norm` and rows of
+    `out_proj` this device has, all of them where None; B's and C's
+    columns and channels are whole on every device. A head's slices are
+    initialised from its own published index, so the shares of one layer
+    add up. Two things cross devices: `out_proj`'s partial sums (the
+    caller's all-reduce, as `LatentAttention`'s `Wo`) and the gated norm's
+    mean square, which runs over ALL heads' lanes: with `norm_axis` the
+    norm sums its squares and its lane count over that mesh axis; without,
+    the mean is over the lanes held, which is what a device has before
+    the exchange.
+
+    Initialised as Mamba-2 publishes: dt log-uniform in `DT_RANGE`
+    with `dt_bias` its inverse softplus, A uniform in [1, 16], D 1. Scopes: `ssm_mixer` round everything, inside it `ssm_conv`,
+    `ssm_core` (`ops/selective_scan.py`'s chunked scan alone) and
+    `ssm_gate_norm`. State: `ssm_chunk_carry`, the last step's mean over
+    heads and chunks of `exp(sum of dt A over a chunk)`, how much of a
+    state survives `chunk` tokens, which `fit()` publishes as the gauge
+    `ssm_chunk_carry{layer=}` where an epoch synchronises. Training and
+    scoring only."""
+
+    CONSUMES = "rnn"
+
+    n_in: Optional[int] = None
+    n_out: Optional[int] = None       # model dim (defaults to n_in)
+    num_heads: int = 4
+    heads_held: Optional[Any] = None  # (first, count); None -> all
+    head_dim: int = 16
+    state_size: int = 16
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk: int = 256
+    norm_eps: float = 1e-5
+    norm_axis: Optional[str] = None   # mesh axis the gated norm sums over
+
+    DT_RANGE = (1e-3, 1e-1)           # of the initial step sizes
+
+    # n_in and n_out from the input, [B, T, n_out] out: as the attention
+    # layers', and the heads held as `LatentAttention` reads them
+    infer_n_in = LatentAttention.infer_n_in
+    output_type = LatentAttention.output_type
+
+    @property
+    def _held(self):
+        first, count = LatentAttention._held.fget(self)
+        if self.n_groups != 1 and count != self.num_heads:
+            raise ValueError("a share of the heads goes with one group of "
+                             "B and C for all heads")
+        return int(first), int(count)
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        first, count = self._held
+        p, gn = self.head_dim, self.n_groups * self.state_size
+        ks = jax.random.split(key, 9)
+        winit = self._winit()
+
+        def heads(key, shape, axis, make=None):   # a head's own slices
+            make = make or (lambda k, s: winit(k, s, dtype))
+            return jnp.concatenate(
+                [make(jax.random.fold_in(key, first + i), shape)
+                 for i in range(count)], axis=axis)
+
+        tap = lambda k, s: jax.random.uniform(
+            k, s, dtype, -1.0, 1.0) / math.sqrt(self.conv_kernel)
+        dt = jnp.exp(heads(ks[6], (1,), 0, lambda k, s: jax.random.uniform(
+            k, s, jnp.float32, *map(math.log, self.DT_RANGE))))
+        a = heads(ks[7], (1,), 0, lambda k, s: jax.random.uniform(
+            k, s, jnp.float32, 1.0, 16.0))
+        return {
+            "in_proj": jnp.concatenate([
+                heads(ks[0], (self.n_in, p), 1),                    # z
+                heads(ks[1], (self.n_in, p), 1),                    # x
+                winit(ks[2], (self.n_in, 2 * gn), dtype),           # B, C
+                heads(ks[3], (self.n_in, 1), 1)], axis=1),          # dt
+            "conv_w": jnp.concatenate([
+                heads(ks[4], (self.conv_kernel, p), 1, tap),
+                tap(ks[5], (self.conv_kernel, 2 * gn))], axis=1),
+            "conv_b": jnp.zeros((count * p + 2 * gn,), dtype),
+            # softplus(dt_bias) = dt
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "A_log": jnp.log(a).astype(dtype),
+            "D": jnp.ones((count,), dtype),
+            "norm": jnp.ones((count * p,), dtype),
+            "out_proj": heads(ks[8], (p, self.n_out), 0),
+        }, {"ssm_chunk_carry": jnp.zeros((), jnp.float32)}
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        raise NotImplementedError(
+            f"SelectiveStateSpace {self.name!r} has no decode carry yet: "
+            f"its [head_dim, state_size] state a head and the "
+            f"convolution's last conv_kernel - 1 tokens want snapshots in "
+            f"the session carries, which serving does not have")
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        if mask is not None:
+            raise ValueError("SelectiveStateSpace takes no padding mask")
+        from deeplearning4j_tpu.ops.selective_scan import (
+            chunk_carry, selective_scan,
+        )
+
+        B, T, _ = x.shape
+        H, P, K = self._held[1], self.head_dim, self.conv_kernel
+        G, N = self.n_groups, self.state_size
+        inner = H * P
+        f32 = jnp.float32
+        with jax.named_scope("ssm_mixer"):
+            zxbcdt = x @ params["in_proj"]
+            z, xbc, dt = (zxbcdt[..., :inner],
+                          zxbcdt[..., inner:2 * inner + 2 * G * N],
+                          zxbcdt[..., 2 * inner + 2 * G * N:])
+            with jax.named_scope("ssm_conv"):
+                padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+                xbc = jax.nn.silu(sum(
+                    padded[:, k:k + T] * params["conv_w"][k]
+                    for k in range(K)) + params["conv_b"])
+            dt = jax.nn.softplus(dt.astype(f32)
+                                 + params["dt_bias"].astype(f32))
+            a = -jnp.exp(params["A_log"].astype(f32))
+            with jax.named_scope("ssm_core"):
+                y = selective_scan(
+                    xbc[..., :inner].reshape(B, T, H, P), dt, a,
+                    xbc[..., inner:inner + G * N].reshape(B, T, G, N),
+                    xbc[..., inner + G * N:].reshape(B, T, G, N),
+                    params["D"], chunk=self.chunk)
+            with jax.named_scope("ssm_gate_norm"):
+                y = rms_norm(y.reshape(B, T, inner) * jax.nn.silu(z),
+                             params["norm"], self.norm_eps, self.norm_axis)
+            out = y @ params["out_proj"]
+            carried = chunk_carry(dt, a, chunk=self.chunk)
+        return self._act(out), {**(state or {}), "ssm_chunk_carry": carried}
 
 
 @register_layer

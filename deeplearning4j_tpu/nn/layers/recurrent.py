@@ -389,10 +389,25 @@ class GravesBidirectionalLSTM(Layer):
 class RnnOutputLayer(OutputLayer):
     """Per-timestep dense + loss over time. Reference:
     `nn/conf/layers/RnnOutputLayer.java` (3-D in/out, time-distributed W·x+b,
-    masked loss)."""
+    masked loss).
+
+    `tied_to` names a layer of the same `MultiLayerNetwork` (its name, or
+    its index) whose `W` [n_out, n_in] this head reads transposed (a tied
+    embedding): the head then has no `W` of its own, the net hands it the
+    other layer's, and that one leaf's gradient is the sum of both uses."""
+
+    tied_to: Optional[Any] = None
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        if self.tied_to is None:
+            return super().init_params(key, input_type, dtype)
+        if not self.has_bias:
+            return {}, {}
+        return {"b": jnp.full((self.n_out,), self.bias_init or 0.0,
+                              dtype)}, {}
 
     def infer_n_in(self, input_type: InputType):
         if self.n_in is None:

@@ -128,7 +128,8 @@ def _publish_routing_counters(net) -> None:
     state (`parallel/moe.ExpertFeedForward`'s routing: the `moe_*`
     scalars, `moe_tokens_held` of a layer that routes by groups among
     them; a `MultiHeadAttention` with a block selection: the
-    `sparse_blocks_*` scalars) out of the net's layer state into the
+    `sparse_blocks_*` scalars; a `SelectiveStateSpace`: `ssm_chunk_carry`,
+    a share and so a float) out of the net's layer state into the
     gauges `<counter>{layer=}`. Called where the epoch has just
     synchronised with the device; a net without such a layer pays a walk
     over its state's keys."""
@@ -136,7 +137,7 @@ def _publish_routing_counters(net) -> None:
     for name, st in (getattr(net, "state_tree", None) or {}).items():
         if isinstance(st, dict):
             own = {k: v for k, v in st.items()
-                   if k.startswith(("moe_", "sparse_blocks_"))}
+                   if k.startswith(("moe_", "sparse_blocks_", "ssm_"))}
             if own:
                 counters[name] = own
     if counters:
@@ -145,7 +146,7 @@ def _publish_routing_counters(net) -> None:
         # graft: allow-sync(the epoch has just synchronised; one read)
         for name, values in jax.device_get(counters).items():
             for key, value in values.items():
-                get_registry().gauge(key, layer=name).set(int(value))
+                get_registry().gauge(key, layer=name).set(value.item())
 
 
 class TrainingExecutor:

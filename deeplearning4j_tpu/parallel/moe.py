@@ -404,18 +404,30 @@ def _swiglu(x, w1, w3, w2, dot):
     return dot(jax.nn.silu(dot(x, w1)) * dot(x, w3), w2)
 
 
-def _row_tiers(rows: int, share: float) -> Tuple[int, ...]:
+def _row_tiers(rows: int, share: float,
+               most: Optional[int] = None) -> Tuple[int, ...]:
     """The row counts the routed products are compiled for: four times
     what uniform routing sends to the experts held (`share` of all `rows`
-    pairs), twice and four times that, and all of them. A step runs the smallest
-    that holds the pairs that fell here, so no pair is ever dropped and
-    the work follows them, coarsely: on the chip a router's load on eight
-    of 256 experts read 0.4 to 2.1 times the uniform share from seed to
-    seed and batch to batch, and a tier that every other step crosses
-    makes the step's time a matter of the seed."""
-    up = lambda n: min(rows, -(-int(n) // 128) * 128)
+    pairs), twice and four times that, and `most`, all that can fall here
+    (a token's pairs lie on distinct experts; `rows` where not given). A
+    step runs the smallest that holds the pairs that fell here, so no
+    pair is ever dropped and the work follows them, coarsely: on the chip
+    a router's load on eight of 256 experts read 0.4 to 2.1 times the
+    uniform share from seed to seed and batch to batch, and a tier that
+    every other step crosses makes the step's time a matter of the seed.
+    Where twice the first tier is `most` or more the ladder would have
+    one rung to climb, a step on it runs the layer at twice the cost, and
+    WHEN a training run's routers climb it differs from seed to seed: nine
+    of 72 experts at ten a token crossed in the eighteenth step, one to
+    three layers of ten apart, and a window's rate read 0.73% apart where
+    the other cells read 0.03 (chip runs, PR 42). There the one tier is
+    `most`, and every step costs the same."""
+    most = min(rows, -(-int(rows if most is None else most) // 128) * 128)
+    up = lambda n: min(most, -(-int(n) // 128) * 128)
     first = up(4 * share * rows)
-    return tuple(sorted({first, up(2 * first), up(4 * first), rows}))
+    if 2 * first >= most:
+        return (most,)
+    return tuple(sorted({first, up(2 * first), up(4 * first), most}))
 
 
 def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
@@ -438,7 +450,7 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
         sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
                         dtype=jnp.int32)
         n_held = jnp.sum(sizes)
-    tiers = _row_tiers(rows, count / n_experts)
+    tiers = _row_tiers(rows, count / n_experts, n * min(k, count))
 
     def tier(c):
         # under `jax.checkpoint`: what a tier keeps for its backward pass

@@ -24,8 +24,9 @@ with _span("import.zoo"):
         GoogLeNet, InceptionResNetV1, FaceNetNN4Small2,
     )
     from deeplearning4j_tpu.zoo.transformer import (
-        HybridLinearSparseTransformer, LatentSparseTransformer,
-        SparseSandwichTransformer, TextGenerationTransformer,
+        HybridLinearSparseTransformer, HybridStateSpaceTransformer,
+        LatentSparseTransformer, SparseSandwichTransformer,
+        TextGenerationTransformer,
     )
     from deeplearning4j_tpu.zoo.pretrained import (
         PRETRAINED_CATALOG, PretrainedType, fetch_pretrained, load_pretrained,
@@ -40,5 +41,5 @@ __all__ = [
     "VGG19", "TextGenerationLSTM", "ResNet50", "GoogLeNet",
     "InceptionResNetV1", "FaceNetNN4Small2", "TextGenerationTransformer",
     "SparseSandwichTransformer", "HybridLinearSparseTransformer",
-    "LatentSparseTransformer",
+    "LatentSparseTransformer", "HybridStateSpaceTransformer",
 ]

@@ -16,7 +16,7 @@ from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.attention import (
     LatentAttention, LinearAttention, MultiHeadAttention,
     PositionEmbeddingLayer, PreNormBlock, SandwichTransformerBlock,
-    TransformerEncoderBlock,
+    SelectiveStateSpace, TransformerEncoderBlock,
 )
 from deeplearning4j_tpu.nn.layers.feedforward import EmbeddingSequenceLayer
 from deeplearning4j_tpu.nn.layers.normalization import RMSNormalization
@@ -414,5 +414,130 @@ class LatentSparseTransformer(ZooModel):
             RMSNormalization(eps=eps),
             RnnOutputLayer(n_out=self.num_classes, has_bias=False,
                            activation="softmax", loss="sparse_mcxent"))
+            .set_input_type(InputType.recurrent(1, t))
+            .build())
+
+
+@register_zoo
+class HybridStateSpaceTransformer(ZooModel):
+    """A causal language model of the `granitemoehybrid` family (Granite
+    4.0-H), built from the keys its published `config.json` has: pre-norm
+    blocks (`PreNormBlock`) whose halves enter the residual stream times
+    `residual_multiplier`, with, by `layer_types`, one of two mixers.
+    "mamba": a Mamba-2 selective state-space mixer (`SelectiveStateSpace`:
+    `mamba_n_heads` heads of `mamba_d_head`, `mamba_d_state`,
+    `mamba_n_groups`, a causal convolution of `mamba_d_conv`, chunks of
+    `mamba_chunk_size`). "attention": GQA softmax attention with no
+    positions (`position_embedding_type` "nope") whose softmax scale is
+    `attention_multiplier`. Every block's other half is softmax-routed
+    experts (`parallel/moe.ExpertFeedForward`: `num_local_experts` of
+    `intermediate_size`, `num_experts_per_tok` a token, the weights a
+    softmax over the chosen logits) beside a shared SwiGLU of
+    `shared_intermediate_size`. Embedding rows are scaled by
+    `embedding_multiplier`; the head is the embedding transposed
+    (`tie_word_embeddings`) behind a last RMS norm, the logits divided by
+    `logits_scaling`. No balance loss is built (the config gives no
+    coefficient).
+
+    One device's share of a deployment is built with `heads_held` (first,
+    count): the Mamba heads of every `mamba` layer whose slices live here,
+    `attention_heads_held` and `kv_heads_held`: the query and KV heads of
+    every `attention` layer that do, `experts_held`: the routed experts of
+    every block whose kernels do, and `vocabulary_held`: the rows of the
+    tied embedding that do. Token ids come as `[batch, time]` integers,
+    labels as integers (`sparse_mcxent`)."""
+
+    input_shape = (8192,)
+
+    def __init__(self, config: dict, *, timesteps: int = None,
+                 heads_held=None, attention_heads_held=None,
+                 kv_heads_held=None, experts_held=None,
+                 vocabulary_held: int = None, dtype: str = "float32",
+                 gradient_checkpointing=False, **kw):
+        super().__init__(
+            num_classes=vocabulary_held or config["vocab_size"],
+            input_shape=(timesteps or self.input_shape[0],), **kw)
+        kinds = list(config["layer_types"])
+        if len(kinds) != config["num_hidden_layers"]:
+            raise ValueError(
+                f"layer_types has {len(kinds)} entries for "
+                f"{config['num_hidden_layers']} layers")
+        unknown = set(kinds) - {"mamba", "attention"}
+        if unknown:
+            raise ValueError(f"layer_types {sorted(unknown)} not known")
+        for key, known in (("position_embedding_type", "nope"),
+                           ("normalization_function", "rmsnorm"),
+                           ("hidden_act", "silu"),
+                           ("mamba_proj_bias", False),
+                           ("mamba_conv_bias", True),
+                           ("attention_bias", False),
+                           ("tie_word_embeddings", True)):
+            if config[key] != known:
+                raise ValueError(f"{key} {config[key]!r} is not wired "
+                                 f"({known!r} is)")
+        if (config["mamba_expand"] * config["hidden_size"]
+                != config["mamba_n_heads"] * config["mamba_d_head"]):
+            raise ValueError("mamba_expand x hidden_size is not "
+                             "mamba_n_heads x mamba_d_head")
+        if config["shared_intermediate_size"] % config["intermediate_size"]:
+            raise ValueError("the shared expert is no whole number of "
+                             "experts wide")
+        self.config = dict(config)
+        self.heads_held = heads_held
+        self.attention_heads_held = attention_heads_held
+        self.kv_heads_held = kv_heads_held
+        self.experts_held = experts_held
+        self.dtype = dtype
+        self.gradient_checkpointing = gradient_checkpointing
+
+    def _mixer(self, kind: str):
+        c, t = self.config, self.input_shape[0]
+        if kind == "mamba":
+            return SelectiveStateSpace(
+                num_heads=c["mamba_n_heads"], heads_held=self.heads_held,
+                head_dim=c["mamba_d_head"], state_size=c["mamba_d_state"],
+                n_groups=c["mamba_n_groups"], conv_kernel=c["mamba_d_conv"],
+                chunk=c["mamba_chunk_size"], norm_eps=c["rms_norm_eps"])
+        heads = c["num_attention_heads"]
+        held = (self.attention_heads_held or (0, heads))[1]
+        kv_held = (self.kv_heads_held or (0, c["num_key_value_heads"]))[1]
+        return MultiHeadAttention(
+            num_heads=held, num_kv_heads=kv_held,
+            head_dim=c["hidden_size"] // heads, causal=True, rope=False,
+            bias=False, max_cache=t,
+            softmax_scale=c["attention_multiplier"])
+
+    def conf(self):
+        from deeplearning4j_tpu.parallel.moe import ExpertFeedForward
+
+        c, t = self.config, self.input_shape[0]
+        d, eps = c["hidden_size"], c["rms_norm_eps"]
+        experts = ExpertFeedForward(
+            width=c["intermediate_size"], n_experts=c["num_local_experts"],
+            held=None if self.experts_held is None
+            else tuple(self.experts_held),
+            k=c["num_experts_per_tok"], score="softmax", route_norm=True,
+            n_shared=c["shared_intermediate_size"] // c["intermediate_size"])
+        blocks = [
+            PreNormBlock(mixer=self._mixer(kind), ffn=experts,
+                         residual_scale=c["residual_multiplier"], eps=eps)
+            for kind in c["layer_types"]]
+        builder = (NeuralNetConfiguration.builder()
+                   .seed(self.seed)
+                   .updater(self.kw.get("updater", Adam(3e-4)))
+                   .activation("identity")
+                   .weight_init("xavier")
+                   .dtype(self.dtype))
+        if self.gradient_checkpointing:
+            builder = builder.gradient_checkpointing()
+        return (builder.list(
+            EmbeddingSequenceLayer(n_in=self.num_classes, n_out=d,
+                                   activation="identity",
+                                   scale=c["embedding_multiplier"]),
+            *blocks,
+            RMSNormalization(eps=eps, scale=1.0 / c["logits_scaling"]),
+            RnnOutputLayer(n_out=self.num_classes, has_bias=False,
+                           activation="softmax", loss="sparse_mcxent",
+                           tied_to=0))
             .set_input_type(InputType.recurrent(1, t))
             .build())

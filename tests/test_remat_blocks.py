@@ -37,10 +37,10 @@ from deeplearning4j_tpu.parallel.moe import ExpertFeedForward, _row_tiers
 names = importlib.import_module("deeplearning4j_tpu.ops.attention")
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# 256 tokens x 2 a token over 2 of 16 experts: two tiers, so the routed
-# products sit under a `lax.switch` as they do in the cells
+# 256 tokens x 2 a token over 2 of 32 experts: three tiers, so the routed
+# products sit under a `lax.switch` as they do in the cells with a ladder
 T, WIDTH, CLASSES, LAYERS = 256, 32, 8, 2
-EXPERTS = dict(n_experts=16, score="sigmoid", n_shared=1)
+EXPERTS = dict(n_experts=32, score="sigmoid", n_shared=1)
 HEADS = dict(num_heads=4, num_kv_heads=2)
 SELECTION = BlockSelection(block_size=8, topk=5, init_blocks=1,
                            window_size=12, kernel_size=4, kernel_stride=2,
@@ -150,7 +150,7 @@ def _gauge(net, name="block_residuals_kept"):
 
 
 def test_the_tiers_sit_under_a_switch_here():
-    assert len(_row_tiers(T * 2, 2 / 16)) == 2
+    assert len(_row_tiers(T * 2, 2 / 32, T * 2)) == 3
 
 
 @pytest.mark.parametrize("kind", sorted(BLOCKS))

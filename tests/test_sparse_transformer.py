@@ -359,6 +359,8 @@ def test_the_rows_multiplied_follow_the_pairs_that_fell_here():
     assert _row_tiers(32768, 8 / 256) == (4096, 8192, 16384, 32768)
     assert _row_tiers(512, 1 / 32) == (128, 256, 512)
     assert _row_tiers(256, 4 / 16) == (256,)
+    # all that can fall here tops the ladder: k pairs on distinct experts
+    assert _row_tiers(1024, 1 / 64, 512) == (128, 256, 512)
     layer = ExpertFeedForward(n_in=16, width=24, n_experts=32, held=(5, 1),
                               k=2, score="softmax", selection_bias=True,
                               weight_init="xavier")

@@ -15,6 +15,7 @@ chip cannot be read back without one.
 """
 
 import importlib
+import math
 import os
 import re
 
@@ -619,3 +620,76 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
         assert _kernel_calls(compiled, "latent_attention" + kernel) == layers
     square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", compiled.as_text())
     assert not square, sorted(set(square))[:5]
+
+
+# --- the benchmark's `granite_4_0_h_small` step at the cell's own size
+# (one sequence of 8,192 ids, bf16, every layer checkpointed, 32 of 128
+# Mamba-2 heads, 8 of 32 attention heads and 9 of 72 experts held, the
+# head tied to the embedding), built by the benchmark's own model file: it
+# fits the chip with the scan's [chunks, heads, 256, 256] float32 tensors
+# counted, the tied embedding is ONE argument (1.340G parameters, not
+# 1.392G), the attention layer's flash kernels are in it once, and nothing
+# [heads, T, T] exists.
+def _granite_step(monkeypatch):
+    import json
+
+    from benchmarks import harness
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(harness.BENCH_DIR, "configs",
+                           "granite_4_0_h_small.json"),
+              encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    net = harness.load_module("models", "granite_4_0_h_small.py").build(cfg, 0)
+    shapes = jax.eval_shape(
+        lambda: (net.init().params_tree, net.updater_state, net.state_tree))
+    t = cfg["input_shape"][0]
+    spec = lambda tree: jax.tree_util.tree_map(
+        lambda leaf: (leaf.shape, leaf.dtype), tree)
+    return (net.make_step_fn(), [
+        *map(spec, shapes), ((), I32), ((1, t), I32), ((1, t), I32), None,
+        None, ((2,), jnp.uint32)], cfg)
+
+
+def test_granite_step_fits_the_chip_with_one_tied_embedding(chip):
+    with pytest.MonkeyPatch.context() as mp:
+        step, shapes, cfg = _granite_step(mp)
+        args = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(*s, sharding=chip), shapes,
+            is_leaf=lambda s: isinstance(s, tuple))
+        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            *args).compile()
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
+            < 2 ** 20)
+    # bf16 parameters and both moments of 1,340,223,584: the embedding once
+    assert memory.argument_size_in_bytes == pytest.approx(
+        6 * 1_340_223_584, rel=1e-3)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 13 * 2 ** 30)
+    t = cfg["input_shape"][0]
+    for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
+        assert _kernel_calls(compiled, "flash_attention" + kernel) == 1
+    square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", compiled.as_text())
+    assert not square, sorted(set(square))[:5]
+
+
+def test_selective_scan_makes_c_b_t_once_a_chunk_not_once_a_head(chip):
+    """The forward of `ops/selective_scan.py` at the cell's widths (32
+    heads of 64, a state of 128, chunks of 256; four chunks here): of the
+    matrix products the chip runs, exactly one gives a [256, 256] tile, the
+    chunk's `C B^T` over the state's 128 lanes, [chunks, 256, 256] with
+    no heads in it; the per-head [chunks, heads, 256, 256] scores are made
+    from it elementwise."""
+    scan = importlib.import_module("deeplearning4j_tpu.ops.selective_scan")
+    t, h, p, n = 1024, 32, 64, 128
+    compiled = _compile(
+        chip, lambda *a: scan.selective_scan(*a, chunk=256),
+        [((1, t, h, p), BF16), ((1, t, h), F32), ((h,), F32),
+         ((1, t, 1, n), BF16), ((1, t, 1, n), BF16), ((h,), F32)])
+    products = re.findall(r"= (\w+\[[\d,]+\])\S* convolution\(",
+                          compiled.as_text())
+    tiles = [s for s in products if re.search(r"256,256\]$", s)]
+    assert len(tiles) == 1, products
+    dims = [int(d) for d in re.findall(r"\d+", tiles[0].split("[")[1])]
+    assert math.prod(dims) == (t // 256) * 256 * 256, tiles
