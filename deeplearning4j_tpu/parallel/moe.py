@@ -23,9 +23,11 @@ axis and its all_to_all are its reason to stay. `ExpertFeedForward` is
 one device's share of a large sparse model's expert layer: a router over
 all `n_experts`, of which this device holds `held`, the (token, expert)
 pairs sorted by expert, the rows of the experts held gathered, grouped
-matrix products over them (`jax.lax.ragged_dot`), and the results
-gathered back by token. No pair is dropped at any imbalance and no
-[N, E, C] tensor exists; what the absent experts would add is left out.
+matrix products over them (`jax.lax.ragged_dot`; on the TPU, where the
+rows come in one tier, `ops/grouped_matmul.grouped_dot`, which passes by
+the tiles that hold no pair), and the results gathered back by token.
+No pair is dropped at any imbalance and no [N, E, C] tensor exists; what
+the absent experts would add is left out.
 """
 
 from __future__ import annotations
@@ -42,7 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.nn.activations import Activation
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
-from deeplearning4j_tpu.parallel.mesh import AXIS_EXPERT
+from deeplearning4j_tpu.parallel.mesh import AXIS_EXPERT, current_mesh_context
 
 
 _ACTIVE_MESH: List[Tuple[Mesh, str]] = []
@@ -421,7 +423,13 @@ def _row_tiers(rows: int, share: float,
     of 72 experts at ten a token crossed in the eighteenth step, one to
     three layers of ten apart, and a window's rate read 0.73% apart where
     the other cells read 0.03 (chip runs, PR 42). There the one tier is
-    `most`, and every step costs the same."""
+    `most`: what passes over the tier's rows (the sort, the gathers, the
+    elementwise work) costs the same every step, and the grouped products,
+    whose rows past the pairs held are in no group there, follow the pairs
+    with no threshold to cross (`held_experts`; six seeds' rates lay 0.04%
+    apart by their quartiles where the two tiers' lay 0.73: chip runs,
+    PR 43). A layer WITH a ladder counts those rows to the last expert
+    held, so each of its tiers costs the same whatever fell into it."""
     most = min(rows, -(-int(rows if most is None else most) // 128) * 128)
     up = lambda n: min(most, -(-int(n) // 128) * 128)
     first = up(4 * share * rows)
@@ -451,6 +459,26 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
                         dtype=jnp.int32)
         n_held = jnp.sum(sizes)
     tiers = _row_tiers(rows, count / n_experts, n * min(k, count))
+    # A layer with ONE tier has nothing between its pairs and all that can
+    # fall here. On the TPU (on one device: under a mesh the operands may
+    # be sharded) its grouped products are `ops/grouped_matmul`'s kernel,
+    # whose grid visits only the row tiles that hold a row of a group, and
+    # the rows past the pairs held are left in NO group, so the kernel
+    # passes them by: the products' time follows the pairs, continuously,
+    # with no tier to cross. A layer with a ladder, and every layer on any
+    # other backend, counts those rows to the last expert held and runs
+    # XLA's `ragged_dot`: a tier then costs the same whatever fell into
+    # it, its padding is bounded by the ladder (four times the uniform
+    # share), and a step's time is not a matter of the seed. (The kernel
+    # is the faster there too, 1.5 to 2.4 times at the laddered cells'
+    # shapes with every row in a group, but a ladder's four tiers a layer
+    # are four times the call sites to trace, lower and compile, and the
+    # cells' set-up grew by a fifth: chip runs, PR 43.)
+    from deeplearning4j_tpu.ops.grouped_matmul import (
+        grouped_dot, rows_visited, schedule,
+    )
+
+    kernel = len(tiers) == 1 and _kernel_runs()
 
     def tier(c):
         # under `jax.checkpoint`: what a tier keeps for its backward pass
@@ -469,20 +497,24 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
             # A row past the pairs held is no pair of ours. Left in no
             # group, what a grouped product puts in it is undefined on the
             # chip, in its result and in its cotangent alike (the CPU gives
-            # zeros), and a product's time there follows the rows that ARE
-            # in a group (a step read 10 ms longer at 6% of the pairs held
-            # than at 2%). So such rows are put at zero on both sides of
-            # every product and counted to the last expert held: they add
-            # nothing, and a tier costs what it costs whatever fell into it.
+            # zeros), so such rows are put at zero on both sides of every
+            # product, whichever product runs and whether or not they are
+            # counted to the last expert held (`kernel`, above).
             live = (jnp.arange(c) < jnp.sum(sizes))[:, None]
             rows = lambda v: jnp.where(live, v, 0)
-            full = sizes.at[-1].add(
+            in_group = sizes if kernel else sizes.at[-1].add(
                 (c - jnp.sum(sizes)).astype(sizes.dtype))
             with jax.named_scope("dispatch"):
                 taken = rows(_take_rows(x, token[:c], back))
             with jax.named_scope("experts_held"):
-                out = _swiglu(taken, w1, w3, w2, lambda a, w: rows(
-                    jax.lax.ragged_dot(a, w, group_sizes=full)))
+                if kernel:      # one schedule for the three products
+                    plan = schedule(in_group, c)
+                    product = lambda a, w: grouped_dot(a, w, plan)
+                else:
+                    product = lambda a, w: jax.lax.ragged_dot(
+                        a, w, group_sizes=in_group)
+                out = _swiglu(taken, w1, w3, w2,
+                              lambda a, w: rows(product(a, w)))
             with jax.named_scope("combine"):
                 return _sum_rows(out * pair_weight[:c, None].astype(
                     out.dtype), token[:c], back)
@@ -494,17 +526,31 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
     y = (jax.lax.switch(which, [tier(c) for c in tiers], *args)
          if len(tiers) > 1 else tier(tiers[0])(*args))
     taken = jnp.asarray(tiers, jnp.int32)[which]
+    visited = rows_visited(schedule(sizes, tiers[0])) if kernel else taken
     return y, dict(zip(COUNTERS, (
         jnp.asarray(rows, jnp.int32), n_held,
-        jnp.maximum(n_held - taken, 0), jnp.max(sizes), jnp.min(sizes))))
+        jnp.maximum(n_held - taken, 0), jnp.max(sizes), jnp.min(sizes),
+        taken, visited.astype(jnp.int32))))
+
+
+def _kernel_runs() -> bool:
+    """Whether an expert layer traced now may run `ops/grouped_matmul`'s
+    kernel: on the TPU backend, its operands on one device (under a mesh
+    context they may be sharded, which the kernel is not written for)."""
+    return (jax.default_backend() == "tpu"
+            and current_mesh_context() is None)
 
 
 # a step's routing counters, in an expert layer's state: the pairs the
 # router chose (tokens x k), those that fell on experts held, those of
-# them not computed (0: there is no capacity), and the largest and
-# smallest load of an expert held
+# them not computed (0: there is no capacity), the largest and smallest
+# load of an expert held, the rows of the tier the step ran, and the rows
+# its grouped products multiplied: the row tiles their schedule visits
+# times a tile's rows (`ops/grouped_matmul.schedule`), the tier's rows
+# where every row is in a group
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs_dropped",
-            "moe_load_max", "moe_load_min")
+            "moe_load_max", "moe_load_min", "moe_rows_tier",
+            "moe_rows_visited")
 # and, of a layer that routes by groups, the tokens with at least one pair
 # on an expert held: what the exchange would send this device, which is
 # what group limits exist to bound

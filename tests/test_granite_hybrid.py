@@ -7,6 +7,8 @@ the uncut layer; `route`'s softmax over the chosen logits; one tied leaf
 read twice; and `zoo.HybridStateSpaceTransformer` against the benchmark's
 plain reference, loss, every leaf's gradient and three Adam steps."""
 
+import functools
+import importlib
 import json
 import math
 import os
@@ -345,6 +347,106 @@ def test_a_router_collapsed_onto_the_experts_held_drops_nothing():
         _close(got, want, 1e-5)
         assert int(counters["moe_pairs_dropped"]) == 0
     assert int(counters["moe_pairs_held"]) == 128 * 9
+
+
+def _one_rung_case():
+    """Nine of 72 experts held, ten a token: one tier of 1,024 x 9 rows,
+    of which uniform routing fills a seventh."""
+    cfg = _tiny(num_local_experts=72, num_experts_per_tok=10,
+                experts_held=[0, 9])
+    layer = _expert_layer(cfg, (0, 9))
+    p, _ = layer.init_params(jax.random.PRNGKey(3),
+                             InputType.recurrent(32, 512))
+    x, ct = _normal(14, (2, 512, 32), (2, 512, 32))
+
+    def run():
+        def loss(p, x):
+            y, counters = layer.apply(p, x)
+            return jnp.sum(y * ct), (y, counters)
+        grads, (y, counters) = jax.grad(loss, (0, 1), has_aux=True)(p, x)
+        return y, grads, counters
+
+    experts, _ = moe.route(x.reshape(-1, 32), p["router"], None, k=10,
+                           score="softmax", route_norm=True, route_scale=1.0)
+    sizes = np.bincount(np.asarray(experts).ravel(), minlength=72)[:9]
+    return run, sizes
+
+
+@pytest.mark.parametrize("product", ["ragged_dot", "kernel"])
+def test_rows_in_no_group_change_nothing_in_a_layer_of_one_tier(
+        product, monkeypatch):
+    """Where the kernel runs, a layer of one tier leaves the rows past the
+    pairs held in no group: output and every gradient are what they are
+    with those rows counted to the last expert held (the CPU's path,
+    unchanged), whether `ragged_dot` or the kernel (in interpret mode)
+    makes the products; and the counter reads the tier on the path that
+    walks every row and the visited tiles' rows through the kernel."""
+    gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
+    run, sizes = _one_rung_case()
+    want, want_grads, counters = run()
+    tier = 1024 * 9
+    assert int(counters["moe_rows_tier"]) == tier
+    assert int(counters["moe_rows_visited"]) == tier
+    assert int(counters["moe_pairs_held"]) == sizes.sum() < tier // 4
+
+    seen = []
+    products = {
+        "ragged_dot": lambda a, w, plan: jax.lax.ragged_dot(
+            a, w, group_sizes=plan.end - plan.start),
+        "kernel": functools.partial(gm.grouped_dot, interpret=True)}
+
+    def grouped(a, w, plan):
+        seen.append(plan)
+        return products[product](a, w, plan)
+
+    monkeypatch.setattr(moe, "_kernel_runs", lambda: True)
+    monkeypatch.setattr(gm, "grouped_dot", grouped)
+    got, got_grads, counters = run()
+    assert len(seen) == 3           # a tier is traced once
+    _close(got, want, 1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        _close(a, b, 1e-5)
+    rows = gm.tile_rows(tier)
+    end = np.cumsum(sizes)
+    visits = sum((e - 1) // rows - (e - n) // rows + 1
+                 for n, e in zip(sizes, end) if n)
+    assert int(counters["moe_rows_tier"]) == tier
+    assert int(counters["moe_rows_visited"]) == visits * rows < tier
+
+
+def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
+    """Two of 64 experts at two a token have four tiers: where the kernel
+    could run, such a layer still gives `ragged_dot` every row of the tier
+    that runs, the rows past the pairs held counted to the last expert,
+    so a tier costs the same whatever fell into it, and the counter reads
+    the tier."""
+    gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
+    layer = ExpertFeedForward(n_in=32, width=16, n_experts=64, held=(0, 2),
+                              k=2, score="softmax", route_norm=True,
+                              weight_init="xavier")
+    p, _ = layer.init_params(jax.random.PRNGKey(4),
+                             InputType.recurrent(32, 512))
+    x, = _normal(15, (1, 512, 32))
+    assert len(moe._row_tiers(1024, 2 / 64, 1024)) == 4
+    want, _ = layer.apply(p, x)
+    seen, ragged_dot = [], jax.lax.ragged_dot
+
+    def spy(a, w, group_sizes):     # only the tier that runs calls back
+        jax.debug.callback(
+            lambda n: seen.append((a.shape[0], int(n))), jnp.sum(group_sizes))
+        return ragged_dot(a, w, group_sizes=group_sizes)
+
+    monkeypatch.setattr(moe, "_kernel_runs", lambda: True)
+    monkeypatch.setattr(gm, "grouped_dot", lambda *a, **kw: 1 / 0)
+    monkeypatch.setattr(jax.lax, "ragged_dot", spy)
+    got, counters = layer.apply(p, x)
+    jax.effects_barrier()
+    np.testing.assert_array_equal(got, want)
+    assert seen == [(128, 128)] * 3
+    assert int(counters["moe_pairs_held"]) < 128
+    assert int(counters["moe_rows_visited"]) \
+        == int(counters["moe_rows_tier"]) == 128
 
 
 # ---------------------------------------------------------- the tied head

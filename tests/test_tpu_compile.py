@@ -31,6 +31,7 @@ flash = importlib.import_module("deeplearning4j_tpu.ops.attention")
 banded = importlib.import_module("deeplearning4j_tpu.ops.banded_attention")
 sparse = importlib.import_module("deeplearning4j_tpu.ops.sparse_attention")
 latent = importlib.import_module("deeplearning4j_tpu.ops.latent_attention")
+grouped = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -164,7 +165,30 @@ def _latent(train, T=8192, heads=32, nope=128, rope=64, value=128):
         ((1, T, rope), BF16), ((1, T, heads, value), BF16)]
 
 
+def _grouped(m, k, n, groups, train=True):
+    # an expert layer's grouped product at a cell's widths, each kernel at
+    # the tile and the column blocks it picks for itself
+    def fwd(lhs, rhs, sizes):
+        return grouped.grouped_dot(lhs, rhs, sizes)
+
+    def loss(lhs, rhs, sizes):
+        return fwd(lhs, rhs, sizes).astype(F32).sum()
+
+    return (jax.grad(loss, argnums=(0, 1)) if train else fwd), [
+        ((m, k), BF16), ((groups, k, n), BF16), ((groups,), I32)]
+
+
 CASES = {
+    # `granite_4_0_h_small`'s one tier, both of an expert's widths;
+    # `trinity_large`'s and `deepseek_v2`'s first tiers
+    "grouped_dot_train_73728_4096_768": lambda: _grouped(73728, 4096, 768, 9),
+    "grouped_dot_train_73728_768_4096": lambda: _grouped(73728, 768, 4096, 9),
+    "grouped_dot_train_4096_3072_3072": lambda: _grouped(4096, 3072, 3072, 8),
+    "grouped_dot_train_9856_5120_1536": lambda: _grouped(9856, 5120, 1536, 8),
+    "grouped_dot_train_9856_1536_5120": lambda: _grouped(9856, 1536, 5120, 8),
+    "grouped_dot_fwd_f32": lambda: (
+        lambda lhs, rhs, sizes: grouped.grouped_dot(lhs, rhs, sizes),
+        [((4096, 1024), F32), ((8, 1024, 512), F32), ((8,), I32)]),
     "latent_fwd_32": lambda: _latent(train=False),
     "latent_train_32": lambda: _latent(train=True),
     "sparse_fwd_32_2": lambda: _sparse(train=False),
@@ -529,40 +553,46 @@ def test_checkpointed_attention_runs_its_forward_kernel_once(chip):
     assert grown <= 2 * 103e6, grown
 
 
+# --- the token cells' whole steps
+def _cell_step(chip, config):
+    """(lowered, compiled, cfg): a token cell's train step at the cell's
+    own size, built by the benchmark's own model file from
+    `benchmarks/configs/<config>.json` and compiled for the described chip
+    with parameters, moments and layer state donated."""
+    import json
+
+    from benchmarks import harness
+
+    with pytest.MonkeyPatch.context() as mp:
+        # the policies ask which backend runs; the described chip is not it
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        with open(os.path.join(harness.BENCH_DIR, "configs",
+                               config + ".json"), encoding="utf-8") as fh:
+            cfg = json.load(fh)
+        net = harness.load_module("models", config + ".py").build(cfg, 0)
+        shapes = jax.eval_shape(lambda: (
+            net.init().params_tree, net.updater_state, net.state_tree))
+        t = cfg["input_shape"][0]
+        spec = lambda tree: jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=chip), tree)
+        ids = jax.ShapeDtypeStruct((1, t), I32, sharding=chip)
+        lowered = jax.jit(net.make_step_fn(), donate_argnums=(0, 1, 2)).lower(
+            *map(spec, shapes), jax.ShapeDtypeStruct((), I32, sharding=chip),
+            ids, ids, None, None,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip))
+        return lowered, lowered.compile(), cfg
+
+
 # --- the benchmark's `minicpm_sala` step at the cell's own size (one
 # sequence of 16,384 ids, bf16, every layer checkpointed), built by the
 # benchmark's own model file: it fits the chip with room, each block-sparse
 # kernel is in it once (the forward's output and log-sum-exp are kept, not
 # remade), and nothing [heads, T, T] exists. The MLP's width is 16,384 too,
 # so a [16384, 16384] tensor of rank 2 is the MLP's and says nothing.
-def _minicpm_step(monkeypatch):
-    import json
-
-    from benchmarks import harness
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with open(os.path.join(harness.BENCH_DIR, "configs", "minicpm_sala.json"),
-              encoding="utf-8") as fh:
-        cfg = json.load(fh)
-    net = harness.load_module("models", "minicpm_sala.py").build(cfg, 0)
-    shapes = jax.eval_shape(
-        lambda: (net.init().params_tree, net.updater_state, net.state_tree))
-    t = cfg["input_shape"][0]
-    spec = lambda tree: jax.tree_util.tree_map(
-        lambda leaf: (leaf.shape, leaf.dtype), tree)
-    return (net.make_step_fn(), [
-        *map(spec, shapes), ((), I32), ((1, t), I32), ((1, t), I32), None,
-        None, ((2,), jnp.uint32)], t)
-
-
 def test_minicpm_sala_step_fits_the_chip_with_nothing_t_by_t(chip):
-    with pytest.MonkeyPatch.context() as mp:
-        step, shapes, t = _minicpm_step(mp)
-        args = jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(*s, sharding=chip), shapes,
-            is_leaf=lambda s: isinstance(s, tuple))
-        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-            *args).compile()
+    _, compiled, cfg = _cell_step(chip, "minicpm_sala")
+    t = cfg["input_shape"][0]
     memory = compiled.memory_analysis()
     # parameters, moments and layer state are donated: all but the batch
     assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
@@ -576,40 +606,40 @@ def test_minicpm_sala_step_fits_the_chip_with_nothing_t_by_t(chip):
     assert not square, sorted(set(square))[:5]
 
 
+def _grouped_products(compiled):
+    """(`ragged-dot` instructions, calls of `ops/grouped_matmul`'s kernels
+    by name) of a compiled step."""
+    text = compiled.as_text()
+    return (len(re.findall(r"^\s*%ragged-dot-none", text, re.M)),
+            {kernel: _kernel_calls(compiled, kernel)
+             for kernel in ("grouped_dot", "grouped_dot_dlhs",
+                            "grouped_dot_drhs")})
+
+
+# --- the benchmark's `trinity_large` step at the cell's own size, built
+# by the benchmark's own model file: its four expert layers have ladders,
+# so their grouped products stay `ragged_dot`'s (twelve a tier: three
+# forward, three in the tier's own checkpoint, six backward) and none is
+# `ops/grouped_matmul`'s.
+def test_trinity_large_step_keeps_ragged_dot_for_its_ladders(chip):
+    _, compiled, _ = _cell_step(chip, "trinity_large")
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    ragged, kernels = _grouped_products(compiled)
+    # four expert layers, each a switch over four tiers of which a step
+    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own
+    assert ragged == 192 and not any(kernels.values())
+
+
 # --- the benchmark's `deepseek_v2` step at the cell's own size (one
 # sequence of 8,192 ids, bf16, every layer checkpointed, 32 of 128 heads
 # and 8 of 160 experts held), built by the benchmark's own model file: it
 # fits the chip with room, each latent-attention kernel is in it once a
 # layer (the forward's output and log-sum-exp are kept, not remade), and
 # nothing [heads, T, T] exists.
-def _deepseek_step(monkeypatch):
-    import json
-
-    from benchmarks import harness
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with open(os.path.join(harness.BENCH_DIR, "configs", "deepseek_v2.json"),
-              encoding="utf-8") as fh:
-        cfg = json.load(fh)
-    net = harness.load_module("models", "deepseek_v2.py").build(cfg, 0)
-    shapes = jax.eval_shape(
-        lambda: (net.init().params_tree, net.updater_state, net.state_tree))
-    t = cfg["input_shape"][0]
-    spec = lambda tree: jax.tree_util.tree_map(
-        lambda leaf: (leaf.shape, leaf.dtype), tree)
-    return (net.make_step_fn(), [
-        *map(spec, shapes), ((), I32), ((1, t), I32), ((1, t), I32), None,
-        None, ((2,), jnp.uint32)], cfg)
-
-
 def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
-    with pytest.MonkeyPatch.context() as mp:
-        step, shapes, cfg = _deepseek_step(mp)
-        args = jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(*s, sharding=chip), shapes,
-            is_leaf=lambda s: isinstance(s, tuple))
-        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-            *args).compile()
+    _, compiled, cfg = _cell_step(chip, "deepseek_v2")
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
             < 2 ** 20)
@@ -618,6 +648,10 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
     layers, t = cfg["num_hidden_layers"], cfg["input_shape"][0]
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "latent_attention" + kernel) == layers
+    ragged, kernels = _grouped_products(compiled)
+    # four expert layers, each a switch over four tiers of which a step
+    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own
+    assert ragged == 192 and not any(kernels.values())
     square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", compiled.as_text())
     assert not square, sorted(set(square))[:5]
 
@@ -628,37 +662,19 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
 # head tied to the embedding), built by the benchmark's own model file: it
 # fits the chip with the scan's [chunks, heads, 256, 256] float32 tensors
 # counted, the tied embedding is ONE argument (1.340G parameters, not
-# 1.392G), the attention layer's flash kernels are in it once, and nothing
-# [heads, T, T] exists.
-def _granite_step(monkeypatch):
-    import json
-
-    from benchmarks import harness
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with open(os.path.join(harness.BENCH_DIR, "configs",
-                           "granite_4_0_h_small.json"),
-              encoding="utf-8") as fh:
-        cfg = json.load(fh)
-    net = harness.load_module("models", "granite_4_0_h_small.py").build(cfg, 0)
-    shapes = jax.eval_shape(
-        lambda: (net.init().params_tree, net.updater_state, net.state_tree))
-    t = cfg["input_shape"][0]
-    spec = lambda tree: jax.tree_util.tree_map(
-        lambda leaf: (leaf.shape, leaf.dtype), tree)
-    return (net.make_step_fn(), [
-        *map(spec, shapes), ((), I32), ((1, t), I32), ((1, t), I32), None,
-        None, ((2,), jnp.uint32)], cfg)
-
-
+# 1.392G), the attention layer's flash kernels are in it once, nothing
+# [heads, T, T] exists, and the ten expert layers' 120 grouped products are
+# `ops/grouped_matmul`'s three kernels and no `ragged-dot`, lowered as a
+# body a shape and not a body a call.
 def test_granite_step_fits_the_chip_with_one_tied_embedding(chip):
-    with pytest.MonkeyPatch.context() as mp:
-        step, shapes, cfg = _granite_step(mp)
-        args = jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(*s, sharding=chip), shapes,
-            is_leaf=lambda s: isinstance(s, tuple))
-        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-            *args).compile()
+    lowered, compiled, cfg = _cell_step(chip, "granite_4_0_h_small")
+    ragged, kernels = _grouped_products(compiled)
+    assert ragged == 0
+    assert kernels == {"grouped_dot": 60, "grouped_dot_dlhs": 30,
+                       "grouped_dot_drhs": 30}
+    # 3 kernels x 2 shapes, some of them once more where a checkpoint's
+    # partial evaluation split a body; and the flash kernels' three
+    assert lowered.as_text().count("tpu_custom_call") <= 50
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
             < 2 ** 20)
