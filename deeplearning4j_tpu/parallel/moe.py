@@ -25,7 +25,9 @@ all `n_experts`, of which this device holds `held`, the (token, expert)
 pairs sorted by expert, the rows of the experts held gathered, grouped
 matrix products over them (`jax.lax.ragged_dot`; on the TPU, where the
 rows come in one tier, `ops/grouped_matmul.grouped_dot`, which passes by
-the tiles that hold no pair), and the results gathered back by token.
+the tiles that hold no pair), and the results gathered back by token (on
+the TPU, in such a layer, both gathers by `ops/row_gather`'s kernels,
+which move only the rows that hold a pair).
 No pair is dropped at any imbalance and no [N, E, C] tensor exists; what
 the absent experts would add is left out.
 """
@@ -423,13 +425,15 @@ def _row_tiers(rows: int, share: float,
     of 72 experts at ten a token crossed in the eighteenth step, one to
     three layers of ten apart, and a window's rate read 0.73% apart where
     the other cells read 0.03 (chip runs, PR 42). There the one tier is
-    `most`: what passes over the tier's rows (the sort, the gathers, the
-    elementwise work) costs the same every step, and the grouped products,
-    whose rows past the pairs held are in no group there, follow the pairs
-    with no threshold to cross (`held_experts`; six seeds' rates lay 0.04%
-    apart by their quartiles where the two tiers' lay 0.73: chip runs,
-    PR 43). A layer WITH a ladder counts those rows to the last expert
-    held, so each of its tiers costs the same whatever fell into it."""
+    `most`: what passes over the tier's rows (the sort, and the SwiGLU's
+    elementwise work between the products) costs the same every step, and
+    on the TPU the grouped products and the gathers round them, for which
+    a row past the pairs held is no row, follow the pairs with no
+    threshold to cross (`held_experts`; the products since PR 43, six
+    seeds' rates 0.04% apart by their quartiles where the two tiers' lay
+    0.73; the gathers since PR 44: chip runs). A layer WITH a ladder
+    counts those rows to the last expert held, so each of its tiers costs
+    the same whatever fell into it."""
     most = min(rows, -(-int(rows if most is None else most) // 128) * 128)
     up = lambda n: min(most, -(-int(n) // 128) * 128)
     first = up(4 * share * rows)
@@ -464,9 +468,12 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
     # be sharded) its grouped products are `ops/grouped_matmul`'s kernel,
     # whose grid visits only the row tiles that hold a row of a group, and
     # the rows past the pairs held are left in NO group, so the kernel
-    # passes them by: the products' time follows the pairs, continuously,
-    # with no tier to cross. A layer with a ladder, and every layer on any
-    # other backend, counts those rows to the last expert held and runs
+    # passes them by; and its two gathers are `ops/row_gather`'s kernels,
+    # which move the rows of the pairs held and no other, a row a DMA: the
+    # layer's time but for the sort and the narrow elementwise work follows
+    # the pairs, continuously, with no tier to cross. A layer with a ladder,
+    # and every layer on any other backend, counts those rows to the last
+    # expert held, gathers with XLA's gather over the tier and runs
     # XLA's `ragged_dot`: a tier then costs the same whatever fell into
     # it, its padding is bounded by the ladder (four times the uniform
     # share), and a step's time is not a matter of the seed. (The kernel
@@ -475,8 +482,9 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
     # are four times the call sites to trace, lower and compile, and the
     # cells' set-up grew by a fifth: chip runs, PR 43.)
     from deeplearning4j_tpu.ops.grouped_matmul import (
-        grouped_dot, rows_visited, schedule,
+        grouped_dot, rows_visited, schedule, tile_rows,
     )
+    from deeplearning4j_tpu.ops.row_gather import sum_rows, take_rows
 
     kernel = len(tiers) == 1 and _kernel_runs()
 
@@ -493,19 +501,38 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
         # layer's policy keeps it, and JAX drops the dead run
         @jax.checkpoint
         def run(x, w1, w3, w2, token, place, pair_weight, sizes):
-            back = jnp.where(place < c, place, c)
-            # A row past the pairs held is no pair of ours. Left in no
-            # group, what a grouped product puts in it is undefined on the
-            # chip, in its result and in its cotangent alike (the CPU gives
-            # zeros), so such rows are put at zero on both sides of every
-            # product, whichever product runs and whether or not they are
-            # counted to the last expert held (`kernel`, above).
-            live = (jnp.arange(c) < jnp.sum(sizes))[:, None]
-            rows = lambda v: jnp.where(live, v, 0)
-            in_group = sizes if kernel else sizes.at[-1].add(
-                (c - jnp.sum(sizes)).astype(sizes.dtype))
-            with jax.named_scope("dispatch"):
-                taken = rows(_take_rows(x, token[:c], back))
+            held = jnp.sum(sizes)
+            weight = pair_weight[:c].astype(x.dtype)
+            if kernel:
+                # Nothing reads a row past the pairs held: the gathers
+                # move the live rows alone (`ops/row_gather`: a place at
+                # or past `held` is no row), the products pass the rest
+                # by, so such a row may hold anything, on both sides of
+                # every product, and no mask is made over the tier. The
+                # rows come back twice, one array: each product's
+                # cotangent goes into the transposed gather on its own,
+                # which adds them as it packs them, and it is the gather
+                # that weighs a row as it packs it.
+                rows = lambda v: v
+                with jax.named_scope("dispatch"):
+                    taken = take_rows(x, token[:c], place, held, 2)
+                summed = lambda v: sum_rows(v, weight, token[:c], place,
+                                            held)
+                in_group = sizes
+            else:
+                # A row past the pairs held is no pair of ours. It is
+                # counted to the last expert held, and what `ragged_dot`
+                # reads must be real zeros, in its operands and in its
+                # cotangents alike, so such rows are put at zero on both
+                # sides of every product.
+                back = jnp.where(place < c, place, c)
+                live = (jnp.arange(c) < held)[:, None]
+                rows = lambda v: jnp.where(live, v, 0)
+                with jax.named_scope("dispatch"):
+                    taken = (rows(_take_rows(x, token[:c], back)),) * 2
+                summed = lambda v: _sum_rows(v * weight[:, None],
+                                             token[:c], back)
+                in_group = sizes.at[-1].add((c - held).astype(sizes.dtype))
             with jax.named_scope("experts_held"):
                 if kernel:      # one schedule for the three products
                     plan = schedule(in_group, c)
@@ -513,11 +540,11 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
                 else:
                     product = lambda a, w: jax.lax.ragged_dot(
                         a, w, group_sizes=in_group)
-                out = _swiglu(taken, w1, w3, w2,
-                              lambda a, w: rows(product(a, w)))
+                dot = lambda a, w: rows(product(a, w))
+                out = dot(jax.nn.silu(dot(taken[0], w1)) * dot(taken[1], w3),
+                          w2)
             with jax.named_scope("combine"):
-                return _sum_rows(out * pair_weight[:c, None].astype(
-                    out.dtype), token[:c], back)
+                return summed(out)
         return run
 
     which = jnp.sum(n_held > jnp.asarray(tiers[:-1], jnp.int32)) \
@@ -527,10 +554,12 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
          if len(tiers) > 1 else tier(tiers[0])(*args))
     taken = jnp.asarray(tiers, jnp.int32)[which]
     visited = rows_visited(schedule(sizes, tiers[0])) if kernel else taken
+    tile = tile_rows(tiers[0])
+    gathered = (n_held + (tile - 1)) // tile * tile if kernel else taken
     return y, dict(zip(COUNTERS, (
         jnp.asarray(rows, jnp.int32), n_held,
         jnp.maximum(n_held - taken, 0), jnp.max(sizes), jnp.min(sizes),
-        taken, visited.astype(jnp.int32))))
+        taken, visited.astype(jnp.int32), gathered.astype(jnp.int32))))
 
 
 def _kernel_runs() -> bool:
@@ -547,10 +576,12 @@ def _kernel_runs() -> bool:
 # load of an expert held, the rows of the tier the step ran, and the rows
 # its grouped products multiplied: the row tiles their schedule visits
 # times a tile's rows (`ops/grouped_matmul.schedule`), the tier's rows
-# where every row is in a group
+# where every row is in a group, and the rows its gather moved: the row
+# tiles that hold a pair times a tile's rows (`ops/row_gather.take_rows`),
+# the tier's rows wherever XLA's gather runs
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs_dropped",
             "moe_load_max", "moe_load_min", "moe_rows_tier",
-            "moe_rows_visited")
+            "moe_rows_visited", "moe_rows_gathered")
 # and, of a layer that routes by groups, the tokens with at least one pair
 # on an expert held: what the exchange would send this device, which is
 # what group limits exist to bound

@@ -349,15 +349,19 @@ def test_a_router_collapsed_onto_the_experts_held_drops_nothing():
     assert int(counters["moe_pairs_held"]) == 128 * 9
 
 
-def _one_rung_case():
+def _one_rung_case(boost=0.0):
     """Nine of 72 experts held, ten a token: one tier of 1,024 x 9 rows,
-    of which uniform routing fills a seventh."""
+    of which uniform routing fills a seventh; with `boost` the router has
+    collapsed onto the experts held and every row of the tier is a pair."""
     cfg = _tiny(num_local_experts=72, num_experts_per_tok=10,
                 experts_held=[0, 9])
     layer = _expert_layer(cfg, (0, 9))
     p, _ = layer.init_params(jax.random.PRNGKey(3),
                              InputType.recurrent(32, 512))
     x, ct = _normal(14, (2, 512, 32), (2, 512, 32))
+    if boost:       # the rows are positive, so the boost is every token's
+        p = {**p, "router": p["router"].at[:, :9].add(boost)}
+        x = jnp.abs(x)
 
     def run():
         def loss(p, x):
@@ -372,24 +376,44 @@ def _one_rung_case():
     return run, sizes
 
 
+@jax.custom_vjp
+def _poison(v, live):
+    """`v` with NaN in every row at or past `live`, and its cotangent
+    too: what a kernel may leave in a row it never writes."""
+    return jnp.where((jnp.arange(v.shape[0]) < live)[:, None], v, jnp.nan)
+
+
+_poison.defvjp(lambda v, live: (_poison(v, live), live),
+               lambda live, g: (_poison(g, live), None))
+
+
+@pytest.mark.parametrize("router", ["uniform", "collapsed"])
 @pytest.mark.parametrize("product", ["ragged_dot", "kernel"])
 def test_rows_in_no_group_change_nothing_in_a_layer_of_one_tier(
-        product, monkeypatch):
-    """Where the kernel runs, a layer of one tier leaves the rows past the
-    pairs held in no group: output and every gradient are what they are
-    with those rows counted to the last expert held (the CPU's path,
-    unchanged), whether `ragged_dot` or the kernel (in interpret mode)
-    makes the products; and the counter reads the tier on the path that
-    walks every row and the visited tiles' rows through the kernel."""
+        product, router, monkeypatch):
+    """Where the kernels run, a layer of one tier leaves the rows past the
+    pairs held in no group and no gather moves them: output and every
+    gradient (the input's, the three kernels', the router's) are what they
+    are on the CPU's path, XLA's gathers with those rows counted to the
+    last expert held and put at zero, whether `ragged_dot` or the kernel
+    (in interpret mode) makes the products between `ops/row_gather`'s
+    kernels (in interpret mode), at a seventh of the tier held and at all
+    of it. With the kernel's products every row past the pairs held holds
+    NaN after every gather and product, forward and backward: nothing
+    reads one. And the counters read the tier on the path that walks every
+    row, the tiles that hold a pair through the kernels."""
     gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
-    run, sizes = _one_rung_case()
+    rg = importlib.import_module("deeplearning4j_tpu.ops.row_gather")
+    run, sizes = _one_rung_case(30.0 if router == "collapsed" else 0.0)
     want, want_grads, counters = run()
-    tier = 1024 * 9
-    assert int(counters["moe_rows_tier"]) == tier
-    assert int(counters["moe_rows_visited"]) == tier
-    assert int(counters["moe_pairs_held"]) == sizes.sum() < tier // 4
+    tier, held = 1024 * 9, sizes.sum()
+    assert held == tier if router == "collapsed" else held < tier // 4
+    for name in ("moe_rows_tier", "moe_rows_visited", "moe_rows_gathered"):
+        assert int(counters[name]) == tier
+    assert int(counters["moe_pairs_held"]) == held
 
     seen = []
+    dead = _poison if product == "kernel" else (lambda v, live: v)
     products = {
         "ragged_dot": lambda a, w, plan: jax.lax.ragged_dot(
             a, w, group_sizes=plan.end - plan.start),
@@ -397,30 +421,42 @@ def test_rows_in_no_group_change_nothing_in_a_layer_of_one_tier(
 
     def grouped(a, w, plan):
         seen.append(plan)
-        return products[product](a, w, plan)
+        return dead(products[product](a, w, plan), plan.end[-1])
 
+    take_rows, sum_rows = rg.take_rows, rg.sum_rows
     monkeypatch.setattr(moe, "_kernel_runs", lambda: True)
     monkeypatch.setattr(gm, "grouped_dot", grouped)
+    monkeypatch.setattr(
+        rg, "take_rows", lambda v, index, back, live, copies: tuple(
+            dead(t, live)
+            for t in take_rows(v, index, back, live, copies, True)))
+    monkeypatch.setattr(
+        rg, "sum_rows", lambda v, weight, index, back, live: sum_rows(
+            dead(v, live), weight, index, back, live, True))
+    monkeypatch.setattr(moe, "_take_rows", lambda *a: 1 / 0)
     got, got_grads, counters = run()
     assert len(seen) == 3           # a tier is traced once
     _close(got, want, 1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(got_grads),
                     jax.tree_util.tree_leaves(want_grads)):
+        assert np.isfinite(np.asarray(a)).all()
         _close(a, b, 1e-5)
     rows = gm.tile_rows(tier)
     end = np.cumsum(sizes)
     visits = sum((e - 1) // rows - (e - n) // rows + 1
                  for n, e in zip(sizes, end) if n)
     assert int(counters["moe_rows_tier"]) == tier
-    assert int(counters["moe_rows_visited"]) == visits * rows < tier
+    assert int(counters["moe_rows_visited"]) == visits * rows <= tier
+    assert int(counters["moe_rows_gathered"]) == -(-held // rows) * rows
+    assert int(counters["moe_pairs_dropped"]) == 0
 
 
 def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
     """Two of 64 experts at two a token have four tiers: where the kernel
     could run, such a layer still gives `ragged_dot` every row of the tier
     that runs, the rows past the pairs held counted to the last expert,
-    so a tier costs the same whatever fell into it, and the counter reads
-    the tier."""
+    so a tier costs the same whatever fell into it, XLA's gathers move its
+    rows, and the counters read the tier."""
     gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
     layer = ExpertFeedForward(n_in=32, width=16, n_experts=64, held=(0, 2),
                               k=2, score="softmax", route_norm=True,
@@ -437,8 +473,11 @@ def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
             lambda n: seen.append((a.shape[0], int(n))), jnp.sum(group_sizes))
         return ragged_dot(a, w, group_sizes=group_sizes)
 
+    rg = importlib.import_module("deeplearning4j_tpu.ops.row_gather")
     monkeypatch.setattr(moe, "_kernel_runs", lambda: True)
     monkeypatch.setattr(gm, "grouped_dot", lambda *a, **kw: 1 / 0)
+    monkeypatch.setattr(rg, "take_rows", lambda *a, **kw: 1 / 0)
+    monkeypatch.setattr(rg, "sum_rows", lambda *a, **kw: 1 / 0)
     monkeypatch.setattr(jax.lax, "ragged_dot", spy)
     got, counters = layer.apply(p, x)
     jax.effects_barrier()
@@ -446,6 +485,7 @@ def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
     assert seen == [(128, 128)] * 3
     assert int(counters["moe_pairs_held"]) < 128
     assert int(counters["moe_rows_visited"]) \
+        == int(counters["moe_rows_gathered"]) \
         == int(counters["moe_rows_tier"]) == 128
 
 

@@ -32,6 +32,7 @@ banded = importlib.import_module("deeplearning4j_tpu.ops.banded_attention")
 sparse = importlib.import_module("deeplearning4j_tpu.ops.sparse_attention")
 latent = importlib.import_module("deeplearning4j_tpu.ops.latent_attention")
 grouped = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
+gather = importlib.import_module("deeplearning4j_tpu.ops.row_gather")
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -178,7 +179,24 @@ def _grouped(m, k, n, groups, train=True):
         ((m, k), BF16), ((groups, k, n), BF16), ((groups,), I32)]
 
 
+def _row_gathers(n, k, c, d, dt=BF16):
+    # an expert layer's dispatch and combine at a cell's widths, forward
+    # and as each other's transpose: the packing of both sources (the
+    # second with two cotangents to add), the row copies, the weights
+    def loss(x, weight, index, back, live):
+        taken = gather.take_rows(x, index, back, live, 2)
+        return gather.sum_rows(taken[0] + taken[1], weight, index, back,
+                               live).astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1)), [
+        ((n, d), dt), ((c,), dt), ((c,), I32), ((n, k), I32), ((), I32)]
+
+
 CASES = {
+    # `granite_4_0_h_small`'s dispatch and combine over its one tier
+    "row_gathers_train_8192_10_73728_4096": lambda: _row_gathers(
+        8192, 10, 73728, 4096),
+    "row_gathers_train_f32": lambda: _row_gathers(1024, 4, 2048, 512, F32),
     # `granite_4_0_h_small`'s one tier, both of an expert's widths;
     # `trinity_large`'s and `deepseek_v2`'s first tiers
     "grouped_dot_train_73728_4096_768": lambda: _grouped(73728, 4096, 768, 9),
@@ -628,8 +646,10 @@ def test_trinity_large_step_keeps_ragged_dot_for_its_ladders(chip):
             < 15.75 * 2 ** 30)
     ragged, kernels = _grouped_products(compiled)
     # four expert layers, each a switch over four tiers of which a step
-    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own
+    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own,
+    # between XLA's own gathers
     assert ragged == 192 and not any(kernels.values())
+    assert not any(_row_kernels(compiled).values())
 
 
 # --- the benchmark's `deepseek_v2` step at the cell's own size (one
@@ -650,8 +670,10 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
         assert _kernel_calls(compiled, "latent_attention" + kernel) == layers
     ragged, kernels = _grouped_products(compiled)
     # four expert layers, each a switch over four tiers of which a step
-    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own
+    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own,
+    # between XLA's own gathers
     assert ragged == 192 and not any(kernels.values())
+    assert not any(_row_kernels(compiled).values())
     square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", compiled.as_text())
     assert not square, sorted(set(square))[:5]
 
@@ -663,18 +685,58 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
 # fits the chip with the scan's [chunks, heads, 256, 256] float32 tensors
 # counted, the tied embedding is ONE argument (1.340G parameters, not
 # 1.392G), the attention layer's flash kernels are in it once, nothing
-# [heads, T, T] exists, and the ten expert layers' 120 grouped products are
-# `ops/grouped_matmul`'s three kernels and no `ragged-dot`, lowered as a
-# body a shape and not a body a call.
+# [heads, T, T] exists, the ten expert layers' 120 grouped products are
+# `ops/grouped_matmul`'s three kernels and no `ragged-dot`, and their
+# gathers are `ops/row_gather`'s: three `take_rows` a layer (forward, the
+# tier's own recomputation, and `sum_rows`' transpose), two `sum_rows`
+# (forward and `take_rows`' transpose; the recomputed one is dead and
+# dropped) and a packing of each one's source, and NO op of XLA's reads or
+# writes the tier's 73,728 (or all 81,920) rows of 4,096: no gather, no
+# mask, no weighing, no sum of two cotangents. Lowered as a body a shape
+# and not a body a call.
+def _row_kernels(compiled):
+    return {kernel: _kernel_calls(compiled, kernel)
+            for kernel in ("take_rows", "sum_rows", "pack_rows")}
+
+
+def _tier_passes(compiled, rows=(73728, 81920), width=4096):
+    """The instructions of a compiled step, kernels apart, that write or
+    read an array of a tier's rows by the model's width (in row form too),
+    by name."""
+    text = compiled.as_text()
+    shape = re.compile(r"\[(%s),(%d|16,128)\]" % (
+        "|".join(map(str, rows)), width))
+    made = dict(re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) [\w\-]+\(", text,
+        re.M))
+    found = []
+    for name, out, op, rest in re.findall(
+            r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$",
+            text, re.M):
+        if op in ("custom-call", "parameter", "get-tuple-element", "tuple",
+                  "bitcast"):
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split(", metadata=")[0]
+                              .split(", calls=")[0])
+        if shape.search(out) or any(shape.search(made.get(o, ""))
+                                    for o in operands):
+            found.append(f"{op} {name}")
+    return found
+
+
 def test_granite_step_fits_the_chip_with_one_tied_embedding(chip):
     lowered, compiled, cfg = _cell_step(chip, "granite_4_0_h_small")
     ragged, kernels = _grouped_products(compiled)
     assert ragged == 0
     assert kernels == {"grouped_dot": 60, "grouped_dot_dlhs": 30,
                        "grouped_dot_drhs": 30}
-    # 3 kernels x 2 shapes, some of them once more where a checkpoint's
+    assert _row_kernels(compiled) == {"take_rows": 30, "sum_rows": 20,
+                                      "pack_rows": 50}
+    assert not _tier_passes(compiled)
+    # the grouped products' 3 kernels x 2 shapes and the row gathers' 3
+    # (each with its packing), many of them once more where a checkpoint's
     # partial evaluation split a body; and the flash kernels' three
-    assert lowered.as_text().count("tpu_custom_call") <= 50
+    assert lowered.as_text().count("tpu_custom_call") <= 111
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
             < 2 ** 20)
