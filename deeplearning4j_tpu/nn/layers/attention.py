@@ -786,7 +786,7 @@ class MultiHeadAttention(Layer):
         if self.window is not None and mask is None and not drop:
             # Sliding window (no mask/dropout): the banded kernel serves
             # this O(T·w) by grid construction, forward and backward,
-            # GQA-native. Banded-vs-dense is the measured policy's call
+            # GQA-native. Banded-vs-dense is the policy's call
             # (kernel_defaults.banded_policy; env hatch
             # DL4J_TPU_ATTN=banded|dense).
             from deeplearning4j_tpu.ops.kernel_defaults import (
@@ -815,10 +815,9 @@ class MultiHeadAttention(Layer):
                                           window=self.window,
                                           scale=self.softmax_scale)
         # Flash-vs-dense, tile config, and backward selection all come
-        # from the measured-winner policy (ops/kernel_defaults.py) —
-        # the kernel must have a recorded hardware row beating XLA
-        # dense at this mode/length, or dense memory pressure must
-        # make the O(T) path mandatory. Env hatches: DL4J_TPU_ATTN*.
+        # from ops/kernel_defaults.attention_policy: flash where dense
+        # memory pressure makes the O(T) path mandatory, dense below.
+        # Env hatches: DL4J_TPU_ATTN*.
         from deeplearning4j_tpu.ops.kernel_defaults import attention_policy
 
         pol = attention_policy(T, train=train)
@@ -939,7 +938,7 @@ class TransformerEncoderBlock(Layer):
 
     Modern extension (no reference counterpart — SURVEY §5 notes the
     reference predates attention). Composes the framework's own pieces:
-    MultiHeadAttention (measured-policy attention core, ring attention
+    MultiHeadAttention (policy-dispatched attention core, ring attention
     under a seq mesh, GQA via num_kv_heads) and either a dense FFN or a
     MoEFeedForward (set n_experts > 0) for conditional compute.
 

@@ -24,8 +24,8 @@ Backends that report nothing (the CPU backend returns None from
 every test in this repo exercises the real code path.
 
 Polling is pull-based: `sample_once()` costs one runtime query per
-device and runs (a) on demand from the `/devices` endpoint, bench.py,
-and StatsListener reports, and (b) optionally on a background thread
+device and runs (a) on demand from the `/devices` endpoint and
+StatsListener reports, and (b) optionally on a background thread
 (`start()`, or DL4J_TPU_DEVICEMON=1 + `maybe_start_monitor()` which the
 TrainingExecutor calls at every fit). Each sample also lands in the
 FlightRecorder ring, so a crash dump always carries recent device
@@ -285,7 +285,7 @@ def _tree_leaves(tree):
 
 def device_memory_summary() -> Optional[List[dict]]:
     """One best-effort sample for embedding in reports (StatsListener,
-    bench.py, flight dumps); None when jax is unavailable or broken."""
+    flight dumps); None when jax is unavailable or broken."""
     try:
         return get_device_monitor().sample_once()
     except Exception:

@@ -5,8 +5,8 @@
     python -m deeplearning4j_tpu.observe.dump --live
 
 Three inputs, auto-detected:
-- a registry snapshot (`MetricsRegistry.snapshot()` saved as JSON, or a
-  BENCH_*.json blob embedding one under "registry") → aligned table;
+- a registry snapshot (`MetricsRegistry.snapshot()` saved as JSON, or any
+  JSON blob embedding one under "registry") → aligned table;
 - a span/metric JSONL log (`SpanLog`, the `.spans.jsonl` that
   `ProfilerListener` writes beside a device trace, `export_jsonl`) → one
   formatted line per event, `--tail N` for the last N;
@@ -90,7 +90,7 @@ def dump_file(path: str, tail: Optional[int] = None) -> str:
         return "\n".join(format_jsonl_line(e) for e in events)
     with open(path) as f:
         blob = json.load(f)
-    # BENCH blobs embed the snapshot under "registry"
+    # a blob may embed the snapshot under "registry"
     if "registry" in blob and isinstance(blob["registry"], dict):
         blob = blob["registry"]
     return format_snapshot(blob)
@@ -102,7 +102,7 @@ def main(argv=None) -> int:
         description="Pretty-print a metrics registry snapshot or tail a "
                     "span/metrics JSONL log.")
     ap.add_argument("path", nargs="?",
-                    help="snapshot .json (or BENCH blob) / span .jsonl")
+                    help="snapshot .json / span .jsonl")
     ap.add_argument("--tail", type=int, default=None, metavar="N",
                     help="only the last N JSONL events")
     ap.add_argument("--live", action="store_true",

@@ -270,7 +270,7 @@ class MetricsRegistry:
     # --------------------------------------------------------- exporters
     def snapshot(self) -> dict:
         """Plain-dict view of every series — the JSON `/metrics` payload
-        body and the blob bench.py embeds in BENCH JSON."""
+        body."""
         out: Dict[str, list] = {}
         for inst in self.series():
             out.setdefault(inst.name, []).append({

@@ -21,7 +21,6 @@ with _span("import.ops"):
         banded_attention,
         banded_decode_attention,
         banded_eligible,
-        decode_eligible,
     )
 
 __all__ = [
@@ -31,5 +30,4 @@ __all__ = [
     "banded_attention",
     "banded_decode_attention",
     "banded_eligible",
-    "decode_eligible",
 ]

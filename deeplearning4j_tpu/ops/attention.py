@@ -37,9 +37,9 @@ K/V tile pinned in VMEM scratch while sweeping Q blocks, and a dQ kernel
 with the Q tile pinned while sweeping K blocks, using the softmax-vjp
 identity ds = p * (dp - Δ) with Δ = rowsum(do · o) precomputed by XLA.
 `backward="dense"` keeps the previous whole-[T, T] XLA recompute as a
-fallback/oracle path. The default (`backward=None`) resolves from the
-measured-winner table in `ops/kernel_defaults.py` — see that module for
-the dispatch policy and its env escape hatches.
+fallback/oracle path. The default (`backward=None`) resolves through
+`ops/kernel_defaults.attention_backward` — see that module for the
+dispatch policy and its env escape hatches.
 
 The backward's tile is written once, here, for the six backward kernels
 of the three families (`_bwd_scores`, `_dq_step`, `_dkdv_step`). dQ
@@ -299,9 +299,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
 
 def _fit_block(block: int, t: int) -> int:
     """Largest block <= requested that divides t (t must be a multiple of
-    the 128-lane minimum). Block size is the decisive perf lever on TPU;
-    the production sizes come from the measured-winner table in
-    ops/kernel_defaults.py, populated by tools/kernel_bench.py."""
+    the 128-lane minimum). Block size is the decisive perf lever on TPU:
+    the policies in ops/kernel_defaults.py say where the blocks start,
+    and `_pick_tile` picks each kernel's own tile from them."""
     block = min(block, t)
     while block > 128 and t % block:
         block -= 128
@@ -769,10 +769,9 @@ def flash_eligible(tq: int, tk: Optional[int] = None, *,
     capability one — the kernel runs from 128 up, but below ~512 it
     cannot amortize its block machinery, so the default floor suits
     structural users (ring attention's lse merge) that gate on this
-    alone. The measured flash-vs-dense verdict, block sizes, and
-    backward selection live in `kernel_defaults.attention_policy`,
-    which consults capability (min_t=128) for the memory-necessity
-    path."""
+    alone. The flash-vs-dense verdict, block sizes, and backward
+    selection live in `kernel_defaults.attention_policy`, which asks
+    for capability (min_t=128): its memory hazard holds from there."""
     tk = tq if tk is None else tk
     return (jax.default_backend() == "tpu" and tq % 128 == 0
             and tk % 128 == 0 and min(tq, tk) >= min_t)
@@ -794,7 +793,7 @@ def _unfold3(x, shape):
 
 
 def _resolve_backward(backward: Optional[str], tq: int, tk: int) -> str:
-    """None -> the measured-winner default (kernel_defaults). Resolved
+    """None -> the policy's default (kernel_defaults). Resolved
     ONCE, in the forward rule; the backward rule keys off whether lse
     was actually saved, so a mid-process env flip can never make the
     two rules disagree."""
@@ -819,7 +818,7 @@ def flash_attention(q, k, v, causal: bool = False,
     dq/dk/dv are produced: "pallas" rematerializes score tiles blockwise
     in two Pallas kernels — the [T, T] matrix never exists; "dense" is
     the whole-matrix XLA recompute kept as the oracle/fallback path.
-    None (default) resolves to the measured winner via
+    None (default) resolves via
     `kernel_defaults.attention_backward` (env hatch:
     DL4J_TPU_ATTN_BACKWARD)."""
     s = scale if scale is not None else q.shape[-1] ** -0.5

@@ -56,9 +56,8 @@ log-sum-exp `attention_out` and `attention_lse`
 them and its recomputed forward does not run the kernel a second time.
 
 Dispatch is NOT decided here: `kernel_defaults.banded_policy` owns the
-banded-vs-dense verdict under the measured-winner discipline (env hatch
-`DL4J_TPU_ATTN=banded` forces it; new MEASURED rows come from
-`tools/kernel_bench.py --banded` on hardware).
+banded-vs-dense verdict (banded where the dense scores are a memory
+hazard; env hatch `DL4J_TPU_ATTN=banded` forces it).
 """
 
 from __future__ import annotations
@@ -481,7 +480,7 @@ def banded_eligible(t: int, h: int, hkv: int, *, min_t: int = 256,
     """SHAPE eligibility for the full-sequence banded kernel: TPU backend,
     128-lane-tileable T, and a clean GQA grouping. `min_t` is the perf
     floor (below it the band is most of the matrix and dense wins on
-    launch overhead); the measured verdict lives in
+    launch overhead); the verdict lives in
     `kernel_defaults.banded_policy`. `any_backend=True` waives the TPU
     requirement (env-forced routing runs interpret-mode off-TPU — a
     production force must not silently un-force itself)."""
@@ -660,13 +659,6 @@ def _decode_kernel(qpos_ref, end_ref, *refs, cache_len: int,
     def _():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-
-
-def decode_eligible(cache_len: int, h: int, hkv: int) -> bool:
-    """Shape eligibility for the decode kernel on hardware: TPU backend,
-    lane-tileable ring length, clean GQA grouping."""
-    return (jax.default_backend() == "tpu" and cache_len % 128 == 0
-            and hkv >= 1 and h % hkv == 0)
 
 
 def banded_decode_attention(q, cache_k, cache_v, qpos, end,

@@ -1,28 +1,18 @@
-"""Measured-data-driven kernel dispatch policy.
+"""Which kernel serves a call: ten policies, one kind of rule.
 
-The framework hand-writes TPU kernels in two places (flash attention,
-fused LSTM). Whether the hand-written kernel — and which tile
-configuration of it — actually beats the XLA baseline is an empirical
-question answered by `tools/kernel_bench.py` on real hardware, and the
-answer has flipped more than once during development. This module makes
-the dispatch *derive from the recorded measurements* instead of from
-prose: `tools/update_kernel_defaults.py` regenerates the MEASURED table
-below from `tools/kernel_bench_results.json`, and a suite guard
-(`tests/test_kernel_defaults.py`) fails if a shipped default contradicts
-the best recorded row — a default can never again ship on prose.
+`attention_policy` (with `attention_backward`), `banded_policy`,
+`sparse_policy`, `latent_policy`, `decode_attention_policy`,
+`decode_loop_policy`, `spec_decode_policy`, `kv_dtype_policy`,
+`prefix_cache_policy` and `lstm_policy` each answer at TRACE time, and
+each is, in order:
 
-This is the same "earn your dispatch with measurements" discipline the
-reference applied to its vendor kernels (cuDNN helpers are picked over
-built-ins only where they win — `deeplearning4j-cuda/.../
-CudnnConvolutionHelper.java:54`), applied to Pallas-vs-XLA.
-
-Policy, in order:
-  1. Env escape hatches always win (ops run in production; a lowering
-     bug or perf regression must be routable around without a release):
+  1. An env force, which always wins (ops run in production; a
+     lowering bug or perf regression must be routable around without a
+     release):
        DL4J_TPU_ATTN           = auto|flash|banded|dense
        DL4J_TPU_ATTN_BACKWARD  = auto|pallas|dense
        DL4J_TPU_ATTN_BLOCK     = "512" or "512x256"   (block_q x block_k)
-       DL4J_TPU_DENSE_MAX_T    = int (memory-necessity threshold)
+       DL4J_TPU_DENSE_MAX_T    = int (the hazard threshold below)
        DL4J_TPU_DECODE_ATTN    = auto|banded|dense   (serving decode step)
        DL4J_TPU_DECODE_LOOP    = auto|fused|stepwise (serving decode loop)
        DL4J_TPU_DECODE_K       = int (fused decode window length; bucketed)
@@ -31,66 +21,27 @@ Policy, in order:
        DL4J_TPU_KV_DTYPE       = auto|native|int8|fp8 (KV-cache storage)
        DL4J_TPU_PREFIX_CACHE   = auto|on|off  (paged KV prefix reuse)
        DL4J_TPU_KV_PAGE        = int (KV page length; snapped to divisors)
-  2. Shape eligibility: flash needs the TPU backend and 128-lane-tileable
-     sequence lengths; otherwise dense.
-  3. Memory necessity: when Tq*Tk >= DENSE_MAX_T^2 (default 8192^2) the
-     dense [Tq, Tk] score matrix is prohibitive regardless of speed (32
-     heads of 8192^2 f32 scores = 8 GiB on a 16 GiB chip — and a
-     Tq=4096 x Tk=16384 cross-attention is the same 8 GiB), so flash +
-     the Pallas O(T) backward is mandatory.
-  4. Otherwise the MEASURED verdict at the nearest benchmarked T decides,
-     including the winning block sizes and backward implementation. With
-     no winning measured row, the conservative default is the XLA dense
-     path (it is the measured winner everywhere rows exist today).
+       DL4J_TPU_LSTM           = auto|fused|scan
+  2. Eligibility: the TPU backend and a shape the kernel tiles (its own
+     `*_eligible` predicate), or what the caller says the model can do
+     (`capable`); otherwise the XLA path.
+  3. One predicate on the shape. For flash and banded attention it is
+     the memory hazard: when Tq*Tk >= DENSE_MAX_T^2 (default 8192^2) the
+     dense [Tq, Tk] score matrix cannot be afforded (32 heads of 8192^2
+     f32 scores = 8 GiB on a 16 GiB chip, and a Tq=4096 x Tk=16384
+     cross-attention is the same 8 GiB), so the kernel and its O(T)
+     Pallas backward are the path. Below the hazard dense is the default
+     because no cell has measured today's kernels there (ROADMAP D3).
+     The other policies have no shape to weigh: the decode step stays
+     dense, the fused window, speculative decoding and the prefix cache
+     are on where the model is capable, KV storage is native unless
+     asked, the LSTM core is the fused kernel.
 """
 
 from __future__ import annotations
 
 import os
 from typing import NamedTuple, Optional
-
-# --- BEGIN GENERATED (tools/update_kernel_defaults.py) ---
-MEASURED: dict = {'attention': {'fwd': {1024: {'backward': 'n/a',
-                              'block_k': 128,
-                              'block_q': 128,
-                              'dense_ms': 0.119,
-                              'flash_ms': 0.629,
-                              'winner': 'dense'},
-                       2048: {'backward': 'n/a',
-                              'block_k': 128,
-                              'block_q': 128,
-                              'dense_ms': 1.148,
-                              'flash_ms': 2.302,
-                              'winner': 'dense'},
-                       4096: {'backward': 'n/a',
-                              'block_k': 128,
-                              'block_q': 128,
-                              'dense_ms': 4.419,
-                              'flash_ms': 11.742,
-                              'winner': 'dense'}},
-               'train': {1024: {'backward': 'dense',
-                                'block_k': 128,
-                                'block_q': 128,
-                                'dense_ms': 0.475,
-                                'flash_ms': 1.097,
-                                'winner': 'dense'},
-                         2048: {'backward': 'dense',
-                                'block_k': 128,
-                                'block_q': 128,
-                                'dense_ms': 3.993,
-                                'flash_ms': 5.953,
-                                'winner': 'dense'},
-                         4096: {'backward': 'dense',
-                                'block_k': 128,
-                                'block_q': 128,
-                                'dense_ms': 14.989,
-                                'flash_ms': 23.392,
-                                'winner': 'dense'}}},
- 'devices': ['TPU v5 lite0'],
- 'lstm': {'train': {'fused_ms': 1.697,
-                    'scan_ms': 3.991,
-                    'winner': 'fused'}}}
-# --- END GENERATED ---
 
 
 class AttentionPolicy(NamedTuple):
@@ -115,7 +66,7 @@ def record_dispatch(op: str, impl: str) -> None:
     `impl`" — a decode stack showing anything but one banded row per
     bucket, or a counter that keeps growing across steps (shape churn
     re-tracing), is diagnostic, not cosmetic. Visible in `/metrics` and
-    in bench snapshots."""
+    in the serving snapshot."""
     from deeplearning4j_tpu.observe import get_registry
 
     get_registry().counter("kernel_dispatch_total", op=op, impl=impl).inc()
@@ -128,7 +79,7 @@ def _env(name: str, default: str = "auto") -> str:
 
 def dense_max_t() -> int:
     """Sequence length at which the dense [T, T] path becomes a memory
-    hazard and flash is used regardless of measured speed."""
+    hazard and the kernels take over."""
     return int(os.environ.get("DL4J_TPU_DENSE_MAX_T", "8192"))
 
 
@@ -138,25 +89,6 @@ def _mem_hazard(tq: int, tk: int) -> bool:
     (Tq=4096, Tk=16384) is exactly as dangerous as self-attention at
     sqrt(Tq*Tk). Threshold: product >= DENSE_MAX_T^2."""
     return tq * tk >= dense_max_t() ** 2
-
-
-def _t_eff(tq: int, tk: int) -> int:
-    """Effective length for measured-row lookup: the geometric mean, so
-    a [Tq, Tk] problem maps to the self-attention T with the same score
-    -matrix area (the measured rows are all self-attention)."""
-    import math
-
-    return max(128, int(round(math.sqrt(tq * tk))))
-
-
-def _nearest_measured(table: dict, t: int) -> Optional[int]:
-    """Benchmarked T closest to t in log-space (perf scales ~T^2, so the
-    nearest decade is the right generalization)."""
-    if not table:
-        return None
-    import math
-
-    return min(table, key=lambda mt: abs(math.log(mt) - math.log(max(t, 1))))
 
 
 def _blocks_from_env() -> Optional[tuple]:
@@ -169,52 +101,33 @@ def _blocks_from_env() -> Optional[tuple]:
     return bq, bk
 
 
-def _shape_eligible(tq: int, tk: int, *, min_t: int = 512) -> bool:
-    # one canonical predicate for "can flash run here" — ops.attention.
-    # min_t=128 is raw kernel capability (memory-necessity path); the
-    # default 512 is the perf floor for measured-verdict consults.
-    from deeplearning4j_tpu.ops.attention import flash_eligible
-
-    return flash_eligible(tq, tk, min_t=min_t)
-
-
 def attention_backward(tq: int, tk: Optional[int] = None) -> str:
-    """Backward implementation for an already-chosen flash path: "dense"
-    (whole-[Tq, Tk] XLA recompute — numerically the oracle, and the
-    measured train winner wherever rows exist; ADVICE r4 medium) unless
-    a winning measured pallas row or memory necessity says otherwise."""
+    """Backward implementation for an already-chosen flash path: "pallas"
+    (two blockwise kernels, O(T) memory) at the memory hazard, where it
+    is the point of taking flash; "dense" (whole-[Tq, Tk] XLA recompute,
+    numerically the oracle) below it."""
     tk = tq if tk is None else tk
     forced = _env("DL4J_TPU_ATTN_BACKWARD")
     if forced in ("pallas", "dense"):
         return forced
-    if _mem_hazard(tq, tk):
-        return "pallas"       # the O(T)-memory backward is the point
-    table = MEASURED.get("attention", {}).get("train", {})
-    mt = _nearest_measured(table, _t_eff(tq, tk))
-    if mt is not None:
-        row = table[mt]
-        if row["winner"] == "flash" and row.get("backward") == "pallas":
-            return "pallas"
-    return "dense"
+    return "pallas" if _mem_hazard(tq, tk) else "dense"
 
 
 def attention_policy(tq: int, tk: Optional[int] = None,
                      train: bool = False) -> AttentionPolicy:
-    """Decide flash-vs-dense (and tile config) for one attention call.
-
-    tq/tk are the query/key sequence lengths; `train` selects which
-    measured mode (fwd-only vs fwd+bwd) the verdict comes from.
-    """
+    """Decide flash-vs-dense (and where the tiles start) for one
+    attention call. tq/tk are the query/key sequence lengths; `train`
+    does not move the verdict (the hazard holds in both directions)."""
     tk = tq if tk is None else tk
-    t = _t_eff(tq, tk)
     forced = _env("DL4J_TPU_ATTN")
-    can_flash = _shape_eligible(tq, tk, min_t=128)   # kernel capability
     blocks = _blocks_from_env()
+    from deeplearning4j_tpu.ops.attention import flash_eligible
 
-    def flash(bq, bk, reason):
-        if blocks is not None:
-            bq, bk = blocks
+    can_flash = flash_eligible(tq, tk, min_t=128)   # kernel capability
+
+    def flash(reason):
         record_dispatch("attention", "flash")
+        bq, bk = blocks or (512, 512)
         return AttentionPolicy("flash", bq, bk,
                                attention_backward(tq, tk), reason)
 
@@ -228,58 +141,14 @@ def attention_policy(tq: int, tk: Optional[int] = None,
         if not can_flash:
             return dense("DL4J_TPU_ATTN=flash but shape ineligible "
                          f"(backend/tiling, tq={tq} tk={tk})")
-        return flash(512, 512, "forced by DL4J_TPU_ATTN=flash")
+        return flash("forced by DL4J_TPU_ATTN=flash")
     if not can_flash:
         return dense(f"shape ineligible (tq={tq}, tk={tk})")
     if _mem_hazard(tq, tk):
-        # capability floor (128), not the perf floor: a short-query
-        # cross-attention over a huge context must still avoid the
-        # [Tq, Tk] dense materialization
-        row = _best_measured_flash("train" if train else "fwd", t)
-        bq, bk = (row["block_q"], row["block_k"]) if row else (512, 512)
-        return flash(bq, bk,
-                     f"memory necessity: Tq*Tk >= {dense_max_t()}^2")
-    if not _shape_eligible(tq, tk):     # perf floor for measured consults
-        return dense(f"below flash perf floor (tq={tq}, tk={tk})")
-    mode = "train" if train else "fwd"
-    table = MEASURED.get("attention", {}).get(mode, {})
-    mt = _nearest_measured(table, t)
-    if mt is not None and table[mt]["winner"] == "flash":
-        row = table[mt]
-        return flash(row["block_q"], row["block_k"],
-                     f"measured win at T={mt} "
-                     f"({row['flash_ms']} vs {row['dense_ms']} ms)")
-    if mt is not None:
-        row = table[mt]
-        return dense(f"measured loss at T={mt} "
-                     f"({row.get('flash_ms')} vs {row['dense_ms']} ms)")
-    return dense("no measured rows; conservative default")
-
-
-def _best_measured_flash(mode: str, t: int) -> Optional[dict]:
-    """Tile config worth adopting: only a WINNING flash row — a losing
-    row's blocks are the measured-worst configuration (128^2 runs 2-5x
-    behind dense), exactly what the memory-necessity path must not
-    inherit. No winning row -> caller falls back to the 512^2 default."""
-    table = MEASURED.get("attention", {}).get(mode, {})
-    mt = _nearest_measured(table, t)
-    if mt is None:
-        return None
-    row = table[mt]
-    return row if (row.get("block_q") and row["winner"] == "flash") else None
-
-
-def _best_measured_banded(mode: str, t: int) -> Optional[dict]:
-    """Winning banded row's tile config (same rule as
-    `_best_measured_flash`: a losing row's blocks are the measured-worst
-    configuration and must not be inherited)."""
-    table = MEASURED.get("banded", {}).get(mode, {})
-    mt = _nearest_measured(table, t)
-    if mt is None:
-        return None
-    row = table[mt]
-    return row if (row.get("block_q") and row["winner"] == "banded") \
-        else None
+        # the floor is capability (128): a short-query cross-attention
+        # over a huge context must still avoid the [Tq, Tk] scores
+        return flash(f"memory necessity: Tq*Tk >= {dense_max_t()}^2")
+    return dense(f"below the memory hazard (Tq*Tk < {dense_max_t()}^2)")
 
 
 def banded_policy(t: int, h: int, hkv: int,
@@ -287,13 +156,14 @@ def banded_policy(t: int, h: int, hkv: int,
     """Banded-vs-dense for one windowed/GQA attention call (the shapes
     `attention_policy` never serves: its flash kernel is full-context).
 
-    Same lattice as `attention_policy`: env force, then shape capability,
-    then memory necessity, then the measured verdict, with dense the
-    no-data default. Memory necessity holds for training shapes as for
-    forward-only ones: the banded backward is blockwise over the band's
-    tiles (`ops/banded_attention._run_banded_bwd`), so where the dense
-    scores cannot exist (T 8,192 with 48 heads: 6 GiB a copy in bf16, and
-    the backward holds three) banded is the path in both directions.
+    Same order as `attention_policy`: env force, then shape capability,
+    then the memory hazard, with dense below it. The hazard holds for
+    training shapes as for forward-only ones: the banded backward is
+    blockwise over the band's tiles
+    (`ops/banded_attention._run_banded_bwd`), so where the dense scores
+    cannot exist (T 8,192 with 48 heads: 6 GiB a copy in bf16, and the
+    backward holds three; the dense form asked the compiler for 18.7 GiB)
+    banded is the path in both directions.
 
     What the chip showed for training at 8,192 (`trinity_large_fit`: 48
     query heads over 8 KV heads of 128, window 4,096, bf16; PERF.md
@@ -302,20 +172,14 @@ def banded_policy(t: int, h: int, hkv: int,
     (`ops/attention._pick_tile`): the group's 256 tokens (1,536 rows) by
     512 keys in all three. The forward takes 5.3 ms a call since PR 36,
     where it took 14.7; dQ 7.7 and dK/dV 8.9 since PR 39, where a Q block
-    of 128 took 9.9 and 16.5 (host clock). There is no MEASURED row for
-    it and there cannot be one: a row is a head-to-head, and the dense
-    contender does not fit the chip at this shape (it asked the compiler
-    for 18.7 GiB)."""
+    of 128 took 9.9 and 16.5 (host clock)."""
     forced = _env("DL4J_TPU_ATTN")
     blocks = _blocks_from_env()
     from deeplearning4j_tpu.ops.banded_attention import banded_eligible
 
-    can = banded_eligible(t, h, hkv, min_t=128)
-
-    def banded(bq, bk, reason):
-        if blocks is not None:
-            bq, bk = blocks
+    def banded(reason):
         record_dispatch("banded_attention", "banded")
+        bq, bk = blocks or (256, 256)
         return BandedPolicy("banded", bq, bk, reason)
 
     def dense(reason):
@@ -334,27 +198,12 @@ def banded_policy(t: int, h: int, hkv: int,
         if not banded_eligible(t, h, hkv, min_t=128, any_backend=True):
             return dense("DL4J_TPU_ATTN=banded but shape ineligible "
                          f"(tiling, t={t} h={h} hkv={hkv})")
-        return banded(256, 256, "forced by DL4J_TPU_ATTN=banded")
-    if not can:
+        return banded("forced by DL4J_TPU_ATTN=banded")
+    if not banded_eligible(t, h, hkv, min_t=128):
         return dense(f"shape ineligible (t={t}, h={h}, hkv={hkv})")
     if _mem_hazard(t, t):
-        row = _best_measured_banded("train" if train else "fwd", t)
-        bq, bk = (row["block_q"], row["block_k"]) if row else (256, 256)
-        return banded(bq, bk,
-                      f"memory necessity: T^2 >= {dense_max_t()}^2")
-    mode = "train" if train else "fwd"
-    table = MEASURED.get("banded", {}).get(mode, {})
-    mt = _nearest_measured(table, t)
-    if mt is not None and table[mt]["winner"] == "banded":
-        row = table[mt]
-        return banded(row["block_q"], row["block_k"],
-                      f"measured win at T={mt} "
-                      f"({row['banded_ms']} vs {row['dense_ms']} ms)")
-    if mt is not None:
-        row = table[mt]
-        return dense(f"measured loss at T={mt} "
-                     f"({row.get('banded_ms')} vs {row['dense_ms']} ms)")
-    return dense("no measured rows; conservative default")
+        return banded(f"memory necessity: T^2 >= {dense_max_t()}^2")
+    return dense(f"below the memory hazard (T^2 < {dense_max_t()}^2)")
 
 
 class SparsePolicy(NamedTuple):
@@ -447,48 +296,28 @@ class DecodePolicy(NamedTuple):
 def decode_attention_policy(cache_len: int, h: int, hkv: int,
                             record: bool = True) -> DecodePolicy:
     """Single-query decode-step attention: the Pallas kernel that reads
-    the KVSlotPool layout directly vs the layer's dense einsum. Env hatch
-    DL4J_TPU_DECODE_ATTN=auto|banded|dense; measured rows live under
-    MEASURED["decode"] keyed by cache length. `record=False` is for
-    observers (serving snapshots) that ask what WOULD dispatch —
-    kernel_dispatch_total must count only real dispatch sites."""
+    the KVSlotPool layout directly vs the layer's dense einsum. Dense
+    unless DL4J_TPU_DECODE_ATTN=banded forces the kernel: no serving
+    cell has measured it. `record=False` is for observers (serving
+    snapshots) that ask what WOULD dispatch — kernel_dispatch_total
+    must count only real dispatch sites."""
     forced = _env("DL4J_TPU_DECODE_ATTN")
-    from deeplearning4j_tpu.ops.banded_attention import decode_eligible
 
-    can = decode_eligible(cache_len, h, hkv)
-
-    def banded(bl, reason):
+    def verdict(kind, block_l, reason):
         if record:
-            record_dispatch("decode_attention", "banded")
-        return DecodePolicy("banded", bl, reason)
-
-    def dense(reason):
-        if record:
-            record_dispatch("decode_attention", "dense")
-        return DecodePolicy("dense", 0, reason)
+            record_dispatch("decode_attention", kind)
+        return DecodePolicy(kind, block_l, reason)
 
     if forced == "dense":
-        return dense("forced by DL4J_TPU_DECODE_ATTN=dense")
+        return verdict("dense", 0, "forced by DL4J_TPU_DECODE_ATTN=dense")
     if forced == "banded":
         # An explicit force runs even off-TPU (interpret mode): that is
         # the CPU parity/integration seam, and production force-routing
         # must not silently un-force itself.
-        return banded(512, "forced by DL4J_TPU_DECODE_ATTN=banded")
-    if not can:
-        return dense(f"shape ineligible (L={cache_len}, h={h}, "
-                     f"hkv={hkv})")
-    table = MEASURED.get("decode", {})
-    mt = _nearest_measured(table, cache_len)
-    if mt is not None and table[mt]["winner"] == "banded":
-        row = table[mt]
-        return banded(row.get("block_l", 512),
-                      f"measured win at L={mt} "
-                      f"({row['banded_ms']} vs {row['dense_ms']} ms)")
-    if mt is not None:
-        row = table[mt]
-        return dense(f"measured loss at L={mt} "
-                     f"({row.get('banded_ms')} vs {row['dense_ms']} ms)")
-    return dense("no measured rows; conservative default")
+        return verdict("banded", 512,
+                       "forced by DL4J_TPU_DECODE_ATTN=banded")
+    return verdict("dense", 0,
+                   "no serving cell has measured the decode kernel")
 
 
 class DecodeLoopPolicy(NamedTuple):
@@ -515,12 +344,10 @@ def decode_loop_policy(k: Optional[int] = None, *, capable: bool = True,
                        record: bool = True) -> DecodeLoopPolicy:
     """Fused-K decode loop (one `lax.scan` dispatch advances every active
     session K tokens, sampling on-device) vs the stepwise one-token-per-
-    dispatch loop. Same lattice as the other policies — env force, then
-    capability, then the measured verdict — but the no-data default is
-    FUSED, not conservative: both sides lower through the identical
-    per-step XLA program (no hand-written kernel to mistrust), and the
-    K-fold host round-trip amortization is structural, exactly like
-    `lstm_policy`'s fused default. `k` is the caller's requested window
+    dispatch loop. Env force, then capability, and the default is FUSED:
+    both sides lower through the identical per-step XLA program (no
+    hand-written kernel to mistrust), and the K-fold host round-trip
+    amortization is structural. `k` is the caller's requested window
     (None = the default bucket); it is snapped to DECODE_K_BUCKETS so
     request churn costs zero compiles. `capable=False` (the model has no
     `session_decode_window`, e.g. a ComputationGraph endpoint) degrades
@@ -551,17 +378,6 @@ def decode_loop_policy(k: Optional[int] = None, *, capable: bool = True,
         return fused(want_k, "forced by DL4J_TPU_DECODE_LOOP=fused")
     if not capable:
         return stepwise("model has no session_decode_window")
-    row = MEASURED.get("decode_loop")
-    if row is not None:
-        mt = _nearest_measured(row, want_k)
-        if mt is not None and row[mt]["winner"] == "stepwise":
-            return stepwise(f"measured loss at K={mt} "
-                            f"({row[mt]['fused_ms']} vs "
-                            f"{row[mt]['stepwise_ms']} ms)")
-        if mt is not None:
-            return fused(want_k, f"measured win at K={mt} "
-                         f"({row[mt]['fused_ms']} vs "
-                         f"{row[mt]['stepwise_ms']} ms)")
     return fused(want_k, "structural default: identical per-step XLA "
                  "program, K-fold fewer host round-trips")
 
@@ -576,9 +392,8 @@ def spec_decode_policy(k: Optional[int] = None, *, capable: bool = True,
                        record: bool = True) -> SpecDecodePolicy:
     """Draft-model speculative decoding (draft proposes D tokens per
     lane, the target verifies all D in ONE chunk dispatch, accept/reject
-    on device) vs the plain fused window. Same lattice as
-    `decode_loop_policy` — env force, then capability, then the measured
-    verdict. The no-data default is SPEC when a draft is wired up:
+    on device) vs the plain fused window. As `decode_loop_policy`: env
+    force, then capability. The default is SPEC when a draft is wired up:
     verification lowers through the same chunked forward the prefill
     path already runs, and replacing D sequential target steps with one
     chunk is structural. `capable=False` means no draft model is
@@ -614,17 +429,6 @@ def spec_decode_policy(k: Optional[int] = None, *, capable: bool = True,
     if not capable:
         return plain("no rewindable draft/target pair (draft missing, "
                      "recurrent carries, or rolling KV rings)")
-    row = MEASURED.get("spec_decode")
-    if row is not None:
-        mt = _nearest_measured(row, want_k)
-        if mt is not None and row[mt]["winner"] == "plain":
-            return plain(f"measured loss at D={mt} "
-                         f"({row[mt]['spec_ms']} vs "
-                         f"{row[mt]['plain_ms']} ms)")
-        if mt is not None:
-            return spec(want_k, f"measured win at D={mt} "
-                        f"({row[mt]['spec_ms']} vs "
-                        f"{row[mt]['plain_ms']} ms)")
     return spec(want_k, "structural default: one chunk verify replaces "
                 "D sequential target dispatches")
 
@@ -650,10 +454,8 @@ def kv_dtype_policy(kind: Optional[str] = None, *,
     kernel's block loads and the dense fallback), or "fp8" (e4m3, same
     scale rows, capable backends only). Env hatch DL4J_TPU_KV_DTYPE
     always wins; `kind` is the caller's request (server knob); the
-    no-data default is NATIVE — quantization trades ulps for slots, and
-    that trade is opted into per deployment, not defaulted. A MEASURED
-    ["kv_dtype"] verdict (from the autotune sweep) can flip the auto
-    default once rows exist."""
+    default is NATIVE — quantization trades ulps for slots, and that
+    trade is opted into per deployment, not defaulted."""
     forced = _env("DL4J_TPU_KV_DTYPE")
     want = forced if forced != "auto" else (kind or "").strip().lower()
     if want not in ("", "auto", "native", "int8", "fp8"):
@@ -677,14 +479,7 @@ def kv_dtype_policy(kind: Optional[str] = None, *,
                            "support; int8 carries the same scale rows")
         src = "DL4J_TPU_KV_DTYPE" if forced != "auto" else "caller"
         return verdict("fp8", f"forced by {src}=fp8")
-    row = MEASURED.get("kv_dtype")
-    if row is not None and row.get("winner") in ("int8", "fp8"):
-        kd = row["winner"]
-        if kd == "fp8" and not _fp8_capable():
-            kd = "int8"
-        return verdict(kd, f"measured win ({row})")
-    return verdict("native", "no measured rows; quantization is "
-                   "opt-in per deployment")
+    return verdict("native", "quantization is opt-in per deployment")
 
 
 class PrefixCachePolicy(NamedTuple):
@@ -698,13 +493,11 @@ def prefix_cache_policy(page_len: Optional[int] = None, *,
                         capable: bool = True,
                         record: bool = True) -> PrefixCachePolicy:
     """Paged KV storage + radix prefix cache vs monolithic per-slot
-    caches. Same lattice as the other policies — env force, then
-    capability — but like `decode_loop_policy` the no-data default is
-    ON when the model is capable: a warm prefix replaces its whole
-    prefill with admission-time page-table writes, and that bookkeeping
-    costs the steady-state window nothing (page indices are traced
-    scalars, one compiled program either way), so there is no measured
-    trade to wait on. `capable=False` (recurrent carries, rolling KV
+    caches. Env force, then capability, and like `decode_loop_policy`
+    the default is ON when the model is capable: a warm prefix replaces
+    its whole prefill with admission-time page-table writes, and that
+    bookkeeping costs the steady-state window nothing (page indices are
+    traced scalars, one compiled program either way). `capable=False` (recurrent carries, rolling KV
     rings, non-uniform max_cache, or an active draft model whose own
     cache cannot skip the prefill) degrades to off. The page length
     (DL4J_TPU_KV_PAGE, or `page_len`, default 128 — the TPU lane tile,
@@ -749,23 +542,13 @@ def prefix_cache_policy(page_len: Optional[int] = None, *,
 
 
 def lstm_policy(train: bool = True) -> str:
-    """"fused" (Pallas) or "scan" (lax.scan baseline) for the LSTM core.
-
-    The fused kernel exists precisely because the recurrence carry is a
-    fusion XLA cannot do across scan steps; the measured train win is
-    2.35x (tools/kernel_bench_results.json: lstm_train_fused). An
-    unmeasured mode falls back to the other mode's verdict (documented:
-    both run the identical kernel; only the cotangent pass differs).
-    """
+    """"fused" (Pallas) or "scan" (lax.scan baseline) for the LSTM core:
+    fused unless DL4J_TPU_LSTM forces otherwise, in both modes (they run
+    the identical kernel; only the cotangent pass differs). The fused
+    kernel exists because the recurrence carry is a fusion XLA cannot do
+    across scan steps. Whether a shape can take the kernel is the call
+    site's check (`nn/layers/recurrent.py`)."""
     forced = _env("DL4J_TPU_LSTM")
-    if forced in ("fused", "scan"):
-        record_dispatch("lstm", forced)
-        return forced
-    table = MEASURED.get("lstm", {})
-    mode = "train" if train else "fwd"
-    row = table.get(mode) or table.get("fwd" if train else "train")
-    verdict = "fused"   # no data at all: structural argument above
-    if row is not None:
-        verdict = "fused" if row["winner"] == "fused" else "scan"
+    verdict = forced if forced in ("fused", "scan") else "fused"
     record_dispatch("lstm", verdict)
     return verdict

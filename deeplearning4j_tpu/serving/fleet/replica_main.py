@@ -39,9 +39,8 @@ import threading
 def build_bench_lm(spec: dict):
     """The fleet bench/test model: a tiny seeded transformer LM with a
     NON-rolling uniform cache, which is what makes it pageable
-    (`prefix_cache_capable`) and therefore handoff-capable. Mirrors
-    the bench.py spec-pair geometry; `seed` varies the weights for
-    hot-swap legs."""
+    (`prefix_cache_capable`) and therefore handoff-capable. `seed`
+    varies the weights for hot-swap legs."""
     from deeplearning4j_tpu.models import MultiLayerNetwork
     from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
     from deeplearning4j_tpu.nn.inputs import InputType

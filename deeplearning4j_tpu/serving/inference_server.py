@@ -64,8 +64,8 @@ Dispatch modes:
       ContinuousBatchingScheduler: requests join the next device
       dispatch as soon as a slot frees
   batched=True,  scheduler="collect" — the legacy fixed
-      collect-then-run loop (ParallelInference BATCHED); kept as the
-      bench baseline (`bench.py --serving` compares the two)
+      collect-then-run loop (ParallelInference BATCHED); kept until a
+      serving cell compares the two (ROADMAP D8)
   batched=False — direct synchronous dispatch per HTTP thread
 """
 
@@ -192,7 +192,7 @@ class InferenceServer(JsonHttpServer):
         cache storage; `page_len` requests a KV page length for the
         prefix cache (paged storage + radix prefix reuse — on by
         default when the model can page its KV). All defer to their
-        kernel_defaults policy lattice — DL4J_TPU_SPEC_DECODE /
+        kernel_defaults policy — DL4J_TPU_SPEC_DECODE /
         DL4J_TPU_DRAFT_K / DL4J_TPU_KV_DTYPE / DL4J_TPU_PREFIX_CACHE /
         DL4J_TPU_KV_PAGE force-override."""
         if self.mode != "continuous":

@@ -7,8 +7,8 @@ scheduling discipline real inference servers use: a request joins the
 very next device dispatch as soon as a slot frees. While a slot is busy
 the queue naturally accumulates arrivals, so batches grow under load and
 shrink to singletons when idle — occupancy tracks load with no tuned
-wait timer, which is exactly where the p99 win over collect-then-run
-comes from (measured in `bench.py --serving`).
+wait timer (no chip run has compared its p99 with collect-then-run's:
+ROADMAP D8).
 
 Admission control is a bounded queue with a configurable policy:
 

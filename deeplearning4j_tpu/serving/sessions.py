@@ -1296,8 +1296,8 @@ class DecodeSessionManager:
         """Which decode-attention kernel each cached-attention layer
         shape would dispatch to (kernel_defaults.decode_attention_policy
         — same call the layer makes per step), so snapshots show WHERE
-        single-token steps run without reverse-engineering env + measured
-        tables. Best-effort: policy evaluation must never take down
+        single-token steps run without reverse-engineering env
+        hatches. Best-effort: policy evaluation must never take down
         /metrics."""
         try:
             from deeplearning4j_tpu.ops.kernel_defaults import (

@@ -9,7 +9,7 @@ with _span("import.utils"):
         NTPTimeSource, SystemClockTimeSource, TimeSource, TimeSourceProvider,
     )
     from deeplearning4j_tpu.utils.profiling import (
-        ProfilerListener, peak_flops, peak_hbm_bytes, peak_ici_bytes,
+        ProfilerListener, peak_flops, peak_ici_bytes,
         step_flops, trace,
     )
 
@@ -18,6 +18,5 @@ __all__ = [
     "flatten_params", "unflatten_params", "param_count", "tree_norm",
     "TimeSource", "SystemClockTimeSource", "NTPTimeSource",
     "TimeSourceProvider", "ProfilerListener", "peak_flops",
-    "peak_ici_bytes",
-    "peak_hbm_bytes", "step_flops", "trace",
+    "peak_ici_bytes", "step_flops", "trace",
 ]

@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives.
 
-Called by entry points only (`chip_smoke.py`, `bench.py`'s child,
-`serving/fleet/replica_main.py`, `tools/kernel_bench.py`,
-`tools/shardmap_smoke.py`), before their first compile; never at package
+Called by entry points only (`chip_smoke.py`, the benchmark's
+processes, `serving/fleet/replica_main.py`, `tools/shardmap_smoke.py`),
+before their first compile; never at package
 import and never from `tests/conftest.py`. The directory is part of the
 cache key, so it must not move between runs: no temporary name, process
 id or time in it.

@@ -39,29 +39,12 @@ PEAK_FLOPS_BY_KIND = (
     ("v2", 45e12),
 )
 
-# Peak HBM bandwidth per chip, bytes/s (public spec sheets). Paired with
-# PEAK_FLOPS_BY_KIND these define each chip's machine balance (FLOP per
-# byte at the roofline ridge) — tools/roofline_report.py joins them
-# against watchdog compile costs to rank jit owners by roofline gap.
-PEAK_HBM_BYTES_BY_KIND = (
-    ("v6", 1.640e12),     # Trillium / v6e
-    ("v5p", 2.765e12),
-    ("v5 lite", 0.819e12),
-    ("v5litepod", 0.819e12),
-    ("v5e", 0.819e12),
-    ("v5", 2.765e12),
-    ("v4", 1.228e12),
-    ("v3", 0.900e12),
-    ("v2", 0.700e12),
-)
-
 # Peak inter-chip interconnect (ICI) bandwidth per chip, bytes/s —
 # aggregate across links, one direction (public spec sheets; v5e/v6e
 # figures are the 4-link 2D-torus aggregates, v4/v5p the 6-link 3D).
 # Paired with the commsmon comm ledger's per-device wire bytes these
-# price a program's collective time the way PEAK_HBM prices its memory
-# time — tools/comm_report.py joins the two to classify jit owners
-# compute-bound vs comm-bound.
+# price a program's collective time — tools/comm_report.py joins the
+# two to classify jit owners compute-bound vs comm-bound.
 PEAK_ICI_BYTES_BY_KIND = (
     ("v6", 0.448e12),     # Trillium / v6e: 4 x ~112 GB/s
     ("v5p", 0.600e12),    # 6 x 100 GB/s
@@ -98,27 +81,6 @@ def peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
             "peak known, so MFU will not be reported. Add the kind to "
             "PEAK_FLOPS_BY_KIND or pass peak_flops= explicitly.",
             device_kind)
-    return None
-
-
-def peak_hbm_bytes(device_kind: Optional[str] = None) -> Optional[float]:
-    """Per-chip peak HBM bandwidth (bytes/s) for a device kind (default:
-    device 0). Same contract as `peak_flops`: unknown kinds return None
-    and warn once — callers must omit, never fabricate, a roofline."""
-    if device_kind is None:
-        # spec-sheet lookup keys off the chip model, not placement
-        device_kind = jax.devices()[0].device_kind  # graft: allow(GL501): roofline reads device kind only
-    kind = device_kind.lower()
-    for key, peak in PEAK_HBM_BYTES_BY_KIND:
-        if key in kind:
-            return peak
-    warn_key = ("hbm", kind)
-    if warn_key not in _warned_kinds:
-        _warned_kinds.add(warn_key)
-        logger.warning(
-            "peak_hbm_bytes: unrecognized device kind %r — no spec-sheet "
-            "bandwidth known. Add the kind to PEAK_HBM_BYTES_BY_KIND or "
-            "pass the peak explicitly.", device_kind)
     return None
 
 

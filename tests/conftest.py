@@ -2,7 +2,7 @@
 
 Mirrors the reference's distributed-without-a-cluster strategy (Spark
 `local[N]` — `BaseSparkTest.java:89`): multi-chip sharding is tested on
-virtual CPU devices; real-TPU benchmarking happens in bench.py.
+virtual CPU devices; the chip is measured by `benchmarks/run.py`.
 float64 is enabled for gradient checks (reference runs them in double).
 """
 

@@ -308,8 +308,7 @@ class TestCommLedgerEndToEnd:
     def test_dp_all_reduce_reconciles(self, devices8):
         """The replicated-leg gradient all-reduce prices at the textbook
         4 * param_count * (n-1)/n ring bytes (+ the scalar-loss
-        all-reduce's ~4B of slack) — the bench.py --sharding
-        reconciliation, pinned as a test."""
+        all-reduce's ~4B of slack)."""
         from deeplearning4j_tpu.parallel import ParallelWrapper, make_mesh
         from test_sharding_spine import _net, _toy
 

@@ -648,7 +648,7 @@ class TestDumpTool:
         assert "reqs" in out and "model=m" in out and "5" in out
         assert "count=1" in out
 
-        # BENCH blobs embed the snapshot under "registry"
+        # a blob may embed the snapshot under "registry"
         bench_path = tmp_path / "BENCH_x.json"
         bench_path.write_text(json.dumps(
             {"metric": "ips", "registry": reg.snapshot()}))

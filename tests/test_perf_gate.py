@@ -1,9 +1,9 @@
 """Unit tests for the slim perf gate (tools/perf_gate.py) and the
-roofline advisor (tools/roofline_report.py).
+snapshot reader of the comm advisor (tools/comm_report.py).
 
-Both tools keep their decision logic pure — compare() and analyze()
-take dicts in, lists out — precisely so the gate semantics can be
-tested here without running the workload or touching a device. The
+The gate keeps its decision logic pure — compare() takes dicts in,
+lists out — precisely so the gate semantics can be tested here
+without running the workload or touching a device. The
 workload run itself is exercised by CI via `tools/ci_check.sh --perf`.
 """
 import os
@@ -15,7 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
                                 "tools"))
 
 import perf_gate            # noqa: E402
-import roofline_report      # noqa: E402
+import comm_report          # noqa: E402
 
 
 def _measured(**over):
@@ -128,58 +128,9 @@ def _snapshot():
 
 
 class TestRoofline:
-    PEAK_F, PEAK_B = 100e12, 1e12     # balance = 100 flop/byte
-
     def test_extract_raw_and_nested(self):
         snap = _snapshot()
-        assert roofline_report.extract_watchdog(snap) is snap
-        assert roofline_report.extract_watchdog(
-            {"watchdog": snap}) is snap
-        assert roofline_report.extract_watchdog(
-            {"observability": {"recompile_watchdog": snap}}) is snap
+        assert comm_report.extract_watchdog(snap) is snap
+        assert comm_report.extract_watchdog({"watchdog": snap}) is snap
         with pytest.raises(ValueError):
-            roofline_report.extract_watchdog({"metric": "nope"})
-
-    def test_bound_classification_and_gap(self):
-        rows = roofline_report.analyze(_snapshot(), self.PEAK_F,
-                                       self.PEAK_B)
-        by = {r["owner"].split("@")[0]: r for r in rows}
-        ew, mm = by["Elementwise"], by["Matmul"]
-        assert ew["bound"] == "memory"
-        assert mm["bound"] == "compute"
-        # elementwise: intensity 1/16 flop/byte -> attainable =
-        # (1/16)*peak_bytes; gap = balance * 16 = 1600
-        assert ew["intensity"] == pytest.approx(1 / 16)
-        assert ew["gap"] == pytest.approx(1600.0)
-        # matmul: intensity 500 >= balance -> compute bound, gap 1.0
-        assert mm["intensity"] == pytest.approx(500.0)
-        assert mm["gap"] == pytest.approx(1.0)
-        # zero-cost program skipped but counted
-        assert mm["uncosted"] == 1 and mm["programs"] == 1
-
-    def test_ranking_is_time_weighted(self):
-        # the matmul owns 40ms of bound time at gap 1 (weight 0.04);
-        # the elementwise has gap 1600 but only 16us of bound time
-        # (weight 0.026) — time-weighted, the matmul ranks first
-        rows = roofline_report.analyze(_snapshot(), self.PEAK_F,
-                                       self.PEAK_B)
-        assert rows[0]["owner"].startswith("Matmul")
-        # flip the weights: make the elementwise own the runtime
-        snap = _snapshot()
-        snap["per_owner"]["Elementwise@0x1"]["costs"]["sig_a"] = {
-            "flops": 1e12, "bytes_accessed": 1.6e13}
-        rows = roofline_report.analyze(snap, self.PEAK_F, self.PEAK_B)
-        assert rows[0]["owner"].startswith("Elementwise")
-
-    def test_owner_without_costs_is_dropped(self):
-        snap = _snapshot()
-        snap["per_owner"]["Silent@0x3"] = {"compiles": 5,
-                                           "signatures": 5, "costs": {}}
-        rows = roofline_report.analyze(snap, self.PEAK_F, self.PEAK_B)
-        assert not any(r["owner"].startswith("Silent") for r in rows)
-
-    def test_peak_hbm_table_covers_known_kinds(self):
-        from deeplearning4j_tpu.utils.profiling import peak_hbm_bytes
-        assert peak_hbm_bytes("TPU v4") == pytest.approx(1.228e12)
-        assert peak_hbm_bytes("TPU v5e") == pytest.approx(0.819e12)
-        assert peak_hbm_bytes("TPU v6 lite") == pytest.approx(1.640e12)
+            comm_report.extract_watchdog({"metric": "nope"})

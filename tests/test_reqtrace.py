@@ -500,7 +500,6 @@ class TestTraceView:
         doc = self._doc(sampled)
         assert trace_view.extract_trees(doc) == [doc]          # /trace/{id}
         assert trace_view.extract_trees({"traces": [doc]}) == [doc]
-        assert trace_view.extract_trees({"trace": doc}) == [doc]
         assert trace_view.extract_trees({"metric": "x"}) == []
 
     def test_renders_waterfall(self, sampled, tmp_path, capsys):

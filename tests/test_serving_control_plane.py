@@ -673,8 +673,8 @@ class TestObservability:
 
 
 class TestCollectModeBackCompat:
-    """The legacy fixed collect-then-run loop stays available (it is the
-    bench.py --serving baseline) and serves through the same routes."""
+    """The legacy fixed collect-then-run loop stays available and
+    serves through the same routes."""
 
     def test_collect_mode_serves(self, nets):
         from deeplearning4j_tpu.serving import InferenceServer

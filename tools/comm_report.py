@@ -25,9 +25,10 @@ not kernel tuning. Owners are ranked by absolute comm time so the
 report surfaces where interconnect cycles actually go. Programs with
 zero collectives are pure compute rows (comm_frac 0) and rank last.
 
-Input is a watchdog snapshot like tools/roofline_report.py: `--snapshot
-FILE` accepts a raw snapshot, a flight dump ("watchdog" key), or a
-BENCH blob; with no file the tool reads the live process watchdog.
+Input is a watchdog snapshot: `--snapshot FILE` accepts a raw
+`RecompileWatchdog.snapshot()` JSON or a flight-recorder dump (the
+snapshot under its "watchdog" key); with no file the tool reads the
+live process watchdog.
 Peaks come from --device-kind or explicit --peak-flops / --peak-ici;
 off-TPU there is no default and the tool says so.
 """
@@ -39,7 +40,18 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from roofline_report import extract_watchdog  # noqa: E402
+
+def extract_watchdog(blob: dict) -> dict:
+    """Accept a raw watchdog snapshot or a flight dump; return the
+    watchdog snapshot dict (with `per_owner`)."""
+    if "per_owner" in blob:
+        return blob
+    inner = blob.get("watchdog")
+    if isinstance(inner, dict) and "per_owner" in inner:
+        return inner
+    raise ValueError(
+        "no watchdog snapshot found (expected a 'per_owner' mapping, "
+        "possibly under a 'watchdog' key)")
 
 
 def analyze(snapshot: dict, peak_flops: float, peak_ici: float) -> list:
@@ -160,8 +172,8 @@ def _resolve_peaks(args):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--snapshot", help="watchdog snapshot / flight dump "
-                    "/ BENCH blob JSON (default: live process watchdog)")
+    ap.add_argument("--snapshot", help="watchdog snapshot or flight dump "
+                    "JSON (default: live process watchdog)")
     ap.add_argument("--device-kind", help="spec-sheet lookup key, e.g. "
                     "'TPU v4' (default: the attached device)")
     ap.add_argument("--peak-flops", type=float,

@@ -12,14 +12,6 @@ reasons) — as unicode sparklines and tables, entirely from the stdlib:
   python tools/dash.py --url ... --html dash.html             single-file
                                                               HTML (inline
                                                               SVG, no JS)
-  python tools/dash.py --bench                                bench history
-                                                              trajectory from
-                                                              BENCH_history.jsonl
-
-The --bench mode needs no server: it renders the timestamped rows
-bench.py appends to BENCH_history.jsonl (one per invocation, every
-mode), grouped by (mode, metric) so the throughput/latency trajectory
-across sessions is one glance.
 """
 
 from __future__ import annotations
@@ -27,14 +19,11 @@ from __future__ import annotations
 import argparse
 import html as _html
 import json
-import os
 import sys
 import time
 import urllib.request
 
 _BARS = "▁▂▃▄▅▆▇█"
-DEFAULT_HISTORY = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "..", "BENCH_history.jsonl")
 
 
 # --------------------------------------------------------------- fetch
@@ -212,166 +201,6 @@ def render_html(series, slo, healthz, *, prefix: str = "",
     return "".join(head) + "".join(body)
 
 
-# ---------------------------------------------------------- bench mode
-def _load_history(path):
-    rows = []
-    try:
-        with open(path) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rows.append(json.loads(line))
-                except json.JSONDecodeError:
-                    continue
-    # graft: allow(GL403): a missing/unreadable history file renders as
-    # the empty-state message below
-    except OSError:
-        pass
-    return rows
-
-
-def render_bench(path: str, *, mode: str = "", width: int = 40) -> str:
-    rows = _load_history(path)
-    if mode:
-        rows = [r for r in rows if r.get("mode") == mode]
-    if not rows:
-        return (f"no bench history at {path}"
-                + (f" for mode {mode!r}" if mode else "")
-                + " — run bench.py first\n")
-    groups = {}
-    for r in rows:
-        groups.setdefault((r.get("mode", "?"), r.get("metric", "?")),
-                          []).append(r)
-    lines = [f"bench history: {len(rows)} runs, {len(groups)} "
-             f"mode/metric groups ({os.path.relpath(path)})"]
-    for (m, metric), rs in sorted(groups.items()):
-        vals = [r["value"] for r in rs
-                if isinstance(r.get("value"), (int, float))]
-        last = rs[-1]
-        unit = last.get("unit", "")
-        lines.append("")
-        lines.append(f"[{m}] {metric}  ({len(rs)} runs, "
-                     f"last {last.get('ts', '?')})")
-        if vals:
-            trend = ""
-            if len(vals) >= 2 and vals[0]:
-                trend = f"  ({(vals[-1] / vals[0] - 1) * 100:+.1f}% vs first)"
-            lines.append(f"  value {spark(vals, width)} "
-                         f"{_fmt(vals[-1])} {unit}{trend}")
-        for extra in ("mfu", "ttft_p99_ms", "itl_p99_ms",
-                      "continuous_p99_ms", "opt_state_shard_factor",
-                      "spec_tokens_per_s", "spec_acceptance_rate",
-                      "spec_speedup_vs_stepwise",
-                      "prefix_hit_rate", "prefix_ttft_speedup",
-                      "comm_step_all_reduce_bytes"):
-            evals = [r[extra] for r in rs
-                     if isinstance(r.get(extra), (int, float))]
-            if evals:
-                lines.append(f"  {extra:22} {spark(evals, width)} "
-                             f"{_fmt(evals[-1])}")
-        # the spec/kv matrix from the latest run, one line per leg
-        matrix = last.get("spec_matrix")
-        if isinstance(matrix, list) and matrix:
-            lines.append("  spec/kv matrix (latest run):")
-            for leg in matrix:
-                tag = (f"{'spec' if leg.get('spec') else 'plain'}"
-                       f"/{leg.get('kv', '?'):6}")
-                acc = leg.get("acceptance_rate")
-                slots = leg.get("slots_factor")
-                lines.append(
-                    f"    {tag} k={leg.get('k')}: "
-                    f"{_fmt(leg.get('tokens_per_s'))} tok/s"
-                    + (f", acceptance {_fmt(acc)}"
-                       if isinstance(acc, (int, float)) else "")
-                    + (f", {_fmt(slots)}x slots/chip"
-                       if isinstance(slots, (int, float))
-                       and slots != 1.0 else ""))
-        # the prefix-cache panel from the latest run: warm-vs-cold
-        # TTFT plus the radix counters (evictions, CoW forks)
-        if isinstance(last.get("prefix_ttft_speedup"), (int, float)):
-            bits = [f"{_fmt(last['prefix_ttft_speedup'])}x TTFT "
-                    f"warm-vs-cold"]
-            if isinstance(last.get("prefix_hit_rate"), (int, float)):
-                bits.append(f"hit rate {_fmt(last['prefix_hit_rate'])}")
-            for key, tag in (("prefix_cow_forks", "CoW forks"),
-                             ("prefix_evicted_pages", "evictions"),
-                             ("prefix_no_overlap_ttft_ratio",
-                              "no-overlap ratio")):
-                if isinstance(last.get(key), (int, float)):
-                    bits.append(f"{tag} {_fmt(last[key])}")
-            lines.append("  prefix cache (latest run): "
-                         + ", ".join(bits))
-        # the comm-ledger panel: per-step gradient all-reduce wire
-        # bytes vs the analytic 4*params*(n-1)/n, and whether the
-        # latest run reconciled (bench.py --sharding comm_ledger block)
-        if isinstance(last.get("comm_step_all_reduce_bytes"),
-                      (int, float)):
-            bits = [f"{_fmt(last['comm_step_all_reduce_bytes'])} B "
-                    f"all-reduce/step"]
-            if isinstance(last.get("comm_rec_error"), (int, float)):
-                bits.append(f"vs analytic "
-                            f"{last['comm_rec_error'] * 100:+.2f}%")
-            if last.get("comm_reconciled") is not None:
-                bits.append("reconciled" if last["comm_reconciled"]
-                            else "NOT RECONCILED")
-            lines.append("  comm ledger (latest run): " + ", ".join(bits))
-        # the serving-fleet panel: replica count, router traffic
-        # verbs (reroutes/handoffs/migrations/SLO drains), fleet p99,
-        # and the per-replica-count scaling legs from the latest run
-        fl = last.get("fleet")
-        if isinstance(fl, dict):
-            bits = [f"{_fmt(fl.get('replicas'))} replicas"]
-            for key, tag in (("reroutes", "reroutes"),
-                             ("handoffs", "handoffs"),
-                             ("migrations", "migrations"),
-                             ("slo_drains", "SLO drains")):
-                if isinstance(fl.get(key), (int, float)):
-                    bits.append(f"{_fmt(fl[key])} {tag}")
-            if isinstance(fl.get("ttft_p99_ms"), (int, float)):
-                bits.append(f"fleet TTFT p99 {_fmt(fl['ttft_p99_ms'])} ms")
-            if isinstance(fl.get("scaling"), (int, float)):
-                bits.append(f"{_fmt(fl['scaling'])}x 1→N scaling")
-            if fl.get("reconciled") is not None:
-                bits.append("metrics "
-                            + ("reconciled" if fl["reconciled"]
-                               else "MISMATCHED"))
-            lines.append("  fleet (latest run): " + ", ".join(bits))
-            # federation row across ALL history rows in the group:
-            # scrape freshness, stale replicas, and the fleet SLO burn
-            # sparkline (how close the merged objectives ran to firing)
-            fed_bits = []
-            if isinstance(fl.get("scrape_age_s"), (int, float)):
-                fed_bits.append(
-                    f"scrape age {_fmt(fl['scrape_age_s'])}s")
-            if isinstance(fl.get("stale_replicas"), (int, float)):
-                n = fl["stale_replicas"]
-                fed_bits.append(f"{_fmt(n)} stale replica(s)"
-                                if n else "0 stale")
-            burns = [r["fleet"]["slo_burn"] for r in rs
-                     if isinstance(r.get("fleet"), dict)
-                     and isinstance(r["fleet"].get("slo_burn"),
-                                    (int, float))]
-            if burns:
-                fed_bits.append(f"SLO burn {spark(burns, width // 2)} "
-                                f"{_fmt(burns[-1])}")
-            if fed_bits:
-                lines.append("  federation: " + ", ".join(fed_bits))
-            legs = last.get("scale_legs")
-            if isinstance(legs, list):
-                for leg in legs:
-                    lines.append(
-                        f"    {_fmt(leg.get('replicas'))} replica(s): "
-                        f"{_fmt(leg.get('tokens_per_s'))} tok/s, "
-                        f"TTFT p99 {_fmt(leg.get('ttft_p99_ms'))} ms"
-                        + ("" if leg.get("reconciled")
-                           else ", metrics MISMATCHED"))
-        if last.get("error"):
-            lines.append("  last run FAILED (see its BENCH_*.json)")
-    return "\n".join(lines) + "\n"
-
-
 # ----------------------------------------------------------------- cli
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -387,17 +216,7 @@ def main(argv=None) -> int:
                     help="write a single-file HTML dashboard and exit")
     ap.add_argument("--refresh", type=int, default=0,
                     help="auto-refresh interval baked into the HTML")
-    ap.add_argument("--bench", nargs="?", const=DEFAULT_HISTORY,
-                    metavar="JSONL",
-                    help="render BENCH_history.jsonl instead of a server")
-    ap.add_argument("--mode", default="",
-                    help="with --bench: only this bench mode")
     args = ap.parse_args(argv)
-
-    if args.bench:
-        sys.stdout.write(render_bench(args.bench, mode=args.mode,
-                                      width=args.width))
-        return 0
 
     base = args.url.rstrip("/")
     series, slo, healthz = _fetch_all(base)

@@ -5,8 +5,6 @@ waterfall.
     python tools/trace_view.py trace.json        # GET /trace/{id} output
     python tools/trace_view.py flight_*.json     # flight dump: renders
                                                  # its `traces` block
-    python tools/trace_view.py BENCH_serving_decode.json   # bench
-                                                 # exemplar `trace` block
     curl -s :8080/trace/t1a2b-000003 | python tools/trace_view.py -
 
 Each span prints as one indented line: offset from the trace root,
@@ -113,14 +111,12 @@ def extract_trees(doc) -> list:
         return [doc]
     if isinstance(doc.get("traces"), list):    # flight dump block
         return [t for t in doc["traces"] if isinstance(t, dict)]
-    if isinstance(doc.get("trace"), dict):     # bench exemplar block
-        return [doc["trace"]]
     return []
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("path", help="trace/flight/bench JSON, or - for stdin")
+    ap.add_argument("path", help="trace/flight JSON, or - for stdin")
     ap.add_argument("--last", type=int, default=0,
                     help="render only the last N traces (default: all)")
     args = ap.parse_args(argv)
@@ -133,9 +129,8 @@ def main(argv=None) -> int:
 
     trees = extract_trees(doc)
     if not trees:
-        sys.exit("no trace tree found (expected /trace/{id} JSON, a "
-                 "flight dump with a `traces` block, or bench output "
-                 "with a `trace` block)")
+        sys.exit("no trace tree found (expected /trace/{id} JSON or a "
+                 "flight dump with a `traces` block)")
     if args.last:
         trees = trees[-args.last:]
     for i, t in enumerate(trees):
