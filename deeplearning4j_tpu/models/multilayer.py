@@ -44,7 +44,7 @@ from deeplearning4j_tpu.nn.layers.convolution import (
 from deeplearning4j_tpu.nn.layers.recurrent import (
     BaseRecurrentLayer, Bidirectional, GravesBidirectionalLSTM, LastTimeStep,
 )
-from deeplearning4j_tpu.nn.layers.special import CenterLossOutputLayer
+from deeplearning4j_tpu.nn.layers.special import LoopedStack
 from deeplearning4j_tpu.observe.registry import get_registry
 from deeplearning4j_tpu.observe.trace import span
 from deeplearning4j_tpu.observe.watchdog import listen_for_compiles
@@ -127,24 +127,28 @@ def _checkpointed(apply_fn, mask):
     and the pair (kernel calls whose pair was named, block values named).
     Shared by MultiLayerNetwork and ComputationGraph so the remat
     semantics can't drift."""
-    from deeplearning4j_tpu.ops.attention import (
-        KEPT_NAMES, block_residuals_named, residuals_named,
-    )
+    from deeplearning4j_tpu.ops.attention import KEPT_NAMES
 
     remat = jax.checkpoint(
         lambda p, x, st, lr, _a=apply_fn:
         _a(p, x, state=st, train=True, rng=lr, mask=mask),
         policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
 
-    def tally():
-        return np.array([residuals_named(), block_residuals_named()])
-
     def apply(p, x, st, lr):
-        before = tally()
+        before = _named_so_far()
         out = remat(p, x, st, lr)
-        return out, tally() - before
+        return out, _named_so_far() - before
 
     return apply
+
+
+def _named_so_far():
+    """(kernel calls, block values) this thread has named while tracing."""
+    from deeplearning4j_tpu.ops.attention import (
+        block_residuals_named, residuals_named,
+    )
+
+    return np.array([residuals_named(), block_residuals_named()])
 
 
 def record_residuals_kept(model, kept) -> None:
@@ -165,6 +169,21 @@ def record_residuals_kept(model, kept) -> None:
                             "block_residuals_kept"), kept):
         get_registry().gauge(name, model=type(model).__name__).set(
             int(value))
+
+
+def record_loops(model, layers) -> None:
+    """Gauges `loop_passes{model=<class>}` and
+    `loop_block_applications{model=<class>}`: over the `LoopedStack`s of
+    `layers`, the passes a step makes and the member applications they
+    come to (4 and 24 for the benchmark's `ouro_2_6b`). Set at trace
+    time, and only for a model that has such a layer."""
+    loops = [l for l in layers if isinstance(l, LoopedStack)]
+    if loops:
+        for name, value in (
+                ("loop_passes", sum(l.passes for l in loops)),
+                ("loop_block_applications",
+                 sum(l.passes * len(l.layers) for l in loops))):
+            get_registry().gauge(name, model=type(model).__name__).set(value)
 
 
 def record_sparse_dense(model, dense: int) -> None:
@@ -332,6 +351,18 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     x, tail = layer.split(params[layer.name], x,
                                           train=train, rng=lrng)
                     tails[i + 1], new_st = (layer.name, tail), st
+                elif remat and isinstance(layer, LoopedStack):
+                    # the unit is one member's one application: the loop
+                    # layer checkpoints each, and is not wrapped again.
+                    # Its passes are one traced body, and a kernel's
+                    # forward rule names its pair when the loop is
+                    # differentiated, inside `run`: what was named by
+                    # its end was named once for every pass
+                    before = _named_so_far()
+                    x, new_st = layer.run(
+                        self._params_of(params, layer), x, train=True,
+                        rng=lrng, mask=fmask, unit=_checkpointed), st
+                    kept += (_named_so_far() - before) * layer.passes
                 elif remat and not (layer.is_output_layer and i == n - 1):
                     # remat this layer's activations in the backward pass
                     # (memory ∝ depth → the layers' inputs and what they
@@ -356,6 +387,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
             record_deferred_pairs(self, len(tails))
             record_residuals_kept(self, kept)
             record_sparse_dense(self, dense_runs() - dense_before)
+            record_loops(self, self.layers)
         return x, out_in, new_states, acts
 
     # ------------------------------------------------------------- loss
@@ -373,12 +405,13 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
         )
         # the output layer's own work in a train step is its score
         with jax.named_scope(out_layer.name), jax.named_scope("loss"):
-            if isinstance(out_layer, CenterLossOutputLayer):
-                score, cstate = out_layer.score_and_state(
-                    params[out_layer.name], out_in, labels,
-                    states[out_layer.name], score_mask,
-                )
-                new_states[out_layer.name] = cstate
+            if hasattr(out_layer, "score_and_state"):
+                # a head that keeps state from its score (class centres,
+                # the exit distribution's counters)
+                score, new_states[out_layer.name] = (
+                    out_layer.score_and_state(
+                        self._params_of(params, out_layer), out_in, labels,
+                        states[out_layer.name], score_mask))
             else:
                 score = out_layer.score(self._params_of(params, out_layer),
                                         out_in, labels, score_mask)

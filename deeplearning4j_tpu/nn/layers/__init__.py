@@ -26,10 +26,11 @@ from deeplearning4j_tpu.nn.layers.normalization import (
 from deeplearning4j_tpu.nn.layers.pooling import GlobalPoolingLayer, PoolingType
 from deeplearning4j_tpu.nn.layers.recurrent import (
     LSTM, GravesLSTM, GravesBidirectionalLSTM, SimpleRnn, GRU, RnnOutputLayer,
-    Bidirectional, LastTimeStep,
+    Bidirectional, ExitGatedOutputLayer, LastTimeStep,
 )
 from deeplearning4j_tpu.nn.layers.special import (
-    FrozenLayer, CenterLossOutputLayer, VariationalAutoencoder, RBM,
+    FrozenLayer, CenterLossOutputLayer, LoopedStack, VariationalAutoencoder,
+    RBM,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     LatentAttention, LinearAttention, MultiHeadAttention, PreNormBlock,
@@ -53,4 +54,5 @@ __all__ = [
     "FrozenLayer", "CenterLossOutputLayer", "VariationalAutoencoder", "RBM",
     "MultiHeadAttention", "SandwichTransformerBlock", "LinearAttention",
     "LatentAttention", "PreNormBlock", "SelectiveStateSpace",
+    "LoopedStack", "ExitGatedOutputLayer",
 ]

@@ -135,6 +135,7 @@ class MultiHeadAttention(Layer):
     attn_dropout: float = 0.0
     max_cache: int = 1024             # KV-cache length for decode stepping
     rope: bool = False                # rotary position embedding on q/k
+    rope_base: float = 10000.0        # the rotary base (`rope_theta`)
     window: Optional[int] = None      # sliding-window (local) attention:
     # each position sees at most `window` keys back (causal) or within
     # |i-j| < window (bidirectional) — Mistral-style locality; O(T*w)
@@ -270,7 +271,8 @@ class MultiHeadAttention(Layer):
         if not self.rope:
             return q, k
         positions = jnp.arange(q.shape[1])
-        return rope_rotate(q, positions), rope_rotate(k, positions)
+        return (rope_rotate(q, positions, self.rope_base),
+                rope_rotate(k, positions, self.rope_base))
 
     def _project_out(self, params, o, gate):
         """[B, T, H, Dh] heads -> the layer's output, gated where the
@@ -454,8 +456,8 @@ class MultiHeadAttention(Layer):
                      else valid.sum(axis=1).astype(pos.dtype))  # [B]
             q_ids = pos[:, None] + jnp.arange(T)               # [B, T]
             if self.rope:
-                q = rope_rotate(q, q_ids)
-                k = rope_rotate(k, q_ids)
+                q = rope_rotate(q, q_ids, self.rope_base)
+                k = rope_rotate(k, q_ids, self.rope_base)
             rows = jnp.arange(B)[:, None]
             tgt = q_ids % L if self.rolling_cache else q_ids
             if valid is not None:
@@ -529,8 +531,8 @@ class MultiHeadAttention(Layer):
             # stored metadata.
             if self.rope:
                 positions = pos + jnp.arange(T)
-                q = rope_rotate(q, positions)
-                k = rope_rotate(k, positions)
+                q = rope_rotate(q, positions, self.rope_base)
+                k = rope_rotate(k, positions, self.rope_base)
             slots = (pos + jnp.arange(T)) % L
             ck = state["cache_k"].at[:, slots].set(
                 k.astype(state["cache_k"].dtype))
@@ -553,8 +555,8 @@ class MultiHeadAttention(Layer):
                 # rotate with ABSOLUTE positions continuing from the
                 # carry; the cache stores rotated keys (standard RoPE)
                 positions = pos + jnp.arange(T)
-                q = rope_rotate(q, positions)
-                k = rope_rotate(k, positions)
+                q = rope_rotate(q, positions, self.rope_base)
+                k = rope_rotate(k, positions, self.rope_base)
             q = jnp.where(pos + T <= L, q, jnp.nan)
             z = jnp.zeros((), pos.dtype)   # index dtypes must match `pos`
             ck = jax.lax.dynamic_update_slice(
@@ -1092,7 +1094,8 @@ class SandwichTransformerBlock(Layer):
 
     The attention half is a `MultiHeadAttention` with this block's options
     handed through (GQA, `head_dim`, `qk_norm`, `output_gate`, `window`,
-    `rope`: a layer with `rope=False` sees no positions at all). The other
+    `rope` at `rope_base`: a layer with `rope=False` sees no positions at
+    all). The other
     half is a SwiGLU of `ffn_width`, or, with `n_experts`, an
     `ExpertFeedForward` (`parallel/moe.py`): a router over `n_experts`
     with `moe_k` a token, of which this device holds `experts_held`
@@ -1115,6 +1118,7 @@ class SandwichTransformerBlock(Layer):
     output_gate: bool = False
     causal: bool = True
     rope: bool = False
+    rope_base: float = 10000.0
     window: Optional[int] = None
     max_cache: int = 1024
     ffn_width: Optional[int] = None      # dense half; None -> 4 x n_in
@@ -1145,7 +1149,7 @@ class SandwichTransformerBlock(Layer):
             qk_norm=self.qk_norm, output_gate=self.output_gate, bias=False,
             causal=self.causal, activation="identity",
             weight_init=self.weight_init, max_cache=self.max_cache,
-            rope=self.rope, window=self.window)
+            rope=self.rope, rope_base=self.rope_base, window=self.window)
         moe = None
         if self.n_experts > 0:
             from deeplearning4j_tpu.parallel.moe import ExpertFeedForward

@@ -35,7 +35,7 @@ from deeplearning4j_tpu.nn.activations import Activation
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, Params, register_layer
 from deeplearning4j_tpu.nn.layers.feedforward import OutputLayer
-from deeplearning4j_tpu.nn.losses import LossFunction
+from deeplearning4j_tpu.nn.losses import LossFunction, _reduce
 
 
 def _mask_carry(new, old, m):
@@ -423,6 +423,124 @@ class RnnOutputLayer(OutputLayer):
     def score(self, params, x, labels, mask=None):
         preout = self.pre_output(params, x)  # [B, T, nOut]
         return LossFunction.get(self.loss)(labels, preout, self.activation, mask)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class ExitGatedOutputLayer(Layer):
+    """The head of a looped model (`LoopedStack`): ONE matrix `W`
+    [n_in, n_out] scores the state of every pass, a one-column gate
+    (`gate_W` [n_in], `gate_b` [1]) says after each pass but the last with
+    what probability a token leaves there, and the loss is the expectation
+    of the passes' cross-entropies under that exit distribution, less
+    `beta` times its entropy:
+
+        lam_t = sigmoid(h_t . gate_W + gate_b),  t < passes
+        p_t   = lam_t prod_{s<t} (1 - lam_s),    p_passes = prod (1 - lam_s)
+        loss  = mean_i (sum_t p_t[i] ce_t[i] + beta sum_t p_t[i] log p_t[i])
+
+    Its input is the loop layer's output `[passes, B, T, n_in]` (`[B, T,
+    n_in]` where `passes` is 1, and the score is then `RnnOutputLayer`'s
+    to the bit: p_1 = 1, no entropy). The log-softmax, the gate's products
+    and the entropy are float32 where the model's dtype is narrower; the
+    exits are scored one at a time, each under a checkpoint, so one
+    `[B, T, n_out]` tensor is alive at a time, forward and backward. `apply` gives the
+    last pass's softmax. Labels and masks as `RnnOutputLayer` takes them
+    (`loss` "sparse_mcxent" with integer labels, or "mcxent"). State: the
+    last step's `exit_mass` (the mean of `p_t` over the tokens, `[passes]`)
+    and `exit_entropy` (the mean of the distribution's entropy), which
+    `fit()` publishes as gauges where an epoch synchronises."""
+
+    CONSUMES = "rnn"
+
+    n_in: Optional[int] = None
+    n_out: Optional[int] = None
+    passes: int = 1
+    beta: float = 0.1
+    loss: Any = "sparse_mcxent"
+
+    @property
+    def is_output_layer(self) -> bool:
+        return True
+
+    def infer_n_in(self, input_type: InputType):
+        if self.n_in is None:
+            return dataclasses.replace(self, n_in=input_type.size)
+        return self
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_out, input_type.timesteps)
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        if str(self.loss).lower() not in ("sparse_mcxent", "mcxent"):
+            raise ValueError(f"{self.name}: the exits are scored by "
+                             f"sparse_mcxent or mcxent, not {self.loss!r}")
+        if self.passes < 1:
+            raise ValueError(f"{self.name}: {self.passes} passes")
+        kw, kg = jax.random.split(key)
+        winit = self._winit()
+        return ({"W": winit(kw, (self.n_in, self.n_out), dtype),
+                 "gate_W": winit(kg, (self.n_in, 1), dtype)[:, 0],
+                 "gate_b": jnp.zeros((1,), dtype)},
+                {"exit_mass": jnp.zeros((self.passes,), jnp.float32),
+                 "exit_entropy": jnp.zeros((), jnp.float32)})
+
+    def _states(self, x):
+        """`[passes, B, T, n_in]` of the loop layer's output."""
+        if self.passes == 1 and x.ndim == 3:
+            x = x[None]
+        if x.ndim != 4 or x.shape[0] != self.passes:
+            raise ValueError(f"{self.name}: {self.passes} passes scored, "
+                             f"the input is {x.shape}")
+        return x
+
+    def exit_distribution(self, params, x):
+        """(log p, p), each `[passes, B, T]` and float32 (or wider, where
+        the states are), from the states of all passes."""
+        f32 = jnp.promote_types(x.dtype, jnp.float32)
+        with jax.named_scope("exit_gate"):
+            z = (jnp.sum(x[:-1].astype(f32) * params["gate_W"].astype(f32),
+                         axis=-1) + params["gate_b"].astype(f32))
+            last = jnp.zeros((1,) + x.shape[1:3], f32)
+            # log of: still in after passes 1..t-1, then out at t
+            stayed = jnp.concatenate(
+                [last, jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)])
+            logp = stayed + jnp.concatenate([jax.nn.log_sigmoid(z), last])
+            return logp, jnp.exp(logp)
+
+    def _exit_losses(self, params, x, labels):
+        """Every exit's per-token cross-entropy, `[passes, B, T]`."""
+        sparse = str(self.loss).lower() == "sparse_mcxent"
+
+        @jax.checkpoint
+        def one(h):
+            logits = h @ params["W"]
+            logp = jax.nn.log_softmax(logits.astype(jnp.promote_types(
+                logits.dtype, jnp.float32)), axis=-1)
+            if sparse:
+                return -jnp.take_along_axis(
+                    logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+            return -jnp.sum(labels * logp, axis=-1)
+
+        with jax.named_scope("exit_loss"):
+            return lax.map(one, x)
+
+    def score_and_state(self, params, x, labels, state, mask=None):
+        x = self._states(x)
+        logp, p = self.exit_distribution(params, x)
+        ce = self._exit_losses(params, x, labels)
+        entropy = -jnp.sum(p * logp, axis=0)
+        score = _reduce(jnp.sum(p * ce, axis=0) - self.beta * entropy, mask)
+        return score, {
+            "exit_mass": jax.vmap(lambda p_t: _reduce(p_t, mask))(p),
+            "exit_entropy": _reduce(entropy, mask)}
+
+    def score(self, params, x, labels, mask=None):
+        return self.score_and_state(params, x, labels, None, mask)[0]
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        return self._act(self._states(x)[-1] @ params["W"]), state
 
 
 @register_layer

@@ -52,6 +52,131 @@ class FrozenLayer(Layer):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class LoopedStack(Layer):
+    """A span of layers run `passes` times over ONE set of leaves: pass t
+    starts from what pass t - 1 gave, `norm` (a layer, an
+    `RMSNormalization` say) is applied after every pass where there is
+    one, and the output is the states of all passes, `[passes, ...]` (the
+    plain span's output, with no leading axis, where `passes` is 1). The
+    members' leaves lie once in this layer's tree under their names
+    (`block0_<leaf>`, ..., `norm_<leaf>`), so the optimizer holds one
+    state a leaf, a saved model one copy, and a leaf's gradient is the sum
+    over the passes by differentiation.
+
+    The passes are one traced body under `lax.scan`, whatever their
+    number. Members keep their shape and nothing from pass to pass: a
+    layer with state (batch statistics, an expert layer's counters and
+    `aux_loss`) is refused by name. Under `gradient_checkpointing` the
+    unit is one member's ONE application (`run`), and
+    `MultiLayerNetwork._forward` does not wrap the whole layer again.
+    There is no decode carry yet."""
+
+    CONSUMES = "any"
+
+    layers: Tuple[Any, ...] = ()
+    passes: int = 1
+    norm: Optional[Any] = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "layers", tuple(self.layers))
+
+    def _members(self):
+        """Every member in the order a pass runs them, the norm last."""
+        return self.layers + (() if self.norm is None else (self.norm,))
+
+    def with_defaults(self, **defaults):
+        named = lambda l, name: dataclasses.replace(
+            l.with_defaults(**defaults), name=l.name or name)
+        return dataclasses.replace(
+            super().with_defaults(**defaults),
+            layers=tuple(named(l, f"block{i}")
+                         for i, l in enumerate(self.layers)),
+            norm=None if self.norm is None else named(self.norm, "norm"))
+
+    def infer_n_in(self, input_type: InputType):
+        members = []
+        for l in self._members():
+            l = l.infer_n_in(input_type)
+            if l.output_type(input_type) != input_type:
+                raise ValueError(
+                    f"{self.name}: {l.name} ({type(l).__name__}) turns "
+                    f"{input_type} into {l.output_type(input_type)}; a "
+                    f"looped span keeps its shape")
+            members.append(l)
+        n = len(self.layers)
+        return dataclasses.replace(
+            self, layers=tuple(members[:n]),
+            norm=members[n] if self.norm is not None else None)
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        if self.passes < 1 or not self.layers:
+            raise ValueError(f"{self.name}: {self.passes} passes over "
+                             f"{len(self.layers)} layers")
+        params = {}
+        members = self._members()
+        for l, k in zip(members, jax.random.split(key, len(members))):
+            p, state = l.init_params(k, input_type, dtype)
+            if state:
+                raise ValueError(
+                    f"{self.name}: {l.name} ({type(l).__name__}) keeps "
+                    f"{sorted(state)} from step to step; a looped span's "
+                    f"layers hold nothing across passes")
+            params.update({f"{l.name}_{leaf}": v for leaf, v in p.items()})
+        return params, {}
+
+    @staticmethod
+    def _of(params, member):
+        prefix = member.name + "_"
+        return {k[len(prefix):]: v for k, v in params.items()
+                if k.startswith(prefix)}
+
+    def regularization(self, params):
+        return sum(l.regularization(self._of(params, l))
+                   for l in self._members())
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        raise NotImplementedError(
+            f"LoopedStack {self.name!r} has no decode carry yet: every "
+            f"(pass, layer) pair wants a cache of its own, which "
+            f"`session_carries` and serving's pool do not hold")
+
+    def run(self, params, x, *, train=False, rng=None, mask=None,
+            unit=None):
+        """The states of all passes. `unit(apply, mask)` wraps one
+        member's apply as `models/multilayer._checkpointed` does, a
+        checkpoint of its own round every application; without it the
+        members are applied as they are. Where there is more than one
+        pass the body is traced ONCE: what a member names while it is
+        traced, it names once for all its passes."""
+        def one_pass(h, prng):
+            for i, l in enumerate(self._members()):
+                scope = "pass_norm" if l is self.norm else l.name
+                lrng = None if prng is None else jax.random.fold_in(prng, i)
+                with jax.named_scope(scope):
+                    if unit is None:
+                        h, _ = l.apply(self._of(params, l), h, train=train,
+                                       rng=lrng, mask=mask)
+                    else:
+                        (h, _), _ = unit(l.apply, mask)(
+                            self._of(params, l), h, None, lrng)
+            return h
+
+        if self.passes == 1:
+            return one_pass(x, rng)
+        def body(h, t):
+            h = one_pass(h, None if rng is None
+                         else jax.random.fold_in(rng, t))
+            return h, h
+
+        return jax.lax.scan(body, x, jnp.arange(self.passes))[1]
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        return self.run(params, x, train=train, rng=rng, mask=mask), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class CenterLossOutputLayer(OutputLayer):
     """Output layer with center loss (Wen et al.). Reference:
     `nn/layers/training/CenterLossOutputLayer.java`: per-class feature centers

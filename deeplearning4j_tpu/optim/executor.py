@@ -129,15 +129,17 @@ def _publish_routing_counters(net) -> None:
     scalars, `moe_tokens_held` of a layer that routes by groups among
     them; a `MultiHeadAttention` with a block selection: the
     `sparse_blocks_*` scalars; a `SelectiveStateSpace`: `ssm_chunk_carry`,
-    a share and so a float) out of the net's layer state into the
-    gauges `<counter>{layer=}`. Called where the epoch has just
-    synchronised with the device; a net without such a layer pays a walk
-    over its state's keys."""
+    a share and so a float; an `ExitGatedOutputLayer`: `exit_entropy`
+    and `exit_mass`, a value a pass and so `exit_mass{layer=, pass=}`)
+    out of the net's layer state into the gauges `<counter>{layer=}`.
+    Called where the epoch has just synchronised with the device; a net
+    without such a layer pays a walk over its state's keys."""
     counters = {}
     for name, st in (getattr(net, "state_tree", None) or {}).items():
         if isinstance(st, dict):
             own = {k: v for k, v in st.items()
-                   if k.startswith(("moe_", "sparse_blocks_", "ssm_"))}
+                   if k.startswith(("moe_", "sparse_blocks_", "ssm_",
+                                    "exit_"))}
             if own:
                 counters[name] = own
     if counters:
@@ -146,7 +148,12 @@ def _publish_routing_counters(net) -> None:
         # graft: allow-sync(the epoch has just synchronised; one read)
         for name, values in jax.device_get(counters).items():
             for key, value in values.items():
-                get_registry().gauge(key, layer=name).set(value.item())
+                if value.ndim == 0:
+                    get_registry().gauge(key, layer=name).set(value.item())
+                    continue
+                for i, one in enumerate(value.tolist(), start=1):
+                    get_registry().gauge(key, layer=name,
+                                         **{"pass": str(i)}).set(one)
 
 
 class TrainingExecutor:

@@ -25,8 +25,8 @@ with _span("import.zoo"):
     )
     from deeplearning4j_tpu.zoo.transformer import (
         HybridLinearSparseTransformer, HybridStateSpaceTransformer,
-        LatentSparseTransformer, SparseSandwichTransformer,
-        TextGenerationTransformer,
+        LatentSparseTransformer, LoopedSandwichTransformer,
+        SparseSandwichTransformer, TextGenerationTransformer,
     )
     from deeplearning4j_tpu.zoo.pretrained import (
         PRETRAINED_CATALOG, PretrainedType, fetch_pretrained, load_pretrained,
@@ -42,4 +42,5 @@ __all__ = [
     "InceptionResNetV1", "FaceNetNN4Small2", "TextGenerationTransformer",
     "SparseSandwichTransformer", "HybridLinearSparseTransformer",
     "LatentSparseTransformer", "HybridStateSpaceTransformer",
+    "LoopedSandwichTransformer",
 ]

@@ -20,7 +20,10 @@ from deeplearning4j_tpu.nn.layers.attention import (
 )
 from deeplearning4j_tpu.nn.layers.feedforward import EmbeddingSequenceLayer
 from deeplearning4j_tpu.nn.layers.normalization import RMSNormalization
-from deeplearning4j_tpu.nn.layers.recurrent import RnnOutputLayer
+from deeplearning4j_tpu.nn.layers.recurrent import (
+    ExitGatedOutputLayer, RnnOutputLayer,
+)
+from deeplearning4j_tpu.nn.layers.special import LoopedStack
 from deeplearning4j_tpu.optim.updaters import Adam
 from deeplearning4j_tpu.zoo.base import ZooModel, register_zoo
 
@@ -184,6 +187,7 @@ class SparseSandwichTransformer(ZooModel):
                 num_kv_heads=c["num_key_value_heads"],
                 head_dim=c["head_dim"], qk_norm=True, output_gate=True,
                 causal=True, rope=sliding,
+                rope_base=float(c.get("rope_theta", 10000)),
                 window=c["sliding_window"] if sliding else None,
                 max_cache=t, eps=c["rms_norm_eps"],
                 ffn_width=c["intermediate_size"],
@@ -194,8 +198,6 @@ class SparseSandwichTransformer(ZooModel):
                 n_shared=c["num_shared_experts"],
                 score=c.get("score_func", "sigmoid"), selection_bias=True,
                 route_norm=c["route_norm"], route_scale=c["route_scale"]))
-        if c.get("rope_theta", 10000) != 10000:
-            raise ValueError("rope_theta other than 10000 is not wired")
         builder = (NeuralNetConfiguration.builder()
                    .seed(self.seed)
                    .updater(self.kw.get("updater", Adam(3e-4)))
@@ -212,6 +214,81 @@ class SparseSandwichTransformer(ZooModel):
             RMSNormalization(eps=c["rms_norm_eps"]),
             RnnOutputLayer(n_out=self.num_classes, has_bias=False,
                            activation="softmax", loss="sparse_mcxent"))
+            .set_input_type(InputType.recurrent(1, t))
+            .build())
+
+
+@register_zoo
+class LoopedSandwichTransformer(ZooModel):
+    """A looped causal language model of the `ouro` family (ByteDance
+    Ouro), built from the keys its published `config.json` has:
+    `num_hidden_layers` sandwich-norm blocks (`SandwichTransformerBlock`:
+    `num_attention_heads` heads of `head_dim` with `num_key_value_heads`,
+    rotary positions at `rope_theta`, a SwiGLU of `intermediate_size`, RMS
+    norms at `rms_norm_eps`, no bias) whose whole stack runs
+    `total_ut_steps` times over the same weights (`LoopedStack`), the last
+    norm after every pass and its output the next pass's input; a head of
+    its own scores every pass and a learned gate weighs them
+    (`ExitGatedOutputLayer`, `exit_entropy_beta` 0.1 where the config
+    gives none). Inference runs all the passes and reads the last.
+
+    `layer_types` other than "full_attention", a tied head, and an
+    activation other than silu are errors. Token ids come as
+    `[batch, time]` integers, labels as integers (`sparse_mcxent`)."""
+
+    input_shape = (8192,)
+
+    def __init__(self, config: dict, *, timesteps: int = None,
+                 dtype: str = "float32", gradient_checkpointing=False, **kw):
+        super().__init__(num_classes=config["vocab_size"],
+                         input_shape=(timesteps or self.input_shape[0],),
+                         **kw)
+        kinds = list(config.get("layer_types")
+                     or ["full_attention"] * config["num_hidden_layers"])
+        if len(kinds) != config["num_hidden_layers"]:
+            raise ValueError(
+                f"layer_types has {len(kinds)} entries for "
+                f"{config['num_hidden_layers']} layers")
+        unknown = set(kinds) - {"full_attention"}
+        if unknown:
+            raise ValueError(f"layer_types {sorted(unknown)} not known: a "
+                             f"looped stack here is full attention")
+        if config.get("tie_word_embeddings", False):
+            raise ValueError("a tied head is not wired for a looped model")
+        if config.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"hidden_act {config['hidden_act']!r}: the "
+                             f"block's feed-forward is a SwiGLU")
+        self.config = dict(config)
+        self.dtype = dtype
+        self.gradient_checkpointing = gradient_checkpointing
+
+    def conf(self):
+        c, t = self.config, self.input_shape[0]
+        d, eps = c["hidden_size"], c["rms_norm_eps"]
+        block = SandwichTransformerBlock(
+            num_heads=c["num_attention_heads"],
+            num_kv_heads=c["num_key_value_heads"],
+            head_dim=c.get("head_dim") or d // c["num_attention_heads"],
+            causal=True, rope=True, rope_base=float(c["rope_theta"]),
+            max_cache=t, eps=eps, ffn_width=c["intermediate_size"])
+        builder = (NeuralNetConfiguration.builder()
+                   .seed(self.seed)
+                   .updater(self.kw.get("updater", Adam(3e-4)))
+                   .activation("identity")
+                   .weight_init("xavier")
+                   .dtype(self.dtype))
+        if self.gradient_checkpointing:
+            builder = builder.gradient_checkpointing()
+        passes = c["total_ut_steps"]
+        return (builder.list(
+            EmbeddingSequenceLayer(n_in=self.num_classes, n_out=d,
+                                   activation="identity"),
+            LoopedStack(layers=(block,) * c["num_hidden_layers"],
+                        passes=passes, norm=RMSNormalization(eps=eps)),
+            ExitGatedOutputLayer(n_out=self.num_classes, passes=passes,
+                                 beta=c.get("exit_entropy_beta", 0.1),
+                                 activation="softmax",
+                                 loss="sparse_mcxent"))
             .set_input_type(InputType.recurrent(1, t))
             .build())
 

@@ -752,6 +752,39 @@ def test_granite_step_fits_the_chip_with_one_tied_embedding(chip):
     assert not square, sorted(set(square))[:5]
 
 
+# --- the benchmark's `ouro_2_6b` step at the cell's own size (one sequence
+# of 8,192 ids, bf16, 8 blocks run 4 times over shared leaves, each block
+# application a checkpoint of its own, four exits of a 49,152-row head),
+# built by the benchmark's own model file. The passes are ONE traced body:
+# the step holds each block's flash kernels once, 8 call sites a kernel
+# for 32 applications, and the kernels' forward is not run a second time
+# in the backward. Its memory figures are the ones the configuration's
+# file states.
+def test_ouro_step_holds_each_block_once_for_its_four_passes(chip):
+    _, compiled, cfg = _cell_step(chip, "ouro_2_6b")
+    for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
+        assert _kernel_calls(compiled, "flash_attention" + kernel) == 8
+    memory = compiled.memory_analysis()
+    # parameters, moments and layer state are donated: all but the batch
+    assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
+            < 2 ** 20)
+    # bf16 parameters and both moments of 612,438,017: one copy a leaf
+    assert memory.argument_size_in_bytes == pytest.approx(
+        6 * 612_438_017, rel=1e-3)
+    stated = cfg["compiled_for_v5e"]
+    assert memory.argument_size_in_bytes / 2 ** 30 == pytest.approx(
+        stated["argument_gib"], abs=2e-3)
+    assert memory.temp_size_in_bytes / 2 ** 30 == pytest.approx(
+        stated["temporary_gib"], abs=0.05)
+    t = cfg["input_shape"][0]
+    text = compiled.as_text()
+    square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", text)
+    assert not square, sorted(set(square))[:5]
+    # no exit's logits are float32 for more than one exit at a time: none
+    # carries the passes' axis
+    assert not re.findall(rf"\[4,(?:1,)?{t},{cfg['vocab_size']}\]", text)
+
+
 def test_selective_scan_makes_c_b_t_once_a_chunk_not_once_a_head(chip):
     """The forward of `ops/selective_scan.py` at the cell's widths (32
     heads of 64, a state of 128, chunks of 256; four chunks here): of the
