@@ -9,9 +9,9 @@ dtype, sums in float32. The rows past `sum(group_sizes)` are in no group
 and what stands in them afterwards is undefined, as it is after
 `ragged_dot` on the chip.
 
-An expert layer whose rows come in one tier sizes it for the worst
-routing (`parallel/moe._row_tiers`) and a step fills a seventh to a third
-of it, so here the GRID follows the groups: a
+An expert layer on one TPU device has one tier of rows, sized for the
+worst routing (`parallel/moe.held_experts`), and a step fills a
+two-hundredth to a third of it, so here the GRID follows the groups: a
 schedule made from `group_sizes` on the device and handed to the kernels
 by scalar prefetch lists the (row tile, group) visits, a tile that
 straddles two groups once for each with the other's rows masked on the

@@ -6,7 +6,7 @@
 for the rows `r < live` and the places `back[t, j] < live` alone: what an
 expert layer's dispatch and combine are (`parallel/moe.held_experts`), each
 the other's transpose, over a tier of `C` rows sized for the worst routing
-of which a step fills a seventh to a third. XLA's gather walks the tier;
+of which a step fills a two-hundredth to a third. XLA's gather walks the tier;
 here a row is ONE DMA, issued only where the row is live, several in
 flight, and a grid whose bound the device computes from `live` stops at
 the last tile that holds one.

@@ -23,11 +23,11 @@ axis and its all_to_all are its reason to stay. `ExpertFeedForward` is
 one device's share of a large sparse model's expert layer: a router over
 all `n_experts`, of which this device holds `held`, the (token, expert)
 pairs sorted by expert, the rows of the experts held gathered, grouped
-matrix products over them (`jax.lax.ragged_dot`; on the TPU, where the
-rows come in one tier, `ops/grouped_matmul.grouped_dot`, which passes by
-the tiles that hold no pair), and the results gathered back by token (on
-the TPU, in such a layer, both gathers by `ops/row_gather`'s kernels,
-which move only the rows that hold a pair).
+matrix products over them (`jax.lax.ragged_dot`; on one TPU device
+`ops/grouped_matmul.grouped_dot`, which passes by the tiles that hold no
+pair), and the results gathered back by token (on one TPU device both
+gathers by `ops/row_gather`'s kernels, which move only the rows that
+hold a pair).
 No pair is dropped at any imbalance and no [N, E, C] tensor exists; what
 the absent experts would add is left out.
 """
@@ -410,7 +410,8 @@ def _swiglu(x, w1, w3, w2, dot):
 
 def _row_tiers(rows: int, share: float,
                most: Optional[int] = None) -> Tuple[int, ...]:
-    """The row counts the routed products are compiled for: four times
+    """The row counts the routed products are compiled for where XLA's
+    `ragged_dot` and gathers run (the CPU, any mesh context): four times
     what uniform routing sends to the experts held (`share` of all `rows`
     pairs), twice and four times that, and `most`, all that can fall here
     (a token's pairs lie on distinct experts; `rows` where not given). A
@@ -425,15 +426,17 @@ def _row_tiers(rows: int, share: float,
     of 72 experts at ten a token crossed in the eighteenth step, one to
     three layers of ten apart, and a window's rate read 0.73% apart where
     the other cells read 0.03 (chip runs, PR 42). There the one tier is
-    `most`: what passes over the tier's rows (the sort, and the SwiGLU's
-    elementwise work between the products) costs the same every step, and
-    on the TPU the grouped products and the gathers round them, for which
-    a row past the pairs held is no row, follow the pairs with no
-    threshold to cross (`held_experts`; the products since PR 43, six
-    seeds' rates 0.04% apart by their quartiles where the two tiers' lay
-    0.73; the gathers since PR 44: chip runs). A layer WITH a ladder
-    counts those rows to the last expert held, so each of its tiers costs
-    the same whatever fell into it."""
+    `most`. A layer with a ladder counts the rows past the pairs held to
+    the last expert held, so each of its tiers costs the same whatever
+    fell into it. Where the kernels run (`_kernel_runs`) every layer has
+    the LAST of these alone, `most`: a row past the pairs held is no row
+    to the grouped products and the gathers round them, which follow the
+    pairs with no threshold to cross, and what passes over the tier's
+    rows (the sort, and the SwiGLU's elementwise work between the
+    products) costs the same every step (`held_experts`; the products
+    since PR 43, six seeds' rates 0.04% apart by their quartiles where
+    the two tiers' lay 0.73; the gathers since PR 44; the layers that
+    have a ladder elsewhere since PR 47: chip runs)."""
     most = min(rows, -(-int(rows if most is None else most) // 128) * 128)
     up = lambda n: min(most, -(-int(n) // 128) * 128)
     first = up(4 * share * rows)
@@ -462,31 +465,31 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
         sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
                         dtype=jnp.int32)
         n_held = jnp.sum(sizes)
+    # On the TPU (on one device: under a mesh the operands may be sharded)
+    # every layer has ONE tier, all that can fall here: its grouped
+    # products are `ops/grouped_matmul`'s kernel, whose grid visits only
+    # the row tiles that hold a row of a group, and the rows past the
+    # pairs held are left in NO group, so the kernel passes them by; and
+    # its two gathers are `ops/row_gather`'s kernels, which move the rows
+    # of the pairs held and no other, a row a DMA: the layer's time but
+    # for the sort and the elementwise work between the products follows
+    # the pairs, continuously, with no tier to cross. On any other backend
+    # and under a mesh a layer has `_row_tiers`' ladder, counts the rows
+    # past the pairs held to the last expert held, gathers with XLA's
+    # gather over the tier and runs XLA's `ragged_dot`: a tier then costs
+    # the same whatever fell into it, its padding is bounded by the ladder
+    # (four times the uniform share), and a step's time is not a matter
+    # of the seed. (The kernel in each of a ladder's tiers was faster on
+    # the TPU too, but four times the call sites to trace, lower and
+    # compile, and set-up grew by a fifth: chip runs, PR 43.)
+    kernel = _kernel_runs()
     tiers = _row_tiers(rows, count / n_experts, n * min(k, count))
-    # A layer with ONE tier has nothing between its pairs and all that can
-    # fall here. On the TPU (on one device: under a mesh the operands may
-    # be sharded) its grouped products are `ops/grouped_matmul`'s kernel,
-    # whose grid visits only the row tiles that hold a row of a group, and
-    # the rows past the pairs held are left in NO group, so the kernel
-    # passes them by; and its two gathers are `ops/row_gather`'s kernels,
-    # which move the rows of the pairs held and no other, a row a DMA: the
-    # layer's time but for the sort and the narrow elementwise work follows
-    # the pairs, continuously, with no tier to cross. A layer with a ladder,
-    # and every layer on any other backend, counts those rows to the last
-    # expert held, gathers with XLA's gather over the tier and runs
-    # XLA's `ragged_dot`: a tier then costs the same whatever fell into
-    # it, its padding is bounded by the ladder (four times the uniform
-    # share), and a step's time is not a matter of the seed. (The kernel
-    # is the faster there too, 1.5 to 2.4 times at the laddered cells'
-    # shapes with every row in a group, but a ladder's four tiers a layer
-    # are four times the call sites to trace, lower and compile, and the
-    # cells' set-up grew by a fifth: chip runs, PR 43.)
+    if kernel:
+        tiers = tiers[-1:]
     from deeplearning4j_tpu.ops.grouped_matmul import (
         grouped_dot, rows_visited, schedule, tile_rows,
     )
     from deeplearning4j_tpu.ops.row_gather import sum_rows, take_rows
-
-    kernel = len(tiers) == 1 and _kernel_runs()
 
     def tier(c):
         # under `jax.checkpoint`: what a tier keeps for its backward pass

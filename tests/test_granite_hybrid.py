@@ -349,6 +349,25 @@ def test_a_router_collapsed_onto_the_experts_held_drops_nothing():
     assert int(counters["moe_pairs_held"]) == 128 * 9
 
 
+def _output_and_gradients(layer, p, x, ct):
+    """(y, the gradients of sum(y * ct) by `p` and `x`, counters)."""
+    def loss(p, x):
+        y, counters = layer.apply(p, x)
+        return jnp.sum(y * ct), (y, counters)
+    grads, (y, counters) = jax.grad(loss, (0, 1), has_aux=True)(p, x)
+    return y, grads, counters
+
+
+def _pairs_of_the_experts_held(layer, p, x):
+    """How many of the tokens' pairs fall on each expert held."""
+    experts, _ = moe.route(x.reshape(-1, x.shape[-1]), p["router"], None,
+                           k=layer.k, score="softmax", route_norm=True,
+                           route_scale=1.0)
+    first, count = layer._held
+    return np.bincount(np.asarray(experts).ravel(),
+                       minlength=layer.n_experts)[first:first + count]
+
+
 def _one_rung_case(boost=0.0):
     """Nine of 72 experts held, ten a token: one tier of 1,024 x 9 rows,
     of which uniform routing fills a seventh; with `boost` the router has
@@ -362,18 +381,8 @@ def _one_rung_case(boost=0.0):
     if boost:       # the rows are positive, so the boost is every token's
         p = {**p, "router": p["router"].at[:, :9].add(boost)}
         x = jnp.abs(x)
-
-    def run():
-        def loss(p, x):
-            y, counters = layer.apply(p, x)
-            return jnp.sum(y * ct), (y, counters)
-        grads, (y, counters) = jax.grad(loss, (0, 1), has_aux=True)(p, x)
-        return y, grads, counters
-
-    experts, _ = moe.route(x.reshape(-1, 32), p["router"], None, k=10,
-                           score="softmax", route_norm=True, route_scale=1.0)
-    sizes = np.bincount(np.asarray(experts).ravel(), minlength=72)[:9]
-    return run, sizes
+    return (functools.partial(_output_and_gradients, layer, p, x, ct),
+            _pairs_of_the_experts_held(layer, p, x))
 
 
 @jax.custom_vjp
@@ -385,6 +394,54 @@ def _poison(v, live):
 
 _poison.defvjp(lambda v, live: (_poison(v, live), live),
                lambda live, g: (_poison(g, live), None))
+
+
+def _kernels_in_interpret_mode(monkeypatch, product="kernel"):
+    """An expert layer traced after this takes the path of the TPU on one
+    device, its kernels in interpret mode (with `product` "ragged_dot",
+    XLA's product between `ops/row_gather`'s kernels), and a call of XLA's
+    own gathers fails. With the kernel's products every row past the
+    pairs held holds NaN after every gather and product, forward and
+    backward. Returns the list the products' schedules are put on as they
+    are traced, each beside its left operand's rows."""
+    gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
+    rg = importlib.import_module("deeplearning4j_tpu.ops.row_gather")
+    seen = []
+    dead = _poison if product == "kernel" else (lambda v, live: v)
+    products = {
+        "ragged_dot": lambda a, w, plan: jax.lax.ragged_dot(
+            a, w, group_sizes=plan.end - plan.start),
+        "kernel": functools.partial(gm.grouped_dot, interpret=True)}
+
+    def grouped(a, w, plan):
+        seen.append((a.shape[0], plan))
+        return dead(products[product](a, w, plan), plan.end[-1])
+
+    take_rows, sum_rows = rg.take_rows, rg.sum_rows
+    monkeypatch.setattr(moe, "_kernel_runs", lambda: True)
+    monkeypatch.setattr(gm, "grouped_dot", grouped)
+    monkeypatch.setattr(
+        rg, "take_rows", lambda v, index, back, live, copies: tuple(
+            dead(t, live)
+            for t in take_rows(v, index, back, live, copies, True)))
+    monkeypatch.setattr(
+        rg, "sum_rows", lambda v, weight, index, back, live: sum_rows(
+            dead(v, live), weight, index, back, live, True))
+    monkeypatch.setattr(moe, "_take_rows", lambda *a: 1 / 0)
+    return seen
+
+
+def _rows_of_the_kernels(sizes, tier):
+    """(visited, gathered): the rows the grouped products multiply, the
+    visits of their schedule times a tile's rows, and the rows the gather
+    moves, the tiles that hold a pair times a tile's rows, of experts
+    holding `sizes` pairs in a tier of `tier` rows."""
+    gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
+    rows = gm.tile_rows(tier)
+    end = np.cumsum(sizes)
+    visits = sum((e - 1) // rows - (e - n) // rows + 1
+                 for n, e in zip(sizes, end) if n)
+    return visits * rows, -(-sizes.sum() // rows) * rows
 
 
 @pytest.mark.parametrize("router", ["uniform", "collapsed"])
@@ -402,8 +459,6 @@ def test_rows_in_no_group_change_nothing_in_a_layer_of_one_tier(
     NaN after every gather and product, forward and backward: nothing
     reads one. And the counters read the tier on the path that walks every
     row, the tiles that hold a pair through the kernels."""
-    gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
-    rg = importlib.import_module("deeplearning4j_tpu.ops.row_gather")
     run, sizes = _one_rung_case(30.0 if router == "collapsed" else 0.0)
     want, want_grads, counters = run()
     tier, held = 1024 * 9, sizes.sum()
@@ -412,28 +467,7 @@ def test_rows_in_no_group_change_nothing_in_a_layer_of_one_tier(
         assert int(counters[name]) == tier
     assert int(counters["moe_pairs_held"]) == held
 
-    seen = []
-    dead = _poison if product == "kernel" else (lambda v, live: v)
-    products = {
-        "ragged_dot": lambda a, w, plan: jax.lax.ragged_dot(
-            a, w, group_sizes=plan.end - plan.start),
-        "kernel": functools.partial(gm.grouped_dot, interpret=True)}
-
-    def grouped(a, w, plan):
-        seen.append(plan)
-        return dead(products[product](a, w, plan), plan.end[-1])
-
-    take_rows, sum_rows = rg.take_rows, rg.sum_rows
-    monkeypatch.setattr(moe, "_kernel_runs", lambda: True)
-    monkeypatch.setattr(gm, "grouped_dot", grouped)
-    monkeypatch.setattr(
-        rg, "take_rows", lambda v, index, back, live, copies: tuple(
-            dead(t, live)
-            for t in take_rows(v, index, back, live, copies, True)))
-    monkeypatch.setattr(
-        rg, "sum_rows", lambda v, weight, index, back, live: sum_rows(
-            dead(v, live), weight, index, back, live, True))
-    monkeypatch.setattr(moe, "_take_rows", lambda *a: 1 / 0)
+    seen = _kernels_in_interpret_mode(monkeypatch, product)
     got, got_grads, counters = run()
     assert len(seen) == 3           # a tier is traced once
     _close(got, want, 1e-5)
@@ -441,28 +475,31 @@ def test_rows_in_no_group_change_nothing_in_a_layer_of_one_tier(
                     jax.tree_util.tree_leaves(want_grads)):
         assert np.isfinite(np.asarray(a)).all()
         _close(a, b, 1e-5)
-    rows = gm.tile_rows(tier)
-    end = np.cumsum(sizes)
-    visits = sum((e - 1) // rows - (e - n) // rows + 1
-                 for n, e in zip(sizes, end) if n)
+    visited, gathered = _rows_of_the_kernels(sizes, tier)
     assert int(counters["moe_rows_tier"]) == tier
-    assert int(counters["moe_rows_visited"]) == visits * rows <= tier
-    assert int(counters["moe_rows_gathered"]) == -(-held // rows) * rows
+    assert int(counters["moe_rows_visited"]) == visited <= tier
+    assert int(counters["moe_rows_gathered"]) == gathered
     assert int(counters["moe_pairs_dropped"]) == 0
 
 
-def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
-    """Two of 64 experts at two a token have four tiers: where the kernel
-    could run, such a layer still gives `ragged_dot` every row of the tier
-    that runs, the rows past the pairs held counted to the last expert,
-    so a tier costs the same whatever fell into it, XLA's gathers move its
-    rows, and the counters read the tier."""
-    gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
-    layer = ExpertFeedForward(n_in=32, width=16, n_experts=64, held=(0, 2),
-                              k=2, score="softmax", route_norm=True,
-                              weight_init="xavier")
+def _laddered_layer(n_experts, count, k):
+    layer = ExpertFeedForward(n_in=32, width=16, n_experts=n_experts,
+                              held=(0, count), k=k, score="softmax",
+                              route_norm=True, weight_init="xavier")
     p, _ = layer.init_params(jax.random.PRNGKey(4),
                              InputType.recurrent(32, 512))
+    return layer, p
+
+
+def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
+    """Two of 64 experts at two a token have four tiers where the kernels
+    do not run (the CPU, any mesh context): such a layer gives
+    `ragged_dot` every row of the tier that runs, the rows past the pairs
+    held counted to the last expert, so a tier costs the same whatever
+    fell into it, XLA's gathers move its rows, and the counters read the
+    tier."""
+    gm = importlib.import_module("deeplearning4j_tpu.ops.grouped_matmul")
+    layer, p = _laddered_layer(64, 2, 2)
     x, = _normal(15, (1, 512, 32))
     assert len(moe._row_tiers(1024, 2 / 64, 1024)) == 4
     want, _ = layer.apply(p, x)
@@ -474,7 +511,7 @@ def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
         return ragged_dot(a, w, group_sizes=group_sizes)
 
     rg = importlib.import_module("deeplearning4j_tpu.ops.row_gather")
-    monkeypatch.setattr(moe, "_kernel_runs", lambda: True)
+    assert not moe._kernel_runs()
     monkeypatch.setattr(gm, "grouped_dot", lambda *a, **kw: 1 / 0)
     monkeypatch.setattr(rg, "take_rows", lambda *a, **kw: 1 / 0)
     monkeypatch.setattr(rg, "sum_rows", lambda *a, **kw: 1 / 0)
@@ -487,6 +524,43 @@ def test_a_layer_with_a_ladder_counts_every_row_to_a_group(monkeypatch):
     assert int(counters["moe_rows_visited"]) \
         == int(counters["moe_rows_gathered"]) \
         == int(counters["moe_rows_tier"]) == 128
+
+
+@pytest.mark.parametrize("n_experts,count,k", [(64, 2, 2), (160, 8, 6)])
+def test_where_the_kernels_run_a_laddered_layer_has_one_tier(
+        n_experts, count, k, monkeypatch):
+    """The same layer where the kernels run (in interpret mode here), and
+    eight of 160 experts at six a token: ONE tier of all that can fall on
+    the experts held and no switch, the rows past the pairs held in no
+    group and moved by no gather (they hold NaN after every gather and
+    product, forward and backward), output and every gradient what the
+    ladder's tier gives, the counters the tier's rows and the tiles that
+    hold a pair, nothing dropped."""
+    layer, p = _laddered_layer(n_experts, count, k)
+    x, ct = _normal(15, (1, 512, 32), (1, 512, 32))
+    most = 512 * k
+    tiers = moe._row_tiers(most, count / n_experts, most)
+    assert len(tiers) == 4 and tiers[-1] == most
+    run = functools.partial(_output_and_gradients, layer, p, x, ct)
+    want, want_grads, counters = run()
+    assert int(counters["moe_rows_tier"]) < most
+    seen = _kernels_in_interpret_mode(monkeypatch)
+    monkeypatch.setattr(jax.lax, "switch", lambda *a, **kw: 1 / 0)
+    monkeypatch.setattr(jax.lax, "ragged_dot", lambda *a, **kw: 1 / 0)
+    got, got_grads, counters = run()
+    assert [rows for rows, _ in seen] == [most] * 3    # traced once
+    _close(got, want, 1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        assert np.isfinite(np.asarray(a)).all()
+        _close(a, b, 1e-5)
+    sizes = _pairs_of_the_experts_held(layer, p, x)
+    visited, gathered = _rows_of_the_kernels(sizes, most)
+    assert int(counters["moe_pairs_held"]) == sizes.sum()
+    assert int(counters["moe_rows_tier"]) == most
+    assert int(counters["moe_rows_visited"]) == visited < most
+    assert int(counters["moe_rows_gathered"]) == gathered < most
+    assert int(counters["moe_pairs_dropped"]) == 0
 
 
 # ---------------------------------------------------------- the tied head

@@ -193,14 +193,26 @@ def _row_gathers(n, k, c, d, dt=BF16):
 
 
 CASES = {
-    # `granite_4_0_h_small`'s dispatch and combine over its one tier
+    # the dispatch and combine over the one tier of `granite_4_0_h_small`,
+    # `trinity_large` and `deepseek_v2`
     "row_gathers_train_8192_10_73728_4096": lambda: _row_gathers(
         8192, 10, 73728, 4096),
+    "row_gathers_train_8192_4_32768_3072": lambda: _row_gathers(
+        8192, 4, 32768, 3072),
+    "row_gathers_train_8192_6_49152_5120": lambda: _row_gathers(
+        8192, 6, 49152, 5120),
     "row_gathers_train_f32": lambda: _row_gathers(1024, 4, 2048, 512, F32),
     # `granite_4_0_h_small`'s one tier, both of an expert's widths;
-    # `trinity_large`'s and `deepseek_v2`'s first tiers
+    # `trinity_large`'s and `deepseek_v2`'s, and a tier of an eighth and a
+    # fifth of their rows
     "grouped_dot_train_73728_4096_768": lambda: _grouped(73728, 4096, 768, 9),
     "grouped_dot_train_73728_768_4096": lambda: _grouped(73728, 768, 4096, 9),
+    "grouped_dot_train_32768_3072_3072": lambda: _grouped(32768, 3072, 3072,
+                                                          8),
+    "grouped_dot_train_49152_5120_1536": lambda: _grouped(49152, 5120, 1536,
+                                                          8),
+    "grouped_dot_train_49152_1536_5120": lambda: _grouped(49152, 1536, 5120,
+                                                          8),
     "grouped_dot_train_4096_3072_3072": lambda: _grouped(4096, 3072, 3072, 8),
     "grouped_dot_train_9856_5120_1536": lambda: _grouped(9856, 5120, 1536, 8),
     "grouped_dot_train_9856_1536_5120": lambda: _grouped(9856, 1536, 5120, 8),
@@ -634,30 +646,88 @@ def _grouped_products(compiled):
                             "grouped_dot_drhs")})
 
 
+def _row_kernels(compiled):
+    return {kernel: _kernel_calls(compiled, kernel)
+            for kernel in ("take_rows", "sum_rows", "pack_rows")}
+
+
+def _tier_passes(compiled, rows=(73728, 81920), width=4096):
+    """The instructions of a compiled step, kernels apart, that write or
+    read an array of a tier's rows by the model's width (in row form too:
+    a bf16 row is `[width // 256, 128]` words), by name."""
+    text = compiled.as_text()
+    shape = re.compile(r"\[(%s),(%d|%d,128)\]" % (
+        "|".join(map(str, rows)), width, width // 256))
+    made = dict(re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) [\w\-]+\(", text,
+        re.M))
+    found = []
+    for name, out, op, rest in re.findall(
+            r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$",
+            text, re.M):
+        if op in ("custom-call", "parameter", "get-tuple-element", "tuple",
+                  "bitcast"):
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split(", metadata=")[0]
+                              .split(", calls=")[0])
+        if shape.search(out) or any(shape.search(made.get(o, ""))
+                                    for o in operands):
+            found.append(f"{op} {name}")
+    return found
+
+
+def _expert_layers_run_the_kernels(compiled, layers):
+    """The `layers` expert layers of a compiled step have one tier each:
+    twelve grouped products a layer (three forward, three in the tier's
+    own checkpoint, six backward), all `ops/grouped_matmul`'s kernels and
+    no `ragged-dot`; three `take_rows` a layer (forward, the tier's own
+    recomputation, and `sum_rows`' transpose), two `sum_rows` (forward
+    and `take_rows`' transpose; the recomputed one is dead and dropped)
+    and a packing of each one's source; no switch over tiers."""
+    ragged, kernels = _grouped_products(compiled)
+    assert ragged == 0
+    assert kernels == {"grouped_dot": 6 * layers,
+                       "grouped_dot_dlhs": 3 * layers,
+                       "grouped_dot_drhs": 3 * layers}
+    assert _row_kernels(compiled) == {"take_rows": 3 * layers,
+                                      "sum_rows": 2 * layers,
+                                      "pack_rows": 5 * layers}
+    assert " conditional(" not in compiled.as_text()
+
+
 # --- the benchmark's `trinity_large` step at the cell's own size, built
-# by the benchmark's own model file: its four expert layers have ladders,
-# so their grouped products stay `ragged_dot`'s (twelve a tier: three
-# forward, three in the tier's own checkpoint, six backward) and none is
-# `ops/grouped_matmul`'s.
-def test_trinity_large_step_keeps_ragged_dot_for_its_ladders(chip):
+# by the benchmark's own model file: its four expert layers, which have a
+# ladder of four tiers where XLA's `ragged_dot` runs, have ONE tier of all
+# 32,768 pairs that can fall on the experts held, and what
+# `test_granite_step_fits_the_chip_with_one_tied_embedding` says of an
+# expert layer holds: the kernels' counts, no `ragged-dot`, no switch. An
+# expert's width is the model's here (3,072), so the SwiGLU's elementwise
+# work between the products (three fusions a layer: forward, recomputed,
+# backward) has the shape of the tier's rows, and nothing else of XLA's
+# reads or writes them, in row form either.
+ELEMENTWISE = {"fusion", "convert", "broadcast", "negate", "exponential",
+               "add", "subtract", "multiply", "divide"}
+
+
+def test_trinity_large_step_runs_the_kernels_over_one_tier(chip):
     _, compiled, _ = _cell_step(chip, "trinity_large")
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            < 15.75 * 2 ** 30)
-    ragged, kernels = _grouped_products(compiled)
-    # four expert layers, each a switch over four tiers of which a step
-    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own,
-    # between XLA's own gathers
-    assert ragged == 192 and not any(kernels.values())
-    assert not any(_row_kernels(compiled).values())
+            < 13 * 2 ** 30)
+    _expert_layers_run_the_kernels(compiled, 4)
+    passes = _tier_passes(compiled, rows=(32768,), width=3072)
+    assert {p.split()[0] for p in passes} <= ELEMENTWISE, passes
+    assert sum(p.startswith("fusion ") for p in passes) == 3 * 4
 
 
 # --- the benchmark's `deepseek_v2` step at the cell's own size (one
 # sequence of 8,192 ids, bf16, every layer checkpointed, 32 of 128 heads
 # and 8 of 160 experts held), built by the benchmark's own model file: it
 # fits the chip with room, each latent-attention kernel is in it once a
-# layer (the forward's output and log-sum-exp are kept, not remade), and
-# nothing [heads, T, T] exists.
+# layer (the forward's output and log-sum-exp are kept, not remade),
+# nothing [heads, T, T] exists, and its four expert layers run the kernels
+# over ONE tier of 49,152 rows, of which no op of XLA's reads or writes a
+# row of 5,120.
 def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
     _, compiled, cfg = _cell_step(chip, "deepseek_v2")
     memory = compiled.memory_analysis()
@@ -668,12 +738,8 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
     layers, t = cfg["num_hidden_layers"], cfg["input_shape"][0]
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "latent_attention" + kernel) == layers
-    ragged, kernels = _grouped_products(compiled)
-    # four expert layers, each a switch over four tiers of which a step
-    # runs one: twelve products a tier, 48 of the 192 a step, XLA's own,
-    # between XLA's own gathers
-    assert ragged == 192 and not any(kernels.values())
-    assert not any(_row_kernels(compiled).values())
+    _expert_layers_run_the_kernels(compiled, 4)
+    assert not _tier_passes(compiled, rows=(49152,), width=5120)
     square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", compiled.as_text())
     assert not square, sorted(set(square))[:5]
 
@@ -694,44 +760,9 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
 # writes the tier's 73,728 (or all 81,920) rows of 4,096: no gather, no
 # mask, no weighing, no sum of two cotangents. Lowered as a body a shape
 # and not a body a call.
-def _row_kernels(compiled):
-    return {kernel: _kernel_calls(compiled, kernel)
-            for kernel in ("take_rows", "sum_rows", "pack_rows")}
-
-
-def _tier_passes(compiled, rows=(73728, 81920), width=4096):
-    """The instructions of a compiled step, kernels apart, that write or
-    read an array of a tier's rows by the model's width (in row form too),
-    by name."""
-    text = compiled.as_text()
-    shape = re.compile(r"\[(%s),(%d|16,128)\]" % (
-        "|".join(map(str, rows)), width))
-    made = dict(re.findall(
-        r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) [\w\-]+\(", text,
-        re.M))
-    found = []
-    for name, out, op, rest in re.findall(
-            r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$",
-            text, re.M):
-        if op in ("custom-call", "parameter", "get-tuple-element", "tuple",
-                  "bitcast"):
-            continue
-        operands = re.findall(r"%([\w.\-]+)", rest.split(", metadata=")[0]
-                              .split(", calls=")[0])
-        if shape.search(out) or any(shape.search(made.get(o, ""))
-                                    for o in operands):
-            found.append(f"{op} {name}")
-    return found
-
-
 def test_granite_step_fits_the_chip_with_one_tied_embedding(chip):
     lowered, compiled, cfg = _cell_step(chip, "granite_4_0_h_small")
-    ragged, kernels = _grouped_products(compiled)
-    assert ragged == 0
-    assert kernels == {"grouped_dot": 60, "grouped_dot_dlhs": 30,
-                       "grouped_dot_drhs": 30}
-    assert _row_kernels(compiled) == {"take_rows": 30, "sum_rows": 20,
-                                      "pack_rows": 50}
+    _expert_layers_run_the_kernels(compiled, 10)
     assert not _tier_passes(compiled)
     # the grouped products' 3 kernels x 2 shapes and the row gathers' 3
     # (each with its packing), many of them once more where a checkpoint's
