@@ -154,8 +154,11 @@ class DropoutLayer(Layer):
 class EmbeddingLayer(Layer):
     """Index → vector lookup, one index per example. Reference:
     `nn/conf/layers/EmbeddingLayer.java` (+ feedforward/embedding impl).
-    On TPU the lookup is a gather (`jnp.take`), which XLA lowers natively —
-    no one-hot matmul needed."""
+    The lookup is a gather (`jnp.take`), which XLA lowers natively, and
+    its gradient XLA's scatter-add: no cell runs this layer. The sequence
+    layer below shares the forward; its gradient on one TPU device is a
+    one-hot product after all, over the ids sorted by vocabulary tile
+    (`ops/embedding.py`)."""
 
     TAKES_IDS = True
 
@@ -192,7 +195,9 @@ class EmbeddingLayer(Layer):
 @dataclasses.dataclass(frozen=True)
 class EmbeddingSequenceLayer(Layer):
     """[batch, time] indices → [batch, time, n_out] vectors (modern
-    counterpart of reference EmbeddingSequenceLayer)."""
+    counterpart of reference EmbeddingSequenceLayer). The lookup is
+    `ops/embedding.lookup`: `jnp.take` forward, and where the one-device
+    kernels run a grouped product backward in place of XLA's scatter-add."""
 
     CONSUMES = "rnn"   # sequence input — no RnnToFeedForward before it
     TAKES_IDS = True
@@ -210,7 +215,9 @@ class EmbeddingSequenceLayer(Layer):
     def apply(self, params, x, *, state=None, train=False, rng=None, mask=None):
         if x.ndim == 3 and x.shape[-1] == 1:
             x = x[..., 0]  # [B, T, 1] token-id tensors (InputType.recurrent(1))
-        emb = jnp.take(params["W"], x.astype(jnp.int32), axis=0)
+        from deeplearning4j_tpu.ops.embedding import lookup
+
+        emb = lookup(params["W"], x)
         if self.scale is not None:
             emb = emb * jnp.asarray(self.scale, emb.dtype)
         return self._act(emb), state

@@ -33,6 +33,15 @@ accumulator that is written where the schedule leaves the group, and
 walks one step more for each group of no rows, which writes that group's
 zeros. Each is under a `jax.jit` of its own, so a program with a hundred
 calls traces and lowers a body a shape, not a body a call.
+
+A second caller uses `grouped_dot_drhs` on its own: the token embedding's
+gradient (`ops/embedding.py`). There a group is a TILE of the vocabulary's
+rows, the rows are the step's tokens sorted by id, `lhs` is the one-hot of
+an id's place inside its tile and `d_out` the lookup's cotangent in
+sorted order, so `d_rhs[g]` is that tile of the table's gradient: fifty
+to two hundred groups of some 40 to 230 rows each over 32 or 64
+row tiles, most visits a tile that straddles groups, where an expert layer
+has eight or nine groups of hundreds to thousands.
 """
 
 from __future__ import annotations
@@ -61,7 +70,11 @@ def tile_rows(m: int) -> int:
     lie within 4% of each other wherever a group has a thousand rows or
     more, 256 first or within 2% of it; where it has a few hundred (4,096
     rows in 8 groups) 512 is a fifth slower, most of its visits being
-    tiles that straddle two groups (chip runs, PR 43)."""
+    tiles that straddle two groups (chip runs, PR 43). 256 holds for the
+    embedding's gradient too (49 to 192 groups over 32 or 64 row tiles,
+    `ops/embedding.py`): the whole gradient read 0.57 to 2.63 ms with it,
+    0.03 to 0.09 more than with 128 and 0.09 to 0.30 less than with 512
+    (chip runs, PR 48), so the one rule stays."""
     return min(256, -(-m // 16) * 16)
 
 
@@ -285,9 +298,12 @@ def _drhs_blocks(rows: int, k: int, n: int, size: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _drhs(lhs, dout, plan: Schedule, *, interpret: bool):
+def grouped_dot_drhs(lhs, dout, plan: Schedule, *, interpret: bool = False):
     """`lhs` [M, K] and `dout` [M, N] -> [G, K, N]: each group's
-    `lhs^T @ dout` over its own rows, zeros for a group of none."""
+    `lhs^T @ dout` over its own rows, summed in float32 and rounded once,
+    zeros for a group of none. `grouped_dot`'s gradient by `rhs`, and on
+    its own the sum of `dout`'s rows by whatever `lhs` marks
+    (`ops/embedding.py`: a one-hot)."""
     (m, k), rows, groups = lhs.shape, plan.rows, plan.start.shape[0]
     n = dout.shape[1]
     kc, nc, need = _drhs_blocks(rows, k, n, lhs.dtype.itemsize)
@@ -335,7 +351,7 @@ def _fwd(lhs, rhs, group_sizes, rows, interpret):
 def _bwd(rows, interpret, res, dout):
     lhs, rhs, plan = res
     return (_dot(dout, rhs, plan, transpose_rhs=True, interpret=interpret),
-            _drhs(lhs, dout, plan, interpret=interpret), None)
+            grouped_dot_drhs(lhs, dout, plan, interpret=interpret), None)
 
 
 grouped_dot.defvjp(_fwd, _bwd)

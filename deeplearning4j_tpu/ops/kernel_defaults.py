@@ -36,6 +36,10 @@ each is, in order:
      dense, the fused window, speculative decoding and the prefix cache
      are on where the model is capable, KV storage is native unless
      asked, the LSTM core is the fused kernel.
+
+Two more answers at trace time have steps 2 and 3 alone, no force:
+`kernels_run` (may the kernels written for one device's arrays run) and
+`embedding_backward_tile` (the embedding's gradient as a grouped product).
 """
 
 from __future__ import annotations
@@ -70,6 +74,34 @@ def record_dispatch(op: str, impl: str) -> None:
     from deeplearning4j_tpu.observe import get_registry
 
     get_registry().counter("kernel_dispatch_total", op=op, impl=impl).inc()
+
+
+def kernels_run() -> bool:
+    """Whether a layer traced now may run the kernels written for ONE
+    device's arrays (`ops/grouped_matmul`, `ops/row_gather`, and through
+    them an expert layer's tier and the embedding's gradient): on the TPU
+    backend with no mesh context, under which their operands may be
+    sharded. No force: the backend and the mesh are all there is to ask."""
+    import jax
+
+    from deeplearning4j_tpu.parallel.mesh import current_mesh_context
+
+    return (jax.default_backend() == "tpu"
+            and current_mesh_context() is None)
+
+
+def embedding_backward_tile(width: int) -> Optional[int]:
+    """The rows of a vocabulary tile for the embedding's gradient as a
+    grouped product (`ops/embedding.py`), or None where XLA's scatter-add
+    stays: a row that is not whole lanes of 128, which the kernel's blocks
+    are cut in. One size, no rule on the table's height or the tokens: at
+    the five token cells' shapes (12,544 to 49,152 rows of 2,048 to 5,120,
+    8,192 or 16,384 ids) the whole gradient read 0.57 to 2.63 ms with
+    tiles of 256 rows, within 0.08 ms of 128 at every shape, and 512 lost
+    0.02 to 0.27, where the scatter read 1.61 to 23.32: `ouro_2_6b`'s 192
+    tiles and `deepseek_v2`'s 50 want no rule between them (chip runs,
+    PR 48)."""
+    return None if width % 128 else 256
 
 
 def _env(name: str, default: str = "auto") -> str:

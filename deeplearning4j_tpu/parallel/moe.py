@@ -46,7 +46,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.nn.activations import Activation
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
-from deeplearning4j_tpu.parallel.mesh import AXIS_EXPERT, current_mesh_context
+from deeplearning4j_tpu.ops.kernel_defaults import kernels_run as _kernel_runs
+from deeplearning4j_tpu.parallel.mesh import AXIS_EXPERT
 
 
 _ACTIVE_MESH: List[Tuple[Mesh, str]] = []
@@ -428,8 +429,9 @@ def _row_tiers(rows: int, share: float,
     the other cells read 0.03 (chip runs, PR 42). There the one tier is
     `most`. A layer with a ladder counts the rows past the pairs held to
     the last expert held, so each of its tiers costs the same whatever
-    fell into it. Where the kernels run (`_kernel_runs`) every layer has
-    the LAST of these alone, `most`: a row past the pairs held is no row
+    fell into it. Where the kernels run
+    (`ops/kernel_defaults.kernels_run`) every layer has the LAST of these
+    alone, `most`: a row past the pairs held is no row
     to the grouped products and the gathers round them, which follow the
     pairs with no threshold to cross, and what passes over the tier's
     rows (the sort, and the SwiGLU's elementwise work between the
@@ -563,14 +565,6 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
         jnp.asarray(rows, jnp.int32), n_held,
         jnp.maximum(n_held - taken, 0), jnp.max(sizes), jnp.min(sizes),
         taken, visited.astype(jnp.int32), gathered.astype(jnp.int32))))
-
-
-def _kernel_runs() -> bool:
-    """Whether an expert layer traced now may run `ops/grouped_matmul`'s
-    kernel: on the TPU backend, its operands on one device (under a mesh
-    context they may be sharded, which the kernel is not written for)."""
-    return (jax.default_backend() == "tpu"
-            and current_mesh_context() is None)
 
 
 # a step's routing counters, in an expert layer's state: the pairs the

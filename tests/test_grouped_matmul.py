@@ -134,7 +134,7 @@ def test_many_calls_trace_one_body_a_shape_and_share_a_schedule(monkeypatch):
     assert made == [(32, 8)] * 5
     assert text.count("pallas_call") == 3
     assert (text.count("jit[name=_dot ") == 10
-            and text.count("jit[name=_drhs ") == 5)
+            and text.count("jit[name=grouped_dot_drhs ") == 5)
     del made[:]
     shared = jax.grad(lambda x, rhs: loss(x, rhs, gm.schedule(sizes, 32, 8)),
                       (0, 1))(x, rhs)

@@ -199,3 +199,19 @@ def test_policy_is_the_parents(monkeypatch, policy, args, kwargs, want):
     if not isinstance(got, str):
         got = tuple(v for f, v in zip(got._fields, got) if f != "reason")
     assert got == want
+
+
+@pytest.mark.parametrize("width, tile", [
+    (5120, 256), (3072, 256), (4096, 256), (2048, 256), (128, 256),
+    (96, None), (5000, None)])
+def test_embedding_backward_tile_is_one_size_for_rows_of_whole_lanes(
+        width, tile):
+    """The five token cells' widths take the grouped product with one
+    tile; a row off the lanes of 128 keeps XLA's scatter-add. No force."""
+    assert kd.embedding_backward_tile(width) == tile
+
+
+def test_the_one_device_kernels_run_on_the_tpu_backend_alone(monkeypatch):
+    assert not kd.kernels_run()     # the suite's backend is the CPU
+    _tpu(monkeypatch)
+    assert kd.kernels_run()

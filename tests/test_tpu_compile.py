@@ -632,6 +632,9 @@ def test_minicpm_sala_step_fits_the_chip_with_nothing_t_by_t(chip):
     text = compiled.as_text()
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "sparse_attention" + kernel) == 1
+    # no expert layer: the one grouped product is the embedding's gradient
+    _embedding_backward_is_grouped(compiled, cfg)
+    assert _kernel_calls(compiled, "grouped_dot_drhs") == 1
     square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", text)
     assert not square, sorted(set(square))[:5]
 
@@ -676,6 +679,19 @@ def _tier_passes(compiled, rows=(73728, 81920), width=4096):
     return found
 
 
+def _embedding_backward_is_grouped(compiled, cfg):
+    """The table's gradient is `ops/embedding.py`'s grouped product over
+    the ids sorted by vocabulary tile: no `scatter` of XLA's writes an
+    array of the table's shape. Its one `grouped_dot_drhs` call site is
+    counted with the expert layers' (`_expert_layers_run_the_kernels`) or,
+    in a step with none, by the caller."""
+    table = "[%d,%d]" % (cfg.get("vocabulary_held", cfg["vocab_size"]),
+                         cfg["hidden_size"])
+    scatters = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+) scatter\(",
+                          compiled.as_text(), re.M)
+    assert not [out for out in scatters if table in out], scatters
+
+
 def _expert_layers_run_the_kernels(compiled, layers):
     """The `layers` expert layers of a compiled step have one tier each:
     twelve grouped products a layer (three forward, three in the tier's
@@ -683,12 +699,14 @@ def _expert_layers_run_the_kernels(compiled, layers):
     no `ragged-dot`; three `take_rows` a layer (forward, the tier's own
     recomputation, and `sum_rows`' transpose), two `sum_rows` (forward
     and `take_rows`' transpose; the recomputed one is dead and dropped)
-    and a packing of each one's source; no switch over tiers."""
+    and a packing of each one's source; no switch over tiers. One more
+    `grouped_dot_drhs` than the expert layers account for: the
+    embedding's gradient."""
     ragged, kernels = _grouped_products(compiled)
     assert ragged == 0
     assert kernels == {"grouped_dot": 6 * layers,
                        "grouped_dot_dlhs": 3 * layers,
-                       "grouped_dot_drhs": 3 * layers}
+                       "grouped_dot_drhs": 3 * layers + 1}
     assert _row_kernels(compiled) == {"take_rows": 3 * layers,
                                       "sum_rows": 2 * layers,
                                       "pack_rows": 5 * layers}
@@ -710,11 +728,12 @@ ELEMENTWISE = {"fusion", "convert", "broadcast", "negate", "exponential",
 
 
 def test_trinity_large_step_runs_the_kernels_over_one_tier(chip):
-    _, compiled, _ = _cell_step(chip, "trinity_large")
+    _, compiled, cfg = _cell_step(chip, "trinity_large")
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 13 * 2 ** 30)
     _expert_layers_run_the_kernels(compiled, 4)
+    _embedding_backward_is_grouped(compiled, cfg)
     passes = _tier_passes(compiled, rows=(32768,), width=3072)
     assert {p.split()[0] for p in passes} <= ELEMENTWISE, passes
     assert sum(p.startswith("fusion ") for p in passes) == 3 * 4
@@ -739,6 +758,9 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "latent_attention" + kernel) == layers
     _expert_layers_run_the_kernels(compiled, 4)
+    # no `scatter` whose result is [12800,5120]: 23.3 ms of a 511 ms step
+    # on the chip (PR 47), the longest op of the cell
+    _embedding_backward_is_grouped(compiled, cfg)
     assert not _tier_passes(compiled, rows=(49152,), width=5120)
     square = re.findall(rf"\[(?:\d+,)+{t},{t}\]", compiled.as_text())
     assert not square, sorted(set(square))[:5]
@@ -763,11 +785,14 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
 def test_granite_step_fits_the_chip_with_one_tied_embedding(chip):
     lowered, compiled, cfg = _cell_step(chip, "granite_4_0_h_small")
     _expert_layers_run_the_kernels(compiled, 10)
+    _embedding_backward_is_grouped(compiled, cfg)
     assert not _tier_passes(compiled)
     # the grouped products' 3 kernels x 2 shapes and the row gathers' 3
     # (each with its packing), many of them once more where a checkpoint's
-    # partial evaluation split a body; and the flash kernels' three
-    assert lowered.as_text().count("tpu_custom_call") <= 111
+    # partial evaluation split a body; the flash kernels' three; and, the
+    # 112th, the embedding's gradient: `grouped_dot_drhs` at a shape of its
+    # own (a one-hot `[8192, 256]` by the cotangent `[8192, 4096]`)
+    assert lowered.as_text().count("tpu_custom_call") <= 112
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
             < 2 ** 20)
@@ -795,6 +820,9 @@ def test_ouro_step_holds_each_block_once_for_its_four_passes(chip):
     _, compiled, cfg = _cell_step(chip, "ouro_2_6b")
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "flash_attention" + kernel) == 8
+    # no expert layer: the one grouped product is the embedding's gradient
+    _embedding_backward_is_grouped(compiled, cfg)
+    assert _kernel_calls(compiled, "grouped_dot_drhs") == 1
     memory = compiled.memory_analysis()
     # parameters, moments and layer state are donated: all but the batch
     assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
