@@ -239,12 +239,16 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
 
     def _params_of(self, params, layer):
         """The layer's own leaves and, for a head with `tied_to`, the
-        named layer's `W` transposed beside them: one leaf in the tree,
-        read twice."""
+        named layer's `W` beside them: one leaf in the tree, read twice.
+        Transposed and as the head's own `W`, or as the layer's `TIED_AS`
+        = (key, transposed) says (a head that keeps a `W` of its own and
+        looks its labels up in the embedding's table)."""
         own = params[layer.name]
         if layer.name not in self._tied:
             return own
-        return {**own, "W": params[self._tied[layer.name]]["W"].T}
+        key, transposed = getattr(layer, "TIED_AS", ("W", True))
+        w = params[self._tied[layer.name]]["W"]
+        return {**own, key: w.T if transposed else w}
 
     @property
     def score_(self) -> Optional[float]:
@@ -282,11 +286,17 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                     continue
                 source = self._tied[layer.name]
                 w = params[source].get("W")
-                if w is None or w.shape != (layer.n_out, layer.n_in):
+                # a head reads it transposed: [n_out, n_in]; one that
+                # looks labels up in it (`TIED_AS`) needs its rows' width
+                want = ((layer.n_out, layer.n_in)
+                        if getattr(layer, "TIED_AS", ("W", True))[1]
+                        else (None, layer.n_in))
+                if w is None or w.ndim != 2 or any(
+                        n is not None and n != m
+                        for n, m in zip(want, w.shape)):
                     raise ValueError(
                         f"{layer.name} is tied to {source}, whose W is "
-                        f"{None if w is None else w.shape}, not "
-                        f"{(layer.n_out, layer.n_in)}")
+                        f"{None if w is None else w.shape}, not {want}")
             self.params_tree = params
             self.state_tree = states
             self._build_updaters()

@@ -27,6 +27,7 @@ from deeplearning4j_tpu.nn.layers.pooling import GlobalPoolingLayer, PoolingType
 from deeplearning4j_tpu.nn.layers.recurrent import (
     LSTM, GravesLSTM, GravesBidirectionalLSTM, SimpleRnn, GRU, RnnOutputLayer,
     Bidirectional, ExitGatedOutputLayer, LastTimeStep,
+    MultiTokenOutputLayer,
 )
 from deeplearning4j_tpu.nn.layers.special import (
     FrozenLayer, CenterLossOutputLayer, LoopedStack, VariationalAutoencoder,
@@ -34,7 +35,7 @@ from deeplearning4j_tpu.nn.layers.special import (
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     LatentAttention, LinearAttention, MultiHeadAttention, PreNormBlock,
-    SandwichTransformerBlock, SelectiveStateSpace,
+    PreNormSublayer, SandwichTransformerBlock, SelectiveStateSpace,
 )
 
 __all__ = [
@@ -54,5 +55,6 @@ __all__ = [
     "FrozenLayer", "CenterLossOutputLayer", "VariationalAutoencoder", "RBM",
     "MultiHeadAttention", "SandwichTransformerBlock", "LinearAttention",
     "LatentAttention", "PreNormBlock", "SelectiveStateSpace",
-    "LoopedStack", "ExitGatedOutputLayer",
+    "LoopedStack", "ExitGatedOutputLayer", "PreNormSublayer",
+    "MultiTokenOutputLayer",
 ]

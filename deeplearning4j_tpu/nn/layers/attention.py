@@ -1428,8 +1428,9 @@ class LatentAttention(Layer):
 @dataclasses.dataclass(frozen=True)
 class SelectiveStateSpace(Layer):
     """A Mamba-2 mixer over [batch, time, features] (the `mamba` layers of
-    the `granitemoehybrid` family), causal, no bias but the convolution's.
-    With P = `head_dim`, N = `state_size`, G = `n_groups`, `a` the input:
+    the `granitemoehybrid` family, the `M` layers of `nemotron_h`), causal,
+    no bias but the convolution's. With P = `head_dim`, N = `state_size`,
+    G = `n_groups`, `a` the input:
 
         [z | xBC | dt] = a W_in        z [T, H P], xBC [T, H P + 2 G N], dt [T, H]
         xBC = silu(conv(xBC) + b_conv)     causal, depthwise, `conv_kernel`
@@ -1438,21 +1439,29 @@ class SelectiveStateSpace(Layer):
         x [T, H, P], B [T, G, N], C [T, G, N] = split(xBC)
         dt = softplus(dt + dt_bias);  A_h = -exp(A_log_h)
         S_t = exp(dt_t,h A_h) S_{t-1} + dt_t,h x_t,h B_t^T     [P, N] a head
-        y_t,h = S_t C_t + D_h x_t,h
-        out = norm(y * silu(z); norm) W_out    the RMS norm over all lanes
+        y_t,h = S_t C_t + D_h x_t,h    head h reads group h // (H / G)'s B, C
+        out = norm(y * silu(z); norm) W_out    the RMS norm PER GROUP: over
+                                               each group's H P / G lanes on
+                                               its own (all lanes where G = 1)
 
-    `num_heads` is the published count; `heads_held` = (first, count)
-    names the heads whose z, x and dt columns of `in_proj`, x channels of
-    the convolution, `dt_bias`, `A_log`, `D`, lanes of `norm` and rows of
-    `out_proj` this device has, all of them where None; B's and C's
-    columns and channels are whole on every device. A head's slices are
-    initialised from its own published index, so the shares of one layer
-    add up. Two things cross devices: `out_proj`'s partial sums (the
-    caller's all-reduce, as `LatentAttention`'s `Wo`) and the gated norm's
-    mean square, which runs over ALL heads' lanes: with `norm_axis` the
-    norm sums its squares and its lane count over that mesh axis; without,
-    the mean is over the lanes held, which is what a device has before
-    the exchange.
+    `chunk` is the config's `chunk_size` (`mamba_chunk_size`: 256 in
+    granite, 128 in nemotron_h). `num_heads` is the published count;
+    `heads_held` = (first, count) names the heads whose z, x and dt columns
+    of `in_proj`, x channels of the convolution, `dt_bias`, `A_log`, `D`,
+    lanes of `norm` and rows of `out_proj` this device has, all of them
+    where None. With one group B's and C's columns and channels are whole
+    on every device. With more, a share is WHOLE GROUPS (`first` and
+    `count` multiples of `num_heads / n_groups`) and takes those groups' B
+    and C columns and channels with it, so nothing of the mixer is held
+    twice. A head's slices (and, with more groups than one, a group's) are
+    initialised from their own published index, so the shares of one layer
+    add up. What crosses devices: `out_proj`'s partial sums (the caller's
+    all-reduce, as `LatentAttention`'s `Wo`) and, with ONE group, the gated
+    norm's mean square, which then runs over all heads' lanes: with
+    `norm_axis` the norm sums its squares and its lane count over that
+    mesh axis; without, the mean is over the lanes held, which is what a
+    device has before the exchange. A share by groups norms each group it
+    holds on its own and exchanges nothing there (`norm_axis` is refused).
 
     Initialised as Mamba-2 publishes: dt log-uniform in `DT_RANGE`
     with `dt_bias` its inverse softplus, A uniform in [1, 16], D 1. Scopes: `ssm_mixer` round everything, inside it `ssm_conv`,
@@ -1487,22 +1496,53 @@ class SelectiveStateSpace(Layer):
     @property
     def _held(self):
         first, count = LatentAttention._held.fget(self)
-        if self.n_groups != 1 and count != self.num_heads:
-            raise ValueError("a share of the heads goes with one group of "
-                             "B and C for all heads")
         return int(first), int(count)
+
+    @property
+    def _groups_held(self):
+        """(first, count) of the groups of B and C held: the one group
+        whole on every device, or the groups of the heads held."""
+        first, count = self._held
+        if self.num_heads % self.n_groups:
+            raise ValueError(f"{self.num_heads} heads in {self.n_groups} "
+                             f"groups")
+        if self.n_groups == 1:
+            return 0, 1
+        per = self.num_heads // self.n_groups
+        if first % per or count % per:
+            raise ValueError(
+                f"heads_held {(first, count)}: with {self.n_groups} groups "
+                f"of B and C a share is whole groups of {per} heads")
+        if self.norm_axis is not None:
+            raise ValueError("the gated norm is per group and a share is "
+                             "whole groups: there is nothing to sum over "
+                             f"{self.norm_axis!r}")
+        return first // per, count // per
 
     def init_params(self, key, input_type, dtype=jnp.float32):
         first, count = self._held
-        p, gn = self.head_dim, self.n_groups * self.state_size
+        g_first, g_count = self._groups_held
+        p, n = self.head_dim, self.state_size
+        gn = g_count * n
         ks = jax.random.split(key, 9)
         winit = self._winit()
+        kernel = lambda k, s: winit(k, s, dtype)
 
-        def heads(key, shape, axis, make=None):   # a head's own slices
-            make = make or (lambda k, s: winit(k, s, dtype))
+        def own(key, shape, axis, make, first, count):
             return jnp.concatenate(
                 [make(jax.random.fold_in(key, first + i), shape)
                  for i in range(count)], axis=axis)
+
+        def heads(key, shape, axis, make=kernel):   # a head's own slices
+            return own(key, shape, axis, make, first, count)
+
+        def groups(key, rows, make):    # B's and C's columns, [rows, 2 gn]
+            if self.n_groups == 1:
+                return make(key, (rows, 2 * n))
+            # a group's own, B's then C's: the shares add up
+            return jnp.concatenate([
+                own(jax.random.fold_in(key, half), (rows, n), 1, make,
+                    g_first, g_count) for half in (0, 1)], axis=1)
 
         tap = lambda k, s: jax.random.uniform(
             k, s, dtype, -1.0, 1.0) / math.sqrt(self.conv_kernel)
@@ -1514,11 +1554,11 @@ class SelectiveStateSpace(Layer):
             "in_proj": jnp.concatenate([
                 heads(ks[0], (self.n_in, p), 1),                    # z
                 heads(ks[1], (self.n_in, p), 1),                    # x
-                winit(ks[2], (self.n_in, 2 * gn), dtype),           # B, C
+                groups(ks[2], self.n_in, kernel),                   # B, C
                 heads(ks[3], (self.n_in, 1), 1)], axis=1),          # dt
             "conv_w": jnp.concatenate([
                 heads(ks[4], (self.conv_kernel, p), 1, tap),
-                tap(ks[5], (self.conv_kernel, 2 * gn))], axis=1),
+                groups(ks[5], self.conv_kernel, tap)], axis=1),
             "conv_b": jnp.zeros((count * p + 2 * gn,), dtype),
             # softplus(dt_bias) = dt
             "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
@@ -1545,7 +1585,7 @@ class SelectiveStateSpace(Layer):
 
         B, T, _ = x.shape
         H, P, K = self._held[1], self.head_dim, self.conv_kernel
-        G, N = self.n_groups, self.state_size
+        G, N = self._groups_held[1], self.state_size
         inner = H * P
         f32 = jnp.float32
         with jax.named_scope("ssm_mixer"):
@@ -1568,8 +1608,14 @@ class SelectiveStateSpace(Layer):
                     xbc[..., inner + G * N:].reshape(B, T, G, N),
                     params["D"], chunk=self.chunk)
             with jax.named_scope("ssm_gate_norm"):
-                y = rms_norm(y.reshape(B, T, inner) * jax.nn.silu(z),
-                             params["norm"], self.norm_eps, self.norm_axis)
+                y = y.reshape(B, T, inner) * jax.nn.silu(z)
+                if G == 1:
+                    y = rms_norm(y, params["norm"], self.norm_eps,
+                                 self.norm_axis)
+                else:   # each group held over its own lanes
+                    y = rms_norm(y.reshape(B, T, G, inner // G),
+                                 params["norm"].reshape(G, inner // G),
+                                 self.norm_eps).reshape(B, T, inner)
             out = y @ params["out_proj"]
             carried = chunk_carry(dt, a, chunk=self.chunk)
         return self._act(out), {**(state or {}), "ssm_chunk_carry": carried}
@@ -1579,7 +1625,9 @@ class SelectiveStateSpace(Layer):
 @dataclasses.dataclass(frozen=True)
 class PreNormBlock(Layer):
     """A pre-norm decoder block around any sequence mixer:
-    `h = x + r mixer(norm(x))`, then `h + r F(norm(h))`, with
+    `h = x + r mixer(norm(x))`, then `h + r F(norm(h))` (always both
+    halves; a model whose layers are ONE of them each, a mixer or a
+    feed-forward part alone, is built of `PreNormSublayer`s), with
     `r = residual_scale`, both norms RMS with a gain, and F a bias-free
     SwiGLU of `ffn_width`. The mixer is a layer of its own
     (`MultiHeadAttention` with whatever options, `LinearAttention`,
@@ -1676,3 +1724,58 @@ class PreNormBlock(Layer):
                 y = (jax.nn.silu(h @ params["ffn_w1"])
                      * (h @ params["ffn_w3"])) @ params["ffn_w2"]
         return x + self.residual_scale * y, new_state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class PreNormSublayer(Layer):
+    """One pre-norm residual sublayer, `x + f(norm(x))`: one RMS norm with
+    a gain and ONE layer `f`, given whole (the layers of the `nemotron_h`
+    family, each a Mamba-2 mixer, an attention or an expert layer alone:
+    `SelectiveStateSpace`, `MultiHeadAttention`,
+    `parallel/moe.ExpertFeedForward`). `PreNormBlock` is the two-halves
+    form and keeps its leaves. Leaves: `ln_g` and `f_<leaf>` for every
+    leaf of `f`; `f`'s state (routing counters, `ssm_chunk_carry`) is the
+    sublayer's. Under `gradient_checkpointing` each sublayer is a
+    checkpoint of its own and keeps its input alone."""
+
+    CONSUMES = "rnn"   # [B, T, d] sequence activations
+
+    n_in: Optional[int] = None
+    layer: Optional[Any] = None
+    eps: float = 1e-5
+
+    infer_n_in = PreNormBlock.infer_n_in
+    output_type = PreNormBlock.output_type
+
+    def _f(self):
+        if self.layer is None:
+            raise ValueError("PreNormSublayer needs a layer")
+        sizes = {"n_in": self.n_in}
+        if "n_out" in {f.name for f in dataclasses.fields(self.layer)}:
+            sizes.update(n_out=self.n_in, activation="identity")
+        return dataclasses.replace(
+            self.layer, **sizes,
+            weight_init=self.layer.weight_init or self.weight_init,
+            name=self.layer.name or f"{self.name}.f")
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        fp, state = self._f().init_params(key, input_type, dtype)
+        return {"ln_g": jnp.ones((self.n_in,), dtype),
+                **{f"f_{k}": v for k, v in fp.items()}}, state
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        f = self._f()   # an expert layer carries nothing between tokens
+        return ({"f": f.decode_carry(batch, dtype, **kw)}
+                if hasattr(f, "decode_carry") else {})
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        f = self._f()
+        carry = state.get("f") if state else None
+        y, st = f.apply(
+            {k[2:]: v for k, v in params.items() if k.startswith("f_")},
+            rms_norm(x, params["ln_g"], self.eps),
+            state=state if carry is None else carry, train=train, rng=rng,
+            mask=mask)
+        return x + y, (st or {}) if carry is None else {"f": st}

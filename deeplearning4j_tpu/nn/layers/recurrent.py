@@ -34,6 +34,7 @@ from jax import lax
 from deeplearning4j_tpu.nn.activations import Activation
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, Params, register_layer
+from deeplearning4j_tpu.nn.layers.attention import rms_norm
 from deeplearning4j_tpu.nn.layers.feedforward import OutputLayer
 from deeplearning4j_tpu.nn.losses import LossFunction, _reduce
 
@@ -541,6 +542,177 @@ class ExitGatedOutputLayer(Layer):
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None):
         return self._act(self._states(x)[-1] @ params["W"]), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class MultiTokenOutputLayer(Layer):
+    """The head of a model with a multi-token-prediction (MTP) module, as
+    DeepSeek-V3 trains one (arXiv:2412.19437, section 2.2): beside the main
+    head's next-token loss a second term scores the token after the next,
+    from the trunk's state and the NEXT token's embedding, teacher-forced.
+    With `h_t` the layer's input (the trunk's output BEFORE its last norm,
+    which is this layer's `norm_f`), `y` the labels, `e` the embedding
+    layer's table (`tied_to`: that layer's `W`, read as it lies, a lookup
+    of the labels; one leaf in the tree, read twice) and every norm RMS
+    with a gain:
+
+        logits_t  = norm_f(h_t) W
+        g_t       = [norm_h(h_t) ; norm_e(e[y_t])] W_eh        2 d -> d
+        g         = the module's `layers` over g, in turn, each given whole
+                    (`PreNormSublayer`s: an attention, an expert layer)
+        logits2_t = norm_mtp(g_t) W                the main head's own W
+        loss = ce(logits, y) + mtp_weight x ce(logits2_t, y_{t+1}), t < T - 1
+
+    The labels are the ids shifted by one, so `e[y_t]` is the next token's
+    embedding and `y_{t+1}` the target two ahead; the last position has
+    no second target and weighs nothing (it is computed and multiplied by
+    zero: every shape stays whole). Both heads are scored one at a time,
+    each under a checkpoint, log-softmax in float32, so one `[B, T, n_out]`
+    tensor is alive at a time, forward and backward; with `remat` each of
+    the module's layers is a checkpoint of its own too (the policy of
+    `gradient_checkpointing`: a kernel's named residuals stay). Leaves:
+    `norm_f`, `W` [n_in, n_out], `mtp_norm_h`, `mtp_norm_e`, `mtp_eh_proj`
+    [2 n_in, n_in], `mtp_norm`, and `mtp_layer<i>_<leaf>` for the module's
+    layers. State: the last step's `main_loss` and `mtp_loss` (each term
+    before its weight) and what the module's layers wrote in that step
+    (an expert layer's routing counters; the layers are handed no state:
+    nothing of it is read in training, and what comes back is the step's
+    own), which `fit()` publishes as gauges where an epoch synchronises.
+    Scope `mtp` round the module, its head pass and its cross-entropy.
+    `apply` gives the main head's softmax; labels are integers
+    (`sparse_mcxent`). Training and scoring only: at inference the module
+    would draft a second token, which serving does not do."""
+
+    CONSUMES = "rnn"
+    # `models/multilayer.py` hands the `tied_to` layer's W under this key,
+    # transposed or (here) as it lies
+    TIED_AS = ("embedding", False)
+    # the score's own state: each term before its weight
+    LOSS_STATE = ("main_loss", "mtp_loss")
+
+    n_in: Optional[int] = None
+    n_out: Optional[int] = None
+    tied_to: Optional[Any] = None       # the embedding layer
+    layers: Tuple[Any, ...] = ()        # the MTP module's, in order
+    mtp_weight: float = 0.1
+    eps: float = 1e-5
+    remat: bool = False
+    loss: Any = "sparse_mcxent"
+
+    @property
+    def is_output_layer(self) -> bool:
+        return True
+
+    infer_n_in = ExitGatedOutputLayer.infer_n_in
+    output_type = ExitGatedOutputLayer.output_type
+
+    def _layers(self):
+        return [dataclasses.replace(
+            l, n_in=self.n_in, weight_init=l.weight_init or self.weight_init,
+            name=l.name or f"{self.name}.mtp_layer{i}")
+            for i, l in enumerate(self.layers)]
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        if str(self.loss).lower() != "sparse_mcxent":
+            raise ValueError(f"{self.name}: the module looks the labels up "
+                             f"as ids: sparse_mcxent, not {self.loss!r}")
+        if self.tied_to is None:
+            raise ValueError(f"{self.name}: tied_to names the embedding "
+                             f"layer whose table the module reads")
+        d = self.n_in
+        kw, ke, *kl = jax.random.split(key, 2 + len(self.layers))
+        winit = self._winit()
+        one = lambda: jnp.ones((d,), dtype)
+        params = {"norm_f": one(), "W": winit(kw, (d, self.n_out), dtype),
+                  "mtp_norm_h": one(), "mtp_norm_e": one(),
+                  "mtp_eh_proj": winit(ke, (2 * d, d), dtype),
+                  "mtp_norm": one()}
+        state = dict.fromkeys(self.LOSS_STATE, jnp.zeros((), jnp.float32))
+        for i, (layer, k) in enumerate(zip(self._layers(), kl)):
+            lp, st = layer.init_params(k, input_type, dtype)
+            if set(st) & set(state):
+                raise ValueError(f"{self.name}: two of the module's layers "
+                                 f"keep {sorted(set(st) & set(state))}")
+            params.update({f"mtp_layer{i}_{n}": v for n, v in lp.items()})
+            state.update(st)
+        return params, state
+
+    def decode_carry(self, batch: int, dtype=jnp.float32, **kw):
+        raise NotImplementedError(
+            f"MultiTokenOutputLayer {self.name!r} has no decode carry: its "
+            f"module reads the NEXT token's embedding, which at inference "
+            f"is a draft to verify (speculative decoding from the model's "
+            f"own second head), and serving does not have that")
+
+    def _ce(self, params, h, gain, targets):
+        """Per-token cross-entropy `[B, T]` (float32) of `norm(h; gain) W`
+        against `targets`, nothing of `[B, T, n_out]` kept."""
+        @jax.checkpoint
+        def one(h, gain, w, targets):
+            logits = rms_norm(h, gain, self.eps) @ w
+            logp = jax.nn.log_softmax(logits.astype(jnp.promote_types(
+                logits.dtype, jnp.float32)), axis=-1)
+            return -jnp.take_along_axis(
+                logp, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
+
+        return one(h, gain, params["W"], targets)
+
+    def _module(self, params, x, labels):
+        """(g [B, T, d], what the layers wrote as state) of the MTP
+        module."""
+        from deeplearning4j_tpu.ops.attention import KEPT_NAMES
+        from deeplearning4j_tpu.ops.embedding import lookup
+
+        e = lookup(params["embedding"], labels.astype(jnp.int32))
+        g = jnp.concatenate(
+            [rms_norm(x, params["mtp_norm_h"], self.eps),
+             rms_norm(e.astype(x.dtype), params["mtp_norm_e"], self.eps)],
+            axis=-1) @ params["mtp_eh_proj"]
+        states = {}
+        for i, layer in enumerate(self._layers()):
+            prefix = f"mtp_layer{i}_"
+            lp = {k[len(prefix):]: v for k, v in params.items()
+                  if k.startswith(prefix)}
+            run = lambda p, g, _l=layer: _l.apply(p, g, train=True)
+            if self.remat:
+                run = jax.checkpoint(
+                    run, policy=jax.checkpoint_policies
+                    .save_only_these_names(*KEPT_NAMES))
+            with jax.named_scope(f"mtp_layer{i}"):
+                g, st = run(lp, g)
+            states.update(st or {})
+        return g, states
+
+    def score_and_state(self, params, x, labels, state, mask=None):
+        if labels.ndim != 2:
+            raise ValueError(f"{self.name}: integer labels [batch, time], "
+                             f"not {labels.shape}")
+        main = _reduce(self._ce(params, x, params["norm_f"], labels), mask)
+        with jax.named_scope("mtp"):
+            g, states = self._module(params, x, labels)
+            # position t scores y_{t+1}; the last has none (its target
+            # wraps round and weighs nothing)
+            ahead = jnp.roll(labels, -1, axis=1)
+            live = jnp.broadcast_to(
+                jnp.arange(labels.shape[1]) < labels.shape[1] - 1,
+                labels.shape).astype(jnp.float32)
+            if mask is not None:    # both targets must be real
+                m = jnp.broadcast_to(mask, labels.shape).astype(jnp.float32)
+                live = live * m * jnp.roll(m, -1, axis=1)
+            mtp = _reduce(self._ce(params, g, params["mtp_norm"], ahead),
+                          live)
+        score = main + self.mtp_weight * mtp
+        return score, {**states, "main_loss": main.astype(jnp.float32),
+                       "mtp_loss": mtp.astype(jnp.float32)}
+
+    def score(self, params, x, labels, mask=None):
+        return self.score_and_state(params, x, labels, None, mask)[0]
+
+    def apply(self, params, x, *, state=None, train=False, rng=None,
+              mask=None):
+        return self._act(rms_norm(x, params["norm_f"], self.eps)
+                         @ params["W"]), state
 
 
 @register_layer

@@ -130,7 +130,8 @@ def _publish_routing_counters(net) -> None:
     them; a `MultiHeadAttention` with a block selection: the
     `sparse_blocks_*` scalars; a `SelectiveStateSpace`: `ssm_chunk_carry`,
     a share and so a float; an `ExitGatedOutputLayer`: `exit_entropy`
-    and `exit_mass`, a value a pass and so `exit_mass{layer=, pass=}`)
+    and `exit_mass`, a value a pass and so `exit_mass{layer=, pass=}`; a
+    `MultiTokenOutputLayer`: `main_loss` and `mtp_loss`, its two terms)
     out of the net's layer state into the gauges `<counter>{layer=}`.
     Called where the epoch has just synchronised with the device; a net
     without such a layer pays a walk over its state's keys."""
@@ -139,7 +140,7 @@ def _publish_routing_counters(net) -> None:
         if isinstance(st, dict):
             own = {k: v for k, v in st.items()
                    if k.startswith(("moe_", "sparse_blocks_", "ssm_",
-                                    "exit_"))}
+                                    "exit_", "mtp_", "main_loss"))}
             if own:
                 counters[name] = own
     if counters:

@@ -409,6 +409,14 @@ def _swiglu(x, w1, w3, w2, dot):
     return dot(jax.nn.silu(dot(x, w1)) * dot(x, w3), w2)
 
 
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+# an expert's form by name: "swiglu" has `w3` beside `w1` and `w2`
+EXPERT_FORMS = ("swiglu", "relu2")
+
+
 def _row_tiers(rows: int, share: float,
                most: Optional[int] = None) -> Tuple[int, ...]:
     """The row counts the routed products are compiled for where XLA's
@@ -453,7 +461,9 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
     expert(token): x [N, d], experts and weights [N, k], the held experts'
     SwiGLU kernels w1, w3 [count, d, f] and w2 [count, f, d], `first` the
     published index of the first of them among the router's `n_experts`.
-    Returns (y [N, d], counters)."""
+    With `w3` None the experts are two-matrix `relu(x w1)^2 w2` ("relu2"):
+    two grouped products forward where a SwiGLU has three, and the rows
+    gathered once. Returns (y [N, d], counters)."""
     n, k = experts.shape
     count, rows = w1.shape[0], n * k
     with jax.named_scope("dispatch"):
@@ -520,7 +530,10 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
                 # that weighs a row as it packs it.
                 rows = lambda v: v
                 with jax.named_scope("dispatch"):
-                    taken = take_rows(x, token[:c], place, held, 2)
+                    taken = take_rows(x, token[:c], place, held,
+                                      1 if w3 is None else 2)
+                    if w3 is None:
+                        taken = (taken,)
                 summed = lambda v: sum_rows(v, weight, token[:c], place,
                                             held)
                 in_group = sizes
@@ -546,8 +559,11 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
                     product = lambda a, w: jax.lax.ragged_dot(
                         a, w, group_sizes=in_group)
                 dot = lambda a, w: rows(product(a, w))
-                out = dot(jax.nn.silu(dot(taken[0], w1)) * dot(taken[1], w3),
-                          w2)
+                if w3 is None:
+                    out = dot(_relu2(dot(taken[0], w1)), w2)
+                else:
+                    out = dot(jax.nn.silu(dot(taken[0], w1))
+                              * dot(taken[1], w3), w2)
             with jax.named_scope("combine"):
                 return summed(out)
         return run
@@ -599,15 +615,29 @@ class ExpertFeedForward(Layer):
     With `n_group` > 1 a token chooses its `k` inside the `topk_group`
     groups of experts whose best score is highest (`route`).
 
+    `expert_form` "relu2" makes every expert the two-matrix
+    `relu(x w1)^2 w2` in the SwiGLU's place. With `latent` the routed
+    experts live in a latent of that width (LatentMoE): the router still
+    reads the token at the model's width, `u = x latent_down` goes to the
+    experts ([latent -> width -> latent] each) and their weighted sum comes
+    back through `latent_up`, both projections whole on every device;
+    the shared expert stays at the model's width and has the experts'
+    form. `shared_width` gives the shared expert a width of its own
+    (`n_shared` x `width` where None). The defaults are the SwiGLU layer
+    at the model's width, to the bit.
+
     Leaves: `router` [d, n_experts]; `bias` [n_experts] where
     `selection_bias` (added to the scores for the choice only, its
     gradient exactly zero: whoever balances load moves it between steps);
-    `w1`, `w3` [count, d, width], `w2` [count, width, d]; with `n_shared`
-    shared experts `shared_w1`, `shared_w3` [d, n_shared x width] and
-    `shared_w2`. State: the last step's `COUNTERS` and, where it routes
+    `w1`, `w3` [count, d, width], `w2` [count, width, d] (no `w3` for
+    "relu2"; `d` the latent's width where there is one); with a shared
+    expert `shared_w1`, `shared_w3` [d, shared width] and `shared_w2`;
+    with `latent`, `latent_down` [d, latent] and `latent_up` [latent, d].
+    State: the last step's `COUNTERS` and, where it routes
     by groups, `TOKENS_HELD`, which `fit()` publishes as gauges
-    `<name>{layer=}` where an epoch synchronises.
-    Accepts [N, d] or [B, T, d]."""
+    `<name>{layer=}` where an epoch synchronises. Scopes: `router`,
+    `latent_down`, `dispatch`, `experts_held`, `combine`, `latent_up`,
+    `shared_expert`. Accepts [N, d] or [B, T, d]."""
 
     CONSUMES = "any"
 
@@ -623,6 +653,9 @@ class ExpertFeedForward(Layer):
     n_shared: int = 0
     n_group: int = 1
     topk_group: int = 1
+    expert_form: str = "swiglu"
+    latent: Optional[int] = None
+    shared_width: Optional[int] = None
 
     def infer_n_in(self, input_type: InputType) -> "ExpertFeedForward":
         if self.n_in is None:
@@ -643,24 +676,36 @@ class ExpertFeedForward(Layer):
         f = self.width or 4 * d
         if not 1 <= self.k <= self.n_experts:
             raise ValueError(f"k {self.k} of {self.n_experts} experts")
+        if self.expert_form not in EXPERT_FORMS:
+            raise ValueError(f"an expert is one of {EXPERT_FORMS}, "
+                             f"not {self.expert_form!r}")
+        gated = self.expert_form == "swiglu"
         first, count = self._held
         ks = jax.random.split(key, 7)
         winit = self._winit()
+        inner = self.latent or d     # the width the routed experts read
 
         def stack(key, shape):      # an expert's kernel from its own index
             return jnp.stack([winit(jax.random.fold_in(key, first + i),
                                     shape, dtype) for i in range(count)])
 
         params = {"router": winit(ks[0], (d, self.n_experts), dtype),
-                  "w1": stack(ks[1], (d, f)), "w3": stack(ks[2], (d, f)),
-                  "w2": stack(ks[3], (f, d))}
+                  "w1": stack(ks[1], (inner, f)),
+                  "w2": stack(ks[3], (f, inner))}
+        if gated:
+            params["w3"] = stack(ks[2], (inner, f))
         if self.selection_bias:
             params["bias"] = jnp.zeros((self.n_experts,), dtype)
-        if self.n_shared:
-            fs = self.n_shared * f
+        fs = self.shared_width or self.n_shared * f
+        if fs:
             params.update(shared_w1=winit(ks[4], (d, fs), dtype),
-                          shared_w3=winit(ks[5], (d, fs), dtype),
                           shared_w2=winit(ks[6], (fs, d), dtype))
+            if gated:
+                params["shared_w3"] = winit(ks[5], (d, fs), dtype)
+        if self.latent:
+            down, up = jax.random.split(jax.random.fold_in(key, 7))
+            params.update(latent_down=winit(down, (d, inner), dtype),
+                          latent_up=winit(up, (inner, d), dtype))
         names = COUNTERS + ((TOKENS_HELD,) if self.n_group > 1 else ())
         return params, dict.fromkeys(names, jnp.zeros((), jnp.int32))
 
@@ -674,17 +719,34 @@ class ExpertFeedForward(Layer):
                 route_scale=self.route_scale, n_group=self.n_group,
                 topk_group=self.topk_group)
         first, count = self._held
+        routed = tokens
+        if self.latent:
+            with jax.named_scope("latent_down"):
+                routed = tokens @ params["latent_down"]
         y, counters = held_experts(
-            tokens, experts, weights.astype(tokens.dtype), params["w1"],
-            params["w3"], params["w2"], first=first,
+            routed, experts, weights.astype(tokens.dtype), params["w1"],
+            params.get("w3"), params["w2"], first=first,
             n_experts=self.n_experts)
+        if self.latent:
+            from deeplearning4j_tpu.ops.attention import name_block_residual
+
+            # `latent_up`'s own gradient reads the routed sum: named, a
+            # checkpointed layer keeps it ([N, latent]) and its
+            # recomputation leaves the routed path's products out
+            y = name_block_residual(y, "sublayer_out")
+            with jax.named_scope("latent_up"):
+                y = y @ params["latent_up"]
         if self.n_group > 1:
             here = (experts >= first) & (experts < first + count)
             counters[TOKENS_HELD] = jnp.sum(jnp.any(here, axis=-1),
                                             dtype=jnp.int32)
-        if self.n_shared:
+        if "shared_w1" in params:
             with jax.named_scope("shared_expert"):
-                y = y + _swiglu(tokens, params["shared_w1"],
-                                params["shared_w3"], params["shared_w2"],
-                                jnp.dot)
+                if "shared_w3" in params:
+                    y = y + _swiglu(tokens, params["shared_w1"],
+                                    params["shared_w3"],
+                                    params["shared_w2"], jnp.dot)
+                else:
+                    y = y + (_relu2(tokens @ params["shared_w1"])
+                             @ params["shared_w2"])
         return y.reshape(x.shape), counters

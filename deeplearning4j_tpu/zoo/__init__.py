@@ -24,8 +24,8 @@ with _span("import.zoo"):
         GoogLeNet, InceptionResNetV1, FaceNetNN4Small2,
     )
     from deeplearning4j_tpu.zoo.transformer import (
-        HybridLinearSparseTransformer, HybridStateSpaceTransformer,
-        LatentSparseTransformer, LoopedSandwichTransformer,
+        HybridLatentExpertTransformer, HybridLinearSparseTransformer,
+        HybridStateSpaceTransformer, LatentSparseTransformer, LoopedSandwichTransformer,
         SparseSandwichTransformer, TextGenerationTransformer,
     )
     from deeplearning4j_tpu.zoo.pretrained import (
@@ -42,5 +42,5 @@ __all__ = [
     "InceptionResNetV1", "FaceNetNN4Small2", "TextGenerationTransformer",
     "SparseSandwichTransformer", "HybridLinearSparseTransformer",
     "LatentSparseTransformer", "HybridStateSpaceTransformer",
-    "LoopedSandwichTransformer",
+    "LoopedSandwichTransformer", "HybridLatentExpertTransformer",
 ]

@@ -15,13 +15,13 @@ from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.attention import (
     LatentAttention, LinearAttention, MultiHeadAttention,
-    PositionEmbeddingLayer, PreNormBlock, SandwichTransformerBlock,
-    SelectiveStateSpace, TransformerEncoderBlock,
+    PositionEmbeddingLayer, PreNormBlock, PreNormSublayer,
+    SandwichTransformerBlock, SelectiveStateSpace, TransformerEncoderBlock,
 )
 from deeplearning4j_tpu.nn.layers.feedforward import EmbeddingSequenceLayer
 from deeplearning4j_tpu.nn.layers.normalization import RMSNormalization
 from deeplearning4j_tpu.nn.layers.recurrent import (
-    ExitGatedOutputLayer, RnnOutputLayer,
+    ExitGatedOutputLayer, MultiTokenOutputLayer, RnnOutputLayer,
 )
 from deeplearning4j_tpu.nn.layers.special import LoopedStack
 from deeplearning4j_tpu.optim.updaters import Adam
@@ -616,5 +616,169 @@ class HybridStateSpaceTransformer(ZooModel):
             RnnOutputLayer(n_out=self.num_classes, has_bias=False,
                            activation="softmax", loss="sparse_mcxent",
                            tied_to=0))
+            .set_input_type(InputType.recurrent(1, t))
+            .build())
+
+
+@register_zoo
+class HybridLatentExpertTransformer(ZooModel):
+    """A causal language model of the `nemotron_h` family (Nemotron-3),
+    built from the keys its published `config.json` has. Every layer is
+    ONE pre-norm residual sublayer (`PreNormSublayer`), by
+    `hybrid_override_pattern`: `M` a Mamba-2 mixer (`SelectiveStateSpace`:
+    `mamba_num_heads` heads of `mamba_head_dim`, `ssm_state_size`,
+    `n_groups` groups of B and C with the gated norm per group, a causal
+    convolution of `conv_kernel`, chunks of `chunk_size`), `*` GQA softmax
+    attention with no positions (`num_attention_heads` heads of `head_dim`
+    over `num_key_value_heads`; the family applies no rotary embedding, so
+    `rope_theta` and `partial_rotary_factor` are not read), `E` a
+    LatentMoE layer (`parallel/moe.ExpertFeedForward`: a sigmoid router
+    over `n_routed_experts` with a selection bias, `num_experts_per_tok` a
+    token, weights normalised where `norm_topk_prob` and times
+    `routed_scaling_factor`; two-matrix `mlp_hidden_act` experts of
+    `moe_intermediate_size` inside a latent of `moe_latent_size`; a shared
+    expert of `moe_shared_expert_intermediate_size` at the model's
+    width). A last RMS norm and an untied head follow; with
+    `num_nextn_predict_layers` 1 the head is a `MultiTokenOutputLayer`
+    whose module's layers `mtp_hybrid_override_pattern` names and whose
+    second loss term weighs `mtp_loss_scaling_factor` (0.1 where the
+    config gives none). Mamba-2's initial step sizes are the config's
+    `time_step_min` to `time_step_max`. No balance loss and no bias
+    update is built (the config gives no coefficient).
+
+    Refused by name: a pattern letter other than `M`, `*`, `E` (the
+    family's `-`, a dense MLP layer, is not wired), a tied head, biases,
+    activations other than silu (mixer) and relu2 (experts), group-limited
+    routing, more prediction modules than one.
+
+    One device's share of a deployment is built with `heads_held` (first,
+    count): whole groups of the Mamba heads of every `M` layer,
+    `attention_heads_held` and `kv_heads_held`: the query and KV heads of
+    every `*` layer, `experts_held`: the routed experts of every `E`
+    layer, and `vocabulary_held`: the rows of the embedding and the
+    columns of the head. Token ids come as `[batch, time]` integers,
+    labels as integers (`sparse_mcxent`)."""
+
+    input_shape = (8192,)
+
+    def __init__(self, config: dict, *, timesteps: int = None,
+                 heads_held=None, attention_heads_held=None,
+                 kv_heads_held=None, experts_held=None,
+                 vocabulary_held: int = None, dtype: str = "float32",
+                 gradient_checkpointing=False, **kw):
+        super().__init__(
+            num_classes=vocabulary_held or config["vocab_size"],
+            input_shape=(timesteps or self.input_shape[0],), **kw)
+        pattern = config["hybrid_override_pattern"]
+        mtp = config.get("mtp_hybrid_override_pattern", "") \
+            if config.get("num_nextn_predict_layers", 0) else ""
+        if len(pattern) != config["num_hidden_layers"]:
+            raise ValueError(
+                f"hybrid_override_pattern has {len(pattern)} letters for "
+                f"{config['num_hidden_layers']} layers")
+        unknown = set(pattern + mtp) - set("M*E")
+        if unknown:
+            raise ValueError(
+                f"pattern letters {sorted(unknown)} are not wired (M, * "
+                f"and E are)")
+        if config.get("num_nextn_predict_layers", 0) not in (0, 1):
+            raise ValueError("num_nextn_predict_layers "
+                             f"{config['num_nextn_predict_layers']}: one "
+                             f"prediction module is wired")
+        for key, known in (("mamba_hidden_act", "silu"),
+                           ("mlp_hidden_act", "relu2"),
+                           ("mamba_proj_bias", False),
+                           ("use_conv_bias", True), ("use_bias", False),
+                           ("attention_bias", False), ("mlp_bias", False),
+                           ("tie_word_embeddings", False),
+                           ("residual_in_fp32", False),
+                           ("n_group", 1), ("topk_group", 1),
+                           ("n_shared_experts", 1),
+                           ("norm_eps", config["layer_norm_epsilon"])):
+            if config[key] != known:
+                raise ValueError(f"{key} {config[key]!r} is not wired "
+                                 f"({known!r} is)")
+        if (config["expand"] * config["hidden_size"]
+                != config["mamba_num_heads"] * config["mamba_head_dim"]):
+            raise ValueError("expand x hidden_size is not mamba_num_heads "
+                             "x mamba_head_dim")
+        steps = (config["time_step_min"], config["time_step_max"])
+        if steps != SelectiveStateSpace.DT_RANGE \
+                or config["time_step_floor"] > steps[0]:
+            raise ValueError(
+                f"time_step_min, time_step_max {steps} with floor "
+                f"{config['time_step_floor']}: the mixer starts its steps "
+                f"in {SelectiveStateSpace.DT_RANGE}, unclamped")
+        self.config = dict(config)
+        self.heads_held = heads_held
+        self.attention_heads_held = attention_heads_held
+        self.kv_heads_held = kv_heads_held
+        self.experts_held = experts_held
+        self.dtype = dtype
+        self.gradient_checkpointing = gradient_checkpointing
+
+    def _layer(self, letter: str):
+        from deeplearning4j_tpu.parallel.moe import ExpertFeedForward
+
+        c, t = self.config, self.input_shape[0]
+        if letter == "M":
+            inner = SelectiveStateSpace(
+                num_heads=c["mamba_num_heads"], heads_held=self.heads_held,
+                head_dim=c["mamba_head_dim"], state_size=c["ssm_state_size"],
+                n_groups=c["n_groups"], conv_kernel=c["conv_kernel"],
+                chunk=c["chunk_size"], norm_eps=c["layer_norm_epsilon"])
+        elif letter == "*":
+            held = (self.attention_heads_held
+                    or (0, c["num_attention_heads"]))[1]
+            kv_held = (self.kv_heads_held
+                       or (0, c["num_key_value_heads"]))[1]
+            inner = MultiHeadAttention(
+                num_heads=held, num_kv_heads=kv_held,
+                head_dim=c["head_dim"], causal=True, rope=False, bias=False,
+                max_cache=t)
+        else:
+            inner = ExpertFeedForward(
+                width=c["moe_intermediate_size"],
+                n_experts=c["n_routed_experts"],
+                held=None if self.experts_held is None
+                else tuple(self.experts_held),
+                k=c["num_experts_per_tok"], score="sigmoid",
+                selection_bias=True, route_norm=c["norm_topk_prob"],
+                route_scale=float(c["routed_scaling_factor"]),
+                expert_form=c["mlp_hidden_act"],
+                latent=c.get("moe_latent_size"),
+                shared_width=c["moe_shared_expert_intermediate_size"])
+        return PreNormSublayer(layer=inner, eps=c["layer_norm_epsilon"])
+
+    def conf(self):
+        c, t = self.config, self.input_shape[0]
+        d, eps = c["hidden_size"], c["layer_norm_epsilon"]
+        builder = (NeuralNetConfiguration.builder()
+                   .seed(self.seed)
+                   .updater(self.kw.get("updater", Adam(3e-4)))
+                   .activation("identity")
+                   .weight_init("xavier")
+                   .dtype(self.dtype))
+        if self.gradient_checkpointing:
+            builder = builder.gradient_checkpointing()
+        if c.get("num_nextn_predict_layers", 0):
+            head = (MultiTokenOutputLayer(
+                n_out=self.num_classes, tied_to=0, eps=eps,
+                layers=tuple(self._layer(letter) for letter in
+                             c["mtp_hybrid_override_pattern"]),
+                mtp_weight=c.get("mtp_loss_scaling_factor", 0.1),
+                remat=bool(self.gradient_checkpointing),
+                activation="softmax", loss="sparse_mcxent"),)
+        else:
+            head = (RMSNormalization(eps=eps),
+                    RnnOutputLayer(n_out=self.num_classes, has_bias=False,
+                                   activation="softmax",
+                                   loss="sparse_mcxent"))
+        return (builder.list(
+            EmbeddingSequenceLayer(n_in=self.num_classes, n_out=d,
+                                   activation="identity"),
+            *(self._layer(letter)
+              for letter in c["hybrid_override_pattern"]),
+            *head)
             .set_input_type(InputType.recurrent(1, t))
             .build())

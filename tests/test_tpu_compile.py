@@ -844,6 +844,52 @@ def test_ouro_step_holds_each_block_once_for_its_four_passes(chip):
     assert not re.findall(rf"\[4,(?:1,)?{t},{cfg['vocab_size']}\]", text)
 
 
+# --- the benchmark's `nemotron_3_super` step at the cell's own size (one
+# sequence of 8,192 ids, bf16, eleven one-sublayer layers each a checkpoint
+# of its own, a two-term head with a prediction module of an attention and
+# an expert sublayer), built by the benchmark's own model file. Its six
+# LatentMoE layers (five and the module's) have two-matrix experts: eight
+# grouped products a layer (two forward, two in the tier's own checkpoint,
+# four backward; the layer's recomputation runs none, the routed sum that
+# `latent_up`'s gradient reads is named and kept), the row kernels as a
+# SwiGLU layer's, no `ragged-dot`, no switch. The embedding's table is
+# looked up twice (the ids and, in the module, the labels), so two
+# `grouped_dot_drhs` are its gradient's; the flash kernels run once for
+# the trunk's attention layer and once for the module's; and no
+# `[.., 2, T, vocabulary]` float32 tensor holds both heads' logits at once.
+def test_nemotron_step_runs_six_latent_layers_and_two_heads(chip):
+    lowered, compiled, cfg = _cell_step(chip, "nemotron_3_super")
+    layers = 6
+    ragged, kernels = _grouped_products(compiled)
+    assert ragged == 0
+    assert kernels == {"grouped_dot": 4 * layers,
+                       "grouped_dot_dlhs": 2 * layers,
+                       "grouped_dot_drhs": 2 * layers + 2}
+    assert _row_kernels(compiled) == {"take_rows": 3 * layers,
+                                      "sum_rows": 2 * layers,
+                                      "pack_rows": 5 * layers}
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    _embedding_backward_is_grouped(compiled, cfg)
+    for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
+        assert _kernel_calls(compiled, "flash_attention" + kernel) == 2
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes - memory.alias_size_in_bytes
+            < 2 ** 20)
+    # bf16 parameters and both moments of 1,102,491,120
+    assert memory.argument_size_in_bytes == pytest.approx(
+        6 * 1_102_491_120, rel=1e-3)
+    stated = cfg["compiled_for_v5e"]
+    assert memory.argument_size_in_bytes / 2 ** 30 == pytest.approx(
+        stated["argument_gib"], abs=2e-3)
+    assert memory.temp_size_in_bytes / 2 ** 30 == pytest.approx(
+        stated["temporary_gib"], abs=0.05)
+    t, v = cfg["input_shape"][0], cfg["vocabulary_held"]
+    square = re.findall(rf"\[(?:\d+,){{2,}}{t},{t}\]", text)
+    assert not square, sorted(set(square))[:5]
+    assert not re.findall(rf"f32\[(?:\d+,)*2,(?:1,)?{t},{v}\]", text)
+
+
 def test_selective_scan_makes_c_b_t_once_a_chunk_not_once_a_head(chip):
     """The forward of `ops/selective_scan.py` at the cell's widths (32
     heads of 64, a state of 128, chunks of 256; four chunks here): of the
