@@ -117,11 +117,16 @@ def _checkpointed(apply_fn, mask):
       (`BLOCK_RESIDUAL_NAMES`): both halves' outputs of a
       `SandwichTransformerBlock`, whose norms read them (two `[T, d]`
       tensors a block), the stream between the halves of a `PreNormBlock`
-      (one), and a sparse `MultiHeadAttention`'s block selection
-      (`[B, KV heads, T, blocks]` bools). Kept, the product that made each
-      (`Wo`, the feed-forward's or the shared expert's down-projection,
-      the routed experts' tier, the selection) is dead in the recomputed
-      forward and JAX leaves it out; the gradients are the same numbers.
+      (one), a sparse `MultiHeadAttention`'s block selection
+      (`[B, KV heads, T, blocks]` bools), and an expert layer's schedule
+      (five values a `parallel/moe.ExpertFeedForward`: the router's
+      choice `[N, k]`, the pairs' order and places by expert, the
+      experts' sizes, all int32, and the pairs' weights in row order, each
+      N x k). Kept, what made each (`Wo`, the
+      feed-forward's or the shared expert's down-projection, the routed
+      experts' tier, the selection, the router's `top_k`, the schedule's
+      sort and count) is dead in the recomputed forward and JAX leaves it
+      out; the gradients are the same numbers.
 
     A layer that names nothing keeps nothing. Returns the apply's result
     and the pair (kernel calls whose pair was named, block values named).
@@ -160,9 +165,10 @@ def record_residuals_kept(model, kept) -> None:
     `gradient_checkpointing` or without a Pallas attention forward under
     differentiation). Gauge `block_residuals_kept{model=<class>}`: how
     many values the blocks named inside checkpointed layers
-    (`ops/attention.name_block_residual`: 10 for `trinity_large`, two a
-    block; 5 for `deepseek_v2`, the stream of each block; 5 for
-    `minicpm_sala`, four streams and one selection; 0 without
+    (`ops/attention.name_block_residual`: two a sandwich block, the
+    stream of a pre-norm block, a selection, and five an expert layer,
+    its choice and its schedule: 30 for `trinity_large`, 25 for
+    `deepseek_v2`, 5 for `minicpm_sala`; 0 without
     `gradient_checkpointing`). Both are set at trace time and read by no
     benchmark metric."""
     for name, value in zip(("attention_residuals_kept",

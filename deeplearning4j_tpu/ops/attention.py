@@ -90,12 +90,15 @@ _LSE_LANES = 128   # lane width for per-row statistics outputs (TPU tiling)
 # backward reads) and of its rows' one-lane log-sum-exp
 RESIDUAL_NAMES = ("attention_out", "attention_lse")
 # and of what a block names (`name_block_residual`): a sub-layer's output
-# that a norm reads, the stream between a pre-norm block's halves, and a
-# block selection. Each is made by a sub-layer's LAST product (or by a
-# choice with no backward) and read again by the recomputation only as a
-# value: kept, what made it is dead there
+# that a norm reads, the stream between a pre-norm block's halves, a
+# block selection, and an expert layer's schedule (the router's choice
+# [N, k], the pairs' order, places and sizes by expert and their weights
+# in row order: `parallel/moe.route`, `held_experts`). Each is made by a
+# sub-layer's LAST product (or by a choice with no backward) and read
+# again by the recomputation only as a value: kept, what made it is dead
+# there
 BLOCK_RESIDUAL_NAMES = ("sublayer_out", "residual_stream",
-                        "block_selection")
+                        "block_selection", "expert_schedule")
 # every name a checkpointed layer's policy keeps
 # (`models/multilayer._checkpointed`): the one list
 KEPT_NAMES = RESIDUAL_NAMES + BLOCK_RESIDUAL_NAMES
@@ -123,15 +126,16 @@ def block_residuals_named() -> int:
 
 
 def name_block_residual(x, name: str):
-    """`x` under `name`, one of `BLOCK_RESIDUAL_NAMES`, where a block
-    makes a value that its recomputation would make again only to read
-    it: bit for bit the same value, held from the forward pass to the
-    backward instead (one hidden-sized tensor, or a selection's bools).
+    """`x` (an array, or a tuple of them: each counts) under `name`, one
+    of `BLOCK_RESIDUAL_NAMES`, where a block makes a value that its
+    recomputation would make again only to read it: bit for bit the same
+    value, held from the forward pass to the backward instead (one
+    hidden-sized tensor, a selection's bools, a schedule's integers).
     A no-op without a policy that keeps the names; what follows has to
     be made from the named `x`."""
     if name not in BLOCK_RESIDUAL_NAMES:
         raise ValueError(f"{name!r} is not in {BLOCK_RESIDUAL_NAMES}")
-    _named.blocks += 1
+    _named.blocks += len(jax.tree_util.tree_leaves(x))
     return checkpoint_name(x, name)
 
 

@@ -46,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.nn.activations import Activation
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.ops.attention import name_block_residual
 from deeplearning4j_tpu.ops.kernel_defaults import kernels_run as _kernel_runs
 from deeplearning4j_tpu.parallel.mesh import AXIS_EXPERT
 
@@ -332,6 +333,38 @@ class MoEFeedForward(Layer):
 
 
 # ------------------------------------------- one device's share of experts
+def _chosen(s, experts):
+    """`take_along_axis(s, experts, -1)`, the scores [N, E] of each
+    token's `k` distinct experts [N, k], as a sum under a one-hot over
+    the experts' axis (a score or exact zeros), and its transpose the
+    same: on the TPU XLA's gather of N x k scalars reads 1.84 ms where
+    this reads 0.26 (8,192 x 22 of 512), and its scatter-add sorts all
+    the pairs to place them (chip runs, PR 51)."""
+    lanes = jnp.arange(s.shape[-1], dtype=experts.dtype)
+    return jnp.sum(jnp.where(experts[:, :, None] == lanes, s[:, None, :], 0),
+                   axis=-1)
+
+
+def _by(key, v):
+    return jax.lax.sort((key, v), num_keys=1, is_stable=False)[1]
+
+
+@jax.custom_vjp
+def _in_order(v, order, place):
+    """`v[order]` for a permutation `order` [rows] whose inverse is
+    `place`, and its transpose `g[place]`, each made by SORTING the
+    values with the other permutation for key: on the TPU a two-operand
+    sort of 180,224 distinct keys reads 0.11 ms where XLA's gather of as
+    many scalars reads 1.44 and its scatter-add 1.53 with a sort of its
+    own (chip runs, PR 51)."""
+    return _by(place, v)
+
+
+_in_order.defvjp(
+    lambda v, order, place: (_by(place, v), order),
+    lambda order, g: (_by(order, g), None, None))
+
+
 def route(x, router, bias, *, k: int, score: str, route_norm: bool,
           route_scale: float, n_group: int = 1, topk_group: int = 1):
     """Each token's `k` experts and their weights: scores over the
@@ -370,11 +403,15 @@ def route(x, router, bias, *, k: int, score: str, route_norm: bool,
             choice = jnp.where(jnp.repeat(keep, e // n_group, axis=1),
                                choice, 0.0)
     _, experts = jax.lax.top_k(choice, k)
-    weights = jnp.take_along_axis(s, experts, axis=-1)
+    # a choice with no backward: named, a checkpointed layer keeps it and
+    # its recomputation has no `top_k` (and no `group_select`)
+    experts = name_block_residual(experts.astype(jnp.int32),
+                                  "expert_schedule")
+    weights = _chosen(s, experts)
     if route_norm:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                              + 1e-20)
-    return experts.astype(jnp.int32), weights * route_scale
+    return experts, weights * route_scale
 
 
 @jax.custom_vjp
@@ -442,17 +479,68 @@ def _row_tiers(rows: int, share: float,
     alone, `most`: a row past the pairs held is no row
     to the grouped products and the gathers round them, which follow the
     pairs with no threshold to cross, and what passes over the tier's
-    rows (the sort, and the SwiGLU's elementwise work between the
-    products) costs the same every step (`held_experts`; the products
-    since PR 43, six seeds' rates 0.04% apart by their quartiles where
-    the two tiers' lay 0.73; the gathers since PR 44; the layers that
-    have a ladder elsewhere since PR 47: chip runs)."""
+    rows (the SwiGLU's elementwise work between the products; and, over
+    all pairs, `pair_schedule`'s one sort and count, made once a step:
+    a checkpointed layer keeps them) costs the same every step
+    (`held_experts`; the products since PR 43, six seeds' rates 0.04%
+    apart by their quartiles where the two tiers' lay 0.73; the gathers
+    since PR 44; the layers that have a ladder elsewhere since PR 47:
+    chip runs)."""
     most = min(rows, -(-int(rows if most is None else most) // 128) * 128)
     up = lambda n: min(most, -(-int(n) // 128) * 128)
     first = up(4 * share * rows)
     if 2 * first >= most:
         return (most,)
     return tuple(sorted({first, up(2 * first), up(4 * first), most}))
+
+
+_LANES = 128
+
+
+def pair_schedule(key, count: int):
+    """Which pair goes to which row: `key` [rows] int32 holds each pair's
+    expert among the `count` held, `count` for a pair of none of them.
+    Returns (`order`, `place`, `sizes`), all int32: `order` [rows] the
+    pairs by expert and, of one expert, by index (`argsort(key,
+    stable=True)` to the bit), `place` [rows] its inverse (the row of
+    pair p) and `sizes` [count] the pairs of each expert held.
+
+    `order` is ONE single-operand sort of the word `key << bits | p`
+    (`bits` what the index takes: the words are distinct and ties fall
+    in p's order). `place` is made by counting, with no second sort:
+    `place[p] = start[key[p]] + (pairs before p with p's key)`, the
+    pairs' axis on the lanes all through: the running count of each
+    bucket inside a tile of 128 pairs, the tiles' totals summed along
+    the tiles, and `start` the exclusive sum of the buckets' sizes, which
+    are the same totals summed. Where the word does not fit 32 bits, a
+    fact of the shapes (some 2**31 (bucket, pair) cells, which the count
+    would have to write out), the two-operand stable sort and its
+    inverse's."""
+    rows = key.shape[0]
+    key = key.astype(jnp.int32)
+    i32 = dict(dtype=jnp.int32)
+    bits = max(rows - 1, 1).bit_length()
+    if (count + 1) << bits > 2 ** 31:
+        order = jnp.argsort(key, stable=True)
+        return (order.astype(jnp.int32), jnp.argsort(order).astype(jnp.int32),
+                jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32))
+    word = (key << bits) | jnp.arange(rows, **i32)
+    order = jnp.sort(word, stable=False) & ((1 << bits) - 1)
+    # pairs past `rows` fill the last tile as pairs of no expert held:
+    # they lie after every real pair, so no real pair counts them
+    padded = -(-rows // _LANES) * _LANES
+    tiles = jnp.pad(key, (0, padded - rows), constant_values=count)
+    hot = (tiles.reshape(-1, _LANES)[None]
+           == jnp.arange(count + 1, **i32)[:, None, None])
+    within = jnp.cumsum(hot, axis=-1, **i32)    # [count + 1, tiles, 128]
+    in_tile = within[..., -1]
+    before_tile = jnp.cumsum(in_tile, axis=-1, **i32) - in_tile
+    sizes = jnp.sum(in_tile, axis=-1, **i32)
+    start = jnp.cumsum(sizes, **i32) - sizes
+    place = jnp.sum(jnp.where(
+        hot, within + (start[:, None] + before_tile)[..., None], 0),
+        axis=0, **i32) - 1
+    return order, place.reshape(padded)[:rows], sizes[:count]
 
 
 def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
@@ -470,12 +558,18 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
         local = experts.reshape(rows) - first
         is_held = (local >= 0) & (local < count)
         key = jnp.where(is_held, local, count)      # the rest sort last
-        order = jnp.argsort(key, stable=True)
-        place = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
-        token = (order // k).astype(jnp.int32)      # pair p is token p // k
-        pair_weight = jnp.where(is_held, weights.reshape(rows), 0.0)[order]
-        sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
-                        dtype=jnp.int32)
+        # integers with no backward, which a layer's recomputation would
+        # make again only to hand them to the tier: named, a checkpointed
+        # layer keeps them and sorts and counts once a step
+        order, place, sizes = name_block_residual(
+            pair_schedule(key, count), "expert_schedule")
+        token = order // k                          # pair p is token p // k
+        # the weights in row order have a backward, and are kept with the
+        # schedule: the recomputation would sort them again
+        pair_weight = name_block_residual(_in_order(
+            jnp.where(is_held, weights.reshape(rows), 0.0), order, place),
+            "expert_schedule")
+        place = place.reshape(n, k)
         n_held = jnp.sum(sizes)
     # On the TPU (on one device: under a mesh the operands may be sharded)
     # every layer has ONE tier, all that can fall here: its grouped
@@ -484,8 +578,10 @@ def held_experts(x, experts, weights, w1, w3, w2, *, first: int,
     # pairs held are left in NO group, so the kernel passes them by; and
     # its two gathers are `ops/row_gather`'s kernels, which move the rows
     # of the pairs held and no other, a row a DMA: the layer's time but
-    # for the sort and the elementwise work between the products follows
-    # the pairs, continuously, with no tier to cross. On any other backend
+    # for the schedule above (one sort and one count over all pairs, once
+    # a step: the recomputation reads the kept one) and the elementwise
+    # work between the products follows the pairs, continuously, with no
+    # tier to cross. On any other backend
     # and under a mesh a layer has `_row_tiers`' ladder, counts the rows
     # past the pairs held to the last expert held, gathers with XLA's
     # gather over the tier and runs XLA's `ragged_dot`: a tier then costs
@@ -728,8 +824,6 @@ class ExpertFeedForward(Layer):
             params.get("w3"), params["w2"], first=first,
             n_experts=self.n_experts)
         if self.latent:
-            from deeplearning4j_tpu.ops.attention import name_block_residual
-
             # `latent_up`'s own gradient reads the routed sum: named, a
             # checkpointed layer keeps it ([N, latent]) and its
             # recomputation leaves the routed path's products out

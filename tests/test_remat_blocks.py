@@ -1,9 +1,11 @@
 """A checkpointed block keeps what only a norm's backward reads.
 
-`SandwichTransformerBlock`, `PreNormBlock` and a sparse `MultiHeadAttention`
-name (`ops/attention.name_block_residual`) the values their recomputation
-would make again only to read: a sub-layer's output in front of a norm, the
-stream between a pre-norm block's halves, a block selection. A checkpointed
+`SandwichTransformerBlock`, `PreNormBlock`, a sparse `MultiHeadAttention`
+and an `ExpertFeedForward` name (`ops/attention.name_block_residual`) the
+values their recomputation would make again only to read: a sub-layer's
+output in front of a norm, the stream between a pre-norm block's halves, a
+block selection, an expert layer's choice and schedule
+(`tests/test_expert_schedule.py` has the layer alone). A checkpointed
 layer's policy (`models/multilayer._checkpointed`, `KEPT_NAMES`) keeps
 them, and the product that made each is dead in the recomputed forward.
 Counted in the gradient's jaxpr on the CPU at small widths: nothing here is
@@ -56,28 +58,32 @@ def _sandwich(**kw):
 
 
 # name -> (a block, what leaves a block's recomputation once the names are
-# kept: grouped products a tier's path, plain products, `top_k` calls)
+# kept: grouped products a tier's path, plain products, `top_k` calls,
+# sorts)
 BLOCKS = {
-    # the tier's forward (three grouped products); `Wo`, the shared w2
+    # the tier's forward (three grouped products); `Wo`, the shared w2;
+    # the router's choice; the schedule's sort and the weights' into row
+    # order
     "sandwich_experts": (lambda: _sandwich(
-        experts_held=(0, 2), moe_k=2, expert_width=16, **EXPERTS), 3, 2, 0),
+        experts_held=(0, 2), moe_k=2, expert_width=16, **EXPERTS),
+                         3, 2, 1, 2),
     # `Wo`, the SwiGLU's w2
-    "sandwich_dense": (lambda: _sandwich(ffn_width=64), 0, 2, 0),
-    # the mixer's `Wo`; the second half was dead already
+    "sandwich_dense": (lambda: _sandwich(ffn_width=64), 0, 2, 0, 0),
+    # the mixer's `Wo`; the second half's products were dead already, its
+    # choice and its sorts were not
     "prenorm_experts": (lambda: PreNormBlock(
         mixer=_mixer(), ffn=ExpertFeedForward(
-            width=16, held=(0, 2), k=2, **EXPERTS)), 0, 1, 0),
+            width=16, held=(0, 2), k=2, **EXPERTS)), 0, 1, 1, 2),
     "prenorm_dense": (lambda: PreNormBlock(mixer=_mixer(), ffn_width=64),
-                      0, 1, 0),
-    # the choice of blocks: its score product and its `top_k` (a router's
-    # own `top_k` has a backward to serve and stays)
+                      0, 1, 0, 0),
+    # the choice of blocks: its score product and its `top_k`
     "sparse_attention": (lambda: _mixer(n_out=WIDTH, sparse=SELECTION),
-                         0, 1, 1),
+                         0, 1, 1, 0),
 }
 # grouped products a tier's path and a block under the layer's checkpoint:
 # three forward, three the tier's own checkpoint remakes, six backward
 GROUPED = {"sandwich_experts": 12, "prenorm_experts": 12}
-PRIMITIVES = ("ragged_dot", "dot_general", "top_k")
+PRIMITIVES = ("ragged_dot", "dot_general", "top_k", "sort")
 
 
 def _net(kind, checkpointing):
@@ -163,8 +169,9 @@ def test_the_recomputed_forward_leaves_out_what_was_kept(monkeypatch, kind):
         _without_the_names(parent)
         was = np.array(_gradient_counts(net, *PRIMITIVES))
     np.testing.assert_array_equal(was - now, LAYERS * gone)
-    if kind == "sparse_attention":
-        assert now[2] == LAYERS     # forward, and never again
+    assert now[2] == LAYERS * gone[2]   # a choice: forward, and never again
+    # the schedule's sort and the weights' forward; their cotangent's
+    assert now[3] == LAYERS * 3 * kind.endswith("experts")
 
 
 @pytest.mark.parametrize("kind", sorted(BLOCKS))
@@ -192,15 +199,24 @@ def test_the_gauge_counts_the_values_named_under_a_checkpoint(
         kind, checkpointing):
     net = _net(kind, checkpointing)
     jax.make_jaxpr(jax.grad(lambda p: _loss(net)(p)[0]))(net.params_tree)
-    a_block = 2 if kind.startswith("sandwich") else 1
+    # an expert layer's choice, order, places, sizes and sorted weights
+    a_block = (2 if kind.startswith("sandwich") else 1) + 5 * kind.endswith(
+        "experts")
     assert _gauge(net) == checkpointing * LAYERS * a_block
     assert _gauge(net, "attention_residuals_kept") == 0
 
 
-# the benchmark's three token models at their CPU rehearsals' sizes, built
-# by the benchmark's own model files: two values a sandwich block; the
-# stream of each pre-norm block; three streams and one selection
-MODELS = {"trinity_tiny": 6, "deepseek_v2_tiny": 3, "minicpm_sala_tiny": 4}
+# the benchmark's token models at their CPU rehearsals' sizes, built by
+# the benchmark's own model files: two values a sandwich block and five
+# (choice, order, places, sizes, sorted weights) each of two expert
+# layers; the stream of each pre-norm block and five each of two expert
+# layers; three streams and one selection; the stream and the five of
+# each of three blocks; the five and the routed sum of each of two
+# LatentMoE layers (the prediction module's own checkpoint keeps its
+# layer's, outside the gauge)
+MODELS = {"trinity_tiny": 6 + 10, "deepseek_v2_tiny": 3 + 10,
+          "minicpm_sala_tiny": 4, "granite_4_0_h_small_tiny": 3 * 6,
+          "nemotron_3_super_tiny": 2 * 6}
 
 
 @pytest.mark.parametrize("config", sorted(MODELS))

@@ -692,6 +692,27 @@ def _embedding_backward_is_grouped(compiled, cfg):
     assert not [out for out in scatters if table in out], scatters
 
 
+def _expert_layers_sort_once(compiled, layers, pairs):
+    """Of the sorts of a compiled step, those over all `pairs` (token,
+    choice) pairs of a layer: one a layer makes the schedule, the packed
+    word's, a single operand (`parallel/moe.pair_schedule`; the
+    recomputation reads the kept schedule), and two a layer apply it,
+    each with a float payload: the weights into row order and their
+    cotangent back (`_in_order`, where XLA's gather and scatter-add
+    were). No two-integer sort is left (the parent's four `argsort`s a
+    layer) and none is a scatter-add's own (the chip's compiler sorts a
+    scatter's indices with the updates). Returns every sort's output
+    shape."""
+    sorts = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = (\([^=]*?\)|\S+) sort\(",
+                       compiled.as_text(), re.M)
+    over_pairs = [out for out in sorts if f"[{pairs}]" in out]
+    carried = [out for out in over_pairs if out.startswith("(")]
+    assert len(over_pairs) - len(carried) == layers, over_pairs
+    assert len(carried) == 2 * layers, carried
+    assert not [out for out in carried if out.count("s32[") != 1], carried
+    return sorts
+
+
 def _expert_layers_run_the_kernels(compiled, layers):
     """The `layers` expert layers of a compiled step have one tier each:
     twelve grouped products a layer (three forward, three in the tier's
@@ -733,6 +754,7 @@ def test_trinity_large_step_runs_the_kernels_over_one_tier(chip):
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 13 * 2 ** 30)
     _expert_layers_run_the_kernels(compiled, 4)
+    _expert_layers_sort_once(compiled, 4, 8192 * 4)
     _embedding_backward_is_grouped(compiled, cfg)
     passes = _tier_passes(compiled, rows=(32768,), width=3072)
     assert {p.split()[0] for p in passes} <= ELEMENTWISE, passes
@@ -758,6 +780,7 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "latent_attention" + kernel) == layers
     _expert_layers_run_the_kernels(compiled, 4)
+    _expert_layers_sort_once(compiled, 4, 8192 * 6)
     # no `scatter` whose result is [12800,5120]: 23.3 ms of a 511 ms step
     # on the chip (PR 47), the longest op of the cell
     _embedding_backward_is_grouped(compiled, cfg)
@@ -785,6 +808,13 @@ def test_deepseek_v2_step_fits_the_chip_with_nothing_t_by_t(chip):
 def test_granite_step_fits_the_chip_with_one_tied_embedding(chip):
     lowered, compiled, cfg = _cell_step(chip, "granite_4_0_h_small")
     _expert_layers_run_the_kernels(compiled, 10)
+    # 8,192 tokens x 10 choices; `top_k` of 10 of 72 is a whole sort of
+    # every token's scores on this chip and runs forward alone (the choice
+    # is kept), and the embedding's ids are sorted once: 41 sorts, 30 of
+    # them over the pairs, where the parent's step held 71 and 50
+    sorts = _expert_layers_sort_once(compiled, 10, 81920)
+    assert len(sorts) == 41 and sum(
+        out.startswith("(f32[8192,72]") for out in sorts) == 10
     _embedding_backward_is_grouped(compiled, cfg)
     assert not _tier_passes(compiled)
     # the grouped products' 3 kernels x 2 shapes and the row gathers' 3
@@ -870,6 +900,12 @@ def test_nemotron_step_runs_six_latent_layers_and_two_heads(chip):
                                       "pack_rows": 5 * layers}
     text = compiled.as_text()
     assert " conditional(" not in text
+    # 8,192 tokens x 22 choices; six `top_k` sorts of 512 scores a token,
+    # forward alone, and the two lookups' ids: 26 sorts, 18 of them over
+    # the pairs, where the parent's step held 50 and 36
+    sorts = _expert_layers_sort_once(compiled, layers, 8192 * 22)
+    assert len(sorts) == 26 and sum(
+        out.startswith("(f32[8192,512]") for out in sorts) == layers
     _embedding_backward_is_grouped(compiled, cfg)
     for kernel in ("_fwd", "_bwd_dq", "_bwd_dkdv"):
         assert _kernel_calls(compiled, "flash_attention" + kernel) == 2
