@@ -29,6 +29,7 @@ from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
 from deeplearning4j_tpu.optim.step import (
     as_features, build_step, make_train_step, stack_step_args,
+    with_counter_sums,
 )
 from deeplearning4j_tpu.nn.graph import (
     ComputationGraphConfiguration, GraphVertex, LayerVertex,
@@ -114,7 +115,7 @@ class ComputationGraph(SeqCtxJitCache, SeqCtxSolverCache):
                 resolve_output_type(name, v, in_types,
                                     len(self.conf.vertex_inputs[name]), known)
             self.params_tree = params
-            self.state_tree = states
+            self.state_tree = with_counter_sums(states)
             self._build_updaters()
             self.updater_state = {
                 n: u.init(params[n]) for n, u in self._vertex_updaters.items()

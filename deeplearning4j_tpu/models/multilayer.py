@@ -36,6 +36,7 @@ from deeplearning4j_tpu.optim.executor import LossTracker, TrainingExecutor
 from deeplearning4j_tpu.optim.recovery import build_plan, run_with_recovery
 from deeplearning4j_tpu.optim.step import (
     as_features, build_step, make_train_step, stack_step_args,
+    with_counter_sums,
 )
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.convolution import (
@@ -304,7 +305,7 @@ class MultiLayerNetwork(SeqCtxJitCache, SeqCtxSolverCache):
                         f"{layer.name} is tied to {source}, whose W is "
                         f"{None if w is None else w.shape}, not {want}")
             self.params_tree = params
-            self.state_tree = states
+            self.state_tree = with_counter_sums(states)
             self._build_updaters()
             self.updater_state = {
                 name: u.init(params[name])

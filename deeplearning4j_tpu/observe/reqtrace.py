@@ -5,7 +5,7 @@ Dapper-style trace trees built ON TOP of the sync-free span machinery in
 `TraceContext` is minted at the HTTP edge, rides through scheduler
 admission, **fans in** to shared batched dispatches (one dispatch span
 per participating trace, all listing the co-batched trace ids), and
-threads through decode-session steps and training dispatch windows.
+threads through decode-session steps.
 
 Contracts (PERF_NOTES):
 

@@ -31,8 +31,10 @@ each with JAX's `fun_name`, under whatever span is open on the thread
 (the first `fit.dispatch`, a `net.init`, a `data.put`); beside them the
 counter `xla_compiles_total{fetched=}` and the histogram `xla_compile_ms`.
 The watchdog's own probe of a first call is the span `compile.probe` with
-a child for each leg (`compile.probe.lower`, `.compile`, `.cost`,
-`.text`).
+a child for each leg: `compile.probe.lower`, `.compile`, `.cost` and, for
+a program compiled for more than one device, `.text` (the compiled
+module's text, walked for collectives; a program on one device holds none
+and records the empty inventory unread).
 """
 
 from __future__ import annotations
@@ -340,8 +342,8 @@ def _record_lowered_cost(fn, specs, owner_tag, owner_class, key) -> None:
     `compile.probe` with one child a leg: a second lowering of the step
     (a second whole trace), its compile (the comm ledger's; served from
     a cache where the dispatch's own compile filled one), XLA's cost
-    analysis of either, and the text of the compiled module with its
-    parse."""
+    analysis of either, and, where the program runs on more than one
+    device, the text of the compiled module with its parse."""
     with span("compile.probe", owner=owner_class):
         try:
             spec_args, spec_kw = specs
@@ -375,9 +377,32 @@ def _record_lowered_cost(fn, specs, owner_tag, owner_class, key) -> None:
         except Exception as e:
             note_cost_analysis_failure(
                 f"lowering cost analysis failed: {type(e).__name__}")
-        if compiled is not None:
+        if compiled is not None and _device_count(compiled) == 1:
+            # nothing to walk for: what the parse of a one-device module
+            # gives, without its text (nine tenths of the probe)
+            from deeplearning4j_tpu.observe.commsmon import (
+                summarize_collectives,
+            )
+            get_watchdog().record_collectives(
+                owner_tag, owner_class, key, summarize_collectives(()))
+        elif compiled is not None:
             with span("compile.probe.text"):
                 _record_compiled_comm(compiled, owner_tag, owner_class, key)
+
+
+def _device_count(compiled) -> int:
+    """The devices a compiled program runs on, from its own input and
+    output shardings (not the process's: a one-device program may run on
+    a host with four); 0 where they do not say."""
+    try:
+        import jax
+
+        return len(set().union(*(
+            s.device_set for s in jax.tree_util.tree_leaves(
+                (compiled.input_shardings, compiled.output_shardings)))))
+    # graft: allow(GL403): unknown reads as "walk the text", as before
+    except Exception:
+        return 0
 
 
 def _record_compiled_comm(compiled, owner_tag, owner_class, key) -> None:
@@ -418,13 +443,15 @@ class _CostProbe:
     itself. `Lowered.cost_analysis()` traces but does not compile, so
     the cost leg costs one extra trace. The comm-ledger leg
     (`DL4J_TPU_COMPILE_COMM`, default on) additionally AOT-compiles the
-    lowering to walk the post-GSPMD module for collectives, and prices
-    the program from that artifact where the lowering has no cost (the
-    TPU client). JAX serves that compile from its in-memory cache when
-    the lowering matches the dispatch's (one XLA compile, not two: chip
-    run, PR 21); it is never counted as a jit cache insertion and never
-    on a steady-state path; nothing either leg touches can force a
-    device sync."""
+    lowering and prices the program from that artifact where the lowering
+    has no cost (the TPU client); where the artifact's shardings span
+    more than one device it walks the post-GSPMD module's text for
+    collectives, and records the empty inventory of a one-device program
+    without reading the text. JAX serves that compile from its in-memory
+    cache when the lowering matches the dispatch's (one XLA compile, not
+    two: chip run, PR 21); it is never counted as a jit cache insertion
+    and never on a steady-state path; nothing either leg touches can
+    force a device sync."""
 
     __slots__ = ("fn", "_owner_tag", "_owner_class", "_key", "_done",
                  "_lock")

@@ -30,12 +30,17 @@ from __future__ import annotations
 
 from typing import Any, Callable, List, Optional
 
-from deeplearning4j_tpu.observe import get_registry, reqtrace, span
-from deeplearning4j_tpu.observe.trace import flush_span_log, get_span_store
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.observe import get_registry, span
+from deeplearning4j_tpu.observe.trace import flush_span_log
 from deeplearning4j_tpu.observe.commsmon import get_reshard_witness
 from deeplearning4j_tpu.observe.devicemon import maybe_start_monitor
 from deeplearning4j_tpu.observe.flight import get_flight
-from deeplearning4j_tpu.observe.watchdog import get_watchdog
+from deeplearning4j_tpu.optim.step import (
+    COUNTER_PREFIXES, COUNTER_STEPS, counters_of,
+)
 
 __all__ = ["LossTracker", "TrainingExecutor", "SKIP", "STOP"]
 
@@ -46,8 +51,6 @@ _END = object()     # the iterator is exhausted
 
 
 def _is_device_array(x) -> bool:
-    import jax
-
     return isinstance(x, jax.Array)
 
 
@@ -123,38 +126,88 @@ def batch_signature(ds):
             _arr_sig(ds.features_mask), _arr_sig(ds.labels_mask))
 
 
-def _publish_routing_counters(net) -> None:
-    """The last step's counters of every layer that keeps some in its
-    state (`parallel/moe.ExpertFeedForward`'s routing: the `moe_*`
-    scalars, `moe_tokens_held` of a layer that routes by groups among
-    them; a `MultiHeadAttention` with a block selection: the
-    `sparse_blocks_*` scalars; a `SelectiveStateSpace`: `ssm_chunk_carry`,
-    a share and so a float; an `ExitGatedOutputLayer`: `exit_entropy`
-    and `exit_mass`, a value a pass and so `exit_mass{layer=, pass=}`; a
-    `MultiTokenOutputLayer`: `main_loss` and `mtp_loss`, its two terms)
-    out of the net's layer state into the gauges `<counter>{layer=}`.
-    Called where the epoch has just synchronised with the device; a net
-    without such a layer pays a walk over its state's keys."""
-    counters = {}
-    for name, st in (getattr(net, "state_tree", None) or {}).items():
-        if isinstance(st, dict):
-            own = {k: v for k, v in st.items()
-                   if k.startswith(("moe_", "sparse_blocks_", "ssm_",
-                                    "exit_", "mtp_", "main_loss"))}
-            if own:
-                counters[name] = own
-    if counters:
-        import jax
+def _labelled(value, layer: str):
+    """(value, labels) of one counter of one layer: a scalar under
+    `layer=`, a vector (`exit_mass`: a value a pass) under `pass=` too."""
+    if value.ndim == 0:
+        return [(value.item(), {"layer": layer})]
+    return [(one, {"layer": layer, "pass": str(i)})
+            for i, one in enumerate(value.tolist(), start=1)]
 
-        # graft: allow-sync(the epoch has just synchronised; one read)
-        for name, values in jax.device_get(counters).items():
-            for key, value in values.items():
-                if value.ndim == 0:
-                    get_registry().gauge(key, layer=name).set(value.item())
+
+@jax.jit
+def _pack(leaves):
+    """A list of small arrays as one vector a dtype, on the device."""
+    by = {}
+    for leaf in leaves:
+        by.setdefault(leaf.dtype.name, []).append(leaf.ravel())
+    return {kind: jnp.concatenate(v) for kind, v in by.items()}
+
+
+def _read_packed(tree):
+    """`jax.device_get(tree)` of a tree of small leaves in one transfer a
+    dtype: `_pack` on the device (one small program, compiled at the
+    first epoch's end, the warm-up's), cut apart again here. A scalar a
+    transfer read 88 us each on the chip: 16.7 ms for
+    `granite_4_0_h_small_fit`'s 188 (chip run, PR 52)."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    # graft: allow-sync(the epoch has just synchronised; one read)
+    packed, at, out = jax.device_get(_pack(leaves)), {}, []
+    for leaf in leaves:
+        kind, start = leaf.dtype.name, at.get(leaf.dtype.name, 0)
+        at[kind] = start + leaf.size
+        out.append(packed[kind][start:at[kind]].reshape(leaf.shape))
+    return treedef.unflatten(out)
+
+
+def _publish_routing_counters(net) -> None:
+    """The counters of every layer that keeps some in its state
+    (`optim/step.COUNTER_PREFIXES`: an expert layer's routing, a
+    selecting attention's blocks, `ssm_chunk_carry`, `exit_entropy` and
+    `exit_mass`, `main_loss` and `mtp_loss`), read once where the epoch
+    has just synchronised (`_read_packed`), as the span `fit.counters`.
+    For a counter `c` of a layer: the gauge `c{layer=}`, the LAST step's
+    value; and from the sums the step keeps beside it
+    (`optim/step.with_counter_sums`) the registry counters
+    `c_total{layer=}` and `counter_steps_total{layer=}` and the gauge
+    `c_epoch_mean{layer=}`, the sum's growth since the last publication
+    over the steps': the mean over the epoch that just ended.
+    The sums at the last publication stay with the net (`_counters_seen`:
+    no second read), so a net restored from a checkpoint reads, in its
+    first epoch, the mean since `init()`. A net without such a layer pays
+    a walk over its state's keys."""
+    timed = span("fit.counters")
+    with timed:
+        counters = {}
+        for name, st in (getattr(net, "state_tree", None) or {}).items():
+            if counters_of(st):     # the counters, their sums, the steps
+                counters[name] = {
+                    k: v for k, v in st.items()
+                    if k == COUNTER_STEPS or k.startswith(COUNTER_PREFIXES)}
+        timed.attrs.update(layers=len(counters),
+                           values=sum(map(len, counters.values())))
+        if not counters:
+            return
+        reg = get_registry()
+        seen = vars(net).setdefault("_counters_seen", {})
+        for name, values in _read_packed(counters).items():
+            then = seen.get(name, {})
+            steps = max(0, int(values.get(COUNTER_STEPS, 0))
+                        - int(then.get(COUNTER_STEPS, 0)))
+            if steps:
+                reg.counter("counter_steps_total", layer=name).inc(steps)
+            for c in counters_of(values):
+                for one, labels in _labelled(values[c], name):
+                    reg.gauge(c, **labels).set(one)
+                if c + "_sum" not in values:     # a state born without sums
                     continue
-                for i, one in enumerate(value.tolist(), start=1):
-                    get_registry().gauge(key, layer=name,
-                                         **{"pass": str(i)}).set(one)
+                grown = values[c + "_sum"] - then.get(c + "_sum", 0.0)
+                for one, labels in _labelled(grown, name):
+                    reg.counter(c + "_total", **labels).inc(max(one, 0.0))
+                    if steps:
+                        reg.gauge(c + "_epoch_mean",
+                                  **labels).set(one / steps)
+            seen[name] = values
 
 
 class TrainingExecutor:
@@ -166,10 +219,11 @@ class TrainingExecutor:
     a step is timed once, by its span (`observe/trace.py`): `fit.etl`
     (the wait for the next batch; the prefetch iterator's `data.put`
     spans are its children), `fit.dispatch`, `fit.listeners`, and once an
-    epoch `fit.epoch_sync`. The `train_etl_ms` / `train_dispatch_ms`
-    histograms take their values from those spans' own clock reads; the
-    sampled `train.epoch` request trace reads the span store when an
-    epoch ends.
+    epoch `fit.epoch_sync` and, after it, `fit.counters` (the read and
+    the publication of the layers' counters and their sums over the
+    epoch's steps). The spans are `fit()`'s one recorder: the
+    `train_etl_ms` / `train_dispatch_ms` histograms take their values
+    from those spans' own clock reads.
 
     Hooks:
       step(ds) -> loss                one training step (device loss)
@@ -213,9 +267,6 @@ class TrainingExecutor:
         self.epoch_start = epoch_start
         self.epoch_end = epoch_end
         self.stopped = False
-        # per-epoch request trace (reqtrace): None when sampling is off
-        self._rt = None
-        self._rt_from = 0       # the span store's count at the epoch's start
         # commsmon reshard witness — None when DL4J_TPU_COMMSMON is off,
         # so the disabled hot loop pays one attribute read per dispatch
         self._reshard = get_reshard_witness()
@@ -250,23 +301,13 @@ class TrainingExecutor:
                     l.on_fit_start(net)
                 self.stopped = False
                 for _ in range(start_epoch, epochs):
-                    ep = net.epoch
-                    # one sampled trace per epoch, built from the epoch's
-                    # fit.dispatch spans when it ends
-                    self._rt = reqtrace.new_trace("train.epoch")
-                    self._rt_from = get_span_store().count
-                    with span("fit.epoch", epoch=ep):
+                    with span("fit.epoch", epoch=net.epoch):
                         self._run_epoch(iterable)
                     if self.stopped:
-                        self._finish_epoch_trace(ep, stopped=True)
                         break
-                    self._finish_epoch_trace(ep)
                 for l in listeners:
                     l.on_fit_end(net)
         except BaseException as e:
-            # close the epoch trace first so the flight dump's trace
-            # block carries the crashed epoch's dispatch windows
-            self._finish_epoch_trace(net.epoch, error=type(e).__name__)
             # the crash the flight recorder exists for: dump the ring
             # (recent spans, compiles, device memory) next to the error
             flight.dump("training_exception", exc=e)
@@ -334,47 +375,6 @@ class TrainingExecutor:
         _publish_routing_counters(net)
 
     # ---------------------------------------------------------- helpers
-    def _finish_epoch_trace(self, epoch: int, **attrs) -> None:
-        """Close the per-epoch request trace (None-safe; resets _rt): one
-        `train.dispatch` span keyed (epoch, step-window) per `fit.dispatch`
-        span the store holds of this epoch, under the root. A window's
-        duration is the host ENQUEUE time, never a device wait. When the
-        comm ledger has priced this owner's compiled programs, each
-        window also carries the owner-level collective totals (comm_ops /
-        comm_bytes): host-side metadata from the watchdog."""
-        rt, self._rt = self._rt, None
-        if rt is None:
-            return
-        store = get_span_store()
-        comm = self._comm_totals()
-        for ev in store.events(self._rt_from):
-            if ev["name"] != "fit.dispatch":
-                continue
-            a = ev["attrs"]
-            lo, hi = a["batch"], a["batch"] + a["steps"] - 1
-            extra = {} if comm is None else {
-                "comm_ops": comm["ops"], "comm_bytes": comm["wire_bytes"]}
-            reqtrace.record_span(
-                rt.trace_id, "train.dispatch", parent_id=rt.span_id,
-                ts=ev["ts"], dur_ms=ev["dur_ms"], epoch=epoch,
-                window=f"{epoch}:{lo}-{hi}", steps=a["steps"],
-                fused=a["fused"], **extra)
-        reqtrace.finish_root(rt, epoch=epoch, iteration=self.net.iteration,
-                             steps_per_dispatch=self.k, **attrs)
-
-    def _comm_totals(self) -> Optional[dict]:
-        """Owner-level compiled-collective totals for the net's active
-        jit cache, or None when nothing was priced (ledger disabled,
-        probe not fired yet, owner without a WatchedJitCache)."""
-        try:
-            tag = getattr(self.net._jit_cache, "owner_tag", None)
-            if tag is None:
-                return None
-            return get_watchdog().owner_comm_totals(tag)
-        # graft: allow(GL403): span decoration is best-effort by design
-        except Exception:
-            return None
-
     def _witness_batch(self, ds) -> None:
         """Reshard-witness seam (commsmon, GL802): before a dispatch,
         compare the batch's COMMITTED shardings against the mesh spine's

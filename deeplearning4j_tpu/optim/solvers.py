@@ -259,7 +259,10 @@ class Solver:
                 ns = self._refresh(res.x, features, labels, fmask, lmask,
                                    states)
                 states = {
-                    n: (ns[n] if n in stateful and n in ns else states[n])
+                    # merged: a counter layer's sums are the step's to
+                    # write and not in `ns`
+                    n: ({**states[n], **ns[n]}
+                        if n in stateful and n in ns else states[n])
                     for n in states
                 }
             model.state_tree = states

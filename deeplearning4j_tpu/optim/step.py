@@ -23,9 +23,61 @@ from deeplearning4j_tpu.observe import donatemon, span
 from deeplearning4j_tpu.utils.pytrees import tree_norm
 
 __all__ = ["make_train_step", "make_fused_step", "stack_step_args",
-           "jit_step", "build_step", "normalize_grads", "as_features"]
+           "jit_step", "build_step", "normalize_grads", "as_features",
+           "COUNTER_PREFIXES", "COUNTER_STEPS", "counters_of",
+           "with_counter_sums"]
 
 _tmap = jax.tree_util.tree_map
+
+# Which keys of a layer's state are a step's counters, by prefix
+# (`parallel/moe.COUNTERS` and `TOKENS_HELD`, `attention.SPARSE_COUNTERS`,
+# `ssm_chunk_carry`, `exit_entropy` / `exit_mass`, `main_loss` /
+# `mtp_loss`): the step sums them, the executor publishes them. Beside
+# each counter `c` the net's state carries `c_sum` (float32, cumulative
+# since `init()`), and one `counter_steps` (int32) a layer.
+COUNTER_PREFIXES = ("moe_", "sparse_blocks_", "ssm_", "exit_", "mtp_",
+                    "main_loss")
+COUNTER_STEPS = "counter_steps"
+
+
+def counters_of(state) -> list:
+    """The counters among the keys of one layer's state."""
+    if not isinstance(state, dict):
+        return []
+    return [k for k in state
+            if k.startswith(COUNTER_PREFIXES) and not k.endswith("_sum")]
+
+
+def with_counter_sums(states):
+    """A net's state tree as its layers' `init_params` gave it, with the
+    sums born at zero in every layer whose state holds a counter: the
+    step's state then has one structure in and out (donation, the K-step
+    scan's carry). A layer without a counter is handed back as it came."""
+    out = {}
+    for name, st in states.items():
+        own = counters_of(st)
+        if own:
+            st = {**st, COUNTER_STEPS: jnp.zeros((), jnp.int32),
+                  **{c + "_sum": jnp.zeros(jnp.shape(st[c]), jnp.float32)
+                     for c in own}}
+        out[name] = st
+    return out
+
+
+def _integrate(old, new):
+    """One layer's new state with its counters added to the sums the OLD
+    state carried. A layer that hands its incoming state back out
+    (`{**state, ...}`) brings the old sums along: they are overwritten,
+    never added to. A counter leaves in the dtype it came in (with x64 on
+    a layer's int32 counter comes out int64: the scan's carry and the
+    next step's cache key want one type). A state born without sums is
+    `new` itself."""
+    if not isinstance(old, dict) or COUNTER_STEPS not in old:
+        return new
+    own = {c: new[c].astype(old[c].dtype) for c in counters_of(new)}
+    return {**new, **own, COUNTER_STEPS: old[COUNTER_STEPS] + 1,
+            **{c + "_sum": old[c + "_sum"] + v.astype(jnp.float32)
+               for c, v in own.items()}}
 
 
 def normalize_grads(grads, mode: str, threshold: float):
@@ -68,7 +120,9 @@ def make_train_step(loss_fn, updaters, *, grad_norm, stateful,
     them. `updaters` maps
     each top-level key of `params` to its `Updater`, `grad_norm` is the
     configuration's `(mode, threshold)`, and `stateful` names the layers
-    whose new state persists (batch-norm statistics).
+    whose new state persists (batch-norm statistics; a step's counters,
+    which are summed here into the `<counter>_sum` leaves the net's
+    `init()` put beside them: `with_counter_sums`).
 
     Returns `(params, opt_state, states, loss)` and, only with
     `carry_names` (truncated BPTT), a fifth value: those layers' new states
@@ -91,7 +145,8 @@ def make_train_step(loss_fn, updaters, *, grad_norm, stateful,
                 new_params[name], new_opt[name] = u.update_with_params(
                     grads[name], opt_state[name], params[name], step)
         persist = {
-            n: (new_states[n] if n in stateful else states.get(n, {}))
+            n: (_integrate(states[n], new_states[n]) if n in stateful
+                else states.get(n, {}))
             for n in states
         }
         if carry_names is None:

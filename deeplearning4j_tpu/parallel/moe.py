@@ -803,7 +803,8 @@ class ExpertFeedForward(Layer):
             params.update(latent_down=winit(down, (d, inner), dtype),
                           latent_up=winit(up, (inner, d), dtype))
         names = COUNTERS + ((TOKENS_HELD,) if self.n_group > 1 else ())
-        return params, dict.fromkeys(names, jnp.zeros((), jnp.int32))
+        # a buffer each: the step donates its state
+        return params, {n: jnp.zeros((), jnp.int32) for n in names}
 
     def apply(self, params, x, *, state=None, train=False, rng=None,
               mask=None):

@@ -279,6 +279,66 @@ class TestCommLedgerEndToEnd:
         finally:
             set_watchdog(prev)
 
+    def _probed(self, wd, fn, *args):
+        """(the probe's legs, the owner's snapshot) of one first call of
+        `fn` through a watched cache."""
+        from deeplearning4j_tpu.observe import get_span_store
+        from deeplearning4j_tpu.observe.watchdog import WatchedJitCache
+
+        cache = WatchedJitCache(types.SimpleNamespace(),
+                                owner_class="ProbeOwner")
+        step = cache.setdefault("step", fn)
+        store = get_span_store()
+        n0 = store.count
+        jax.block_until_ready(step(*args))
+        events = store.events(n0)
+        probe = next(e for e in events if e["name"] == "compile.probe")
+        legs = [e["name"] for e in events
+                if e["parent_id"] == probe["span_id"]]
+        return legs, wd.snapshot()["per_owner"][cache.owner_tag]
+
+    def test_one_device_program_records_its_empty_inventory_unread(self):
+        """The text of a module compiled for one device holds no
+        collective: the probe records what the parse would have returned
+        and leaves the text leg out; the cost is still priced."""
+        prev, wd = self._fresh_watchdog()
+        try:
+            legs, snap = self._probed(
+                wd, jax.jit(lambda a, b: (a @ b).sum()),
+                np.ones((16, 64), np.float32), np.ones((64, 32), np.float32))
+            assert legs == ["compile.probe.lower", "compile.probe.compile",
+                            "compile.probe.cost"]
+            (row,) = snap["collectives"].values()
+            assert row == {"ops": 0, "payload_bytes": 0, "wire_bytes": 0,
+                           "degenerate_ops": 0, "by_kind": {}}
+            (cost,) = snap["costs"].values()
+            assert cost["flops"] >= 2 * 16 * 64 * 32
+        finally:
+            set_watchdog(prev)
+
+    def test_two_device_psum_keeps_the_text_leg_and_its_inventory(
+            self, devices8):
+        from jax.sharding import NamedSharding
+        from deeplearning4j_tpu.parallel import make_mesh
+
+        prev, wd = self._fresh_watchdog()
+        try:
+            mesh = make_mesh({"data": 2}, devices=devices8[:2])
+            summed = jax.jit(jax.shard_map(
+                lambda a: jax.lax.psum(a.sum(0), "data"), mesh=mesh,
+                in_specs=P("data", None), out_specs=P()))
+            x = jax.device_put(np.ones((4, 64), np.float32),
+                               NamedSharding(mesh, P("data", None)))
+            legs, snap = self._probed(wd, summed, x)
+            assert legs == ["compile.probe.lower", "compile.probe.compile",
+                            "compile.probe.cost", "compile.probe.text"]
+            (row,) = snap["collectives"].values()
+            assert row["ops"] == 1 and set(row["by_kind"]) == {"all-reduce"}
+            assert row["by_kind"]["all-reduce"]["max_group_size"] == 2
+            assert row["wire_bytes"] > 0
+        finally:
+            set_watchdog(prev)
+
     def test_decode_window_has_zero_collectives(self, devices8):
         """ROADMAP item 1's acceptance line, measured: a fused decode
         window on a single-replica model compiles to ZERO collectives

@@ -15,8 +15,6 @@ What these pin:
     exemplar id resolves in the trace store
   * FlightRecorder: dumps embed the last-K sampled traces and the dump
     dir keeps only the newest DL4J_TPU_FLIGHT_KEEP artifacts
-  * training: each epoch roots a trace whose children are the
-    (epoch, step-window)-keyed dispatch windows, fused and unfused
   * tools/trace_view.py renders every JSON shape that carries a tree
 """
 
@@ -414,73 +412,6 @@ class TestFlightTraces:
         for i in range(4):
             fr.dump(f"r{i}")
         assert len(glob.glob(str(tmp_path / "flight_*.json"))) == 4
-
-
-# ------------------------------------------------------ training windows
-class _StubNet:
-    def __init__(self):
-        self.epoch = 0
-        self.iteration = 0
-        self.listeners = ()
-
-        class _LT:
-            on_block = None
-
-            def update(self, loss):
-                pass
-
-            def materialize(self):
-                return 0.0
-
-            def peek(self):
-                return 0.0
-
-        self._loss_tracker = _LT()
-
-
-class _DS:
-    features = np.zeros((2, 2), dtype="float32")
-    labels = np.zeros((2, 1), dtype="float32")
-    features_mask = None
-    labels_mask = None
-
-
-class TestTrainingWindows:
-    def test_epoch_roots_and_dispatch_windows(self, sampled):
-        from deeplearning4j_tpu.optim.executor import TrainingExecutor
-        ex = TrainingExecutor(_StubNet(), step=lambda ds: 0.5)
-        ex.run([_DS(), _DS(), _DS()], 2)
-        assert len(sampled) == 2               # one trace per epoch
-        for i, tid in enumerate(sampled.ids()):
-            doc = sampled.tree(tid)
-            assert doc["depth"] == 2
-            root = doc["tree"][0]
-            assert root["name"] == "train.epoch"
-            assert root["attrs"]["epoch"] == i
-            windows = [c["attrs"] for c in root["children"]]
-            assert [w["window"] for w in windows] == \
-                [f"{i}:{j}-{j}" for j in range(3)]
-            assert all(not w["fused"] and w["steps"] == 1
-                       for w in windows)
-
-    def test_fused_windows_key_on_step_ranges(self, sampled):
-        from deeplearning4j_tpu.optim.executor import TrainingExecutor
-        ex = TrainingExecutor(
-            _StubNet(), step=lambda ds: 0.5,
-            fused_step=lambda batches: [0.5] * len(batches),
-            can_fuse=lambda ds: True, steps_per_dispatch=2)
-        ex.run([_DS(), _DS(), _DS(), _DS()], 1)
-        (tid,) = sampled.ids()
-        root = sampled.tree(tid)["tree"][0]
-        windows = [c["attrs"] for c in root["children"]]
-        assert [w["window"] for w in windows] == ["0:0-1", "0:2-3"]
-        assert all(w["fused"] and w["steps"] == 2 for w in windows)
-
-    def test_training_off_records_nothing(self, unsampled):
-        from deeplearning4j_tpu.optim.executor import TrainingExecutor
-        TrainingExecutor(_StubNet(), step=lambda ds: 0.5).run(
-            [_DS(), _DS()], 2)
-        assert unsampled.spans_recorded == 0
 
 
 # ------------------------------------------------------------ trace_view

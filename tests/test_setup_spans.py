@@ -27,8 +27,9 @@ from deeplearning4j_tpu.optim.updaters import Adam
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 XLA = ("xla.trace", "xla.lower", "xla.compile")
+# a one-device program's probe: the text leg is a sharded program's alone
 PROBE_LEGS = {"compile.probe.lower", "compile.probe.compile",
-              "compile.probe.cost", "compile.probe.text"}
+              "compile.probe.cost"}
 
 
 def _net(n_in=8):
@@ -135,7 +136,7 @@ def test_the_probe_has_a_child_a_leg_and_its_events_stay_under_it(two_fits):
     probe = next(e for e in first if e["name"] == "compile.probe")
     assert probe["attrs"] == {"owner": "MultiLayerNetwork"}
     legs = [e for e in first if e["parent_id"] == probe["span_id"]]
-    assert set(_names(legs)) == PROBE_LEGS and len(legs) == 4
+    assert set(_names(legs)) == PROBE_LEGS and len(legs) == 3
     # what the probe's own lowering and compile fire lies under a leg,
     # never beside the dispatch's own spans
     for e in _under(first, probe):
@@ -147,7 +148,8 @@ def test_the_probe_has_a_child_a_leg_and_its_events_stay_under_it(two_fits):
 
 def test_later_dispatches_and_the_second_fit_leave_none(two_fits):
     first, second = two_fits
-    new = {"step.build", *XLA, "compile.probe", *PROBE_LEGS, "net.init"}
+    new = {"step.build", *XLA, "compile.probe", *PROBE_LEGS,
+           "compile.probe.text", "net.init"}
     for d in _dispatches(first)[1:]:
         assert not _under(first, d)
     assert not new & set(_names(second))
@@ -155,7 +157,7 @@ def test_later_dispatches_and_the_second_fit_leave_none(two_fits):
     # a steady step is what it was: etl (> data.put), dispatch, listeners
     assert set(_names(second)) == {
         "fit", "fit.epoch", "fit.etl", "data.put", "fit.dispatch",
-        "fit.listeners", "fit.epoch_sync"}
+        "fit.listeners", "fit.epoch_sync", "fit.counters"}
 
 
 @jax.jit

@@ -221,8 +221,9 @@ class TestFitSpans:
         for i, epoch in enumerate(epochs):
             names = [e["name"] for e in kids[epoch["span_id"]]]
             step = ["fit.etl", "fit.dispatch", "fit.listeners"]
-            assert names == step * 4 + ["fit.etl", "fit.epoch_sync"]
-            last_etl = kids[epoch["span_id"]][-2]
+            assert names == step * 4 + ["fit.etl", "fit.epoch_sync",
+                                        "fit.counters"]
+            last_etl = kids[epoch["span_id"]][-3]
             assert last_etl["attrs"] == {"exhausted": True}
             dispatches = [e["attrs"] for e in kids[epoch["span_id"]]
                           if e["name"] == "fit.dispatch"]
@@ -237,7 +238,7 @@ class TestFitSpans:
                    for p in puts)
         assert {e["name"] for e in events} == {
             "fit", "fit.epoch", "fit.etl", "data.put", "fit.dispatch",
-            "fit.listeners", "fit.epoch_sync"}
+            "fit.listeners", "fit.epoch_sync", "fit.counters"}
         # one host sync an epoch, as before the spans
         assert net._loss_tracker.host_syncs - syncs == 2
         # the children account for the fit span: what is left is the loop's
